@@ -23,7 +23,7 @@ import json
 import os
 import sys
 
-os.environ.setdefault("JAX_PLATFORMS", "cpu")
+os.environ["JAX_PLATFORMS"] = "cpu"   # the kernels run interpreted: CPU only
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np  # noqa: E402
@@ -75,7 +75,7 @@ def check_attribution(out: dict, reps: int):
 
 def _optimized(net):
     from deeplearning4j_tpu.ops import pallas_kernels as pk
-    pk.install_platform_overrides()     # interpret mode off-TPU
+    pk.install_platform_overrides(interpret=True)
     net.setComputeLayout("NHWC")
     net.setEpilogueFusion(True)
     return net

@@ -4,7 +4,7 @@ Reference parity: the pre-init validation DL4J scatters through
 ``MultiLayerConfiguration.Builder.build`` / ``ComputationGraphConfiguration
 .validate`` (nIn/nOut checks, duplicate-name checks, dangling-vertex
 checks) — unified here into one structured diagnostic stream the way
-TVM's relay type-checker and TensorFlow's pre-session graph validation
+TVM's type-checker and TensorFlow's pre-session graph validation
 report: every finding is a ``Diagnostic(code, severity, location,
 message, fix_hint)`` instead of whichever exception happens to fire
 first deep inside a trace.

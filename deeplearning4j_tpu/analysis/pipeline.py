@@ -1,11 +1,11 @@
 """Input-pipeline feasibility lint (DL4J-W108): can this host feed this
 chip?
 
-BENCH_r05 measured the failure mode this catches: a ResNet-50 input
-pipeline running at 5% of device throughput because single-core decode
-(~744 img/s) and a pathological 6.2 MB/s H2D link bounded the feed far
-below the ~2184 img/s the chip could train. Both bounds are *statically
-decidable* from the declared pipeline configuration — worker count,
+The failure mode this catches: a ResNet-50 input pipeline running at a
+few percent of device throughput because single-core decode and a slow
+H2D link bound the feed far below what the chip could train. Both bounds
+are *statically decidable* from the declared pipeline configuration —
+worker count,
 per-core decode cost, batch geometry, transfer dtype — before any
 worker spawns or XLA compile burns:
 
@@ -31,7 +31,8 @@ from deeplearning4j_tpu.analysis.distribution import (_approx_flops,
                                                       _propagate_types,
                                                       dtype_bytes)
 
-#: public v5e per-chip peak (BASELINE.md), the default for the estimate
+#: public v5e per-chip bf16 peak (Google Cloud documentation, "TPU v5e"),
+#: the default for the estimate
 PEAK_TFLOPS = 197.0
 
 

@@ -3,7 +3,7 @@
 Reference parity: ``datavec-data-audio`` (``WavFileRecordReader``,
 ``AudioRecordReader`` with windowed FFT features — SURVEY.md §2.2
 "DataVec image/audio"). Decode AND feature extraction are HOST-side
-numpy, like the image pipeline: ETL feeding a tunneled/remote device must
+numpy, like the image pipeline: ETL feeding an accelerator must
 not issue per-file eager device ops (a 40-filter eager loop per file per
 epoch costs thousands of dispatch round-trips).
 """

@@ -424,23 +424,29 @@ def hlo_collective_bytes(hlo_text: str) -> Dict[str, int]:
 
 def compiled_train_step_hlo(model, features, labels, steps: int = 1) -> str:
     """Compiled HLO text of the model's train step for this batch
-    signature under the attached sharding plan (MultiLayerNetwork;
-    ``steps>1`` lowers the megastep over ``[K, B, ...]`` stacks).
-    Nothing executes — the program is lowered and compiled only, which
-    is exactly what ``benchmarks/probe_collectives.py`` and the
-    ``--virtual-mesh`` scaling bench need for collective accounting."""
+    signature under the attached sharding plan (either network class,
+    one input and one output; ``steps>1`` lowers the megastep over
+    ``[K, B, ...]`` stacks). Nothing executes — the program is lowered
+    and compiled only, which is exactly what
+    ``benchmarks/probe_collectives.py``, the ``--virtual-mesh`` scaling
+    bench and ``chip_smoke.py`` need to read collectives and kernels off
+    the program the fit loop dispatches."""
     model._ensure_opt_state()
     plan = getattr(model, "_sharding_plan", None)
-    x = np.asarray(features)
-    y = np.asarray(labels)
+    x, y = features, labels
     if plan is not None:
         plan.ensure_placed(model)
         x = plan.place(x, steps > 1)
         y = plan.place(y, steps > 1)
-    step, dummy = model._step_for((False, False), steps)
     clock = jnp.asarray(model._iteration, jnp.int32)
     args = [model._params, model._states, model._opt_state, clock]
     if model._dynamic_scaling():
         args.append(model._ensure_scale_state())
-    args += [x, y, dummy, dummy]
+    graph_inputs = getattr(model.conf, "graph_inputs", None)
+    if graph_inputs is not None:            # ComputationGraph
+        step, dummy = model._step_for(False, steps, 1)
+        args += [{graph_inputs[0]: x}, [y], dummy]
+    else:
+        step, dummy = model._step_for((False, False), steps)
+        args += [x, y, dummy, dummy]
     return step._jit.lower(*args).compile().as_text()

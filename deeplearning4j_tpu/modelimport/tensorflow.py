@@ -812,8 +812,7 @@ def _m_space_to_batch(ctx, node, ins):
 # scatter, image, segment, 3-D conv/pool, linalg, einsum, special functions.
 
 _SIMPLE_OPS_R4 = {
-    "Erfc": jax.lax.erfc if hasattr(jax.lax, "erfc")
-    else (lambda x: 1.0 - jax.lax.erf(x)),
+    "Erfc": jax.lax.erfc,
     "Expm1": jnp.expm1,
     "Lgamma": jax.scipy.special.gammaln,
     "Digamma": jax.scipy.special.digamma,

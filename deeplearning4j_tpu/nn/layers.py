@@ -1568,7 +1568,7 @@ def layer_from_config(d: Dict) -> Layer:
 
 
 # ------------------------------------------------------------- dtype policy
-# BASELINE.md's open perf item ("bf16 plumbing" in the nn/ stack): master
+# "bf16 plumbing" in the nn/ stack: master
 # parameters stay fp32 (updater math, BatchNorm statistics, losses), while
 # matmul/conv/pool layers compute in bfloat16 — the MXU-native dtype
 # (SURVEY.md §6). Enabled per-network via NeuralNetConfiguration.dataType

@@ -22,7 +22,6 @@ the kernel unchanged.
 from __future__ import annotations
 
 import functools
-from typing import Optional
 
 import jax
 import jax.numpy as jnp
@@ -31,12 +30,22 @@ import numpy as np
 from jax.experimental import pallas as pl
 from jax.experimental.pallas import tpu as pltpu
 
-#: the compiler-params class was renamed TPUCompilerParams -> CompilerParams
-#: across jax releases; resolve whichever this pin ships
-_COMPILER_PARAMS = getattr(pltpu, "CompilerParams", None) \
-    or getattr(pltpu, "TPUCompilerParams", None)
-
 _ROW_BLOCK = 256
+#: bytes the pipelined row tiles of one call may hold in VMEM: the input
+#: and output tile, each double-buffered. Half the v5e's 16 MiB scoped
+#: limit; the rest is the kernel's own f32 temporaries.
+_TILE_BYTES = 8 * 2 ** 20
+
+
+def _row_block(n: int, d: int, itemsize: int) -> int:
+    """Rows per grid step for an [n, d] array: the largest divisor of
+    ``n`` reached by halving from ``_ROW_BLOCK`` whose tiles fit
+    ``_TILE_BYTES`` — the block shrinks with the width, so every width
+    the gates admit compiles."""
+    block = min(_ROW_BLOCK, n)
+    while n % block or (block > 8 and 4 * block * d * itemsize > _TILE_BYTES):
+        block //= 2
+    return max(block, 8)
 
 
 def supported(x, axis: int = -1) -> bool:
@@ -65,10 +74,7 @@ def _layer_norm_kernel(x_ref, g_ref, b_ref, o_ref, *, eps: float):
 
 def _layer_norm_fwd_pallas(x, gain, bias, eps: float, interpret: bool):
     n, d = x.shape
-    block = min(_ROW_BLOCK, n)
-    while n % block:
-        block //= 2
-    block = max(block, 8)
+    block = _row_block(n, d, x.dtype.itemsize)
     return pl.pallas_call(
         functools.partial(_layer_norm_kernel, eps=eps),
         out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
@@ -91,15 +97,17 @@ def make_layer_norm_override(interpret: bool = False):
     back to the generic op)."""
     from deeplearning4j_tpu.ops import normalization as norm_ops
 
-    @jax.custom_vjp
+    # eps is static: the kernel closes over it, and a pallas_call may
+    # capture no traced value
+    @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
     def _ln(x, gain, bias, eps):
         return _layer_norm_fwd_pallas(x, gain, bias, eps, interpret)
 
     def _fwd(x, gain, bias, eps):
-        return _ln(x, gain, bias, eps), (x, gain, eps)
+        return _ln(x, gain, bias, eps), (x, gain)
 
-    def _bwd(res, ct):
-        x, gain, eps = res
+    def _bwd(eps, res, ct):
+        x, gain = res
         x32 = x.astype(jnp.float32)
         g32 = ct.astype(jnp.float32)
         m = jnp.mean(x32, axis=1, keepdims=True)
@@ -112,7 +120,7 @@ def make_layer_norm_override(interpret: bool = False):
         dgain = jnp.sum(g32 * xhat, axis=0)
         dbias = jnp.sum(g32, axis=0)
         return (dx.astype(x.dtype), dgain.astype(gain.dtype),
-                dbias.astype(gain.dtype), None)
+                dbias.astype(gain.dtype))
 
     _ln.defvjp(_fwd, _bwd)
 
@@ -121,7 +129,8 @@ def make_layer_norm_override(interpret: bool = False):
                 not supported(jnp.asarray(x),
                               axis if isinstance(axis, int) else -2):
             return norm_ops.layer_norm(x, gain, bias, axis=axis, eps=eps)
-        return _ln(jnp.asarray(x), jnp.asarray(gain), jnp.asarray(bias), eps)
+        return _ln(jnp.asarray(x), jnp.asarray(gain), jnp.asarray(bias),
+                   float(eps))
 
     return layer_norm
 
@@ -137,10 +146,7 @@ def _softmax_kernel(x_ref, o_ref):
 
 def _softmax_fwd_pallas(x, interpret: bool):
     n, d = x.shape
-    block = min(_ROW_BLOCK, n)
-    while n % block:
-        block //= 2
-    block = max(block, 8)
+    block = _row_block(n, d, x.dtype.itemsize)
     return pl.pallas_call(
         _softmax_kernel,
         out_shape=jax.ShapeDtypeStruct((n, d), x.dtype),
@@ -185,21 +191,24 @@ def _scale_shift_act_kernel(x_ref, sc_ref, sh_ref, o_ref, *, alpha: float):
     """One [block, C] tile of the bias+BN+activation epilogue: a single
     VMEM read, per-channel FMA in the input dtype (the batch_norm
     contract: scale/shift were computed fp32 and cast once), select,
-    single write. alpha=0 is relu; alpha>0 the leaky slope."""
+    single write. alpha=0 is relu; alpha>0 the leaky slope. The leaky
+    compare and select run in f32 — the v5e vector unit compares no
+    bf16 — on values already rounded to the input dtype, so the store's
+    cast is exact and the result is the generic op's."""
     y = x_ref[:] * sc_ref[:] + sh_ref[:]
     if alpha == 0.0:
         o_ref[:] = jnp.maximum(y, 0)
     else:
-        o_ref[:] = jnp.where(y >= 0, y, alpha * y)
+        y32 = y.astype(jnp.float32)
+        o_ref[:] = jnp.where(y32 >= 0, y32,
+                             (alpha * y).astype(jnp.float32)
+                             ).astype(o_ref.dtype)
 
 
 def _scale_shift_act_pallas(x2d, scale, shift, alpha: float,
                             interpret: bool):
     n, d = x2d.shape
-    block = min(_ROW_BLOCK, n)
-    while n % block:
-        block //= 2
-    block = max(block, 8)
+    block = _row_block(n, d, x2d.dtype.itemsize)
     return pl.pallas_call(
         functools.partial(_scale_shift_act_kernel, alpha=alpha),
         out_shape=jax.ShapeDtypeStruct((n, d), x2d.dtype),
@@ -365,7 +374,7 @@ def _flash_fwd_pallas(q, k, v, *, causal: bool, bq: int, bk: int,
             pltpu.VMEM((bq, 128), jnp.float32),    # running max
             pltpu.VMEM((bq, 128), jnp.float32),    # running sum
         ],
-        compiler_params=_COMPILER_PARAMS(
+        compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
     )(q, k, v)
@@ -482,13 +491,13 @@ def make_flash_attention_override(interpret: bool = False,
 
 # ------------------------------------------------------------ installation
 
-def install_platform_overrides(interpret: Optional[bool] = None):
+def install_platform_overrides(interpret: bool = False):
     """Register the Pallas kernels over their generic ops (ref: the
-    PlatformHelper loader). ``interpret=None`` auto-selects: compiled on
-    TPU, interpreter elsewhere (tests)."""
+    PlatformHelper loader). The kernels are compiled for the TPU; only a
+    caller that says ``interpret=True`` (the CPU tests) gets the Pallas
+    interpreter — the backend found at run time never decides, so a chip
+    run cannot land in the interpreter and a CPU run claims no kernel."""
     from deeplearning4j_tpu.ops import registry
-    if interpret is None:
-        interpret = jax.default_backend() != "tpu"
     registry.register_platform_override(
         "layer_norm", make_layer_norm_override(interpret))
     registry.register_platform_override(

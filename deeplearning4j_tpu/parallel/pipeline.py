@@ -27,24 +27,7 @@ from typing import Any, Callable, Optional
 
 import jax
 import jax.numpy as jnp
-try:                                    # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map as _shard_map
-except ImportError:                     # newer jax: promoted to top level
-    from jax import shard_map as _shard_map
-
-import inspect as _inspect
-
-_SM_PARAMS = frozenset(_inspect.signature(_shard_map).parameters)
-
-
-def shard_map(*args, **kwargs):
-    """shard_map with the replication-check kwarg spelled for whichever
-    jax is installed (``check_rep`` pre-0.6, ``check_vma`` after)."""
-    if "check_vma" in kwargs and "check_vma" not in _SM_PARAMS:
-        kwargs["check_rep"] = kwargs.pop("check_vma")
-    elif "check_rep" in kwargs and "check_rep" not in _SM_PARAMS:
-        kwargs["check_vma"] = kwargs.pop("check_rep")
-    return _shard_map(*args, **kwargs)
+from jax import shard_map
 from jax.sharding import NamedSharding, PartitionSpec as P
 
 from deeplearning4j_tpu.parallel.mesh import DeviceMesh

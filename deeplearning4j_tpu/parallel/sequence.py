@@ -18,12 +18,8 @@ from functools import partial
 
 import jax
 import jax.numpy as jnp
-from jax import lax
+from jax import lax, shard_map
 from jax.sharding import Mesh, PartitionSpec as P
-try:                                    # jax <= 0.4.x
-    from jax.experimental.shard_map import shard_map
-except ImportError:                     # newer jax: promoted to top level
-    from jax import shard_map
 
 
 def _block_attend(q, kb, vb, q_off, k_off, is_causal, m, l, acc, scale):
@@ -72,7 +68,7 @@ def _ring_attention_local(q, k, v, *, axis_name: str, is_causal: bool,
     acc0 = jnp.zeros((B, H, Tl, D), jnp.float32)
     # mark the accumulators as device-varying so the loop carry type matches
     # (jax's shard_map varying-manual-axes tracking)
-    if varying_axes and hasattr(lax, "pcast"):
+    if varying_axes:
         m0, l0, acc0 = jax.tree_util.tree_map(
             lambda x: lax.pcast(x, tuple(varying_axes), to="varying"),
             (m0, l0, acc0))

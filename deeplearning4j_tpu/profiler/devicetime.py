@@ -53,7 +53,8 @@ from deeplearning4j_tpu import profiler as _prof
 SCOPE_PREFIX = "dl4j_L"
 _SCOPE_RE = re.compile(r"dl4j_L(\d+)_([A-Za-z0-9_.\-]+)")
 
-#: public v5e per-chip peak (BASELINE.md) — callers override for other parts
+#: public v5e per-chip bf16 peak (Google Cloud documentation, "TPU v5e")
+#: — callers override for other parts
 DEFAULT_PEAK_FLOPS = 197e12
 
 

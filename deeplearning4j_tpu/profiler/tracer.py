@@ -28,8 +28,8 @@ Design:
 
 The tracer is orthogonal to ``jax.profiler`` (ProfilingListener): jax
 traces XLA device internals; this traces the *framework* — dispatch,
-transfers, cache behaviour, data-wait vs compute — on hosts where the XLA
-profiler plugin is unavailable (e.g. relayed TPU backends).
+transfers, cache behaviour, data-wait vs compute — and needs no XLA
+profiler plugin.
 """
 
 from __future__ import annotations

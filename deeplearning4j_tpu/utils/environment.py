@@ -51,11 +51,6 @@ class Environment:
     profiling: bool = field(default_factory=lambda: _env("DL4J_TPU_PROFILING", False, bool))
     profile_dir: str = field(default_factory=lambda: _env("DL4J_TPU_PROFILE_DIR", "/tmp/dl4j_tpu_profile", str))
 
-    # -- compile cache --
-    compile_cache_dir: str = field(
-        default_factory=lambda: _env("DL4J_TPU_COMPILE_CACHE", "", str)
-    )
-
     # -- data pipeline --
     prefetch_buffer: int = field(default_factory=lambda: _env("DL4J_TPU_PREFETCH", 2, int))
     loader_threads: int = field(default_factory=lambda: _env("DL4J_TPU_LOADER_THREADS", 4, int))
@@ -94,10 +89,32 @@ KNOBS = {
     "matmul_precision": "MXU compute precision: bfloat16|tensorfloat32|float32",
     "profiling": "Enable per-op profiling (ref: OpProfiler)",
     "profile_dir": "Directory for Chrome-trace profiles (ref: ProfilingListener)",
-    "compile_cache_dir": "Persistent XLA compile cache directory",
     "prefetch_buffer": "Async iterator prefetch depth (ref: AsyncDataSetIterator)",
     "loader_threads": "Host data-loading threads (ref: libnd4j Threads, data only)",
 }
+
+
+#: where JAX's persistent compilation cache lives when nothing outside
+#: placed it: fixed by this file's own location, because the path is part
+#: of the cache's key — a directory that moves never hits
+DEFAULT_JAX_CACHE_DIR = os.path.join(
+    os.path.dirname(os.path.dirname(os.path.dirname(
+        os.path.abspath(__file__)))), ".jax_cache")
+
+
+def place_jax_compile_cache() -> str:
+    """Say where JAX's persistent compilation cache lives, and return the
+    directory. ``JAX_COMPILATION_CACHE_DIR`` set: JAX reads it itself and
+    nothing is changed. Unset: ``<checkout>/.jax_cache``. Called by the
+    entry points (``chip_smoke.py``, ``bench.py``) before their first
+    compile, never at package import — the test suite must not fill the
+    checkout. (``nn/compilecache.py`` is a different, private store.)"""
+    placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    if placed:
+        return placed
+    import jax
+    jax.config.update("jax_compilation_cache_dir", DEFAULT_JAX_CACHE_DIR)
+    return DEFAULT_JAX_CACHE_DIR
 
 
 class NumericsPanicError(ArithmeticError):

@@ -1,0 +1,119 @@
+"""Ahead-of-time compiles of the Pallas overrides for a described TPU v5e.
+
+The CPU suite runs the kernels in the Pallas interpreter, which accepts
+what the chip's compiler refuses (a traced value captured by the kernel,
+a bf16 compare, a tile over the scoped-VMEM limit). These cases hand the
+real kernel, ``interpret=False``, at the shapes the chip smoke runs, to
+the TPU compiler installed here — a compile, not a run.
+
+The topology is described inside a fixture, in the test's own process,
+and in this one file: only one process may load the TPU library (see the
+on-chip-measurement guide, section 2).
+"""
+
+import os
+
+import jax
+import jax.numpy as jnp
+import pytest
+from jax.sharding import SingleDeviceSharding
+
+from deeplearning4j_tpu.ops import pallas_kernels as pk
+
+
+@pytest.fixture(scope="module")
+def one_chip():
+    from jax.experimental import topologies
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    os.environ.setdefault("TPU_LOG_DIR", "disabled")
+    try:
+        topo = topologies.get_topology_desc(platform="tpu",
+                                            topology_name="v5e:2x2")
+    except Exception as e:  # noqa: BLE001 — whatever keeps libtpu from loading
+        pytest.skip(f"no v5e:2x2 topology can be described here: {e}")
+    # an executable compiled for a described chip can be written to JAX's
+    # persistent cache but not read back without one
+    was_on = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield SingleDeviceSharding(topo.devices[0])
+    jax.config.update("jax_enable_compilation_cache", was_on)
+    cc.reset_cache()
+
+
+# each builder: (function, [(shape, dtype) of each argument], whether the
+# kernel's own gate admits the shape — a refused shape would compile the
+# generic lowering and prove nothing)
+def _layer_norm(shape, dtype):
+    ln = pk.make_layer_norm_override(interpret=False)
+    d = shape[-1]
+    return (lambda x, g, b: ln(x, g, b, eps=1e-5),
+            [(shape, dtype), ((d,), jnp.float32), ((d,), jnp.float32)],
+            pk.supported(jax.ShapeDtypeStruct(shape, dtype)))
+
+
+def _softmax(shape, dtype):
+    return (pk.make_softmax_override(interpret=False), [(shape, dtype)],
+            pk.supported(jax.ShapeDtypeStruct(shape, dtype)))
+
+
+def _epilogue(alpha):
+    def build(shape, dtype):
+        ssa = pk.make_scale_shift_act_override(interpret=False)
+        c, axis = shape[-1], len(shape) - 1
+        return (lambda x, sc, sh: ssa(x, sc, sh, alpha=alpha, axis=axis),
+                [(shape, dtype), ((c,), dtype), ((c,), dtype)],
+                pk.epilogue_supported(jax.ShapeDtypeStruct(shape, dtype),
+                                      axis))
+    return build
+
+
+def _flash(shape, dtype):
+    q = jax.ShapeDtypeStruct(shape, dtype)
+    return (pk.make_flash_attention_override(interpret=False),
+            [(shape, dtype)] * 3, pk.flash_supported(q, q, 256, 256))
+
+
+def _flash_grad(shape, dtype):
+    fa, args, admitted = _flash(shape, dtype)
+    return (jax.grad(lambda q, k, v: jnp.sum(fa(q, k, v).astype(jnp.float32)),
+                     argnums=(0, 1, 2)), args, admitted)
+
+
+_BUILDERS = {"layer_norm": _layer_norm, "softmax": _softmax,
+             "scale_shift_relu": _epilogue(0.0),
+             "scale_shift_leaky": _epilogue(0.01),
+             "flash_fwd": _flash, "flash_grad": _flash_grad}
+
+# kernel, shape, dtype — the chip smoke's kernel-phase shapes first, then
+# every shape the chip's compiler refused before PR 22
+_CASES = [
+    ("layer_norm", (4096, 768), "bfloat16"),
+    ("layer_norm", (4096, 768), "float32"),
+    ("layer_norm", (8, 768), "bfloat16"),
+    ("softmax", (4096, 1024), "float32"),
+    ("softmax", (4096, 1024), "bfloat16"),
+    ("scale_shift_relu", (256, 14, 14, 1024), "bfloat16"),
+    ("scale_shift_leaky", (32, 26, 26, 512), "bfloat16"),
+    ("scale_shift_leaky", (32, 13, 13, 1024), "bfloat16"),
+    ("scale_shift_leaky", (32, 104, 104, 128), "bfloat16"),
+    ("scale_shift_leaky", (32, 26, 26, 512), "float32"),
+    ("flash_fwd", (32, 128, 12, 64), "bfloat16"),
+    ("flash_grad", (32, 128, 12, 64), "bfloat16"),
+    # the widest the gates admit, in f32: the row block shrinks to fit the
+    # 16 MiB scoped VMEM limit (256 rows were 16.03 MiB)
+    ("scale_shift_relu", (4096, 4096), "float32"),
+    ("layer_norm", (4096, 4096), "float32"),
+    ("softmax", (4096, 4096), "float32"),
+]
+
+
+@pytest.mark.parametrize(
+    "kernel,shape,dtype", _CASES,
+    ids=[f"{k}-{'x'.join(map(str, s))}-{d}" for k, s, d in _CASES])
+def test_kernel_compiles_for_v5e(one_chip, kernel, shape, dtype):
+    fn, args, admitted = _BUILDERS[kernel](shape, jnp.dtype(dtype))
+    assert admitted
+    specs = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in args]
+    compiled = jax.jit(fn).lower(*specs).compile()
+    assert "tpu_custom_call" in compiled.as_text()

@@ -81,7 +81,7 @@ class TestMLP:
     def test_gradient_check_whole_net(self):
         """fp64 finite differences through the whole network
         (ref: org.deeplearning4j.gradientcheck.GradientCheckTests)."""
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             conf = (NeuralNetConfiguration.Builder().seed(7)
                     .updater(updaters.Sgd(0.1)).dataType("float64")
                     .list()

@@ -268,7 +268,7 @@ class TestLosses:
     def test_loss_gradcheck(self):
         """Finite-difference check through the loss in fp64, like the
         reference's GradCheckUtil (SURVEY §4 centerpiece)."""
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             rng = np.random.RandomState(18)
             logits = jnp.asarray(rng.randn(3, 4))
             labels = jnp.asarray(np.eye(4)[rng.randint(0, 4, 3)])

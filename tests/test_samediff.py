@@ -64,7 +64,7 @@ class TestGraphBasics:
 class TestGradients:
     def test_gradcheck_mlp(self):
         """Finite-difference through a small graph in fp64 (SURVEY §4)."""
-        with jax.experimental.enable_x64():
+        with jax.enable_x64(True):
             sd = SameDiff.create()
             rng = np.random.RandomState(1)
             x_data = rng.randn(4, 3)
