@@ -48,6 +48,8 @@ from typing import List, Optional, Sequence, Tuple
 import jax
 import jax.numpy as jnp
 
+from deeplearning4j_tpu.profiler.stepprogram import AUGMENT_SCOPE
+
 
 class DeviceAugmentation:
     """A chain of fixed-shape augmentation ops applied inside the jitted
@@ -330,4 +332,5 @@ def maybe_augment(augment: Optional[DeviceAugmentation], x, t):
     mixed inputs augments its image inputs and passes the rest through."""
     if augment is None or getattr(x, "ndim", 0) != 4:
         return x
-    return augment.apply(x, augment.step_key(t))
+    with jax.named_scope(AUGMENT_SCOPE):
+        return augment.apply(x, augment.step_key(t))

@@ -35,9 +35,11 @@ from deeplearning4j_tpu.nn.multilayer import (_dynamic_scale_next,
                                               _maybe_attach_env_profiler,
                                               _predict_batches,
                                               _process_and_apply_grads,
-                                              _select_update)
+                                              _select_update,
+                                              _unscale_grads)
 from deeplearning4j_tpu.profiler import devicetime as _devicetime
 from deeplearning4j_tpu.profiler import sanitizer as _sanitizer
+from deeplearning4j_tpu.profiler import stepprogram as _stepprogram
 from deeplearning4j_tpu.train import stepping as _stepping
 
 _MASK_AWARE = (L.LSTM, L.SimpleRnn, L.Bidirectional, L.LastTimeStep,
@@ -500,20 +502,22 @@ class ComputationGraph:
                 fmt[node.name] = fmt[fused_act[node.name]]
                 new_states[node.name] = states[node.name]
                 continue
-            scope = _devicetime.scope_name(ti, node.name)
-            if node.kind == "layer":
-                x = read(node.inputs[0], node.name)
-                cur_nhwc = fmt[node.inputs[0]]
-                if node.name in self.conf.preprocessors:
-                    if cur_nhwc:
-                        x, cur_nhwc = L.to_nchw(x), False
-                    x = self.conf.preprocessors[node.name](x)
-                x, cur_nhwc = L.layout_step(node.obj, x, cur_nhwc, nhwc)
-                p = params[node.name]
-                if cdt is not None:
-                    p, x = L.policy_cast(node.obj, p, x, cdt)
-                key, sub = jax.random.split(key)
-                with jax.named_scope(scope):
+            # every op of a node, the casts and layout steps around its
+            # apply included, carries the node's scope: the step-program
+            # map (profiler.stepprogram) reads layer and phase off it
+            with jax.named_scope(_devicetime.scope_name(ti, node.name)):
+                if node.kind == "layer":
+                    x = read(node.inputs[0], node.name)
+                    cur_nhwc = fmt[node.inputs[0]]
+                    if node.name in self.conf.preprocessors:
+                        if cur_nhwc:
+                            x, cur_nhwc = L.to_nchw(x), False
+                        x = self.conf.preprocessors[node.name](x)
+                    x, cur_nhwc = L.layout_step(node.obj, x, cur_nhwc, nhwc)
+                    p = params[node.name]
+                    if cdt is not None:
+                        p, x = L.policy_cast(node.obj, p, x, cdt)
+                    key, sub = jax.random.split(key)
                     if node.name in plan:          # BN anchoring a fusion
                         act_name, conv_name, alpha = plan[node.name]
                         out, ns = L.fused_bn_act(
@@ -532,30 +536,30 @@ class ComputationGraph:
                     else:
                         out, ns = node.obj.apply(p, states[node.name],
                                                  x, train, sub)
-                new_states[node.name] = ns
-                fmt[node.name] = cur_nhwc and getattr(out, "ndim", 0) == 4
-            else:
-                xs = [read(i) for i in node.inputs]
-                in_fmts = [fmt[i] for i in node.inputs]
-                transparent = isinstance(node.obj, (ElementWiseVertex,
-                                                    ScaleVertex, ShiftVertex))
-                if transparent and any(in_fmts) and all(in_fmts):
-                    out_nhwc = True                # elementwise: keep NHWC
+                    new_states[node.name] = ns
+                    fmt[node.name] = cur_nhwc and getattr(out, "ndim", 0) == 4
                 else:
-                    xs = [L.to_nchw(a) if f else a
-                          for a, f in zip(xs, in_fmts)]
-                    out_nhwc = False
-                if cdt is not None and len(xs) > 1:
-                    # merge/elementwise vertices: align mixed fp32/bf16 inputs
-                    # (e.g. a BN branch meeting a conv branch)
-                    if any(getattr(a, "dtype", None) == jnp.bfloat16
-                           for a in xs):
-                        xs = [a.astype(jnp.bfloat16)
-                              if getattr(a, "dtype", None) == jnp.float32 else a
-                              for a in xs]
-                with jax.named_scope(scope):
+                    xs = [read(i) for i in node.inputs]
+                    in_fmts = [fmt[i] for i in node.inputs]
+                    transparent = isinstance(
+                        node.obj, (ElementWiseVertex, ScaleVertex,
+                                   ShiftVertex))
+                    if transparent and any(in_fmts) and all(in_fmts):
+                        out_nhwc = True            # elementwise: keep NHWC
+                    else:
+                        xs = [L.to_nchw(a) if f else a
+                              for a, f in zip(xs, in_fmts)]
+                        out_nhwc = False
+                    if cdt is not None and len(xs) > 1:
+                        # merge/elementwise vertices: align mixed fp32/bf16
+                        # inputs (e.g. a BN branch meeting a conv branch)
+                        if any(getattr(a, "dtype", None) == jnp.bfloat16
+                               for a in xs):
+                            xs = [a.astype(jnp.bfloat16)
+                                  if getattr(a, "dtype", None) == jnp.float32
+                                  else a for a in xs]
                     out = node.obj.apply(*xs)
-                fmt[node.name] = out_nhwc and getattr(out, "ndim", 0) == 4
+                    fmt[node.name] = out_nhwc and getattr(out, "ndim", 0) == 4
             env[node.name] = out
         return [L.to_nchw(read(o)) if fmt.get(o) else read(o)
                 for o in self.conf.graph_outputs], new_states
@@ -673,29 +677,30 @@ class ComputationGraph:
     def _loss_and_reg(self, params, states, ins, labels: List, train, key,
                       fmask, lmasks: Optional[List]):
         outs, new_states = self._forward(params, states, ins, train, key, fmask)
-        out_layers = self._output_layers()
-        loss = 0.0
-        for i, (ol, out) in enumerate(zip(out_layers, outs)):
-            lm = lmasks[i] if lmasks is not None else None
-            loss = loss + ol.compute_loss(labels[i], out, mask=lm)
-        reg = 0.0
-        for node in self.conf.topo:
-            if node.kind != "layer":
-                continue
-            layer = node.obj
-            l1 = layer.l1 or 0.0
-            l2 = layer.l2 or 0.0
-            p = params.get(node.name) or {}
-            if l1 == 0.0 and l2 == 0.0:
-                continue
-            for pname, w in p.items():
-                if not pname.startswith(("W", "RW")):
+        with jax.named_scope(_stepprogram.LOSS_SCOPE):
+            out_layers = self._output_layers()
+            loss = 0.0
+            for i, (ol, out) in enumerate(zip(out_layers, outs)):
+                lm = lmasks[i] if lmasks is not None else None
+                loss = loss + ol.compute_loss(labels[i], out, mask=lm)
+            reg = 0.0
+            for node in self.conf.topo:
+                if node.kind != "layer":
                     continue
-                if l2:
-                    reg = reg + 0.5 * l2 * jnp.sum(jnp.square(w))
-                if l1:
-                    reg = reg + l1 * jnp.sum(jnp.abs(w))
-        return loss + reg, new_states
+                layer = node.obj
+                l1 = layer.l1 or 0.0
+                l2 = layer.l2 or 0.0
+                p = params.get(node.name) or {}
+                if l1 == 0.0 and l2 == 0.0:
+                    continue
+                for pname, w in p.items():
+                    if not pname.startswith(("W", "RW")):
+                        continue
+                    if l2:
+                        reg = reg + 0.5 * l2 * jnp.sum(jnp.square(w))
+                    if l1:
+                        reg = reg + l1 * jnp.sum(jnp.abs(w))
+            return loss + reg, new_states
 
     # ------------------------------------------------------------------- fit
     def _make_train_step(self, with_lmasks: bool, steps: int = 1):
@@ -744,7 +749,7 @@ class ComputationGraph:
             if loss_scale:
                 inv = 1.0 / loss_scale
                 loss = loss * inv           # listeners/score see true loss
-                grads = jax.tree_util.tree_map(lambda g: g * inv, grads)
+                grads = _unscale_grads(grads, inv)
             new_params, new_opt = _process_and_apply_grads(
                 base, updater, params, grads, opt_state, t.astype(jnp.float32))
             new_params = _stepping.constrain_tree(new_params, psh)
@@ -794,7 +799,7 @@ class ComputationGraph:
                 jax.value_and_grad(loss_fn, has_aux=True)(params)
             inv = 1.0 / scale
             loss = loss * inv           # listeners/score see true loss
-            grads = jax.tree_util.tree_map(lambda g: g * inv, grads)
+            grads = _unscale_grads(grads, inv)
             ok = _grads_all_finite(grads)
             new_params, new_opt = _process_and_apply_grads(
                 base, updater, params, grads, opt_state,
@@ -1102,7 +1107,7 @@ class ComputationGraph:
         from deeplearning4j_tpu.train.resilience import fit_scope
         with fit_scope(session, self, epochs) as n_epochs:
             for _ in range(n_epochs):
-                with _prof.trace_span("train:epoch", epoch=self._epoch):
+                with _stepping.epoch_span(self):
                     # data-wait vs compute split (see MultiLayerNetwork.fit)
                     if steps_per_dispatch > 1:
                         # plan-derived prefetcher placement (see
@@ -1112,7 +1117,8 @@ class ComputationGraph:
                             prefetch,
                             placement=_stepping.batch_placement(self))
                     else:
-                        for ds in _prof.iter_with_data_wait(epoch_stream()):
+                        for ds in _prof.iter_with_data_wait(epoch_stream(),
+                                                          self):
                             self._fit_one(ds)
                 self._epoch += 1
                 for lst in self._listeners:
@@ -1125,6 +1131,8 @@ class ComputationGraph:
     def _fit_one(self, ds):
         if self._sharding_plan is not None:
             self._sharding_plan.ensure_placed(self)  # GSPMD placement guard
+        spans = _stepping.step_spans(self)
+        spans.phase(_stepping.FIT_STAGE)
         stage = lambda a: _stepping.stage_batch(self, a)
         if isinstance(ds, MultiDataSet):
             ins = {name: stage(a)
@@ -1136,6 +1144,7 @@ class ComputationGraph:
             ins = {self.conf.graph_inputs[0]: stage(ds.features)}
             labels = [stage(ds.labels)]
             lmasks = [stage(ds.labels_mask)] if ds.labels_mask is not None else None
+        spans.phase(_stepping.FIT_PREPARE)
         # recompile-churn seam (see MultiLayerNetwork._fit_one)
         _churn.get_churn_detector().record(
             "ComputationGraph.fit",
@@ -1152,6 +1161,7 @@ class ComputationGraph:
         # provenance sanitizer — see MultiLayerNetwork._fit_one
         tok = _sanitizer.snapshot(self, "graph", ins=ins, labels=labels,
                                   lmasks=lmasks)
+        spans.phase(_stepping.FIT_LISTENERS, "start")
         for lst in self._listeners:
             if hasattr(lst, "onIterationStart"):
                 # 1-based, matching iterationDone: hook pair refers to the
@@ -1163,19 +1173,20 @@ class ComputationGraph:
             _stepping.STEPS_PER_DISPATCH.set(1)
             _stepping.TRAIN_ITERATIONS.inc()
         dyn = self._dynamic_scaling()
-        with _prof.timed_region(
-                "train:step", "dl4j_train_step_seconds",
-                "Compiled train-step dispatch time per iteration",
-                iteration=self._iteration + 1):
-            args = [self._params, self._states, self._opt_state,
-                    self._ensure_clock()]
-            if dyn:     # dynamic loss scale: an extra donated carry
-                args.append(self._ensure_scale_state())
-            out = step(*args, ins, labels,
-                       lmasks if lmasks is not None else dummy)
+        # the host's time to ENQUEUE the step: see MultiLayerNetwork._fit_one
+        spans.phase(_stepping.FIT_DISPATCH)
+        args = [self._params, self._states, self._opt_state,
+                self._ensure_clock()]
+        if dyn:     # dynamic loss scale: an extra donated carry
+            args.append(self._ensure_scale_state())
+        args += [ins, labels, lmasks if lmasks is not None else dummy]
+        spans.note(step, args)
+        out = step(*args)
+        spans.phase(_stepping.FIT_COMMIT)
         with _stepping.dispatch_commit(self, gen) as ok:
             if not ok:      # elastic recovery rolled this step back while
-                return      # the dispatch was hung: discard, no bookkeeping
+                spans.done()    # the dispatch was hung: discard, no
+                return          # bookkeeping
             if dyn:
                 (self._params, self._states, self._opt_state, self._t_dev,
                  self._scale_state, loss) = out
@@ -1189,9 +1200,11 @@ class ComputationGraph:
                          context=f"loss at iteration {self._iteration}")
         self._last_batch_size = int(next(iter(ins.values())).shape[0])
         self._iteration += 1
+        spans.phase(_stepping.FIT_LISTENERS, "done")
         for lst in self._listeners:
             if hasattr(lst, "iterationDone"):
                 lst.iterationDone(self, self._iteration, self._epoch)
+        spans.done()
         if res is not None:
             res.after_step()
 
@@ -1204,6 +1217,8 @@ class ComputationGraph:
         if self._sharding_plan is not None:
             self._sharding_plan.ensure_placed(self)  # see _fit_one
         k = mb.steps
+        spans = _stepping.step_spans(self, k)
+        spans.phase(_stepping.FIT_STAGE)
         stage = lambda a: _stepping.stage_batch(self, a, mega=True)
         if mb.multi:
             ins = {name: stage(a)
@@ -1216,6 +1231,7 @@ class ComputationGraph:
             labels = [stage(mb.labels)]
             lmasks = [stage(mb.labels_mask)] \
                 if mb.labels_mask is not None else None
+        spans.phase(_stepping.FIT_PREPARE)
         _churn.get_churn_detector().record(
             "ComputationGraph.megastep",
             _churn.array_fingerprint(
@@ -1231,18 +1247,18 @@ class ComputationGraph:
         if _prof.instrumentation_active():
             _stepping.STEPS_PER_DISPATCH.set(k)
         dyn = self._dynamic_scaling()
-        with _prof.timed_region(
-                "train:megastep", "dl4j_train_step_seconds",
-                "Compiled train-step dispatch time per iteration",
-                iteration=self._iteration + 1, steps=k):
-            args = [self._params, self._states, self._opt_state,
-                    self._ensure_clock()]
-            if dyn:     # dynamic loss scale: an extra scanned carry
-                args.append(self._ensure_scale_state())
-            out = step(*args, ins, labels,
-                       lmasks if lmasks is not None else dummy)
+        spans.phase(_stepping.FIT_DISPATCH)
+        args = [self._params, self._states, self._opt_state,
+                self._ensure_clock()]
+        if dyn:     # dynamic loss scale: an extra scanned carry
+            args.append(self._ensure_scale_state())
+        args += [ins, labels, lmasks if lmasks is not None else dummy]
+        spans.note(step, args)
+        out = step(*args)
+        spans.phase(_stepping.FIT_COMMIT)
         with _stepping.dispatch_commit(self, gen) as ok:
             if not ok:
+                spans.done()
                 return      # abandoned dispatch: see dispatch_commit
             if dyn:
                 (self._params, self._states, self._opt_state, self._t_dev,
@@ -1252,7 +1268,7 @@ class ComputationGraph:
                     losses = out
         _stepping.record_megastep(self, losses, k,
                                   int(next(iter(ins.values())).shape[1]),
-                                  san_token=tok)
+                                  san_token=tok, spans=spans)
 
     # ------------------------------------------------------------- utilities
     def score(self, ds=None) -> float:

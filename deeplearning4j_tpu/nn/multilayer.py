@@ -24,6 +24,7 @@ from deeplearning4j_tpu import profiler as _prof
 from deeplearning4j_tpu.analysis import churn as _churn
 from deeplearning4j_tpu.profiler import devicetime as _devicetime
 from deeplearning4j_tpu.profiler import sanitizer as _sanitizer
+from deeplearning4j_tpu.profiler import stepprogram as _stepprogram
 from deeplearning4j_tpu.data.dataset import (AsyncDataSetIterator, DataSet,
                                              DataSetIterator,
                                              IterableDataSetIterator)
@@ -126,38 +127,47 @@ def _process_and_apply_grads(base, updater, params, grads, opt_state, t):
     matrices (leaf names W/RW), matching the loss-side L1/L2 gating.
     Used by BOTH the regular and the TBPTT compiled steps (advisor r2:
     tBPTT previously skipped clipping + AdamW decay)."""
-    if base.grad_norm == "clip_value":
-        grads = upd.clip_by_value(grads, base.grad_norm_threshold)
-    elif base.grad_norm == "clip_l2":
-        grads = upd.clip_by_norm(grads, base.grad_norm_threshold)
-    elif base.grad_norm == "clip_global":
-        grads = upd.clip_by_global_norm(grads, base.grad_norm_threshold)
-    elif base.grad_norm == "renorm":
-        grads = upd.renormalize_l2(grads)
-    lr = updater.lr_at(t)
-    path_leaves, treedef = jax.tree_util.tree_flatten_with_path(params)
-    g_leaves = treedef.flatten_up_to(grads)
-    s_leaves = treedef.flatten_up_to(opt_state)
-    new_p, new_s = [], []
-    for (path, pv), gv, sv in zip(path_leaves, g_leaves, s_leaves):
-        u, s2 = updater.apply(gv, sv, lr, t)
-        leaf_name = str(getattr(path[-1], "key", path[-1]))
-        if (isinstance(updater, upd.AdamW) and updater.weight_decay
-                and leaf_name.startswith(("W", "RW"))):
-            u = u + updater.weight_decay_update(pv, lr)
-        new_p.append(pv - u)
-        new_s.append(s2)
-    return (jax.tree_util.tree_unflatten(treedef, new_p),
-            jax.tree_util.tree_unflatten(treedef, new_s))
+    with jax.named_scope(_stepprogram.UPDATER_SCOPE):
+        if base.grad_norm == "clip_value":
+            grads = upd.clip_by_value(grads, base.grad_norm_threshold)
+        elif base.grad_norm == "clip_l2":
+            grads = upd.clip_by_norm(grads, base.grad_norm_threshold)
+        elif base.grad_norm == "clip_global":
+            grads = upd.clip_by_global_norm(grads, base.grad_norm_threshold)
+        elif base.grad_norm == "renorm":
+            grads = upd.renormalize_l2(grads)
+        lr = updater.lr_at(t)
+        path_leaves, treedef = jax.tree_util.tree_flatten_with_path(params)
+        g_leaves = treedef.flatten_up_to(grads)
+        s_leaves = treedef.flatten_up_to(opt_state)
+        new_p, new_s = [], []
+        for (path, pv), gv, sv in zip(path_leaves, g_leaves, s_leaves):
+            u, s2 = updater.apply(gv, sv, lr, t)
+            leaf_name = str(getattr(path[-1], "key", path[-1]))
+            if (isinstance(updater, upd.AdamW) and updater.weight_decay
+                    and leaf_name.startswith(("W", "RW"))):
+                u = u + updater.weight_decay_update(pv, lr)
+            new_p.append(pv - u)
+            new_s.append(s2)
+        return (jax.tree_util.tree_unflatten(treedef, new_p),
+                jax.tree_util.tree_unflatten(treedef, new_s))
 
 
 def _grads_all_finite(grads):
     """Scalar bool: no gradient leaf overflowed/NaN'd — the dynamic
     loss-scaling overflow detector (shared by both network classes)."""
-    ok = jnp.asarray(True)
-    for g in jax.tree_util.tree_leaves(grads):
-        ok = jnp.logical_and(ok, jnp.all(jnp.isfinite(g)))
-    return ok
+    with jax.named_scope(_stepprogram.UPDATER_SCOPE):
+        ok = jnp.asarray(True)
+        for g in jax.tree_util.tree_leaves(grads):
+            ok = jnp.logical_and(ok, jnp.all(jnp.isfinite(g)))
+        return ok
+
+
+def _unscale_grads(grads, inv):
+    """The loss scale divided back out of the gradients, under the
+    updater's scope: the first thing the update does with them."""
+    with jax.named_scope(_stepprogram.UPDATER_SCOPE):
+        return jax.tree_util.tree_map(lambda g: g * inv, grads)
 
 
 def _dynamic_scale_next(pol, scale_state, ok):
@@ -188,8 +198,9 @@ def _select_update(ok, new, old):
     """Per-leaf ``jnp.where(ok, new, old)`` over matching pytrees — how
     an overflowed dynamic-scaling step drops its update without a
     host round trip."""
-    return jax.tree_util.tree_map(lambda n, o: jnp.where(ok, n, o),
-                                  new, old)
+    with jax.named_scope(_stepprogram.UPDATER_SCOPE):
+        return jax.tree_util.tree_map(lambda n, o: jnp.where(ok, n, o),
+                                      new, old)
 
 
 class MultiLayerNetwork:
@@ -286,24 +297,28 @@ class MultiLayerNetwork:
         i = 0
         while i < len(self.layers):
             layer = self.layers[i]
-            if i in self.conf.preprocessors:
-                if cur_nhwc:
-                    x, cur_nhwc = L.to_nchw(x), False
-                x = self.conf.preprocessors[i](x)
-            x, cur_nhwc = L.layout_step(layer, x, cur_nhwc, nhwc)
             fuse = plan.get(i)
-            scope = _devicetime.scope_name(
-                i, getattr(layer, "name", None) or type(layer).__name__)
-            if fuse is not None:
-                n_used, conv_leads, alpha = fuse
-                # one RNG split per consumed layer keeps the key stream
-                # identical to the unfused forward (downstream dropout
-                # draws the same bits — the parity pins rely on it)
-                subs = []
-                for _ in range(n_used):
-                    key, sub = jax.random.split(key)
-                    subs.append(sub)
-                with jax.named_scope(scope):
+            # every op of a layer, the preprocessor, layout step and casts
+            # before its apply included, carries the layer's scope: the
+            # step-program map (profiler.stepprogram) reads layer and
+            # phase off it
+            with jax.named_scope(_devicetime.scope_name(
+                    i, getattr(layer, "name", None)
+                    or type(layer).__name__)):
+                if i in self.conf.preprocessors:
+                    if cur_nhwc:
+                        x, cur_nhwc = L.to_nchw(x), False
+                    x = self.conf.preprocessors[i](x)
+                x, cur_nhwc = L.layout_step(layer, x, cur_nhwc, nhwc)
+                if fuse is not None:
+                    n_used, conv_leads, alpha = fuse
+                    # one RNG split per consumed layer keeps the key stream
+                    # identical to the unfused forward (downstream dropout
+                    # draws the same bits — the parity pins rely on it)
+                    subs = []
+                    for _ in range(n_used):
+                        key, sub = jax.random.split(key)
+                        subs.append(sub)
                     bn_idx = i
                     bias = None
                     if conv_leads:
@@ -320,15 +335,14 @@ class MultiLayerNetwork:
                         pbn, x = L.policy_cast(bn, pbn, x, cdt)
                     x, new_states[bn_idx] = L.fused_bn_act(
                         bn, pbn, states[bn_idx], x, train, alpha, bias=bias)
-                for j in range(bn_idx + 1, i + n_used):
-                    new_states[j] = states[j]   # the folded activation
-                i += n_used
-                continue
-            p = params[i]
-            if cdt is not None:
-                p, x = L.policy_cast(layer, p, x, cdt)
-            key, sub = jax.random.split(key)
-            with jax.named_scope(scope):
+                    for j in range(bn_idx + 1, i + n_used):
+                        new_states[j] = states[j]   # the folded activation
+                    i += n_used
+                    continue
+                p = params[i]
+                if cdt is not None:
+                    p, x = L.policy_cast(layer, p, x, cdt)
+                key, sub = jax.random.split(key)
                 if isinstance(layer, _MASK_AWARE):
                     x, ns = layer.apply(p, states[i], x, train, sub,
                                         mask=fmask)
@@ -432,21 +446,22 @@ class MultiLayerNetwork:
         out_layer = self.layers[-1]
         if not isinstance(out_layer, L.BaseOutputLayer):
             raise ValueError("last layer must be an output/loss layer for fit()")
-        loss = out_layer.compute_loss(y, out, mask=lmask)
-        reg = 0.0
-        for layer, p in zip(self.layers, params):
-            l1 = layer.l1 or 0.0
-            l2 = layer.l2 or 0.0
-            if not p or (l1 == 0.0 and l2 == 0.0):
-                continue
-            for name, w in p.items():
-                if not name.startswith(("W", "RW")):
-                    continue  # reference: regularization applies to weights only
-                if l2:
-                    reg = reg + 0.5 * l2 * jnp.sum(jnp.square(w))
-                if l1:
-                    reg = reg + l1 * jnp.sum(jnp.abs(w))
-        return loss + reg, new_states
+        with jax.named_scope(_stepprogram.LOSS_SCOPE):
+            loss = out_layer.compute_loss(y, out, mask=lmask)
+            reg = 0.0
+            for layer, p in zip(self.layers, params):
+                l1 = layer.l1 or 0.0
+                l2 = layer.l2 or 0.0
+                if not p or (l1 == 0.0 and l2 == 0.0):
+                    continue
+                for name, w in p.items():
+                    if not name.startswith(("W", "RW")):
+                        continue  # reference: regularization applies to weights only
+                    if l2:
+                        reg = reg + 0.5 * l2 * jnp.sum(jnp.square(w))
+                    if l1:
+                        reg = reg + l1 * jnp.sum(jnp.abs(w))
+            return loss + reg, new_states
 
     # ------------------------------------------------------------------- fit
     def _make_train_step(self, with_fmask: bool, with_lmask: bool,
@@ -508,7 +523,7 @@ class MultiLayerNetwork:
             if loss_scale:
                 inv = 1.0 / loss_scale
                 loss = loss * inv           # listeners/score see true loss
-                grads = jax.tree_util.tree_map(lambda g: g * inv, grads)
+                grads = _unscale_grads(grads, inv)
             new_params, new_opt = _process_and_apply_grads(
                 base, updater, params, grads, opt_state, tf)
             if frozen:
@@ -579,7 +594,7 @@ class MultiLayerNetwork:
                 jax.value_and_grad(loss_fn, has_aux=True)(params)
             inv = 1.0 / scale
             loss = loss * inv           # listeners/score see true loss
-            grads = jax.tree_util.tree_map(lambda g: g * inv, grads)
+            grads = _unscale_grads(grads, inv)
             # overflow detection on the UNSCALED grads: any non-finite
             # leaf anywhere = the scaled backward left fp16 range
             ok = _grads_all_finite(grads)
@@ -899,12 +914,13 @@ class MultiLayerNetwork:
         from deeplearning4j_tpu.train.resilience import fit_scope
         with fit_scope(session, self, epochs) as n_epochs:
             for _ in range(n_epochs):
-                with _prof.trace_span("train:epoch", epoch=self._epoch):
+                with _stepping.epoch_span(self):
                     # data-wait vs compute split: time spent pulling the next
                     # batch from the (possibly async) iterator is the input
                     # pipeline's bill, not the device's
                     if tbptt_len is not None:
-                        for ds in _prof.iter_with_data_wait(epoch_stream()):
+                        for ds in _prof.iter_with_data_wait(epoch_stream(),
+                                                          self):
                             if ds.features.ndim == 3:
                                 self.fitTBPTT(ds, tbptt_len)
                             else:        # non-sequence batch: nothing to
@@ -917,7 +933,8 @@ class MultiLayerNetwork:
                             prefetch,
                             placement=_stepping.batch_placement(self))
                     else:
-                        for ds in _prof.iter_with_data_wait(epoch_stream()):
+                        for ds in _prof.iter_with_data_wait(epoch_stream(),
+                                                          self):
                             self._fit_one(ds)
                 self._epoch += 1
                 for lst in self._listeners:
@@ -935,10 +952,13 @@ class MultiLayerNetwork:
             # GSPMD path: re-place params/updater state when they are not
             # on the plan's mesh (fresh init or a resilience restore)
             self._sharding_plan.ensure_placed(self)
+        spans = _stepping.step_spans(self)
+        spans.phase(_stepping.FIT_STAGE)
         x = _stepping.stage_batch(self, ds.features)
         y = _stepping.stage_batch(self, ds.labels)
         fmask = _stepping.stage_batch(self, ds.features_mask)
         lmask = _stepping.stage_batch(self, ds.labels_mask)
+        spans.phase(_stepping.FIT_PREPARE)
         # recompile-churn seam: every distinct (shape, dtype) signature
         # here is one XLA compile of the train step
         _churn.get_churn_detector().record(
@@ -959,15 +979,12 @@ class MultiLayerNetwork:
         # part of the snapshot.
         tok = _sanitizer.snapshot(self, "single", x=x, y=y, fmask=fmask,
                                   lmask=lmask)
+        spans.phase(_stepping.FIT_LISTENERS, "start")
         for lst in self._listeners:
             if hasattr(lst, "onIterationStart"):
                 # 1-based, matching iterationDone: hook pair refers to the
                 # same step number
                 lst.onIterationStart(self, self._iteration + 1)
-        # dispatch time of the compiled step (the loss stays on device;
-        # async backends overlap the actual compute with the next host
-        # iteration — the data_wait/step split still shows which side of
-        # the pipeline is the bottleneck)
         if _prof.instrumentation_active():
             # keep the amortization-factor gauge consistent with the
             # histogram samples this block records (a megastep may have
@@ -975,20 +992,24 @@ class MultiLayerNetwork:
             _stepping.STEPS_PER_DISPATCH.set(1)
             _stepping.TRAIN_ITERATIONS.inc()
         dyn = self._dynamic_scaling()
-        with _prof.timed_region(
-                "train:step", "dl4j_train_step_seconds",
-                "Compiled train-step dispatch time per iteration",
-                iteration=self._iteration + 1):
-            args = [self._params, self._states, self._opt_state,
-                    self._ensure_clock()]
-            if dyn:     # dynamic loss scale: an extra donated carry
-                args.append(self._ensure_scale_state())
-            out = step(*args, x, y,
-                       fmask if fmask is not None else dummy,
-                       lmask if lmask is not None else dummy)
+        # fit:dispatch is the host's time to ENQUEUE the compiled step
+        # (the loss stays on device; an asynchronous backend runs it while
+        # the host goes on to the next batch): set beside the device
+        # trace, its end says whether the device ever waited for the host
+        spans.phase(_stepping.FIT_DISPATCH)
+        args = [self._params, self._states, self._opt_state,
+                self._ensure_clock()]
+        if dyn:     # dynamic loss scale: an extra donated carry
+            args.append(self._ensure_scale_state())
+        args += [x, y, fmask if fmask is not None else dummy,
+                 lmask if lmask is not None else dummy]
+        spans.note(step, args)
+        out = step(*args)
+        spans.phase(_stepping.FIT_COMMIT)
         with _stepping.dispatch_commit(self, gen) as ok:
             if not ok:      # elastic recovery rolled this step back while
-                return      # the dispatch was hung: discard, no bookkeeping
+                spans.done()    # the dispatch was hung: discard, no
+                return          # bookkeeping
             if dyn:
                 (self._params, self._states, self._opt_state, self._t_dev,
                  self._scale_state, loss) = out
@@ -1003,9 +1024,11 @@ class MultiLayerNetwork:
                          context=f"loss at iteration {self._iteration}")
         self._last_batch_size = int(ds.features.shape[0])
         self._iteration += 1
+        spans.phase(_stepping.FIT_LISTENERS, "done")
         for lst in self._listeners:
             if hasattr(lst, "iterationDone"):
                 lst.iterationDone(self, self._iteration, self._epoch)
+        spans.done()
         if res is not None:
             res.after_step()
 
@@ -1021,10 +1044,13 @@ class MultiLayerNetwork:
         if self._sharding_plan is not None:
             self._sharding_plan.ensure_placed(self)  # see _fit_one
         k = mb.steps
+        spans = _stepping.step_spans(self, k)
+        spans.phase(_stepping.FIT_STAGE)
         x = _stepping.stage_batch(self, mb.features, mega=True)
         y = _stepping.stage_batch(self, mb.labels, mega=True)
         fmask = _stepping.stage_batch(self, mb.features_mask, mega=True)
         lmask = _stepping.stage_batch(self, mb.labels_mask, mega=True)
+        spans.phase(_stepping.FIT_PREPARE)
         _churn.get_churn_detector().record(
             "MultiLayerNetwork.megastep",
             _churn.array_fingerprint(x, y, fmask, lmask), owner=self)
@@ -1039,19 +1065,19 @@ class MultiLayerNetwork:
         if _prof.instrumentation_active():
             _stepping.STEPS_PER_DISPATCH.set(k)
         dyn = self._dynamic_scaling()
-        with _prof.timed_region(
-                "train:megastep", "dl4j_train_step_seconds",
-                "Compiled train-step dispatch time per iteration",
-                iteration=self._iteration + 1, steps=k):
-            args = [self._params, self._states, self._opt_state,
-                    self._ensure_clock()]
-            if dyn:     # dynamic loss scale: an extra scanned carry
-                args.append(self._ensure_scale_state())
-            out = step(*args, x, y,
-                       fmask if fmask is not None else dummy,
-                       lmask if lmask is not None else dummy)
+        spans.phase(_stepping.FIT_DISPATCH)
+        args = [self._params, self._states, self._opt_state,
+                self._ensure_clock()]
+        if dyn:     # dynamic loss scale: an extra scanned carry
+            args.append(self._ensure_scale_state())
+        args += [x, y, fmask if fmask is not None else dummy,
+                 lmask if lmask is not None else dummy]
+        spans.note(step, args)
+        out = step(*args)
+        spans.phase(_stepping.FIT_COMMIT)
         with _stepping.dispatch_commit(self, gen) as ok:
             if not ok:
+                spans.done()
                 return      # abandoned dispatch: see dispatch_commit
             if dyn:
                 (self._params, self._states, self._opt_state, self._t_dev,
@@ -1060,7 +1086,7 @@ class MultiLayerNetwork:
                 self._params, self._states, self._opt_state, self._t_dev, \
                     losses = out
         _stepping.record_megastep(self, losses, k, int(x.shape[1]),
-                                  san_token=tok)
+                                  san_token=tok, spans=spans)
 
     # ----------------------------------------------------------------- score
     def score(self, ds: DataSet = None) -> float:
@@ -1323,7 +1349,7 @@ class MultiLayerNetwork:
                     has_aux=True)(params)
                 inv = 1.0 / scale
                 loss = loss * inv       # listeners/score see true loss
-                grads = jax.tree_util.tree_map(lambda g: g * inv, grads)
+                grads = _unscale_grads(grads, inv)
                 ok = _grads_all_finite(grads)
                 new_params, new_opt = _process_and_apply_grads(
                     base, updater, params, grads, opt_state,
@@ -1344,8 +1370,7 @@ class MultiLayerNetwork:
                 if loss_scale:
                     inv = 1.0 / loss_scale
                     loss = loss * inv   # listeners/score see true loss
-                    grads = jax.tree_util.tree_map(lambda g: g * inv,
-                                                   grads)
+                    grads = _unscale_grads(grads, inv)
                 new_params, new_opt = _process_and_apply_grads(
                     base, updater, params, grads, opt_state,
                     t.astype(jnp.float32))

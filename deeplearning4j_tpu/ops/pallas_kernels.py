@@ -88,6 +88,7 @@ def _layer_norm_fwd_pallas(x, gain, bias, eps: float, interpret: bool):
         out_specs=pl.BlockSpec((block, d), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
         interpret=interpret,
+        name="dl4j_layer_norm",
     )(x, gain.reshape(1, d), bias.reshape(1, d))
 
 
@@ -156,6 +157,7 @@ def _softmax_fwd_pallas(x, interpret: bool):
         out_specs=pl.BlockSpec((block, d), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
         interpret=interpret,
+        name="dl4j_softmax",
     )(x)
 
 
@@ -222,6 +224,7 @@ def _scale_shift_act_pallas(x2d, scale, shift, alpha: float,
         out_specs=pl.BlockSpec((block, d), lambda i: (i, 0),
                                memory_space=pltpu.VMEM),
         interpret=interpret,
+        name="dl4j_scale_shift_act",
     )(x2d, scale.reshape(1, d), shift.reshape(1, d))
 
 
@@ -377,6 +380,7 @@ def _flash_fwd_pallas(q, k, v, *, causal: bool, bq: int, bk: int,
         compiler_params=pltpu.CompilerParams(
             dimension_semantics=("parallel", "parallel", "arbitrary")),
         interpret=interpret,
+        name="dl4j_flash_attention",
     )(q, k, v)
     return o, lse.reshape(BH, Tq, 128)[:, :, 0]
 

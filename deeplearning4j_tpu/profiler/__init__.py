@@ -22,10 +22,14 @@ static deadlock lint).
 
 Instrumented seams: ``ops.registry`` dispatch, ``native.runtime``
 (compile cache, H2D/D2H), ``parallel.{wrapper,data}`` (replication /
-shard transfers), the ``nn.{multilayer,graph}`` fit loops (step time,
-data-wait vs compute + the ``dl4j_train_overlap_ratio`` gauge /
-:func:`data_overlap_ratio`, ``train:megastep`` spans +
-``dl4j_steps_per_dispatch`` for multi-step dispatch), the input
+shard transfers), the ``nn.{multilayer,graph}`` fit loops (the ``fit:*``
+spans of ``train.stepping.StepSpans``, each with its ``iteration``, in
+the ring and as ``dl4j:fit:*`` annotations in a ``jax.profiler`` trace;
+host enqueue time vs batch wait + the ``dl4j_train_overlap_ratio`` gauge
+/ :func:`data_overlap_ratio`; ``dl4j_train_h2d_bytes_total``;
+``dl4j_steps_per_dispatch`` for multi-step dispatch; ``host:gc``), the
+compiled step itself (:mod:`stepprogram`: which layer and phase each of
+its instructions belongs to), the input
 pipeline (``dl4j_{async_iterator,prefetch}_queue_depth``,
 ``dl4j_prefetch_h2d_bytes_total``, and the staged pipeline's per-stage
 ``dl4j_pipeline_{stage_seconds,stall_seconds,queue_depth,
@@ -45,8 +49,10 @@ Everything is near-zero-cost when disabled: one module-level flag / enum
 read before any span or sample is allocated.
 """
 
+import gc as _gc
 import time as _time
 
+from deeplearning4j_tpu.profiler import stepprogram
 from deeplearning4j_tpu.profiler.aggregate import (FleetScraper,
                                                    HistogramSnapshot,
                                                    MetricsAggregator,
@@ -80,16 +86,17 @@ from deeplearning4j_tpu.profiler.tracecontext import (TraceContext,
                                                       spans_for_trace)
 from deeplearning4j_tpu.profiler.tracer import (SpanTracer, disable_tracing,
                                                 enable_tracing, get_tracer,
-                                                now_us, trace_span,
-                                                tracing_enabled)
+                                                now_us,
+                                                perf_counter_seconds,
+                                                trace_span, tracing_enabled)
 
 __all__ = [
     "Counter", "Gauge", "Histogram", "MetricsRegistry", "get_registry",
     "ProfilingMode", "get_profiling_mode", "set_profiling_mode",
     "SpanTracer", "trace_span", "get_tracer", "enable_tracing",
     "disable_tracing", "tracing_enabled", "instrumentation_active",
-    "now_us", "observe_region", "timed_region", "iter_with_data_wait",
-    "data_overlap_ratio",
+    "now_us", "perf_counter_seconds", "observe_region",
+    "iter_with_data_wait", "data_overlap_ratio", "stepprogram",
     "TraceContext", "current_trace", "record_span", "span", "run_span",
     "merge_chrome_traces", "spans_for_trace",
     "MetricsAggregator", "HistogramSnapshot", "FleetScraper",
@@ -113,64 +120,70 @@ def instrumentation_active() -> bool:
 def observe_region(span_name: str, metric_name: str, help_text: str,
                    started_us: float, seconds: float, **args) -> None:
     """Record one already-measured region: a histogram sample in the
-    registry plus (when tracing) a span on the tracer timeline. The fit
-    loops use this for regions they time with a bare perf_counter so the
-    un-instrumented path stays allocation-free."""
+    registry plus a span in the tracer's ring. The fit loops use this for
+    regions they time with a bare perf_counter, and only while
+    :func:`instrumentation_active`: the un-instrumented path stays
+    allocation-free."""
     get_registry().histogram(metric_name, help_text).observe(seconds)
-    if tracing_enabled():
-        get_tracer().add_event(span_name, started_us, seconds * 1e6,
-                               args or None)
+    get_tracer().add_event(span_name, started_us, seconds * 1e6,
+                           args or None)
 
 
-class timed_region:
-    """Context manager: time a region and feed it to :func:`observe_region`
-    (histogram sample + optional span). No-ops entirely when
-    instrumentation is inactive — the shared shape of the fit loops'
-    step-timing blocks."""
+# ``host:gc``: one span a collection, in the ring and in a jax.profiler
+# trace, while instrumentation is on — a pause of the interpreter is the
+# first suspect for an idle device, and nothing else would show it
+_GC_OPEN = []
 
-    __slots__ = ("span_name", "metric_name", "help_text", "args", "_t0",
-                 "_t0u")
 
-    def __init__(self, span_name: str, metric_name: str, help_text: str,
-                 **args):
-        self.span_name = span_name
-        self.metric_name = metric_name
-        self.help_text = help_text
-        self.args = args
-        self._t0 = None
+def _gc_span(phase, info):
+    if phase == "start":
+        import jax
+        ann = jax.profiler.TraceAnnotation("dl4j:host:gc")
+        ann.__enter__()
+        _GC_OPEN.append((now_us(), ann))
+    elif _GC_OPEN:
+        t0u, ann = _GC_OPEN.pop()
+        ann.__exit__(None, None, None)
+        # deferred: a collection can start inside the tracer's own lock
+        get_tracer().defer_event("host:gc", t0u, now_us() - t0u,
+                                 {"generation": info.get("generation"),
+                                  "collected": info.get("collected")})
 
-    def __enter__(self):
-        if instrumentation_active():
-            self._t0u, self._t0 = now_us(), _time.perf_counter()
-        return self
 
-    def __exit__(self, exc_type, exc, tb):
-        if self._t0 is not None:
-            observe_region(self.span_name, self.metric_name, self.help_text,
-                           self._t0u, _time.perf_counter() - self._t0,
-                           **self.args)
-            self._t0 = None
-        return False
+def _on_switch():
+    """What starts and ends with instrumentation, after the profiling
+    mode or the tracing flag was set."""
+    registered = _gc_span in _gc.callbacks
+    if instrumentation_active():
+        if not registered:
+            _gc.callbacks.append(_gc_span)
+        return
+    if registered:
+        _gc.callbacks.remove(_gc_span)
+        del _GC_OPEN[:]
+    stepprogram.flush()
 
 
 _SENTINEL = object()
 
-# data-wait-vs-compute overlap: 1.0 = the input pipeline is fully hidden
-# behind dispatched compute, 0.5 = the host spends as long waiting for
-# batches as dispatching them (data-starved). Updated by
+# host enqueue time against batch wait: both halves are HOST times (on an
+# asynchronous device dl4j_train_step_seconds times the enqueue of a step,
+# not its run), so 1.0 says the fit loop never waited for its iterator and
+# low values say it mostly did. Whether the DEVICE waited is another
+# question: the fit:* spans beside a device trace answer it. Updated by
 # iter_with_data_wait; dl4j_train_data_wait_seconds / _step_seconds hold
 # the raw halves.
 _OVERLAP_RATIO = get_registry().gauge(
     "dl4j_train_overlap_ratio",
-    "Compiled-dispatch time as a fraction of dispatch + data-wait time "
-    "(1.0 = input pipeline fully overlapped with compute; low values = "
-    "the chip is starving for data)")
+    "Host step-enqueue time as a fraction of enqueue + batch-wait time "
+    "(1.0 = the fit loop never waited for its iterator; low values = it "
+    "mostly waited. Host times both: not a device utilisation)")
 
 
 def data_overlap_ratio():
     """Cumulative dispatch/(dispatch + data_wait) from the two fit-loop
-    histograms — the overlap number the data-pipeline bench reports.
-    None before any instrumented fit ran."""
+    histograms, host times both — the number the data-pipeline bench
+    reports. None before any instrumented fit ran."""
     reg = get_registry()
     step = reg.get("dl4j_train_step_seconds")
     wait = reg.get("dl4j_train_data_wait_seconds")
@@ -180,24 +193,35 @@ def data_overlap_ratio():
     return None if total == 0 else s / total
 
 
-def iter_with_data_wait(batches):
-    """Yield from ``batches`` measuring each pull as ``train:data_wait``
-    (histogram + span) — the data-wait half of the data-wait-vs-compute
-    split both fit loops report (``dl4j_train_overlap_ratio`` tracks the
-    running ratio). The terminal pull (StopIteration) is not recorded: it
-    measures exhaustion, not a batch wait."""
+def iter_with_data_wait(batches, model):
+    """Yield from ``batches`` measuring each pull as ``fit:pull``
+    (``dl4j_train_data_wait_seconds`` sample + span, and a
+    ``dl4j:fit:pull`` annotation in a jax.profiler trace) — the data-wait
+    half of the data-wait-vs-dispatch split both fit loops report
+    (``dl4j_train_overlap_ratio`` tracks the running ratio). The span
+    carries the iteration of ``model`` the batch is pulled for. The
+    terminal pull (StopIteration) is not recorded: it measures
+    exhaustion, not a batch wait."""
+    import jax
     it = iter(batches)
     while True:
         active = instrumentation_active()
         if active:
+            ann = jax.profiler.TraceAnnotation("dl4j:fit:pull")
+            ann.__enter__()
             t0u, t0 = now_us(), _time.perf_counter()
-        ds = next(it, _SENTINEL)
+        try:
+            ds = next(it, _SENTINEL)
+        finally:
+            if active:
+                ann.__exit__(None, None, None)
         if ds is _SENTINEL:
             return
         if active:
-            observe_region("train:data_wait", "dl4j_train_data_wait_seconds",
+            observe_region("fit:pull", "dl4j_train_data_wait_seconds",
                            "Host wait for the next training batch", t0u,
-                           _time.perf_counter() - t0)
+                           _time.perf_counter() - t0, parent="fit:epoch",
+                           iteration=model._iteration + 1)
             ratio = data_overlap_ratio()
             if ratio is not None:
                 _OVERLAP_RATIO.set(ratio)

@@ -1,34 +1,35 @@
-"""Per-op DEVICE timing — the bridge from wall-clock to chip time.
+"""Per-layer device timing — which of the model's layers the chip's time
+went to.
 
 The op histograms PR 1 added (``dl4j_op_dispatch_seconds``) measure host
 dispatch: on an async backend they time the enqueue, not the chip. This
-module closes that gap (the PR-1 carried follow-up) with two capture
-paths and ONE attribution model:
+module gives per-config-layer tables from two sources, and says which:
 
-- **trace** — wrap a run in ``jax.profiler`` trace capture and parse the
-  XLA ``*.xplane.pb`` device planes directly (a ~100-line protobuf
-  wire-format reader; no tensorboard/tensorflow dependency). Fused-op
-  events map back to config layers through the ``dl4j_L<i>_<name>``
-  ``jax.named_scope`` both network forwards now emit — XLA carries the
-  scope in the op metadata, so a fusion that swallowed three layers is
-  attributed to the first layer whose scope it names.
-- **sync** — the everywhere fallback (CPU tests, backends whose profiler
-  exports nothing): re-dispatch each layer's ``apply`` as its own jitted
-  program with a hard ``block_until_ready`` fence around it, min-of-reps.
-  Each per-layer dispatch is synced, so the measured seconds are device
-  seconds (plus one dispatch overhead, which min-of-reps keeps honest);
-  what it cannot see is cross-layer fusion — it measures each layer *as
-  if dispatched alone*, which is exactly the per-layer cost model the
-  MFU attribution needs.
+- **trace** (``source="trace"``) — device time. A run is captured with
+  ``jax.profiler`` and read with ``jax.profiler.ProfileData``; every
+  device op is joined by its instruction's name to the program's own map
+  (:mod:`profiler.stepprogram`: the ``dl4j_L<i>_<name>``
+  ``jax.named_scope`` both network forwards emit, as the compiled program
+  carries it), which says the layer and whether the op is forward or
+  backward work. The default run is the jitted forward; a caller's
+  ``trace_run`` (a ``fit`` of a few batches) gives both columns.
+- **sync** (``source="sync"``) — the everywhere fallback (CPU tests,
+  backends whose profiler exports no device plane): each layer's
+  ``apply`` re-dispatched as its own jitted program between hard
+  ``block_until_ready`` fences, min-of-reps. That is host wall time of a
+  layer *dispatched alone*: it holds one dispatch overhead a layer and
+  sees no cross-layer fusion, so it ranks layers and is not what the
+  layer costs inside the compiled step.
 
 Attribution: per-layer forward FLOPs come from the SAME jax-free
 declared-shape model the analyzer's W105 stage-balance lint uses
 (``analysis.distribution._approx_flops`` over the config's propagated
 InputTypes), times batch, times the bench's train factor (backward = 2x
 forward, so train = 3x). ``DeviceTimeTable`` rows carry (layer, op,
-seconds, flops, mfu, share); ``top_offenders`` names the layers burning
-the most device time at the worst MFU — the list ``bench.py`` prints so
-a bench run names the bottleneck instead of one aggregate number.
+seconds, flops, mfu, share) and, from a trace, ``backward_seconds``;
+``top_offenders`` names the layers burning the most time at the worst
+MFU — the list ``bench.py`` prints so a bench run names the bottleneck
+instead of one aggregate number.
 
 Metrics: :meth:`DeviceTimeTable.export_metrics` publishes
 ``dl4j_op_device_seconds{model,layer,op}``. Export is gated on
@@ -39,19 +40,23 @@ pull-based: only an explicit ``measure()`` call dispatches anything).
 
 from __future__ import annotations
 
+import bisect
 import glob
 import os
 import re
+import shutil
 import tempfile
 import time
 from typing import Dict, List, Optional, Tuple
 
 from deeplearning4j_tpu import profiler as _prof
+from deeplearning4j_tpu.profiler import modes as _modes
+from deeplearning4j_tpu.profiler import stepprogram as _stepprogram
 
-#: scope-name prefix both network forwards emit per layer; the trace
-#: path greps XLA op metadata for it
+#: scope-name prefix both network forwards emit per layer; the compiled
+#: program carries it in every instruction's op_name
 SCOPE_PREFIX = "dl4j_L"
-_SCOPE_RE = re.compile(r"dl4j_L(\d+)_([A-Za-z0-9_.\-]+)")
+_SCOPE_RE = re.compile(r"dl4j_L(\d+)_")
 
 #: public v5e per-chip bf16 peak (Google Cloud documentation, "TPU v5e")
 #: — callers override for other parts
@@ -130,23 +135,29 @@ def layer_flop_model(conf) -> List[Tuple[str, str, int]]:
 class LayerTime:
     """One attribution row: device seconds + FLOP-model MFU for a layer."""
 
-    __slots__ = ("layer", "op", "seconds", "flops", "mfu", "share")
+    __slots__ = ("layer", "op", "seconds", "flops", "mfu", "share",
+                 "backward_seconds")
 
     def __init__(self, layer: str, op: str, seconds: float, flops: float,
-                 mfu: Optional[float], share: float):
+                 mfu: Optional[float], share: float,
+                 backward_seconds: Optional[float] = None):
         self.layer = layer
         self.op = op
-        self.seconds = seconds
+        self.seconds = seconds          # forward
         self.flops = flops
         self.mfu = mfu
         self.share = share
+        self.backward_seconds = backward_seconds    # from a trace only
 
     def as_dict(self) -> dict:
-        return {"layer": self.layer, "op": self.op,
-                "device_ms": round(self.seconds * 1e3, 4),
-                "gflops": round(self.flops / 1e9, 3),
-                "mfu": None if self.mfu is None else round(self.mfu, 4),
-                "time_share": round(self.share, 4)}
+        out = {"layer": self.layer, "op": self.op,
+               "device_ms": round(self.seconds * 1e3, 4),
+               "gflops": round(self.flops / 1e9, 3),
+               "mfu": None if self.mfu is None else round(self.mfu, 4),
+               "time_share": round(self.share, 4)}
+        if self.backward_seconds is not None:
+            out["backward_ms"] = round(self.backward_seconds * 1e3, 4)
+        return out
 
     def __repr__(self):
         return (f"LayerTime({self.layer}, {self.op}, "
@@ -154,7 +165,9 @@ class LayerTime:
 
 
 class DeviceTimeTable:
-    """Per-layer device-time MFU attribution for one model + batch."""
+    """Per-layer time and MFU attribution for one model + batch:
+    device time when ``source == "trace"``, host wall time of each layer
+    dispatched alone when ``source == "sync"``."""
 
     def __init__(self, rows: List[LayerTime], source: str,
                  batch: int, peak_flops: float, train_factor: float):
@@ -166,16 +179,18 @@ class DeviceTimeTable:
 
     @property
     def total_seconds(self) -> float:
-        return sum(r.seconds for r in self.rows)
+        return sum(r.seconds + (r.backward_seconds or 0.0)
+                   for r in self.rows)
 
     def top_offenders(self, n: int = 3) -> List[dict]:
         """The layers burning the most device time, worst first — what a
         bench run should name instead of one aggregate MFU number."""
-        ranked = sorted(self.rows, key=lambda r: -r.seconds)
-        return [r.as_dict() for r in ranked[:n]]
+        return self.as_rows(n)
 
     def as_rows(self, n: Optional[int] = None) -> List[dict]:
-        ranked = sorted(self.rows, key=lambda r: -r.seconds)
+        ranked = sorted(
+            self.rows,
+            key=lambda r: -(r.seconds + (r.backward_seconds or 0.0)))
         if n is not None:
             ranked = ranked[:n]
         return [r.as_dict() for r in ranked]
@@ -188,184 +203,98 @@ class DeviceTimeTable:
             return False
         c = _prof.get_registry().counter(
             "dl4j_op_device_seconds",
-            "Per-layer DEVICE seconds attributed by the devicetime "
-            "bridge (trace-parsed XLA events, or sync-timed per-layer "
-            "dispatch on backends without a trace)",
+            "Per-layer seconds attributed by the devicetime bridge "
+            "(device time from a trace joined to the step-program map, "
+            "or host wall time of each layer dispatched alone where no "
+            "trace exists)",
             labelnames=("model", "layer", "op"))
         for r in self.rows:
             c.labels(model=model_name, layer=r.layer, op=r.op).inc(r.seconds)
         return True
 
 
-# -------------------------------------------------- xplane wire parser
-# Minimal protobuf wire-format reader for the XSpace/XPlane schema
-# (tsl/profiler/protobuf/xplane.proto) — enough to pull (plane name,
-# line name, event name/display/duration) out of a jax.profiler capture
-# without importing tensorflow. Unknown fields are skipped by wire type,
-# so schema drift degrades to missing data, never a crash.
-
-def _read_varint(buf: bytes, i: int) -> Tuple[int, int]:
-    shift = result = 0
-    while True:
-        b = buf[i]
-        i += 1
-        result |= (b & 0x7F) << shift
-        if not b & 0x80:
-            return result, i
-        shift += 7
-
-
-def _fields(buf: bytes):
-    """Yield (field_number, wire_type, value) over one message's bytes;
-    value is an int for varint/fixed types and a bytes slice for
-    length-delimited fields."""
-    i, n = 0, len(buf)
-    while i < n:
-        tag, i = _read_varint(buf, i)
-        fno, wt = tag >> 3, tag & 7
-        if wt == 0:                      # varint
-            val, i = _read_varint(buf, i)
-        elif wt == 2:                    # length-delimited
-            ln, i = _read_varint(buf, i)
-            val = buf[i:i + ln]
-            i += ln
-        elif wt == 5:                    # 32-bit
-            val = int.from_bytes(buf[i:i + 4], "little")
-            i += 4
-        elif wt == 1:                    # 64-bit
-            val = int.from_bytes(buf[i:i + 8], "little")
-            i += 8
-        else:                            # groups: unsupported, stop
-            return
-        yield fno, wt, val
-
-
-def _parse_event_metadata(buf: bytes) -> Tuple[int, str]:
-    """XEventMetadata: id=1, name=2, metadata=3, display_name=4."""
-    mid, name, display = 0, "", ""
-    for fno, wt, val in _fields(buf):
-        if fno == 1 and wt == 0:
-            mid = val
-        elif fno == 2 and wt == 2:
-            name = val.decode("utf-8", "replace")
-        elif fno == 4 and wt == 2:
-            display = val.decode("utf-8", "replace")
-    return mid, (f"{name} {display}".strip() if display else name)
-
-
-def _parse_event(buf: bytes) -> Tuple[int, int]:
-    """XEvent: metadata_id=1, offset_ps=2, duration_ps=3."""
-    mid = dur = 0
-    for fno, wt, val in _fields(buf):
-        if fno == 1 and wt == 0:
-            mid = val
-        elif fno == 3 and wt == 0:
-            dur = val
-    return mid, dur
-
-
-def _parse_line(buf: bytes) -> Tuple[str, List[Tuple[int, int]]]:
-    """XLine: name=2, events=4."""
-    name, events = "", []
-    for fno, wt, val in _fields(buf):
-        if fno == 2 and wt == 2:
-            name = val.decode("utf-8", "replace")
-        elif fno == 4 and wt == 2:
-            events.append(_parse_event(val))
-    return name, events
-
-
-def _parse_plane(buf: bytes) -> dict:
-    """XPlane: name=2, lines=3, event_metadata=4 (map<int64, meta>)."""
-    plane = {"name": "", "lines": [], "event_names": {}}
-    for fno, wt, val in _fields(buf):
-        if fno == 2 and wt == 2:
-            plane["name"] = val.decode("utf-8", "replace")
-        elif fno == 3 and wt == 2:
-            plane["lines"].append(_parse_line(val))
-        elif fno == 4 and wt == 2:
-            key, meta_name = 0, ""
-            for kfno, kwt, kval in _fields(val):   # map entry {key=1, value=2}
-                if kfno == 1 and kwt == 0:
-                    key = kval
-                elif kfno == 2 and kwt == 2:
-                    mid, meta_name = _parse_event_metadata(kval)
-                    key = mid or key
-            plane["event_names"][key] = meta_name
-    return plane
-
-
-def parse_xspace(data) -> List[dict]:
-    """Parse an XSpace (path or bytes) into
-    ``[{name, lines: [(line_name, [(metadata_id, duration_ps)])],
-    event_names: {id: name}}]``."""
-    if isinstance(data, (str, os.PathLike)):
-        with open(data, "rb") as f:
-            data = f.read()
-    planes = []
-    for fno, wt, val in _fields(data):
-        if fno == 1 and wt == 2:         # XSpace.planes
-            planes.append(_parse_plane(val))
-    return planes
-
-
-def _is_device_plane(name: str) -> bool:
-    n = name.lower()
-    return ("/device:tpu" in n or "gpu:" in n.replace("/device:", "")
-            or n.startswith("/device:gpu"))
-
-
-def scope_seconds_from_xspace(planes: List[dict]) -> Dict[int, float]:
-    """Aggregate device-plane event durations per ``dl4j_L<i>`` scope:
-    {layer_index: seconds}. An event naming several scopes (a fusion
-    that swallowed multiple layers) is attributed to the FIRST scope it
-    names — deterministic, and the fused block's cost lands on the layer
-    the fusion is rooted at."""
-    out: Dict[int, float] = {}
-    for plane in planes:
-        if not _is_device_plane(plane["name"]):
+# ------------------------------------------------ trace joined to the map
+def device_events(path: str):
+    """``[(module, instruction, seconds)]`` of every device op in an
+    ``.xplane.pb``, read with ``jax.profiler.ProfileData``: the events of
+    the ``XLA Ops`` line of the first device plane that has one (under
+    data parallelism every chip runs the same program), named by the
+    instruction's head (``fusion.12``), each with the program of the
+    ``XLA Modules`` line it ran inside (``jit_step``)."""
+    from jax.profiler import ProfileData
+    out = []
+    for plane in ProfileData.from_file(path).planes:
+        if out:
+            break
+        if not plane.name.startswith("/device:"):
             continue
-        names = plane["event_names"]
-        for _line_name, events in plane["lines"]:
-            for mid, dur_ps in events:
-                m = _SCOPE_RE.search(names.get(mid, ""))
-                if m is None:
-                    continue
-                idx = int(m.group(1))
-                out[idx] = out.get(idx, 0.0) + dur_ps * 1e-12
+        lines = {line.name: line for line in plane.lines}
+        if "XLA Ops" not in lines:
+            continue
+        modules = sorted(
+            (ev.start_ns, ev.start_ns + ev.duration_ns,
+             ev.name.partition("(")[0])
+            for ev in lines["XLA Modules"].events) \
+            if "XLA Modules" in lines else []
+        starts = [m[0] for m in modules]
+        for ev in lines["XLA Ops"].events:
+            i = bisect.bisect_right(starts, ev.start_ns) - 1
+            module = modules[i][2] if i >= 0 \
+                and ev.start_ns < modules[i][1] else None
+            head = ev.name.partition(" = ")[0].lstrip("%")
+            out.append((module, head, ev.duration_ns * 1e-9))
     return out
 
 
-def _trace_layer_seconds(run_fn, trace_dir: Optional[str] = None
-                         ) -> Optional[Dict[int, float]]:
+def layer_seconds(events, programs) -> Dict[int, Dict[str, float]]:
+    """``{layer index: {"forward": s, "backward": s}}`` from
+    :func:`device_events` and ``{module: step-program map}``. An op the
+    map gives no ``dl4j_L<i>`` layer (the updater, the loss, layout
+    copies) is in no row; a ``mixed`` fusion counts where its convolution
+    does."""
+    out: Dict[int, Dict[str, float]] = {}
+    for module, head, seconds in events:
+        entry = programs.get(module, {}).get(head)
+        if entry is None or entry.phase not in ("forward", "backward"):
+            continue
+        m = _SCOPE_RE.match(entry.layer or "")
+        if m is None:
+            continue
+        row = out.setdefault(int(m.group(1)),
+                             {"forward": 0.0, "backward": 0.0})
+        row[entry.phase] += seconds
+    return out
+
+
+def _trace_layer_seconds(run_fn, programs=None
+                         ) -> Optional[Dict[int, Dict[str, float]]]:
     """Capture ``run_fn()`` under ``jax.profiler`` and return per-layer
-    device seconds, or None when the backend exported no parsable device
-    plane (callers fall back to sync timing)."""
+    device seconds, or None when the backend exported no device plane
+    (callers fall back to sync timing). ``programs`` are the maps of what
+    ``run_fn`` dispatches; without them the run is made with
+    instrumentation on, so that the fit loops note their step functions,
+    and the maps are the program's own (``stepprogram.maps()``)."""
     import jax
-    own = trace_dir is None
-    d = trace_dir or tempfile.mkdtemp(prefix="dl4j_devicetime_")
+    d = tempfile.mkdtemp(prefix="dl4j_devicetime_")
+    was = _modes._OVERRIDE
     try:
+        if programs is None and not _prof.instrumentation_active():
+            _prof.set_profiling_mode(_prof.ProfilingMode.BASIC)
         jax.profiler.start_trace(d)
         try:
             run_fn()
         finally:
             jax.profiler.stop_trace()
-        seconds: Dict[int, float] = {}
-        for path in glob.glob(os.path.join(d, "**", "*.xplane.pb"),
-                              recursive=True):
-            try:
-                per = scope_seconds_from_xspace(parse_xspace(path))
-            except Exception:
-                continue
-            for k, v in per.items():
-                seconds[k] = seconds.get(k, 0.0) + v
-        return seconds or None
-    except Exception:
-        return None
+            if _modes._OVERRIDE is not was:
+                _prof.set_profiling_mode(was)
+        if programs is None:
+            programs = _stepprogram.maps()
+        events = [e for path in glob.glob(
+            os.path.join(d, "**", "*.xplane.pb"), recursive=True)
+            for e in device_events(path)]
+        return layer_seconds(events, programs) or None
     finally:
-        if own:
-            import shutil
-            shutil.rmtree(d, ignore_errors=True)
+        shutil.rmtree(d, ignore_errors=True)
 
 
 # ------------------------------------------------------- sync fallback
@@ -433,9 +362,10 @@ def _walk_layers(model, x):
 
 
 def _sync_layer_seconds(model, x, reps: int = 3) -> Dict[int, float]:
-    """Per-layer forward device seconds by dispatching each layer's apply
-    as its own jitted program with a block_until_ready fence, min of
-    ``reps`` (first call compiles, then timed reps)."""
+    """Per-layer forward seconds by dispatching each layer's apply as its
+    own jitted program with a block_until_ready fence, min of ``reps``
+    (first call compiles, then timed reps): host wall time of the layer
+    alone, one dispatch overhead included — not device time."""
     import jax
     from deeplearning4j_tpu.nn import layers as L
 
@@ -468,14 +398,16 @@ def measure(model, features, *, reps: int = 3, mode: str = "auto",
             peak_flops: float = DEFAULT_PEAK_FLOPS,
             train_factor: float = 3.0,
             trace_run=None) -> DeviceTimeTable:
-    """Measure per-layer device time for one forward batch and attribute
-    MFU per layer against the analyzer's FLOP model.
+    """Measure per-layer time for one batch and attribute MFU per layer
+    against the analyzer's FLOP model.
 
-    ``mode``: ``"trace"`` parses a ``jax.profiler`` capture of
-    ``trace_run()`` (default: the model's jitted forward on
-    ``features``), ``"sync"`` times each layer's own dispatch, and
-    ``"auto"`` tries trace on TPU backends and falls back to sync —
-    so the same call works on the CPU test backend.
+    ``mode``: ``"trace"`` reads a ``jax.profiler`` capture of
+    ``trace_run()`` (default: the model's jitted forward on ``features``)
+    joined to the step-program map — device time, with a backward column
+    when ``trace_run`` trains (``lambda: net.fit(batches)``); ``"sync"``
+    times each layer's own dispatch on the host's clock; ``"auto"``
+    tries trace on TPU backends and falls back to sync — so the same call
+    works on the CPU test backend. The table's ``source`` says which.
 
     ``train_factor`` converts forward seconds/FLOPs into the training
     MFU convention the bench uses (backward = 2x forward → 3.0); pass
@@ -488,31 +420,41 @@ def measure(model, features, *, reps: int = 3, mode: str = "auto",
     flops_rows = layer_flop_model(model.conf)
 
     per_layer: Optional[Dict[int, float]] = None
+    backward: Dict[int, float] = {}
     source = "sync"
     if mode in ("trace", "auto") and (mode == "trace"
                                       or jax.default_backend() == "tpu"):
-        # graph forwards take a name->array dict; coerce a bare array
-        xin = model._as_input_dict(x) \
-            if not isinstance(x, dict) and hasattr(model, "_as_input_dict") \
-            else x
-        n_runs = max(1, reps)
+        if trace_run is not None:
+            traced = _trace_layer_seconds(trace_run)
+            n_runs = 1      # a caller's trace_run owns its iteration count
+        else:
+            # graph forwards take a name->array dict; coerce a bare array
+            xin = model._as_input_dict(x) \
+                if not isinstance(x, dict) \
+                and hasattr(model, "_as_input_dict") else x
+            n_runs = max(1, reps)
+            fwd = model._jit_forward()
+            args = (model._params, model._states, xin,
+                    jax.random.PRNGKey(0))
 
-        def default_run():
-            for _ in range(n_runs):
-                jax.block_until_ready(
-                    model._jit_forward()(model._params, model._states,
-                                         xin, jax.random.PRNGKey(0)))
-        per_layer = _trace_layer_seconds(trace_run or default_run)
-        if per_layer is not None:
+            def default_run():
+                for _ in range(n_runs):
+                    jax.block_until_ready(fwd(*args))
+            text = fwd._jit.lower(*args).compile().as_text()
+            traced = _trace_layer_seconds(
+                default_run, {_stepprogram.module_name(text):
+                              _stepprogram.parse(text)})
+        if traced is not None:
             source = "trace"
-            if trace_run is None:
-                # only default_run repeats n_runs times; a caller-supplied
-                # trace_run owns its own iteration count
-                per_layer = {k: v / n_runs for k, v in per_layer.items()}
+            per_layer = {k: v["forward"] / n_runs
+                         for k, v in traced.items()}
+            backward = {k: v["backward"] / n_runs
+                        for k, v in traced.items()}
         elif mode == "trace":
             raise RuntimeError(
-                "trace capture produced no parsable device plane on this "
-                "backend — use mode='sync' (or 'auto')")
+                "trace capture produced no device plane the step-program "
+                "map joins to on this backend — use mode='sync' (or "
+                "'auto')")
     if per_layer is None:
         per_layer = _sync_layer_seconds(model, x, reps=reps)
 
@@ -529,7 +471,7 @@ def measure(model, features, *, reps: int = 3, mode: str = "auto",
     else:
         keyed = dict(enumerate(flops_rows))
 
-    total = sum(per_layer.values()) or 1.0
+    total = (sum(per_layer.values()) + sum(backward.values())) or 1.0
     rows = []
     for idx, secs in sorted(per_layer.items()):
         name, op, fl = keyed.get(idx, (f"layer_{idx}", "unknown", 0))
@@ -541,7 +483,9 @@ def measure(model, features, *, reps: int = 3, mode: str = "auto",
             if secs > 0 and fl else None
         rows.append(LayerTime(str(name), op, secs, fl_total,
                               None if mfu is None else min(mfu, 1.0),
-                              secs / total))
+                              (secs + backward.get(idx, 0.0)) / total,
+                              backward.get(idx) if source == "trace"
+                              else None))
     return DeviceTimeTable(rows, source, batch, peak_flops, train_factor)
 
 

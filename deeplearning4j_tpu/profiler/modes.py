@@ -28,13 +28,23 @@ class ProfilingMode(enum.Enum):
 
 _OVERRIDE: Optional[ProfilingMode] = None
 
+def switched() -> None:
+    """The mode or the tracer's flag was set: start or end what lives
+    with instrumentation (the ``host:gc`` span, the flush of pending
+    step-program maps)."""
+    from deeplearning4j_tpu.profiler import _on_switch
+    _on_switch()
+
 
 def set_profiling_mode(mode: Optional[ProfilingMode]) -> None:
-    """Set the process-wide mode; ``None`` reverts to Environment-derived."""
+    """Set the process-wide mode; ``None`` reverts to Environment-derived.
+    Leaving a non-OFF mode builds the step-program maps still pending
+    (:mod:`profiler.stepprogram`)."""
     global _OVERRIDE
     if mode is not None and not isinstance(mode, ProfilingMode):
         mode = ProfilingMode(str(mode).lower())
     _OVERRIDE = mode
+    switched()
 
 
 def get_profiling_mode() -> ProfilingMode:

@@ -1,0 +1,303 @@
+"""The step program's own map: which layer and phase each device op is.
+
+The device trace names an operation by its HLO instruction
+(``%fusion.1827``); nothing in the trace says which of the model's layers
+that is, nor whether it ran in the forward pass, the backward pass or the
+updater. The compiled program does: every instruction carries the
+``jax.named_scope`` stack it was traced under in ``metadata={op_name=…}``
+— ``jvp(dl4j_L12_conv)`` in the forward pass, ``transpose(jvp(dl4j_L12_
+conv))`` in the backward pass, ``dl4j_updater`` in the update. The scopes
+are emitted by both network forwards (``dl4j_L<i>_<name>``, see
+:func:`profiler.devicetime.scope_name`) and by the step builders
+(:data:`UPDATER_SCOPE`, :data:`LOSS_SCOPE`, :data:`AUGMENT_SCOPE`); the
+Pallas kernels carry a ``name=`` (``dl4j_scale_shift_act``, …).
+
+:func:`parse` turns a compiled module's text into ``{instruction name:
+Entry(phase, layer, kernel, mixed)}`` — a pure function of the text,
+jax-free. A reader joins it to a trace by the instruction's name.
+
+The map of a running fit is made outside every timed stretch. While
+instrumentation is active the fit loops :func:`note` each step function
+once (the jit and the abstract signature of its arguments, no arrays);
+:func:`flush` lowers that signature again — the trace, the lowering and
+the executable the fit made are all found in JAX's in-memory caches, a
+fraction of a second for ResNet-50 — and keeps the map under the
+program's module name (``jit_step``, as the trace's ``XLA Modules`` line
+calls it). ``set_profiling_mode`` leaving a non-OFF
+mode flushes; :func:`maps` flushes on demand.
+"""
+
+from __future__ import annotations
+
+import re
+import threading
+import time
+import warnings
+import weakref
+from typing import Dict, NamedTuple, Optional
+
+#: scopes the step builders put around what no layer owns
+UPDATER_SCOPE = "dl4j_updater"
+LOSS_SCOPE = "dl4j_loss"
+AUGMENT_SCOPE = "dl4j_augment"
+
+PHASES = ("forward", "backward", "updater")
+
+
+class Entry(NamedTuple):
+    """One instruction of the step program."""
+    phase: str                  # forward | backward | updater | other
+    layer: Optional[str]        # dl4j_L<i>_<name>, dl4j_loss, … or None
+    kernel: Optional[str]       # the Pallas kernel's name= of a custom-call
+    mixed: bool                 # a fusion whose instructions disagree on
+    #                             the phase (weight gradient + Adam, …)
+
+
+_OTHER = Entry("other", None, None, False)
+
+_SCOPE = re.compile(
+    r"dl4j_(?:L\d+_[A-Za-z0-9_.\-]+|updater|loss|augment)")
+_KERNEL = re.compile(r"(dl4j_[A-Za-z0-9_]+)/pallas_call")
+_COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
+_INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
+_OPCODE = re.compile(r" ([a-z][a-z0-9\-]*)\(")
+_OP_NAME = re.compile(r'op_name="((?:[^"\\]|\\.)*)"')
+_CALLED = re.compile(
+    r"\b(calls|body|condition|to_apply)=%?([\w.\-]+)")
+_BRANCHES = re.compile(r"branch_computations=\{([^}]*)\}")
+_MODULE = re.compile(r"^HloModule ([\w.\-]+)", re.M)
+
+
+def classify(op_name: str):
+    """``(phase, layer)`` of one ``op_name``: ``dl4j_updater`` anywhere is
+    the updater; ``transpose(`` is the backward pass; ``jvp(`` or one of
+    this package's scopes without it is the forward pass. Where XLA
+    merged instructions it joins their names with ``;``: the first
+    speaks."""
+    name = op_name.partition(";")[0]
+    scope = _SCOPE.search(name)
+    layer = scope.group(0) if scope else None
+    if layer == UPDATER_SCOPE or UPDATER_SCOPE in name:
+        return "updater", UPDATER_SCOPE
+    if "transpose(" in name:
+        return "backward", layer
+    if "jvp(" in name or layer is not None:
+        return "forward", layer
+    return "other", None
+
+
+def module_name(hlo_text: str) -> Optional[str]:
+    """``jit_step`` from ``HloModule jit_step, is_scheduled=true, …``."""
+    m = _MODULE.search(hlo_text)
+    return m.group(1) if m else None
+
+
+def _split(hlo_text: str):
+    """``({computation: [(name, opcode, rest of the line, is root)]},
+    entry computation's name)``."""
+    comps, entry, cur = {}, None, None
+    for line in hlo_text.splitlines():
+        if cur is None:
+            m = _COMPUTATION.match(line)
+            if m:
+                cur = comps.setdefault(m.group(1), [])
+                if line.startswith("ENTRY"):
+                    entry = m.group(1)
+            continue
+        if line.startswith("}"):
+            cur = None
+            continue
+        m = _INSTRUCTION.match(line)
+        if m is None:
+            continue
+        rest = line[m.end() - 1:]
+        op = _OPCODE.search(rest)
+        cur.append((m.group(1), op.group(1) if op else "", rest,
+                    line.lstrip().startswith("ROOT ")))
+    return comps, entry
+
+
+def _own(rest: str):
+    """(phase, layer) from an instruction's own metadata."""
+    m = _OP_NAME.search(rest)
+    return classify(m.group(1)) if m else ("other", None)
+
+
+#: instructions that do a fusion's heavy work; the elementwise rest rides
+#: along with whatever they read and write
+_LEADS = ("convolution", "dot")
+_REDUCTIONS = ("reduce", "reduce-window", "select-and-scatter", "scatter")
+
+
+def _fusion(body, rest: str) -> Entry:
+    """What one fusion is, from its called computation's instructions.
+
+    XLA fuses across phases freely: a backward fusion recomputes the
+    forward activation it needs (cheap elementwise producers are
+    duplicated into their consumers), and an Adam fusion swallows the
+    last ``convert`` of its gradient. Such a fusion can only run once its
+    latest input exists, so it belongs to the latest phase it holds
+    (forward < backward < updater). What carries the weight is its
+    convolution or matmul, else its reduction: where that is of an
+    earlier phase than the latest one — a weight-gradient convolution
+    fused with Adam's update — two phases' work shares one op, nothing
+    can split its time, and it is ``mixed``."""
+    lead = reduction = latest = None
+    for _name, op, inner, _root in body:
+        phase, layer = _own(inner)
+        if phase not in PHASES:
+            continue
+        if op in _LEADS and lead is None:
+            lead = (phase, layer)
+        elif op in _REDUCTIONS and reduction is None:
+            reduction = (phase, layer)
+        if latest is None or PHASES.index(phase) > PHASES.index(latest[0]) \
+                or (phase == latest[0] and latest[1] is None):
+            latest = (phase, layer)
+    if latest is None:
+        phase, layer = _own(rest)       # the fusion's own name, if any
+        return Entry(phase, layer, None, False)
+    phase, layer = lead or reduction or latest
+    return Entry(phase, layer, None, phase != latest[0])
+
+
+def parse(hlo_text: str) -> Dict[str, Entry]:
+    """``{instruction name: Entry}`` for every instruction the device
+    runs as an op of its own: those of the entry computation and of the
+    computations it reaches through ``while``, ``call``, ``conditional``
+    and asynchronous wrappers (a megastep's scan body). A fusion's called
+    computation is read for its phase, not listed."""
+    comps, entry = _split(hlo_text)
+    out: Dict[str, Entry] = {}
+    seen, todo = set(), [entry]
+    while todo:
+        comp = todo.pop()
+        if comp in seen or comp not in comps:
+            continue
+        seen.add(comp)
+        for name, op, rest, _root in comps[comp]:
+            called = dict(_CALLED.findall(rest))
+            if op == "fusion":
+                out[name] = _fusion(comps.get(called.get("calls"), ()),
+                                    rest)
+                continue
+            phase, layer = _own(rest)
+            kernel = None
+            if op == "custom-call":
+                k = _KERNEL.search(rest)
+                kernel = k.group(1) if k else None
+            out[name] = Entry(phase, layer, kernel, False) \
+                if (phase != "other" or kernel) else _OTHER
+            todo.extend(c for key, c in called.items()
+                        if key != "to_apply" or op == "call")
+            branches = _BRANCHES.search(rest)
+            if branches:
+                todo.extend(b.strip().lstrip("%")
+                            for b in branches.group(1).split(","))
+    return out
+
+
+# ------------------------------------------------- the running fit's maps
+_LOCK = threading.Lock()
+_NOTED = weakref.WeakSet()      # jits noted already: once per function
+_PENDING = []                   # (jit, abstract arguments) not yet mapped
+_MAPS: Dict[str, Dict[str, Entry]] = {}
+#: seconds the last flush that had something to build took
+last_flush_s = 0.0
+
+
+def _abstract(a):
+    """The argument as jit saw it: shape, dtype, and the sharding only of
+    a committed array (a plan's ``device_put``). An uncommitted one lowers
+    with its sharding left open, and naming it would lower — and compile —
+    another program than the one that ran."""
+    import jax
+    committed = getattr(a, "committed", False)
+    return jax.ShapeDtypeStruct(
+        a.shape, a.dtype, sharding=a.sharding if committed else None,
+        weak_type=bool(getattr(a, "weak_type", False)))
+
+
+def note(jit, args) -> None:
+    """Remember what it takes to lower ``jit`` for ``args`` again: the
+    function and the arguments' shapes, dtypes and shardings. Called by
+    the fit loops at a step's dispatch while instrumentation is active;
+    the first signature a function is dispatched with is the one
+    mapped."""
+    if jit in _NOTED:
+        return
+    import jax
+    spec = jax.tree_util.tree_map(_abstract, tuple(args))
+    with _LOCK:
+        _NOTED.add(jit)
+        _PENDING.append((jit, spec))
+
+
+def _compiled_text(jit, spec) -> str:
+    """The compiled step's text with this tree's scopes in it. JAX leaves
+    metadata out of its compilation-cache key, so an executable cached by
+    a tree without the scopes is found again and says what that tree
+    said: such a program is compiled once more under a key that holds the
+    metadata (the instructions and their names are the same, the key
+    apart). The explicit option only steps past the executable the
+    lowering keeps; it is the compiler's default."""
+    import jax
+    lowered = jit.lower(*spec)
+    text = lowered.compile().as_text()
+    if UPDATER_SCOPE in text:
+        return text
+    key = "jax_compilation_cache_include_metadata_in_key"
+    was = getattr(jax.config, key)
+    jax.config.update(key, True)
+    try:
+        return lowered.compile(compiler_options={
+            "xla_embed_ir_in_executable": False}).as_text()
+    finally:
+        jax.config.update(key, was)
+
+
+def flush() -> None:
+    """Build the map of every noted step function and let go of it. A
+    program that cannot be lowered again, or whose text names no
+    :data:`UPDATER_SCOPE` even when compiled afresh, gets no map and a
+    warning (every reader then reports nothing, never a wrong split): a
+    map is an instrument, never a reason for a fit to fail."""
+    global last_flush_s
+    with _LOCK:
+        pending, _PENDING[:] = list(_PENDING), []
+    if not pending:
+        return
+    t0 = time.perf_counter()
+    for jit, spec in pending:
+        try:
+            text = _compiled_text(jit, spec)
+            name = module_name(text)
+            if name is None:
+                continue
+            if UPDATER_SCOPE not in text:
+                warnings.warn(
+                    f"step-program map of {name} not kept: the compiled "
+                    f"program names no {UPDATER_SCOPE} scope",
+                    stacklevel=2)
+                _MAPS.pop(name, None)
+                continue
+            _MAPS[name] = parse(text)
+        except Exception as e:  # noqa: BLE001 — whatever lowering raises
+            warnings.warn(f"step-program map not built: "
+                          f"{type(e).__name__}: {e}", stacklevel=2)
+    last_flush_s = time.perf_counter() - t0
+
+
+def maps() -> Dict[str, Dict[str, Entry]]:
+    """``{module name: {instruction name: Entry}}`` of every step
+    function noted so far, pending ones built now."""
+    flush()
+    return dict(_MAPS)
+
+
+def clear() -> None:
+    """Forget every map and every pending note (tests)."""
+    with _LOCK:
+        _PENDING[:] = []
+        _MAPS.clear()
+        _NOTED.clear()

@@ -42,6 +42,8 @@ import time
 from collections import deque
 from typing import Any, Dict, List, Optional
 
+from deeplearning4j_tpu.profiler import modes as _modes
+
 # module-level fast path: checked before span allocation (see trace_span)
 _ENABLED = False
 
@@ -52,6 +54,9 @@ _EPOCH_NS = time.perf_counter_ns()
 # streamed-trace flush cadence: every N events (the file is also closed
 # cleanly by stop_stream; a killed process loses at most one buffer)
 _STREAM_FLUSH_EVERY = 256
+
+# most ``defer_event`` spans kept between two drains (oldest dropped first)
+_DEFERRED_CAPACITY = 4096
 
 # ambient trace-context stamp (set by profiler.tracecontext on import):
 # returns a small dict of args (e.g. {"trace_id": ...}) merged into every
@@ -72,11 +77,13 @@ def enable_tracing() -> None:
     """Turn span recording on (module-level flag)."""
     global _ENABLED
     _ENABLED = True
+    _modes.switched()
 
 
 def disable_tracing() -> None:
     global _ENABLED
     _ENABLED = False
+    _modes.switched()
 
 
 def tracing_enabled() -> bool:
@@ -92,6 +99,13 @@ def _now_us() -> float:
 now_us = _now_us
 
 
+def perf_counter_seconds(ts_us: float) -> float:
+    """A span's ``ts`` (or ``ts + dur``) as ``time.perf_counter()``
+    seconds: the clock a caller that timed a stretch itself can cut the
+    ring with."""
+    return (ts_us * 1000.0 + _EPOCH_NS) * 1e-9
+
+
 class SpanTracer:
     """Bounded ring buffer of completed spans (thread-safe)."""
 
@@ -99,6 +113,9 @@ class SpanTracer:
         self.capacity = capacity
         self._events: deque = deque(maxlen=capacity)
         self._lock = threading.Lock()
+        # see defer_event(): bounded like the ring, so a mode that only
+        # registers the gc hook cannot grow memory while nothing drains it
+        self._deferred: deque = deque(maxlen=_DEFERRED_CAPACITY)
         self._tls = threading.local()   # per-thread open-span stack
         self._stream = None             # open file: see stream_to()
         self._stream_path: Optional[str] = None
@@ -132,6 +149,12 @@ class SpanTracer:
                   depth: int = 0) -> None:
         """Record one completed span directly (after-the-fact API for call
         sites that measured a region without holding a context manager)."""
+        evs = self._build_deferred()
+        evs.append(self._build(name, ts_us, dur_us, args, depth))
+        self._append(evs)
+
+    @staticmethod
+    def _build(name, ts_us, dur_us, args, depth=0) -> dict:
         ev = {"name": name, "ph": "X", "ts": ts_us, "dur": dur_us,
               "pid": os.getpid(), "tid": threading.get_ident()}
         if args:
@@ -144,18 +167,25 @@ class SpanTracer:
                 a = ev.setdefault("args", {})
                 for k, v in extra.items():
                     a.setdefault(k, v)
+        return ev
+
+    def _append(self, evs: List[dict]) -> None:
+        if not evs:
+            return
         with self._lock:
-            self._events.append(ev)
+            self._events.extend(evs)
             if self._stream is not None:
                 # streamed BEFORE ring eviction can drop it: long fits
                 # keep every span on disk while host memory stays bounded
                 try:
-                    prefix = ",\n" if self._stream_count else ""
-                    self._stream.write(prefix + json.dumps(ev))
-                    self._stream_count += 1
-                    self._stream_tids.add((ev["pid"], ev["tid"]))
-                    if self._stream_count % self._stream_flush_every == 0:
-                        self._stream.flush()
+                    for ev in evs:
+                        prefix = ",\n" if self._stream_count else ""
+                        self._stream.write(prefix + json.dumps(ev))
+                        self._stream_count += 1
+                        self._stream_tids.add((ev["pid"], ev["tid"]))
+                        if (self._stream_count
+                                % self._stream_flush_every == 0):
+                            self._stream.flush()
                 except OSError as e:
                     stream, self._stream = self._stream, None
                     try:
@@ -166,7 +196,33 @@ class SpanTracer:
                     warnings.warn(
                         f"trace stream to {self._stream_path} failed "
                         f"({e}) — streaming disabled, ring buffer "
-                        "retention continues", stacklevel=3)
+                        "retention continues", stacklevel=4)
+
+    def defer_event(self, name: str, ts_us: float, dur_us: float,
+                    args: Optional[Dict[str, Any]] = None) -> None:
+        """Record a span from where no lock may be taken. A ``gc``
+        callback runs inside whatever allocated last — this tracer's own
+        locked stretch included — so its span is set aside (a deque append,
+        atomic; the oldest is dropped past ``_DEFERRED_CAPACITY``) and moved
+        into the ring, in order, by the next :meth:`add_event` or
+        :meth:`events`."""
+        self._deferred.append((name, ts_us, dur_us, args))
+
+    def _build_deferred(self) -> List[dict]:
+        """The deferred spans as events, oldest first, taken off the list
+        one ``popleft`` at a time (atomic, so two draining threads share
+        them out and none is taken twice)."""
+        evs = []
+        while self._deferred:
+            try:
+                item = self._deferred.popleft()
+            except IndexError:      # another thread took the last one
+                break
+            evs.append(self._build(*item))
+        return evs
+
+    def _take_deferred(self) -> None:
+        self._append(self._build_deferred())
 
     def current_depth(self) -> int:
         """Open-span nesting depth on the calling thread."""
@@ -174,15 +230,18 @@ class SpanTracer:
 
     # --------------------------------------------------------------- reading
     def __len__(self) -> int:
+        self._take_deferred()
         with self._lock:
             return len(self._events)
 
     def events(self) -> List[dict]:
         """Snapshot of recorded spans (oldest first)."""
+        self._take_deferred()
         with self._lock:
             return list(self._events)
 
     def clear(self) -> None:
+        self._deferred.clear()
         with self._lock:
             self._events.clear()
 

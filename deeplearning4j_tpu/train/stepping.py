@@ -31,6 +31,7 @@ Pieces:
 
 from __future__ import annotations
 
+import time as _time
 from contextlib import contextmanager
 from typing import Iterable, Iterator, List, Union
 
@@ -48,14 +49,132 @@ STEPS_PER_DISPATCH = _prof.get_registry().gauge(
     "Update steps performed by the most recent compiled train dispatch "
     "(1 = classic per-step dispatch, K = lax.scan megastep)")
 # Total update steps, advanced by K per megastep dispatch. A
-# dl4j_train_step_seconds sample covers ONE dispatch (1 or K steps), so
-# per-step host dispatch time under mixed K is
+# dl4j_train_step_seconds sample covers the host's ENQUEUE of one
+# dispatch (1 or K steps), so per-step host dispatch time under mixed K is
 # rate(dl4j_train_step_seconds_sum) / rate(dl4j_train_iterations_total)
-# — NOT sum/count, which a megastep/tail-fallback mix would skew.
+# — NOT sum/count, which a megastep/tail-fallback mix would skew — and on
+# an asynchronous device it is not the time a step takes to run.
 TRAIN_ITERATIONS = _prof.get_registry().counter(
     "dl4j_train_iterations_total",
     "Update steps performed by compiled train dispatches (a K-step "
     "megastep advances this by K)")
+
+
+# Host bytes the fit functions themselves placed on the device: what a
+# DevicePrefetcher staged ahead is in dl4j_prefetch_h2d_bytes_total.
+TRAIN_H2D_BYTES = _prof.get_registry().counter(
+    "dl4j_train_h2d_bytes_total",
+    "Host bytes stage_batch placed on the device inside the fit loop "
+    "(arrays already on the device are not counted)")
+_STEP_SECONDS = _prof.get_registry().histogram(
+    "dl4j_train_step_seconds",
+    "Host time to enqueue one compiled train dispatch (1 or K steps); "
+    "on an asynchronous device not the time the step runs")
+
+FIT_EPOCH = "fit:epoch"
+FIT_STAGE = "fit:stage"
+FIT_PREPARE = "fit:prepare"
+FIT_LISTENERS = "fit:listeners"
+FIT_DISPATCH = "fit:dispatch"
+FIT_COMMIT = "fit:commit"
+
+
+class _NoSpans:
+    """What :func:`step_spans` hands out while instrumentation is off:
+    every call does nothing, reads no clock and allocates nothing."""
+
+    __slots__ = ()
+
+    def phase(self, name, when=None):
+        pass
+
+    def note(self, step, args):
+        pass
+
+    def done(self):
+        pass
+
+
+_OFF = _NoSpans()
+
+
+class StepSpans:
+    """The host spans of one pass through ``_fit_one`` / ``_fit_mega``,
+    one after the other: ``fit:stage`` (all ``stage_batch`` calls of the
+    batch, arg ``bytes``), ``fit:prepare`` (churn fingerprint, step
+    lookup, resilience and sanitizer hooks), ``fit:listeners``
+    (``when=start``), ``fit:dispatch`` (the host's time to enqueue the
+    compiled step — also the ``dl4j_train_step_seconds`` sample),
+    ``fit:commit``, ``fit:listeners`` (``when=done``). ``phase`` closes
+    the open span and opens the next; each goes into the tracer's ring
+    with the ``iteration`` the spans of one step share and its parent's
+    name, and is open as ``jax.profiler.TraceAnnotation("dl4j:<name>")``
+    meanwhile, so a device trace shows it on the trace's own clock."""
+
+    __slots__ = ("iteration", "steps", "_name", "_when", "_t0", "_t0u",
+                 "_ann", "_bytes0")
+
+    def __init__(self, iteration: int, steps: int = 1):
+        self.iteration = iteration
+        self.steps = steps
+        self._name = None
+
+    def phase(self, name, when=None):
+        self.done()
+        self._name, self._when = name, when
+        self._bytes0 = TRAIN_H2D_BYTES.value if name == FIT_STAGE else None
+        self._ann = jax.profiler.TraceAnnotation(
+            "dl4j:" + name, iteration=self.iteration)
+        self._ann.__enter__()
+        self._t0u, self._t0 = _prof.now_us(), _time.perf_counter()
+
+    def note(self, step, args):
+        """The step function about to be dispatched, for the step-program
+        map (once per function; see ``profiler.stepprogram``)."""
+        _prof.stepprogram.note(step._jit, args)
+
+    def done(self):
+        name = self._name
+        if name is None:
+            return
+        seconds = _time.perf_counter() - self._t0
+        self._ann.__exit__(None, None, None)
+        self._name = None
+        args = {"iteration": self.iteration, "parent": FIT_EPOCH}
+        if self._when is not None:
+            args["when"] = self._when
+        if self._bytes0 is not None:
+            args["bytes"] = int(TRAIN_H2D_BYTES.value - self._bytes0)
+        if name == FIT_DISPATCH:
+            args["steps"] = self.steps
+            _STEP_SECONDS.observe(seconds)
+        _prof.get_tracer().add_event(name, self._t0u, seconds * 1e6, args)
+
+
+def step_spans(model, steps: int = 1):
+    """The span recorder of the dispatch about to be made: a
+    :class:`StepSpans` while instrumentation is active, else the shared
+    no-op — one flag and one enum read a dispatch when it is off."""
+    if _prof.instrumentation_active():
+        return StepSpans(model._iteration + 1, steps)
+    return _OFF
+
+
+@contextmanager
+def epoch_span(model):
+    """``fit:epoch``: the parent of every ``fit:*`` span of one epoch."""
+    if not _prof.instrumentation_active():
+        yield
+        return
+    t0u = _prof.now_us()
+    with jax.profiler.TraceAnnotation("dl4j:" + FIT_EPOCH,
+                                      epoch=model._epoch):
+        try:
+            yield
+        finally:
+            _prof.get_tracer().add_event(
+                FIT_EPOCH, t0u, _prof.now_us() - t0u,
+                {"epoch": model._epoch})
 
 
 def stage_batch(model, a, mega: bool = False):
@@ -65,9 +184,13 @@ def stage_batch(model, a, mega: bool = False):
     batch PartitionSpec (dim 0 — dim 1 under a ``[K, B, ...]``
     megabatch — sharded over the plan's batch axes, replicated over
     model/seq axes). A no-op copy-wise for arrays a DevicePrefetcher
-    already placed with the same sharding."""
+    already placed with the same sharding. While instrumentation is
+    active the bytes of every host array placed count into
+    ``dl4j_train_h2d_bytes_total``."""
     if a is None:
         return None
+    if not isinstance(a, jax.Array) and _prof.instrumentation_active():
+        TRAIN_H2D_BYTES.inc(int(getattr(a, "nbytes", 0)))
     plan = getattr(model, "_sharding_plan", None)
     if plan is None:
         return jnp.asarray(a)
@@ -256,7 +379,7 @@ def scan_megastep(body, num_carry: int):
 
 
 def record_megastep(model, losses, steps: int, batch_size: int,
-                    san_token=None) -> None:
+                    san_token=None, spans=_OFF) -> None:
     """Shared post-dispatch bookkeeping for ``_fit_mega`` (both network
     classes): numerics panic gate over the K-loss vector (with first-
     nonfinite provenance when the sanitizer armed ``san_token``), then
@@ -283,6 +406,7 @@ def record_megastep(model, losses, steps: int, batch_size: int,
         model._iteration += steps
         model._score = losses[steps - 1]
     else:
+        spans.phase(FIT_LISTENERS, "done")   # all K pairs, one span
         for j in range(steps):
             model._score = losses[j]
             model._iteration += 1
@@ -291,6 +415,7 @@ def record_megastep(model, losses, steps: int, batch_size: int,
                     lst.onIterationStart(model, model._iteration)
                 if hasattr(lst, "iterationDone"):
                     lst.iterationDone(model, model._iteration, model._epoch)
+    spans.done()
     # resilience seam (train.resilience): non-finite recovery, periodic
     # checkpoint, and preemption all act at dispatch granularity — the
     # in-flight megastep always completes before any of them fire
@@ -310,7 +435,7 @@ def fit_epoch_multistep(model, batches: Iterable, steps: int,
     from deeplearning4j_tpu.data.dataset import DevicePrefetcher, stage_item
 
     def drive(items):
-        for item in _prof.iter_with_data_wait(items):
+        for item in _prof.iter_with_data_wait(items, model):
             if isinstance(item, MegaBatch):
                 model._fit_mega(item)
             else:

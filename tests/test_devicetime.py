@@ -2,7 +2,6 @@
 layout seam, the fused Pallas epilogues, the Rotate/Resize device
 augment kernels, and the ParallelWrapper replication-path warmup."""
 
-import struct
 import tempfile
 
 import numpy as np
@@ -49,82 +48,6 @@ def small_data(hw=12, n=6, classes=4, seed=0):
     x = rng.randn(n, 3, hw, hw).astype(np.float32)
     y = np.eye(classes, dtype=np.float32)[rng.randint(0, classes, n)]
     return x, y
-
-
-# ----------------------------------------------------- xplane wire parser
-def _varint(v: int) -> bytes:
-    out = b""
-    while True:
-        b7 = v & 0x7F
-        v >>= 7
-        if v:
-            out += bytes([b7 | 0x80])
-        else:
-            return out + bytes([b7])
-
-
-def _field(fno: int, wt: int, payload) -> bytes:
-    tag = _varint((fno << 3) | wt)
-    if wt == 0:
-        return tag + _varint(payload)
-    return tag + _varint(len(payload)) + payload
-
-
-def _make_xspace(plane_name: str, events, extra_meta=()) -> bytes:
-    """Hand-encode XSpace{planes=[XPlane{name, lines=[XLine{events}],
-    event_metadata}]} with ``events`` = [(metadata_id, name, dur_ps)]."""
-    metas = b""
-    evs = b""
-    for mid, name, dur in events:
-        meta = _field(1, 0, mid) + _field(2, 2, name.encode())
-        metas += _field(4, 2, _field(1, 0, mid) + _field(2, 2, meta))
-        evs += _field(4, 2, _field(1, 0, mid) + _field(3, 0, dur))
-    for mid, name in extra_meta:
-        meta = _field(1, 0, mid) + _field(2, 2, name.encode())
-        metas += _field(4, 2, _field(1, 0, mid) + _field(2, 2, meta))
-    line = _field(2, 2, b"XLA Ops") + evs
-    plane = _field(2, 2, plane_name.encode()) + _field(3, 2, line) + metas
-    return _field(1, 2, plane)
-
-
-class TestXspaceParser:
-    def test_roundtrip_and_scope_aggregation(self):
-        data = _make_xspace(
-            "/device:TPU:0",
-            [(1, "fusion.7 dl4j_L0_conv/conv_general_dilated", 2_000_000),
-             (2, "dl4j_L1_bn/add", 500_000),
-             (3, "copy.3", 250_000),
-             (1, "fusion.7 dl4j_L0_conv/conv_general_dilated", 1_000_000)])
-        planes = dt.parse_xspace(data)
-        assert len(planes) == 1
-        assert planes[0]["name"] == "/device:TPU:0"
-        (line_name, events), = planes[0]["lines"]
-        assert line_name == "XLA Ops"
-        assert len(events) == 4
-        per = dt.scope_seconds_from_xspace(planes)
-        assert per[0] == pytest.approx(3e-6)      # 3ms of ps -> seconds
-        assert per[1] == pytest.approx(0.5e-6)
-        assert 3 not in per                       # unscoped op dropped
-
-    def test_host_plane_ignored(self):
-        data = _make_xspace("/host:CPU", [(1, "dl4j_L0_x/op", 1_000_000)])
-        assert dt.scope_seconds_from_xspace(dt.parse_xspace(data)) == {}
-
-    def test_unknown_fields_skipped(self):
-        # prepend an unknown varint field + a fixed64 field at XSpace level
-        junk = _varint((9 << 3) | 0) + _varint(12345) \
-            + _varint((10 << 3) | 1) + struct.pack("<Q", 7)
-        data = junk + _make_xspace(
-            "/device:TPU:0", [(1, "dl4j_L2_y/op", 4_000_000)])
-        per = dt.scope_seconds_from_xspace(dt.parse_xspace(data))
-        assert per == {2: pytest.approx(4e-6)}
-
-    def test_parse_from_file(self, tmp_path):
-        p = tmp_path / "t.xplane.pb"
-        p.write_bytes(_make_xspace("/device:TPU:0",
-                                   [(5, "dl4j_L3_z/op", 1_000)]))
-        per = dt.scope_seconds_from_xspace(dt.parse_xspace(str(p)))
-        assert per == {3: pytest.approx(1e-9)}
 
 
 # --------------------------------------------------------- the sync bridge
@@ -406,15 +329,22 @@ class TestFusedEpilogue:
         """Trace seconds are normalized by ``reps`` only for the default
         run (the only run_fn that loops ``reps`` times) — a caller's
         ``trace_run`` owns its own iteration count."""
-        monkeypatch.setattr(dt, "_trace_layer_seconds",
-                            lambda run: {0: 0.9, 1: 0.1})
+        monkeypatch.setattr(
+            dt, "_trace_layer_seconds",
+            lambda run, programs=None: {
+                0: {"forward": 0.9, "backward": 1.8},
+                1: {"forward": 0.1, "backward": 0.0}})
         net = conv_fixture()
         x, _ = small_data()
         custom = dt.measure(net, x, mode="trace", reps=3,
                             trace_run=lambda: None)
+        assert custom.source == "trace"
         assert custom.rows[0].seconds == pytest.approx(0.9)
+        assert custom.rows[0].backward_seconds == pytest.approx(1.8)
+        assert custom.as_rows(1)[0]["backward_ms"] == pytest.approx(1800.0)
         default = dt.measure(net, x, mode="trace", reps=3)
         assert default.rows[0].seconds == pytest.approx(0.3)
+        assert default.rows[0].backward_seconds == pytest.approx(0.6)
 
     def test_pallas_kernel_matches_generic(self):
         from deeplearning4j_tpu.ops import normalization as norm_ops

@@ -283,7 +283,8 @@ class TestSpanTracer:
             th.start()
         for th in threads:
             th.join()
-        evs = t.events()
+        # a collection while tracing is on leaves a host:gc span of its own
+        evs = [e for e in t.events() if e["name"].startswith("w")]
         assert len(evs) == 8 * 50
         assert len({e["tid"] for e in evs}) == 8   # spans keep their thread
 
@@ -555,10 +556,10 @@ class TestProfilerEndpoints:
             for key in ("ph", "ts", "name"):
                 assert key in ev
         names = {e["name"] for e in evs}
-        assert {"train:epoch", "train:step", "train:data_wait"} <= names
+        assert {"fit:epoch", "fit:dispatch", "fit:pull"} <= names
         # real nesting from a real fit() run: step inside its epoch span
-        epochs = [e for e in evs if e["name"] == "train:epoch"]
-        steps = [e for e in evs if e["name"] == "train:step"]
+        epochs = [e for e in evs if e["name"] == "fit:epoch"]
+        steps = [e for e in evs if e["name"] == "fit:dispatch"]
         assert len(epochs) == 2 and len(steps) == 2
         contained = sum(
             1 for s in steps for ep in epochs
