@@ -372,7 +372,7 @@ class _CnnBench:
     ISSUE 14: the measured configuration is the
     OPTIMIZED conv path — explicit ``PrecisionPolicy("bf16")`` (the
     PR-11 seam: fp32 masters/BN stats/loss, bf16 compute), NHWC compute
-    layout, and fused bias+BN+activation Pallas epilogues. Rows carry a
+    layout, and fused bias+BN+activation epilogues. Rows carry a
     ``precision`` field; an ``fp32_comparison`` sub-row (the legacy
     fp32/NCHW/unfused path, fewer steps) is kept for one release; a
     ``loss_parity`` sub-row pins the bf16-optimized loss curve against
@@ -410,9 +410,9 @@ class _CnnBench:
 
     def _optimize(self, net):
         """The measured configuration: bf16 policy + NHWC layout + fused
-        epilogues (the compiled Pallas kernel where shapes tile; off the
-        TPU — a --quick run — no kernel is installed and the fused
-        epilogue is the generic lowering)."""
+        epilogues, with the Pallas overrides installed on the TPU (none
+        of them is on the conv path: the fused epilogue is the generic
+        op, which the compiler fuses into the convolutions)."""
         if jax.default_backend() == "tpu":
             from deeplearning4j_tpu.ops import pallas_kernels as _pk
             _pk.install_platform_overrides()
@@ -847,7 +847,7 @@ def bench_imported(quick: bool = False):
 def bench_device_timing(quick: bool = False):
     """Device-timing probe (benchmarks/probe_device_timing.py): asserts
     the devicetime bridge produces a non-empty per-layer attribution
-    table matching the analyzer's FLOP model, and that the fused Pallas
+    table matching the analyzer's FLOP model, and that the fused
     epilogue path is bit-close (fp32) / loss-parity (bf16) against the
     reference path."""
     return _run_probe("probe_device_timing.py",

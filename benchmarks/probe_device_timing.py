@@ -7,10 +7,9 @@ Three asserted checks, printed as ONE JSON line (wired as
    conv fixture produces a per-layer table whose rows cover every layer,
    whose time shares sum to ~1, and whose per-layer FLOPs equal the
    analyzer's declared-shape model (the same numbers W105 reasons with).
-2. **Fused epilogue, fp32** — the bias+BN+relu / BN+leaky Pallas
-   epilogue path (NHWC + ``setEpilogueFusion`` + platform overrides in
-   interpret mode off-TPU) is BIT-CLOSE to the reference path: forward
-   max|Δ| and one-fit-step loss delta both under 1e-4.
+2. **Fused epilogue, fp32** — the bias+BN+relu / BN+leaky epilogue
+   path (NHWC + ``setEpilogueFusion``) is BIT-CLOSE to the reference
+   path: forward max|Δ| and one-fit-step loss delta both under 1e-4.
 3. **Fused epilogue, bf16** — under ``PrecisionPolicy("bf16")`` the
    fused+NHWC loss curve tracks the unfused bf16 curve within 10% of
    the curve scale (loss parity, the same guard the bench rows carry).
@@ -23,7 +22,7 @@ import json
 import os
 import sys
 
-os.environ["JAX_PLATFORMS"] = "cpu"   # the kernels run interpreted: CPU only
+os.environ["JAX_PLATFORMS"] = "cpu"   # a probe of contracts, not of speed
 sys.path.insert(0, os.path.dirname(os.path.dirname(os.path.abspath(__file__))))
 
 import numpy as np  # noqa: E402
@@ -74,8 +73,6 @@ def check_attribution(out: dict, reps: int):
 
 
 def _optimized(net):
-    from deeplearning4j_tpu.ops import pallas_kernels as pk
-    pk.install_platform_overrides(interpret=True)
     net.setComputeLayout("NHWC")
     net.setEpilogueFusion(True)
     return net

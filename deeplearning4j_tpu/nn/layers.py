@@ -1693,9 +1693,8 @@ def stamp_layout(layers, fmt: str) -> None:
 # into ONE scale_shift_act op — batch statistics stay the fp32
 # reductions of norm_ops.batch_norm_train, the normalize+activation
 # becomes a single FMA+select the 'scale_shift_act' registry op executes
-# (a Pallas VMEM one-pass kernel when the platform override is installed
-# and the shape tiles; the composed-jnp generic otherwise, which is
-# bit-identical to the unfused batch_norm+activation path). A preceding
+# (composed jnp, bit-identical to the unfused batch_norm+activation path;
+# the compiler fuses it into the neighbouring convolution). A preceding
 # identity-activation conv's bias folds into the shift algebraically
 # (BN subtracts the mean, so the bias cancels in train mode and shifts
 # the recorded running mean; inference un-shifts it from the running

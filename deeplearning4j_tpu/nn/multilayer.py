@@ -718,11 +718,10 @@ class MultiLayerNetwork:
 
     def setEpilogueFusion(self, enabled: bool = True) -> "MultiLayerNetwork":
         """Fuse conv-bias+BN+relu (and BN+leaky-relu) blocks into ONE
-        ``scale_shift_act`` dispatch — a Pallas one-pass VMEM kernel on
-        channels-minor shapes that tile (install
-        ``ops.pallas_kernels.install_platform_overrides()``), the
-        bit-identical composed-jnp lowering otherwise. Opt-in; busts the
-        step caches when toggled."""
+        ``scale_shift_act`` dispatch — one registry op in composed jnp,
+        bit-identical to the unfused stack, which the compiler fuses into
+        the neighbouring convolution. Opt-in; busts the step caches when
+        toggled."""
         enabled = bool(enabled)
         if enabled != self._fuse_epilogues:
             self._train_step_cache = {}

@@ -71,10 +71,10 @@ def scale_shift_act(x, scale, shift, *, alpha: float = 0.0, axis: int = 1):
     caller — see ``nn.layers.fused_bn_act``). ``alpha`` is the negative
     slope: 0.0 = relu, 0.01 = the reference's leaky-relu.
 
-    This generic lowering is bit-identical to ``batch_norm`` followed by
-    the activation; the Pallas platform override (``ops.pallas_kernels.
-    make_scale_shift_act_override``) shadows it with a one-VMEM-pass
-    kernel on channels-minor shapes that tile."""
+    Bit-identical to ``batch_norm`` followed by the activation, and the
+    only lowering: between two convolutions the TPU's compiler folds the
+    FMA+select into their fusions, which a kernel's ``custom-call`` can
+    take no part in (PERF.md, PR 27)."""
     shape = [1] * x.ndim
     shape[axis] = -1
     y = x * jnp.reshape(scale.astype(x.dtype), shape) \

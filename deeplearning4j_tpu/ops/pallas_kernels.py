@@ -187,110 +187,6 @@ def make_softmax_override(interpret: bool = False):
     return softmax
 
 
-# -------------------------------------------------- fused conv epilogue
-
-def _scale_shift_act_kernel(x_ref, sc_ref, sh_ref, o_ref, *, alpha: float):
-    """One [block, C] tile of the bias+BN+activation epilogue: a single
-    VMEM read, per-channel FMA in the input dtype (the batch_norm
-    contract: scale/shift were computed fp32 and cast once), select,
-    single write. alpha=0 is relu; alpha>0 the leaky slope. The leaky
-    compare and select run in f32 — the v5e vector unit compares no
-    bf16 — on values already rounded to the input dtype, so the store's
-    cast is exact and the result is the generic op's."""
-    y = x_ref[:] * sc_ref[:] + sh_ref[:]
-    if alpha == 0.0:
-        o_ref[:] = jnp.maximum(y, 0)
-    else:
-        y32 = y.astype(jnp.float32)
-        o_ref[:] = jnp.where(y32 >= 0, y32,
-                             (alpha * y).astype(jnp.float32)
-                             ).astype(o_ref.dtype)
-
-
-def _scale_shift_act_pallas(x2d, scale, shift, alpha: float,
-                            interpret: bool):
-    n, d = x2d.shape
-    block = _row_block(n, d, x2d.dtype.itemsize)
-    return pl.pallas_call(
-        functools.partial(_scale_shift_act_kernel, alpha=alpha),
-        out_shape=jax.ShapeDtypeStruct((n, d), x2d.dtype),
-        grid=(n // block,),
-        in_specs=[
-            pl.BlockSpec((block, d), lambda i: (i, 0),
-                         memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, d), lambda i: (0, 0), memory_space=pltpu.VMEM),
-            pl.BlockSpec((1, d), lambda i: (0, 0), memory_space=pltpu.VMEM),
-        ],
-        out_specs=pl.BlockSpec((block, d), lambda i: (i, 0),
-                               memory_space=pltpu.VMEM),
-        interpret=interpret,
-        name="dl4j_scale_shift_act",
-    )(x2d, scale.reshape(1, d), shift.reshape(1, d))
-
-
-def epilogue_supported(x, axis: int) -> bool:
-    """Shapes the epilogue kernel takes: channels on the MINOR axis
-    (axis == ndim-1 — the NHWC seam's layout), lane dim a multiple of
-    128, collapsed row count a multiple of the dtype's sublane tile.
-    Everything else falls back to the generic (bit-identical) lowering."""
-    if axis != x.ndim - 1 or x.ndim < 2:
-        return False
-    d = x.shape[-1]
-    rows = 1
-    for s in x.shape[:-1]:
-        rows *= s
-    if d % 128 != 0 or d > 4096:
-        return False
-    sublane = 16 if x.dtype == jnp.bfloat16 else 8
-    if rows % sublane != 0:
-        return False
-    return x.dtype in (jnp.float32, jnp.bfloat16)
-
-
-def make_scale_shift_act_override(interpret: bool = False):
-    """The 'scale_shift_act' platform override: the conv stacks'
-    bias+BN+relu (and YOLO leaky-relu) epilogue as ONE VMEM pass.
-    custom_vjp keeps jax.grad working through it — the backward is
-    composed jnp (select mask + the two channel reductions), which XLA
-    fuses into the surrounding gradient program."""
-    from deeplearning4j_tpu.ops import normalization as norm_ops
-
-    @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-    def _ssa(x2d, scale, shift, alpha):
-        return _scale_shift_act_pallas(x2d, scale, shift, alpha, interpret)
-
-    def _fwd(x2d, scale, shift, alpha):
-        return _ssa(x2d, scale, shift, alpha), (x2d, scale, shift)
-
-    def _bwd(alpha, res, ct):
-        x2d, scale, shift = res
-        sc = scale.astype(x2d.dtype)[None, :]
-        y = x2d * sc + shift.astype(x2d.dtype)[None, :]
-        slope = jnp.where(y >= 0, jnp.ones((), jnp.float32),
-                          jnp.full((), alpha, jnp.float32))
-        g = ct.astype(jnp.float32) * slope
-        dx = (g * scale.astype(jnp.float32)[None, :]).astype(x2d.dtype)
-        dscale = jnp.sum(g * x2d.astype(jnp.float32), axis=0)
-        dshift = jnp.sum(g, axis=0)
-        return dx, dscale.astype(scale.dtype), dshift.astype(shift.dtype)
-
-    _ssa.defvjp(_fwd, _bwd)
-
-    def scale_shift_act(x, scale, shift, *, alpha: float = 0.0,
-                        axis: int = 1):
-        xa = jnp.asarray(x)
-        axis = axis % xa.ndim if xa.ndim else axis
-        if not epilogue_supported(xa, axis):
-            return norm_ops.scale_shift_act(xa, scale, shift, alpha=alpha,
-                                            axis=axis)
-        d = xa.shape[-1]
-        y = _ssa(xa.reshape(-1, d), jnp.asarray(scale).astype(xa.dtype),
-                 jnp.asarray(shift).astype(xa.dtype), float(alpha))
-        return y.reshape(xa.shape)
-
-    return scale_shift_act
-
-
 # ------------------------------------------------------- flash attention
 
 def _flash_fwd_kernel(q_ref, k_ref, v_ref, o_ref, lse_ref,
@@ -508,8 +404,6 @@ def install_platform_overrides(interpret: bool = False):
         "softmax", make_softmax_override(interpret))
     registry.register_platform_override(
         "flash_attention", make_flash_attention_override(interpret))
-    registry.register_platform_override(
-        "scale_shift_act", make_scale_shift_act_override(interpret))
 
 
 def uninstall_platform_overrides():
@@ -517,4 +411,3 @@ def uninstall_platform_overrides():
     registry.clear_platform_override("layer_norm")
     registry.clear_platform_override("softmax")
     registry.clear_platform_override("flash_attention")
-    registry.clear_platform_override("scale_shift_act")

@@ -10,7 +10,7 @@ conv))`` in the backward pass, ``dl4j_updater`` in the update. The scopes
 are emitted by both network forwards (``dl4j_L<i>_<name>``, see
 :func:`profiler.devicetime.scope_name`) and by the step builders
 (:data:`UPDATER_SCOPE`, :data:`LOSS_SCOPE`, :data:`AUGMENT_SCOPE`); the
-Pallas kernels carry a ``name=`` (``dl4j_scale_shift_act``, …).
+Pallas kernels carry a ``name=`` (``dl4j_layer_norm``, …).
 
 :func:`parse` turns a compiled module's text into ``{instruction name:
 Entry(phase, layer, kernel, mixed)}`` — a pure function of the text,

@@ -6,12 +6,20 @@ a bf16 compare, a tile over the scoped-VMEM limit). These cases hand the
 real kernel, ``interpret=False``, at the shapes the chip smoke runs, to
 the TPU compiler installed here — a compile, not a run.
 
+The conv path's fused BatchNorm+activation epilogue is no kernel: the
+last cases compile conv -> ``fused_bn_act`` -> conv at the benchmark
+cells' shapes and hold the compiler to what PR 27 took the Pallas
+epilogue out for — the FMA+select rides inside the neighbouring
+convolutions' fusions, with no ``custom-call`` and no copy or reshape of
+the activation map between them.
+
 The topology is described inside a fixture, in the test's own process,
 and in this one file: only one process may load the TPU library (see the
 on-chip-measurement guide, section 2).
 """
 
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -57,17 +65,6 @@ def _softmax(shape, dtype):
             pk.supported(jax.ShapeDtypeStruct(shape, dtype)))
 
 
-def _epilogue(alpha):
-    def build(shape, dtype):
-        ssa = pk.make_scale_shift_act_override(interpret=False)
-        c, axis = shape[-1], len(shape) - 1
-        return (lambda x, sc, sh: ssa(x, sc, sh, alpha=alpha, axis=axis),
-                [(shape, dtype), ((c,), dtype), ((c,), dtype)],
-                pk.epilogue_supported(jax.ShapeDtypeStruct(shape, dtype),
-                                      axis))
-    return build
-
-
 def _flash(shape, dtype):
     q = jax.ShapeDtypeStruct(shape, dtype)
     return (pk.make_flash_attention_override(interpret=False),
@@ -81,8 +78,6 @@ def _flash_grad(shape, dtype):
 
 
 _BUILDERS = {"layer_norm": _layer_norm, "softmax": _softmax,
-             "scale_shift_relu": _epilogue(0.0),
-             "scale_shift_leaky": _epilogue(0.01),
              "flash_fwd": _flash, "flash_grad": _flash_grad}
 
 # kernel, shape, dtype — the chip smoke's kernel-phase shapes first, then
@@ -93,16 +88,10 @@ _CASES = [
     ("layer_norm", (8, 768), "bfloat16"),
     ("softmax", (4096, 1024), "float32"),
     ("softmax", (4096, 1024), "bfloat16"),
-    ("scale_shift_relu", (256, 14, 14, 1024), "bfloat16"),
-    ("scale_shift_leaky", (32, 26, 26, 512), "bfloat16"),
-    ("scale_shift_leaky", (32, 13, 13, 1024), "bfloat16"),
-    ("scale_shift_leaky", (32, 104, 104, 128), "bfloat16"),
-    ("scale_shift_leaky", (32, 26, 26, 512), "float32"),
     ("flash_fwd", (32, 128, 12, 64), "bfloat16"),
     ("flash_grad", (32, 128, 12, 64), "bfloat16"),
     # the widest the gates admit, in f32: the row block shrinks to fit the
     # 16 MiB scoped VMEM limit (256 rows were 16.03 MiB)
-    ("scale_shift_relu", (4096, 4096), "float32"),
     ("layer_norm", (4096, 4096), "float32"),
     ("softmax", (4096, 4096), "float32"),
 ]
@@ -117,3 +106,62 @@ def test_kernel_compiles_for_v5e(one_chip, kernel, shape, dtype):
     specs = [jax.ShapeDtypeStruct(s, d, sharding=one_chip) for s, d in args]
     compiled = jax.jit(fn).lower(*specs).compile()
     assert "tpu_custom_call" in compiled.as_text()
+
+
+# ------------------------------------------------- the conv path's epilogue
+# activation shape between the two convolutions, epilogue slope: the
+# 128-multiple channel widths of resnet50-fit-b256 (relu) and
+# tinyyolo-fit-b256 (leaky) that the deleted kernel's gate admitted
+_EPILOGUE_CASES = [
+    ((256, 28, 28, 128), 0.0),
+    ((256, 14, 14, 256), 0.0),
+    ((256, 7, 7, 512), 0.0),
+    ((256, 52, 52, 128), 0.01),
+    ((256, 26, 26, 256), 0.01),
+    ((256, 13, 13, 1024), 0.01),
+]
+
+
+def _conv_bn_act_conv(alpha):
+    """1x1 conv -> fused BN+activation (train mode) -> 1x1 conv in NHWC,
+    forward and backward, as a train step runs a block's inside."""
+    from deeplearning4j_tpu.nn.layers import BatchNormalization, fused_bn_act
+    from deeplearning4j_tpu.ops import convolution as conv_ops
+    bn = BatchNormalization()
+    bn.data_format = "NHWC"
+
+    def forward(x, w1, gamma, beta, w2):
+        y = conv_ops.conv2d(x, w1, data_format="NHWC")
+        state = {"mean": jnp.zeros_like(gamma), "var": jnp.ones_like(gamma)}
+        y, _ = fused_bn_act(bn, {"gamma": gamma, "beta": beta}, state, y,
+                            True, alpha)
+        return conv_ops.conv2d(y, w2, data_format="NHWC")
+
+    return jax.grad(lambda *a: jnp.sum(forward(*a).astype(jnp.float32)),
+                    argnums=(0, 1, 2, 3, 4))
+
+
+@pytest.mark.parametrize(
+    "shape,alpha", _EPILOGUE_CASES,
+    ids=[f"{'x'.join(map(str, s))}-{'leaky' if a else 'relu'}"
+         for s, a in _EPILOGUE_CASES])
+def test_fused_epilogue_rides_in_the_conv_fusions(one_chip, shape, alpha):
+    pk.install_platform_overrides()     # as the benchmark's driver does
+    try:
+        c = shape[-1]
+        args = [(shape, jnp.bfloat16), ((c, c, 1, 1), jnp.bfloat16),
+                ((c,), jnp.float32), ((c,), jnp.float32),
+                ((c, c, 1, 1), jnp.bfloat16)]
+        specs = [jax.ShapeDtypeStruct(s, d, sharding=one_chip)
+                 for s, d in args]
+        text = jax.jit(_conv_bn_act_conv(alpha)).lower(*specs).compile() \
+            .as_text()
+    finally:
+        pk.uninstall_platform_overrides()
+    assert "custom-call(" not in text
+    # the scheduled program: what runs between the convolution fusions
+    entry = text[text.index("\nENTRY "):]
+    activation = "bf16[" + ",".join(map(str, shape)) + "]"
+    moved = re.findall(
+        r"= %s\S* (?:copy|reshape|transpose)\(" % re.escape(activation), entry)
+    assert not moved, moved

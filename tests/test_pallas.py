@@ -102,6 +102,20 @@ class TestPlatformDispatch:
         pk.uninstall_platform_overrides()
         assert registry.get("softmax") is registry._REGISTRY["softmax"]
 
+    def test_install_registers_the_three_kernels_and_no_other(self):
+        """The conv path's ``scale_shift_act`` epilogue has no kernel (PR
+        27): it stays the generic op whatever is installed."""
+        assert not registry._PLATFORM_OVERRIDES
+        pk.install_platform_overrides(interpret=True)
+        try:
+            assert set(registry._PLATFORM_OVERRIDES) == {
+                "layer_norm", "softmax", "flash_attention"}
+            assert registry.get("scale_shift_act") \
+                is registry._REGISTRY["scale_shift_act"]
+        finally:
+            pk.uninstall_platform_overrides()
+        assert not registry._PLATFORM_OVERRIDES
+
     def test_samediff_graph_uses_override(self, overrides):
         """A SameDiff graph records registry ops by name — the platform
         override applies when the graph executes."""
