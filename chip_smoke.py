@@ -354,7 +354,8 @@ def phase_kernels(args, phases):
 def phase_model_paths(args, phases):
     """One ``fit()`` step each of two more model paths: TinyYOLO's fused
     leaky epilogues (the compiler's own code, no kernel), and a LayerNorm
-    net with the kernel shown in the compiled step."""
+    net with the kernel shown in the compiled step; then a few steps of a
+    tiny looped language model (``_looped_lm``)."""
     import jax.numpy as jnp
     from deeplearning4j_tpu.data.dataset import DataSet
     from deeplearning4j_tpu.distributed.gspmd import compiled_train_step_hlo
@@ -402,12 +403,35 @@ def phase_model_paths(args, phases):
         out[name] = {"seconds": _split(first, [second]),
                      "losses": list(scores.history),
                      "pallas_calls_in_step_hlo": n_kernels}
+    out["looped_lm_bf16"] = _looped_lm(rng)
     phases.emit("model_paths", seconds={
         "compile": round(sum(v["seconds"]["compile"]
                              for v in out.values()), 3),
         "steady": round(sum(v["seconds"]["steady"]
                             for v in out.values()), 4)},
         yolo_batch=b, yolo_image=hw, **out)
+
+
+def _looped_lm(rng, steps: int = 6):
+    """A tiny looped language model (``zoo.Ouro``: a LoopVertex over two
+    decoder layers run four times, the exit-weighted head) fitted on one
+    token batch under bf16: finite, falling losses."""
+    from deeplearning4j_tpu.data.dataset import DataSet
+    from deeplearning4j_tpu.models import zoo
+    net = zoo.Ouro(num_layers=2, hidden_size=64, num_heads=4, head_dim=16,
+                   intermediate_size=176, vocab_size=512, total_ut_steps=4,
+                   seq_len=32).init()
+    net.setPrecisionPolicy("bf16")
+    rows = rng.integers(0, 512, (4, 33)).astype(np.int32)
+    ds = DataSet(rows[:, :-1], rows[:, 1:])
+    scores = _score_listener(net)
+    times = _timed_fits(net, ds, steps)
+    losses = [float(v) for v in scores.history]
+    _finite(losses, "looped_lm_bf16")
+    if not all(b < a for a, b in zip(losses, losses[1:])):
+        raise AssertionError(f"looped_lm_bf16: the loss of one repeated "
+                             f"batch does not fall: {losses}")
+    return {"seconds": _split(times[0], times[1:]), "losses": losses}
 
 
 def phase_pipeline_workers(phases):

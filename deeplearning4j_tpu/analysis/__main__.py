@@ -307,8 +307,12 @@ def main(argv=None) -> int:
 
     targets: List[Tuple[str, object]] = []
     if args.zoo:
-        targets.extend((name, cls().conf_builder())
-                       for name, cls in _zoo_registry().items())
+        # under the cost model a zoo model is judged at the size its class
+        # says one chip trains (``ZooModel.cost_gate_kwargs``)
+        targets.extend(
+            (name, (cls.for_cost_gate() if cost_spec is not None
+                    else cls()).conf_builder())
+            for name, cls in _zoo_registry().items())
     for t in args.targets:
         targets.extend(_resolve(t))
     for t in args.samediff:

@@ -556,6 +556,11 @@ def _check_reachability(nodes, outputs, report: ValidationReport) -> None:
             continue
         needed.add(name)
         stack.extend(r for r in by_name[name].inputs if r in by_name)
+        # a LoopVertex depends on the body node each pass hands on
+        handed_on = getattr(by_name[name].obj, "output", None) \
+            if _dist.is_loop(by_name[name]) else None
+        if handed_on in by_name:
+            stack.append(handed_on)
     for node in nodes:
         if node.name not in needed:
             report.add(Diagnostic(
@@ -569,15 +574,16 @@ def _propagate_graph(topo, input_types, preprocessors,
                      report: ValidationReport) -> None:
     from deeplearning4j_tpu.nn import preprocessors as pp
     types = dict(input_types)
+    loops = {}          # LoopVertex name -> every pass's type, outside it
     for node in topo:
         loc = _node_loc(node)
-        in_types = []
-        for ref in node.inputs:
-            t = types.get(ref)
-            if t is None:           # upstream already failed; stop here
-                return
-            in_types.append(t)
-        if node.kind == "layer":
+        in_types = _dist.loop_aware_inputs(node, types, loops)
+        if any(t is None for t in in_types):
+            return                  # upstream already failed; stop here
+        if _dist.is_loop(node):
+            types[node.name] = in_types[0]
+            loops[node.name] = node.obj.output_type(in_types[0])
+        elif node.kind == "layer":
             it = in_types[0]
             pre = preprocessors.get(node.name)
             if pre is None:

@@ -450,13 +450,18 @@ def _approx_flops(layer, it, out_it) -> int:
     term a transformer stage's FLOPs read as just its projections and
     the W105 stage-balance lint undercounts it (the PR-4 carried
     follow-up; same for conv-LSTM, whose gate convs now come from
-    ``ConvLSTM2D.param_shapes``)."""
+    ``ConvLSTM2D.param_shapes``). The causal layer counts the whole
+    square too: that is what a plain lowering executes. A layer in a
+    LoopVertex's body is counted for one application here; callers that
+    walk a graph multiply by :func:`loop_steps`."""
     hook = getattr(layer, "approx_flops", None)
     if hook is not None:     # declared-fact hook (graphir's IR entries)
         try:
             return int(hook())
         except Exception:
             return 0
+    if type(layer).__name__.startswith("Embedding"):
+        return 0             # a gather: its [V, d] table is no matmul
     shapes = getattr(layer, "param_shapes", lambda: {})()
     w = sum(_prod(s) for s in shapes.values() if len(s) >= 2)
     mult = 1
@@ -468,7 +473,32 @@ def _approx_flops(layer, it, out_it) -> int:
         mult = t if t > 0 else 1
     flops = 2 * w * mult
     flops += _attention_flops(layer, it)
-    return flops
+    # a head that reads every pass of a LoopVertex runs once a pass
+    return flops * max(int(getattr(it, "dims", {}).get("passes", 1)), 1)
+
+
+def is_loop(node) -> bool:
+    return type(node.obj).__name__ == "LoopVertex"
+
+
+def loop_aware_inputs(node, types, loops) -> List:
+    """``node``'s input types where a LoopVertex may be among its inputs:
+    inside its body a loop's name is the carried value (``types``), outside
+    it stands for every pass's output (``loops``). ``None`` for an input
+    whose type is unknown."""
+    return [loops[r] if r in loops and getattr(node, "loop", None) != r
+            else types.get(r) for r in node.inputs]
+
+
+def loop_steps(conf, node) -> int:
+    """How often a forward pass applies ``node``: the ``steps`` of the
+    LoopVertex whose body it is in, else 1."""
+    loop = getattr(node, "loop", None)
+    if loop is None:
+        return 1
+    vertex = next((n.obj for n in getattr(conf, "nodes", ())
+                   if n.name == loop), None)
+    return max(int(getattr(vertex, "steps", 1) or 1), 1)
 
 
 def _attention_flops(layer, it) -> int:
@@ -589,12 +619,16 @@ def _propagate_graph_types(conf) -> Dict[str, Tuple]:
     preprocessors = dict(getattr(conf, "preprocessors", {}) or {})
     types = dict(input_types)
     nodes = list(conf.nodes)
+    loops = {}          # LoopVertex name -> every pass's type, outside it
     for n in _graph_order_all(conf, nodes):
-        in_types = [types.get(r) for r in n.inputs]
+        in_types = loop_aware_inputs(n, types, loops)
         if any(t is None for t in in_types) or not in_types:
             continue
         try:
-            if n.kind == "layer":
+            if is_loop(n):
+                types[n.name] = in_types[0]
+                loops[n.name] = n.obj.output_type(in_types[0])
+            elif n.kind == "layer":
                 it = in_types[0]
                 pre = preprocessors.get(n.name)
                 if pre is None:

@@ -681,7 +681,8 @@ def from_graph(conf, batch_size: int = 1) -> GraphIR:
                 ir.tensors[full] = t
                 pnames.append(full)
             it, out_it = types.get(n.name, (None, None))
-            flops = _dist._approx_flops(n.obj, it, out_it)
+            flops = _dist._approx_flops(n.obj, it, out_it) \
+                * _dist.loop_steps(conf, n)
         else:
             out_it = None
         out_name = f"{n.name}:act"

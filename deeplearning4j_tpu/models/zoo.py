@@ -19,10 +19,14 @@ from deeplearning4j_tpu.nn.config import InputType, NeuralNetConfiguration
 from deeplearning4j_tpu.nn.graph import (ComputationGraph, ElementWiseVertex,
                                          MergeVertex)
 from deeplearning4j_tpu.nn.layers import (ActivationLayer, BatchNormalization,
+                                          CausalSelfAttentionLayer,
                                           ConvolutionLayer, DenseLayer,
-                                          DropoutLayer, GlobalPoolingLayer,
-                                          LocalResponseNormalization, LSTM,
-                                          OutputLayer, RnnOutputLayer,
+                                          DropoutLayer,
+                                          EmbeddingSequenceLayer, GatedMLP,
+                                          GlobalPoolingLayer,
+                                          LocalResponseNormalization,
+                                          LoopedLMOutputLayer, LSTM,
+                                          OutputLayer, RMSNorm, RnnOutputLayer,
                                           SeparableConvolution2D,
                                           SubsamplingLayer, Upsampling2D)
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
@@ -40,6 +44,15 @@ class ZooModel:
         self.input_shape = input_shape or self.default_input_shape()
         self.updater = updater or updaters.Adam(1e-3)
         self.dtype = dtype  # "bfloat16" enables the nn/ mixed-precision policy
+
+    #: constructor arguments for the size the cost gates judge
+    #: (``analysis --zoo --cost``, ``tools/lint.py``): the default,
+    #: unless the published size cannot train replicated on one chip
+    cost_gate_kwargs: dict = {}
+
+    @classmethod
+    def for_cost_gate(cls):
+        return cls(**cls.cost_gate_kwargs)
 
     def default_input_shape(self):
         return (3, 224, 224)  # (channels, H, W)
@@ -897,6 +910,80 @@ class NASNet(ZooModel):
         return ComputationGraph(g.build())
 
 
+class Ouro(ZooModel):
+    """Ouro, a looped language model (ByteDance 2025, "Scaling Latent
+    Reasoning via Looped Language Models", arXiv:2510.25741; defaults:
+    Ouro-2.6B's config.json). ``num_layers`` decoder layers held ONCE and
+    run ``total_ut_steps`` times a forward pass through a ``LoopVertex``:
+    token embedding, then per pass the layers (sandwich RMSNorm: a norm
+    before and after attention and before and after the SwiGLU MLP, each
+    sub-block added to the stream) and the final norm, whose output the
+    next pass starts from; after every pass an untied head and an exit
+    gate, trained with the exit-weighted loss (``LoopedLMOutputLayer``).
+    Trains on ``DataSet(int32 tokens [N, T], int32 next tokens [N, T])``.
+
+    The published 48 layers hold 2.67 B parameters: 40 GiB with Adam's
+    state, which no single chip trains replicated (the cost model's E120,
+    rightly). The cost gates therefore judge the six-layer stage that the
+    benchmark runs on one chip (``chipbench/configs/ouro-2.6b-l6-bf16``);
+    the structural lints take the whole model."""
+
+    cost_gate_kwargs = {"num_layers": 6}
+
+    def __init__(self, num_layers: int = 48, hidden_size: int = 2048,
+                 num_heads: int = 16, head_dim: int = 128,
+                 intermediate_size: int = 5632, vocab_size: int = 49152,
+                 total_ut_steps: int = 4, rms_norm_eps: float = 1e-6,
+                 rope_theta: float = 1e6, seq_len: int = 4096,
+                 beta: float = 0.1, **kw):
+        self.num_layers, self.hidden_size = int(num_layers), int(hidden_size)
+        self.num_heads, self.head_dim = int(num_heads), int(head_dim)
+        self.intermediate_size = int(intermediate_size)
+        self.vocab_size, self.seq_len = int(vocab_size), int(seq_len)
+        self.total_ut_steps = int(total_ut_steps)
+        self.rms_norm_eps, self.rope_theta = rms_norm_eps, rope_theta
+        self.beta = beta
+        kw.setdefault("updater", updaters.Adam(3e-4, beta2=0.95))
+        super().__init__(num_classes=vocab_size, **kw)
+
+    def default_input_shape(self):
+        return (self.vocab_size, self.seq_len)
+
+    def conf_builder(self) -> ComputationGraph:
+        vocab, seq_len = self.input_shape
+        g = (NeuralNetConfiguration.Builder()
+             .seed(self.seed).updater(self.updater).weightInit("xavier")
+             .graphBuilder())
+        g.addInputs("tokens")
+        g.setInputTypes(InputType.recurrent(vocab, seq_len))
+        g.addLayer("embed", EmbeddingSequenceLayer(nOut=self.hidden_size),
+                   "tokens")
+        g.beginLoop("ut", "embed", steps=self.total_ut_steps)
+        norm = lambda: RMSNorm(eps=self.rms_norm_eps)   # noqa: E731
+        h = "ut"
+        for i in range(self.num_layers):
+            p = f"l{i}_"
+            g.addLayer(p + "n1", norm(), h)
+            g.addLayer(p + "attn", CausalSelfAttentionLayer(
+                nHeads=self.num_heads, headSize=self.head_dim,
+                ropeTheta=self.rope_theta), p + "n1")
+            g.addLayer(p + "n2", norm(), p + "attn")
+            g.addVertex(p + "add1", ElementWiseVertex("Add"), h, p + "n2")
+            g.addLayer(p + "n3", norm(), p + "add1")
+            g.addLayer(p + "mlp", GatedMLP(nHidden=self.intermediate_size),
+                       p + "n3")
+            g.addLayer(p + "n4", norm(), p + "mlp")
+            g.addVertex(p + "add2", ElementWiseVertex("Add"), p + "add1",
+                        p + "n4")
+            h = p + "add2"
+        g.addLayer("fnorm", norm(), h)
+        g.endLoop("fnorm")
+        g.addLayer("lm", LoopedLMOutputLayer(nOut=vocab, beta=self.beta),
+                   "ut")
+        g.setOutputs("lm")
+        return ComputationGraph(g.build())
+
+
 #: Name -> class registry of every shipped architecture (ref:
 #: ZooModel.select-by-name in the reference's zoo). The analysis CLI's
 #: ``--zoo`` mode lints each of these; ``all_zoo_models()`` instantiates
@@ -905,7 +992,7 @@ ZOO_MODELS = {cls.__name__: cls for cls in
               (LeNet, SimpleCNN, AlexNet, VGG16, VGG19, ResNet50,
                Darknet19, SqueezeNet, UNet, Xception, FaceNetNN4Small2,
                TextGenerationLSTM, TinyYOLO, YOLO2, InceptionResNetV1,
-               NASNet)}
+               NASNet, Ouro)}
 
 
 def all_zoo_models():

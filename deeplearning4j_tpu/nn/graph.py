@@ -15,6 +15,7 @@ Multiple inputs and multiple outputs (MultiDataSet) are supported.
 
 from __future__ import annotations
 
+import contextlib
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -243,19 +244,73 @@ class PreprocessorVertex(GraphVertex):
         return PreprocessorVertex(obj)
 
 
+class LoopVertex(GraphVertex):
+    """Runs a sub-graph of this graph ``steps`` times on its own output
+    with ONE set of parameters (a looped / universal-transformer stack).
+
+    Built with ``GraphBuilder.beginLoop(name, input, steps)`` ...
+    ``endLoop(output)``: the nodes added in between are the body. They are
+    ordinary nodes of the graph — each layer's parameters, updater state,
+    checkpoint entries and sharding paths exist once, under the layer's
+    own name — that read the carried value under the loop's name and are
+    applied ``steps`` times a forward pass; a looped weight's gradient is
+    the sum over its uses. Pass 1 starts from the loop's input; pass t+1
+    from ``output``'s value in pass t, which must have the input's type.
+    Outside the body the loop's name stands for EVERY pass's output, in
+    order: a layer that takes passes (``LoopedLMOutputLayer``) reads them
+    all, :class:`PassVertex` picks one for an ordinary layer. With
+    ``steps=1`` the graph computes exactly what the body's nodes stacked
+    plainly compute. The loop is unrolled into the one compiled step
+    (every pass's ops carry ``dl4j_ut<t>``); in a train step each
+    single-entry, single-exit stretch of the body is rematerialised in
+    the backward pass (see ``ComputationGraph._forward``)."""
+
+    def __init__(self, steps: int = 1, output: str = None):
+        steps = int(steps)
+        if steps < 1:
+            raise ValueError(f"LoopVertex: steps must be >= 1, got {steps}")
+        self.steps = steps
+        self.output = output
+
+    def apply(self, *inputs):
+        raise NotImplementedError(
+            "a LoopVertex is run by ComputationGraph._forward, which "
+            "applies its body; it has no apply of its own")
+
+    def output_type(self, it: InputType) -> InputType:
+        return InputType(it.kind, **{**it.dims, "passes": self.steps})
+
+
+class PassVertex(GraphVertex):
+    """One pass's output of a :class:`LoopVertex` (``index`` counts from
+    0; -1 is the last pass), for an ordinary layer to read."""
+
+    def __init__(self, index: int = -1):
+        self.index = int(index)
+
+    def apply(self, passes):
+        return passes[self.index]
+
+    def output_type(self, it: InputType) -> InputType:
+        return InputType(it.kind, **{k: v for k, v in it.dims.items()
+                                     if k != "passes"})
+
+
 _VERTEX_CLASSES = {c.__name__: c for c in
                    [MergeVertex, ElementWiseVertex, SubsetVertex,
                     DotProductVertex, L2NormalizeVertex, ScaleVertex,
                     ShiftVertex, StackVertex, UnstackVertex,
-                    PreprocessorVertex]}
+                    PreprocessorVertex, LoopVertex, PassVertex]}
 
 
 class _GraphNode:
-    def __init__(self, name: str, kind: str, obj, inputs: List[str]):
+    def __init__(self, name: str, kind: str, obj, inputs: List[str],
+                 loop: str = None):
         self.name = name
         self.kind = kind      # 'layer' | 'vertex'
         self.obj = obj
         self.inputs = inputs
+        self.loop = loop      # the LoopVertex whose body this node is in
 
 
 class GraphBuilder:
@@ -267,6 +322,7 @@ class GraphBuilder:
         self.graph_inputs: List[str] = []
         self.graph_outputs: List[str] = []
         self.input_types: Dict[str, InputType] = {}
+        self._loop: Optional[str] = None    # the loop being built, if any
 
     def addInputs(self, *names):
         self.graph_inputs.extend(names)
@@ -279,11 +335,33 @@ class GraphBuilder:
 
     def addLayer(self, name: str, layer, *inputs):
         layer.name = name
-        self.nodes.append(_GraphNode(name, "layer", layer, list(inputs)))
+        self.nodes.append(_GraphNode(name, "layer", layer, list(inputs),
+                                     self._loop))
         return self
 
     def addVertex(self, name: str, vertex: GraphVertex, *inputs):
-        self.nodes.append(_GraphNode(name, "vertex", vertex, list(inputs)))
+        self.nodes.append(_GraphNode(name, "vertex", vertex, list(inputs),
+                                     self._loop))
+        return self
+
+    def beginLoop(self, name: str, input: str, steps: int):
+        """Open a :class:`LoopVertex`: until ``endLoop`` every node added
+        belongs to its body, where ``name`` is the carried value."""
+        if self._loop is not None:
+            raise ValueError(f"beginLoop('{name}'): loop '{self._loop}' is "
+                             f"still open; loops do not nest")
+        self.addVertex(name, LoopVertex(steps), input)
+        self._loop = name
+        return self
+
+    def endLoop(self, output: str):
+        """Close the open loop; ``output`` is the body node whose value
+        the next pass starts from and each pass hands out."""
+        if self._loop is None:
+            raise ValueError("endLoop: no loop is open")
+        loop = next(n for n in self.nodes if n.name == self._loop)
+        loop.obj.output = output
+        self._loop = None
         return self
 
     def setOutputs(self, *names):
@@ -291,6 +369,9 @@ class GraphBuilder:
         return self
 
     def build(self) -> "ComputationGraphConfiguration":
+        if self._loop is not None:
+            raise ValueError(f"build: loop '{self._loop}' was never closed "
+                             f"(endLoop)")
         return ComputationGraphConfiguration(self)
 
     def validate(self, batch_size: int = None, data_devices: int = None,
@@ -332,9 +413,10 @@ class ComputationGraphConfiguration:
         return analyze(self, batch_size=batch_size,
                        data_devices=data_devices, **kw)
 
-    def _toposort(self):
-        order, seen = [], set(self.graph_inputs)
-        remaining = list(self.nodes)
+    @staticmethod
+    def _sorted(nodes, seen):
+        order, seen = [], set(seen)
+        remaining = list(nodes)
         while remaining:
             progressed = False
             for n in list(remaining):
@@ -346,10 +428,67 @@ class ComputationGraphConfiguration:
             if not progressed:
                 missing = {i for n in remaining for i in n.inputs if i not in seen}
                 raise ValueError(f"graph has unresolved inputs/cycle: {missing}")
+        return order, seen
+
+    def _toposort(self):
+        """``topo``: every node once, in an order a forward pass can
+        follow, a loop's body right after its :class:`LoopVertex`. A body
+        reads its own nodes, the carried value and what exists before the
+        loop; nothing outside reads a body node but through the loop."""
+        top, seen_all = self._sorted(
+            [n for n in self.nodes if n.loop is None], self.graph_inputs)
+        self.loop_bodies: Dict[str, List[_GraphNode]] = {}
+        self.loop_segments: Dict[str, List[List[_GraphNode]]] = {}
+        order, seen = [], set(self.graph_inputs)
+        for n in top:
+            order.append(n)
+            seen.add(n.name)
+            if not isinstance(n.obj, LoopVertex):
+                continue
+            body, _ = self._sorted(
+                [b for b in self.nodes if b.loop == n.name], seen)
+            out = n.obj.output
+            if not body or out not in {b.name for b in body}:
+                raise ValueError(
+                    f"loop '{n.name}': endLoop must name one of its body's "
+                    f"nodes, got {out!r}")
+            order.extend(body)
+            self.loop_bodies[n.name] = body
+            self.loop_segments[n.name] = self._segments(n.name, body, out)
+        stray = [b.name for b in self.nodes
+                 if b.loop is not None and b.loop not in self.loop_bodies]
+        if stray:
+            raise ValueError(f"nodes {stray} belong to a loop the graph "
+                             f"does not have")
         self.topo = order
+
+    @staticmethod
+    def _segments(loop, body, out):
+        """The body cut wherever exactly one value goes on (the carried
+        value or a body node's output that a later body node, or the next
+        pass, reads): the smallest single-entry, single-exit stretches,
+        which the train step rematerialises one at a time. A residual
+        block is one stretch (its input is read again by the add), a
+        plain chain is one a node."""
+        made = {loop}
+        segs, cur = [], []
+        for i, node in enumerate(body):
+            cur.append(node)
+            made.add(node.name)
+            later = {r for b in body[i + 1:] for r in b.inputs}
+            live = {v for v in made if v in later}
+            if i == len(body) - 1:
+                live.add(out)
+            if len(live) == 1:
+                segs.append(cur)
+                cur = []
+        if cur:
+            segs.append(cur)
+        return segs
 
     def _propagate_types(self):
         types: Dict[str, InputType] = dict(self.input_types)
+        carried: Dict[str, InputType] = {}
         for node in self.topo:
             in_types = [types[i] for i in node.inputs]
             if node.kind == "layer":
@@ -361,8 +500,20 @@ class ComputationGraphConfiguration:
                 layer.set_defaults(self.base)
                 layer.infer_nin(in_types[0])
                 types[node.name] = layer.output_type(in_types[0])
+            elif isinstance(node.obj, LoopVertex):
+                # inside the body the loop's name is the carried value
+                types[node.name] = carried[node.name] = in_types[0]
             else:
                 types[node.name] = node.obj.output_type(*in_types)
+            loop = node.loop
+            if loop is not None and node is self.loop_bodies[loop][-1]:
+                vertex = self.node_by_name[loop].obj
+                if types[vertex.output] != carried[loop]:
+                    raise ValueError(
+                        f"loop '{loop}': its body turns {carried[loop]} "
+                        f"into {types[vertex.output]}; a pass must hand "
+                        f"on the type it took")
+                types[loop] = vertex.output_type(carried[loop])
         self.types = types
 
     def to_json(self) -> str:
@@ -373,7 +524,8 @@ class ComputationGraphConfiguration:
             "outputs": self.graph_outputs,
             "input_types": {k: v.to_config() for k, v in self.input_types.items()},
             "nodes": [{"name": n.name, "kind": n.kind,
-                       "inputs": n.inputs, "conf": n.obj.to_config()}
+                       "inputs": n.inputs, "conf": n.obj.to_config(),
+                       **({"loop": n.loop} if n.loop else {})}
                       for n in self.nodes],
         })
 
@@ -386,12 +538,14 @@ class ComputationGraphConfiguration:
         b.input_types = {k: InputType.from_config(v)
                          for k, v in d["input_types"].items()}
         for nd in d["nodes"]:
+            b._loop = nd.get("loop")
             if nd["kind"] == "layer":
                 obj = L.layer_from_config(nd["conf"])
                 b.addLayer(nd["name"], obj, *nd["inputs"])
             else:
                 cls = _VERTEX_CLASSES[nd["conf"]["@class"]]
                 b.addVertex(nd["name"], cls.from_config(nd["conf"]), *nd["inputs"])
+        b._loop = None
         b.setOutputs(*d["outputs"])
         return ComputationGraphConfiguration(b)
 
@@ -466,7 +620,17 @@ class ComputationGraph:
         return L.compute_dtype_of(self.conf.base.dtype)
 
     def _forward(self, params, states, inputs: Dict[str, Any], train, key,
-                 fmask=None):
+                 fmask=None, remat: bool = False, heads: Dict = None):
+        """One forward pass over ``conf.topo``. ``remat``: the train step
+        asks for each single-entry, single-exit stretch of a loop's body
+        (``conf.loop_segments``; of a loop of more than one pass) to be
+        rematerialised in the backward pass, so a looped stack keeps one
+        activation a stretch and pass
+        instead of every layer's internals ``steps`` times over; a graph
+        without a loop compiles the same program either way. ``heads``:
+        a dict the loss wants filled with ``{output name: (cast params,
+        input)}`` for output layers that compute their loss from their
+        input (``loss_from_input``) and are then not applied."""
         cdt = self._compute_dtype()
         nhwc = self._compute_layout == "NHWC"
         plan = self._ensure_epilogue_plan() if self._fuse_epilogues else {}
@@ -477,14 +641,17 @@ class ComputationGraph:
                    if cdt is None and getattr(v, "dtype", None) == jnp.uint8
                    else v)
                for k, v in inputs.items()}   # on-device image-byte cast
-        fmt = {k: False for k in env}        # node name -> output is NHWC
+        # node name -> its output is in the compute layout (NHWC for image
+        # maps, feature-last for sequences), not the public one
+        fmt = {k: False for k in env}
         pending_bias: Dict[str, Any] = {}    # fused conv name -> cast bias
         # shared folded convs: env[] holds the BIAS-LESS output (what the
         # fused BN wants); every other consumer reads this re-biased copy
         # (bit-identical to the unfused conv, see L.conv_bias_add)
         biased: Dict[str, Any] = {}
+        index_of = {n.name: i for i, n in enumerate(self.conf.topo)}
 
-        def read(name, consumer=None):
+        def read(env, name, consumer=None):
             if name in biased:
                 if consumer is not None and consumer in plan \
                         and plan[consumer][1] == name:
@@ -492,8 +659,9 @@ class ComputationGraph:
                 return biased[name]
             return env[name]
 
-        new_states = {}
-        for ti, node in enumerate(self.conf.topo):
+        def apply_node(node, params, states, env, fmt, new_states, key):
+            """Run one node: fills ``env``, ``fmt`` and ``new_states``
+            under the node's name and returns the key to go on with."""
             if node.name in fused_act:
                 # folded into its BN's scale_shift_act epilogue; keep the
                 # RNG stream identical to the unfused forward
@@ -501,19 +669,21 @@ class ComputationGraph:
                 env[node.name] = env[fused_act[node.name]]
                 fmt[node.name] = fmt[fused_act[node.name]]
                 new_states[node.name] = states[node.name]
-                continue
+                return key
             # every op of a node, the casts and layout steps around its
             # apply included, carries the node's scope: the step-program
             # map (profiler.stepprogram) reads layer and phase off it
-            with jax.named_scope(_devicetime.scope_name(ti, node.name)):
+            with jax.named_scope(_devicetime.scope_name(index_of[node.name],
+                                                        node.name)):
                 if node.kind == "layer":
-                    x = read(node.inputs[0], node.name)
+                    x = read(env, node.inputs[0], node.name)
                     cur_nhwc = fmt[node.inputs[0]]
                     if node.name in self.conf.preprocessors:
                         if cur_nhwc:
-                            x, cur_nhwc = L.to_nchw(x), False
+                            x, cur_nhwc = L.to_public(x), False
                         x = self.conf.preprocessors[node.name](x)
-                    x, cur_nhwc = L.layout_step(node.obj, x, cur_nhwc, nhwc)
+                    x, cur_nhwc = L.layout_step(node.obj, x, cur_nhwc, nhwc,
+                                                sequences=True)
                     p = params[node.name]
                     if cdt is not None:
                         p, x = L.policy_cast(node.obj, p, x, cdt)
@@ -530,6 +700,10 @@ class ComputationGraph:
                         if node.name in shared:
                             biased[node.name] = L.conv_bias_add(
                                 node.obj, out, p.get("b"))
+                    elif heads is not None and \
+                            getattr(node.obj, "loss_from_input", False):
+                        heads[node.name] = (p, x)
+                        out, ns = None, states[node.name]
                     elif isinstance(node.obj, _MASK_AWARE):
                         out, ns = node.obj.apply(p, states[node.name],
                                                  x, train, sub, mask=fmask)
@@ -537,17 +711,26 @@ class ComputationGraph:
                         out, ns = node.obj.apply(p, states[node.name],
                                                  x, train, sub)
                     new_states[node.name] = ns
-                    fmt[node.name] = cur_nhwc and getattr(out, "ndim", 0) == 4
+                    fmt[node.name] = cur_nhwc and \
+                        getattr(out, "ndim", 0) == L.rank_of(x)
                 else:
-                    xs = [read(i) for i in node.inputs]
+                    xs = [read(env, i) for i in node.inputs]
                     in_fmts = [fmt[i] for i in node.inputs]
                     transparent = isinstance(
                         node.obj, (ElementWiseVertex, ScaleVertex,
-                                   ShiftVertex))
+                                   ShiftVertex, PassVertex))
                     if transparent and any(in_fmts) and all(in_fmts):
-                        out_nhwc = True            # elementwise: keep NHWC
+                        out_nhwc = True        # elementwise: keep layout
+                    elif transparent and any(in_fmts) \
+                            and all(L.rank_of(a) == 3 for a in xs):
+                        # sequences: the one input still [N, C, T] (an
+                        # embedding meeting the residual stream) turns
+                        # once, not the stream at every add
+                        xs = [a if f else jnp.swapaxes(a, 1, 2)
+                              for a, f in zip(xs, in_fmts)]
+                        out_nhwc = True
                     else:
-                        xs = [L.to_nchw(a) if f else a
+                        xs = [L.to_public(a) if f else a
                               for a, f in zip(xs, in_fmts)]
                         out_nhwc = False
                     if cdt is not None and len(xs) > 1:
@@ -559,9 +742,68 @@ class ComputationGraph:
                                   if getattr(a, "dtype", None) == jnp.float32
                                   else a for a in xs]
                     out = node.obj.apply(*xs)
-                    fmt[node.name] = out_nhwc and getattr(out, "ndim", 0) == 4
+                    fmt[node.name] = out_nhwc and \
+                        getattr(out, "ndim", 0) in (3, 4)
             env[node.name] = out
-        return [L.to_nchw(read(o)) if fmt.get(o) else read(o)
+            return key
+
+        def run_segment(seg, src, env, fmt, new_states, key, checkpointed):
+            """One stretch of a loop's body, from the one value ``src``
+            that enters it to its last node's output; ``checkpointed``:
+            rematerialise it in the backward pass."""
+            names = [n.name for n in seg]
+
+            def stretch(p_seg, s_seg, x, key):
+                local, local_fmt, ns = dict(env), dict(fmt), {}
+                local[src] = x
+                with L.rematerialised_stretch() if checkpointed \
+                        else contextlib.nullcontext():
+                    for n in seg:
+                        key = apply_node(n, p_seg, s_seg, local, local_fmt,
+                                         ns, key)
+                fmt.update({k: local_fmt[k] for k in names})
+                return local[names[-1]], ns, key
+
+            if checkpointed:
+                stretch = jax.checkpoint(stretch)
+            out, ns, key = stretch(
+                {k: params[k] for k in names if k in params},
+                {k: states[k] for k in names if k in states}, env[src], key)
+            env[names[-1]] = out
+            new_states.update(ns)
+            return key
+
+        def run_loop(node, key):
+            """Every pass of a LoopVertex, unrolled; the loop's name then
+            stands for the tuple of the passes' outputs."""
+            loop = node.obj
+            x = read(env, node.inputs[0])
+            cur = fmt[node.inputs[0]]
+            passes = []
+            for t in range(loop.steps):
+                with jax.named_scope(_stepprogram.pass_scope(t + 1)):
+                    env[node.name], fmt[node.name] = x, cur
+                    src = node.name
+                    for seg in self.conf.loop_segments[node.name]:
+                        # one pass IS the plain stack, program and all
+                        key = run_segment(seg, src, env, fmt, new_states,
+                                          key, remat and loop.steps > 1)
+                        src = seg[-1].name
+                    x, cur = env[loop.output], fmt[loop.output]
+                    passes.append(x)
+            env[node.name], fmt[node.name] = tuple(passes), cur
+            return key
+
+        new_states = {}
+        for node in self.conf.topo:
+            if node.loop is not None:
+                continue                # run by its loop, every pass
+            if isinstance(node.obj, LoopVertex):
+                key = run_loop(node, key)
+            else:
+                key = apply_node(node, params, states, env, fmt, new_states,
+                                 key)
+        return [L.to_public(read(env, o)) if fmt.get(o) else read(env, o)
                 for o in self.conf.graph_outputs], new_states
 
     def _as_input_dict(self, inputs) -> Dict[str, jnp.ndarray]:
@@ -631,6 +873,10 @@ class ComputationGraph:
     def feedForward(self, inputs, train: bool = False):
         """Per-node activations, PUBLIC layout (NCHW) even under the
         NHWC compute seam."""
+        if self.conf.loop_bodies:
+            raise ValueError(
+                "feedForward: a node in a loop's body has one activation a "
+                "pass; read the passes with output() through a PassVertex")
         ins = self._as_input_dict(inputs)
         env = dict(ins)
         key = jax.random.PRNGKey(0)
@@ -643,9 +889,10 @@ class ComputationGraph:
                 cur_nhwc = fmt[node.inputs[0]]
                 if node.name in self.conf.preprocessors:
                     if cur_nhwc:
-                        x, cur_nhwc = L.to_nchw(x), False
+                        x, cur_nhwc = L.to_public(x), False
                     x = self.conf.preprocessors[node.name](x)
-                x, cur_nhwc = L.layout_step(node.obj, x, cur_nhwc, nhwc)
+                x, cur_nhwc = L.layout_step(node.obj, x, cur_nhwc, nhwc,
+                                            sequences=True)
                 key, sub = jax.random.split(key)
                 if isinstance(node.obj, _MASK_AWARE):
                     out, _ = node.obj.apply(self._params[node.name],
@@ -654,14 +901,15 @@ class ComputationGraph:
                 else:
                     out, _ = node.obj.apply(self._params[node.name],
                                             self._states[node.name], x, train, sub)
-                fmt[node.name] = cur_nhwc and getattr(out, "ndim", 0) == 4
+                fmt[node.name] = cur_nhwc and \
+                    getattr(out, "ndim", 0) == L.rank_of(x)
             else:
-                xs = [L.to_nchw(env[i]) if fmt[i] else env[i]
+                xs = [L.to_public(env[i]) if fmt[i] else env[i]
                       for i in node.inputs]
                 out = node.obj.apply(*xs)
                 fmt[node.name] = False
             env[node.name] = out
-            acts[node.name] = L.to_nchw(out) if fmt[node.name] else out
+            acts[node.name] = L.to_public(out) if fmt[node.name] else out
         return acts
 
     # ------------------------------------------------------------------ loss
@@ -675,13 +923,23 @@ class ComputationGraph:
         return outs
 
     def _loss_and_reg(self, params, states, ins, labels: List, train, key,
-                      fmask, lmasks: Optional[List]):
-        outs, new_states = self._forward(params, states, ins, train, key, fmask)
+                      fmask, lmasks: Optional[List], remat: bool = False):
+        heads: Dict[str, Any] = {}
+        outs, new_states = self._forward(params, states, ins, train, key,
+                                         fmask, remat=remat, heads=heads)
         with jax.named_scope(_stepprogram.LOSS_SCOPE):
             out_layers = self._output_layers()
             loss = 0.0
             for i, (ol, out) in enumerate(zip(out_layers, outs)):
                 lm = lmasks[i] if lmasks is not None else None
+                if ol.name in heads:
+                    # the layer works its loss out from its input (one
+                    # pass's logits alive at a time) and hands its
+                    # per-pass readings on as its state
+                    l, new_states[ol.name] = ol.loss_from(
+                        *heads[ol.name], labels[i], mask=lm)
+                    loss = loss + l
+                    continue
                 loss = loss + ol.compute_loss(labels[i], out, mask=lm)
             reg = 0.0
             for node in self.conf.topo:
@@ -706,7 +964,10 @@ class ComputationGraph:
     def _make_train_step(self, with_lmasks: bool, steps: int = 1):
         """Compile the train step; ``steps=K`` wraps the SAME body in one
         lax.scan program doing K update steps per dispatch (see
-        MultiLayerNetwork._make_train_step)."""
+        MultiLayerNetwork._make_train_step). The step builder, not the
+        user, decides what is rematerialised: the body of every
+        LoopVertex, a stretch at a time (``_forward(remat=True)``); a
+        graph without a loop compiles the program it always did."""
         base = self.conf.base
         updater = base.updater
 
@@ -741,7 +1002,7 @@ class ComputationGraph:
             def loss_fn(p):
                 loss, ns = self._loss_and_reg(
                     p, states, ins, labels, True, key,
-                    None, lmasks if with_lmasks else None)
+                    None, lmasks if with_lmasks else None, remat=True)
                 if loss_scale:
                     loss = loss * loss_scale
                 return loss, ns
@@ -793,7 +1054,7 @@ class ComputationGraph:
             def loss_fn(p):
                 loss, ns = self._loss_and_reg(
                     p, states, ins, labels, True, key,
-                    None, lmasks if with_lmasks else None)
+                    None, lmasks if with_lmasks else None, remat=True)
                 return loss * scale, ns
             (loss, new_states), grads = \
                 jax.value_and_grad(loss_fn, has_aux=True)(params)
@@ -955,7 +1216,8 @@ class ComputationGraph:
         shared: set = set()          # folded convs with extra consumers
         by_name = conf.node_by_name
         for node in conf.topo:
-            if node.kind != "layer" or not L.fusable_bn(node.obj):
+            if node.kind != "layer" or node.loop is not None \
+                    or not L.fusable_bn(node.obj):
                 continue
             cons = consumers.get(node.name, [])
             if len(cons) != 1 or cons[0] == "__output__":
@@ -1126,6 +1388,7 @@ class ComputationGraph:
                         lst.onEpochEnd(self)
                 if session is not None:
                     session.on_epoch_end()
+        _stepping.publish_loop_gauges(self)
         return self
 
     def _fit_one(self, ds):
@@ -1134,14 +1397,15 @@ class ComputationGraph:
         spans = _stepping.step_spans(self)
         spans.phase(_stepping.FIT_STAGE)
         stage = lambda a: _stepping.stage_batch(self, a)
+        stage_x = lambda a: _stepping.stage_batch(self, a, features=True)
         if isinstance(ds, MultiDataSet):
-            ins = {name: stage(a)
+            ins = {name: stage_x(a)
                    for name, a in zip(self.conf.graph_inputs, ds.features)}
             labels = [stage(a) for a in ds.labels]
             lmasks = [stage(m) for m in ds.labels_masks] \
                 if ds.labels_masks else None
         else:
-            ins = {self.conf.graph_inputs[0]: stage(ds.features)}
+            ins = {self.conf.graph_inputs[0]: stage_x(ds.features)}
             labels = [stage(ds.labels)]
             lmasks = [stage(ds.labels_mask)] if ds.labels_mask is not None else None
         spans.phase(_stepping.FIT_PREPARE)
@@ -1220,14 +1484,16 @@ class ComputationGraph:
         spans = _stepping.step_spans(self, k)
         spans.phase(_stepping.FIT_STAGE)
         stage = lambda a: _stepping.stage_batch(self, a, mega=True)
+        stage_x = lambda a: _stepping.stage_batch(self, a, mega=True,
+                                                  features=True)
         if mb.multi:
-            ins = {name: stage(a)
+            ins = {name: stage_x(a)
                    for name, a in zip(self.conf.graph_inputs, mb.features)}
             labels = [stage(a) for a in mb.labels]
             lmasks = [stage(m) for m in mb.labels_mask] \
                 if mb.labels_mask else None
         else:
-            ins = {self.conf.graph_inputs[0]: stage(mb.features)}
+            ins = {self.conf.graph_inputs[0]: stage_x(mb.features)}
             labels = [stage(mb.labels)]
             lmasks = [stage(mb.labels_mask)] \
                 if mb.labels_mask is not None else None

@@ -15,6 +15,7 @@ the RETAIN probability, applied to the layer's input.
 
 from __future__ import annotations
 
+import contextlib
 import difflib
 import inspect
 from typing import Any, Dict, Optional, Tuple
@@ -31,6 +32,7 @@ from deeplearning4j_tpu.ops import convolution as conv_ops
 from deeplearning4j_tpu.ops import losses as loss_ops
 from deeplearning4j_tpu.ops import normalization as norm_ops
 from deeplearning4j_tpu.ops import recurrent as rnn_ops
+from deeplearning4j_tpu.profiler import stepprogram as _stepprogram
 
 
 def _pair(v):
@@ -1607,11 +1609,16 @@ def policy_cast(layer, params, x, compute_dt):
         elif x.dtype == jnp.uint8:
             x = x.astype(jnp.float32)
         return params, x
+    if getattr(layer, "loss_from_input", False):
+        # fp32 master params, activations as they come: the layer casts
+        # its matmul operands itself and keeps float32 logits
+        return params, x
     if isinstance(layer, BaseOutputLayer):
         if jnp.issubdtype(x.dtype, jnp.floating) and x.dtype != jnp.float32:
             x = x.astype(jnp.float32)
         return params, x
-    if isinstance(layer, _POLICY_FP32_PARAM_LAYERS):
+    if isinstance(layer, _POLICY_FP32_PARAM_LAYERS) \
+            or getattr(layer, "fp32_params", False):
         return params, x
     if jnp.issubdtype(x.dtype, jnp.floating) and x.dtype != compute_dt:
         x = x.astype(compute_dt)
@@ -1654,13 +1661,45 @@ def to_nchw(x):
     return jnp.transpose(x, (0, 3, 1, 2))
 
 
-def layout_step(layer, x, cur_nhwc: bool, nhwc_active: bool):
+def rank_of(x) -> int:
+    """Rank of an array, or of one pass of a loop's passes."""
+    if isinstance(x, tuple):
+        return rank_of(x[0])
+    return getattr(x, "ndim", 0)
+
+
+def to_public(x):
+    """Compute layout back to the public one: NHWC -> NCHW for an image
+    map, feature-last [N, T, C] -> the reference's [N, C, T] for a
+    sequence, each pass of a loop's passes alike."""
+    if isinstance(x, tuple):
+        return tuple(to_public(a) for a in x)
+    return to_nchw(x) if x.ndim == 4 else jnp.swapaxes(x, 1, 2)
+
+
+def layout_step(layer, x, cur_nhwc: bool, nhwc_active: bool,
+                sequences: bool = False):
     """THE transpose-at-boundary rule, one layer at a time: returns
     ``(x, now_nhwc)``. Aware layers pull spatial input into NHWC,
     transparent layers keep whatever flows in, everything else (dense,
     output heads, preprocess boundaries) forces NCHW back. Shared by the
     compiled forwards, ``feedForward``, the sanitizer's eager replay
-    walkers, and the devicetime bridge so the mirrors cannot drift."""
+    walkers, and the devicetime bridge so the mirrors cannot drift.
+
+    ``sequences`` (the graph's forwards): a 3-D [N, C, T] sequence is
+    held feature-last, [N, T, C], through the layers that compute that
+    way (:data:`SEQUENCE_LAST`, always: the contracted axis belongs on
+    the lanes, there is nothing to choose) and the transparent ones, and
+    turned back for any other layer; a loop's passes turn together."""
+    if sequences and isinstance(x, tuple):
+        steps = [layout_step(layer, a, cur_nhwc, nhwc_active, True)
+                 for a in x]
+        return tuple(a for a, _ in steps), steps[0][1]
+    if sequences and getattr(x, "ndim", 0) == 3 \
+            and jnp.issubdtype(x.dtype, jnp.floating):
+        want = isinstance(layer, SEQUENCE_LAST) or \
+            (cur_nhwc and isinstance(layer, LAYOUT_TRANSPARENT))
+        return (jnp.swapaxes(x, 1, 2) if want != cur_nhwc else x), want
     if getattr(x, "ndim", 0) != 4:
         return x, False
     want = (nhwc_active and isinstance(layer, LAYOUT_AWARE)) or \
@@ -2425,3 +2464,334 @@ class TimeDistributed(Layer):
 
     def output_type(self, it: InputType) -> InputType:
         return InputType.recurrent(self.nOut, it.dims.get("timesteps", -1))
+
+
+# ------------------------------------------- feature-last sequence layers
+# The blocks of a decoder-only language model. Inside a ComputationGraph
+# they hold a sequence feature-last, [N, T, C] (``layout_step`` turns the
+# reference's [N, C, T] once at each boundary, as it does for NHWC), so
+# every matmul contracts the minor axis and no layer transposes.
+
+_REMAT_STRETCHES = [0]   # depth of rematerialised_stretch() being traced
+
+
+@contextlib.contextmanager
+def rematerialised_stretch():
+    """Entered by a forward while it traces layers inside a stretch that
+    is rematerialised as a whole (a LoopVertex body in a train step): a
+    layer that would rematerialise a part of itself (the attention core)
+    then leaves that to the stretch."""
+    _REMAT_STRETCHES[0] += 1
+    try:
+        yield
+    finally:
+        _REMAT_STRETCHES[0] -= 1
+
+
+def _feature_last(layer, x):
+    """The layers below are handed [N, T, C] by the graph's forwards; a
+    caller that hands them the public [N, C, T] gets told, not garbage."""
+    if x.shape[-1] != layer.nIn:
+        raise ValueError(
+            f"{type(layer).__name__} '{layer.name}' computes feature-last "
+            f"([N, T, {layer.nIn}]) and got {tuple(x.shape)}: it runs "
+            f"inside a ComputationGraph, whose forward keeps sequences "
+            f"in that layout")
+    return x
+
+
+def _sequence_size(it: InputType) -> int:
+    return it.size if it.kind == "rnn" else it.arrayElementsPerExample()
+
+
+class RMSNorm(Layer):
+    """Root-mean-square norm over the feature axis with a learned gain,
+    ``x / sqrt(mean(x^2) + eps) * gain`` (Zhang & Sennrich 2019); no mean,
+    no bias. Statistics in float32 whatever the compute dtype, the gain a
+    float32 parameter under a precision policy (as BatchNorm's)."""
+
+    input_kind = None
+    fp32_params = True
+
+    def __init__(self, eps: float = 1e-6, **kw):
+        super().__init__(**kw)
+        self.eps = float(eps)
+
+    def infer_nin(self, it: InputType):
+        if it.kind not in ("rnn", "ff"):
+            raise ValueError(f"RMSNorm supports [N, D] and sequence "
+                             f"inputs, not {it}")
+        self.nIn = self.nOut = _sequence_size(it)
+
+    def mxu_lane_dims(self):
+        return []
+
+    def param_shapes(self):
+        return {"gain": (self.nIn,)} if self.nIn else {}
+
+    def initialize(self, key):
+        return {"gain": jnp.ones((self.nIn,), jnp.float32)}, {}
+
+    def apply(self, params, state, x, train, key):
+        x = _feature_last(self, x)
+        out = norm_ops.rms_norm(x.astype(jnp.float32),
+                                params["gain"].astype(jnp.float32),
+                                eps=self.eps)
+        return out.astype(x.dtype), state
+
+    def output_type(self, it: InputType) -> InputType:
+        return it
+
+
+class CausalSelfAttentionLayer(Layer):
+    """Causal multi-head self-attention with rotary positions, as
+    decoder-only language models run it: ``q, k, v = x Wq, x Wk, x Wv``
+    (``nHeads`` heads of ``headSize``, chosen apart from nIn; no bias),
+    rotary embedding on q and k over the whole head
+    (``ops.attention.rotary_embedding``, base ``ropeTheta``),
+    ``softmax(q k^T / sqrt(headSize) + causal mask) v``, then ``Wo``. The
+    attention core (``ops.attention.causal_attention``) is rematerialised
+    in the backward pass, by itself or with the stretch of a loop's body
+    it stands in: no [T, T] tensor is kept for it."""
+
+    input_kind = "rnn"
+
+    def __init__(self, nOut=None, nHeads: int = 1, headSize: int = None,
+                 ropeTheta: float = 10000.0, **kw):
+        super().__init__(nOut=nOut, **kw)
+        self.n_heads = int(nHeads)
+        self.head_size = headSize
+        self.rope_theta = float(ropeTheta)
+
+    def infer_nin(self, it: InputType):
+        super().infer_nin(it)
+        if self.nOut is None:
+            self.nOut = self.nIn
+        if self.head_size is None:
+            if self.nIn % self.n_heads:
+                raise ValueError(
+                    f"CausalSelfAttentionLayer: nIn={self.nIn} does not "
+                    f"divide into nHeads={self.n_heads}; give headSize")
+            self.head_size = self.nIn // self.n_heads
+        if self.head_size % 2:
+            raise ValueError(f"CausalSelfAttentionLayer: rotary positions "
+                             f"need an even headSize, got {self.head_size}")
+
+    def param_shapes(self):
+        if not self.nIn or not self.nOut or not self.head_size:
+            return {}
+        E = self.n_heads * self.head_size
+        return {"Wq": (self.nIn, E), "Wk": (self.nIn, E),
+                "Wv": (self.nIn, E), "Wo": (E, self.nOut)}
+
+    def initialize(self, key):
+        ks = jax.random.split(key, 4)
+        return {name: _initialize(shape, self.weight_init, k)
+                for (name, shape), k in zip(self.param_shapes().items(),
+                                            ks)}, {}
+
+    def apply(self, params, state, x, train, key):
+        x = self._maybe_dropout(_feature_last(self, x), train, key)
+        N, T = x.shape[0], x.shape[1]
+        H, hs = self.n_heads, self.head_size
+        q = (x @ params["Wq"]).reshape(N, T, H, hs)
+        k = (x @ params["Wk"]).reshape(N, T, H, hs)
+        v = (x @ params["Wv"]).reshape(N, T, H, hs)
+        q = attention_ops.rotary_embedding(q, self.rope_theta)
+        k = attention_ops.rotary_embedding(k, self.rope_theta)
+        with jax.named_scope(_stepprogram.ATTN_CORE_SCOPE):
+            o = attention_ops.causal_attention(
+                q, k, v, remat=not _REMAT_STRETCHES[0])
+        return o.reshape(N, T, H * hs) @ params["Wo"], state
+
+    def output_type(self, it: InputType) -> InputType:
+        return InputType.recurrent(self.nOut, it.dims.get("timesteps", -1))
+
+
+class GatedMLP(Layer):
+    """Gated feed-forward block (SwiGLU with the default ``swish``):
+    ``(act(x Wg) * (x Wu)) Wd`` with ``nHidden`` inner units, no bias."""
+
+    input_kind = None
+
+    def __init__(self, nOut=None, nHidden: int = None,
+                 activation: str = "swish", **kw):
+        super().__init__(nOut=nOut, activation=activation, **kw)
+        if not nHidden:
+            raise ValueError("GatedMLP needs nHidden, its inner width")
+        self.n_hidden = int(nHidden)
+
+    def infer_nin(self, it: InputType):
+        self.nIn = _sequence_size(it)
+        if self.nOut is None:
+            self.nOut = self.nIn
+
+    def mxu_lane_dims(self):
+        return [self.n_hidden, self.nOut]
+
+    def param_shapes(self):
+        if not self.nIn or not self.nOut:
+            return {}
+        return {"Wg": (self.nIn, self.n_hidden),
+                "Wu": (self.nIn, self.n_hidden),
+                "Wd": (self.n_hidden, self.nOut)}
+
+    def initialize(self, key):
+        ks = jax.random.split(key, 3)
+        return {name: _initialize(shape, self.weight_init, k)
+                for (name, shape), k in zip(self.param_shapes().items(),
+                                            ks)}, {}
+
+    def apply(self, params, state, x, train, key):
+        x = self._maybe_dropout(_feature_last(self, x), train, key)
+        gate = act.get(self.activation)(x @ params["Wg"])
+        return (gate * (x @ params["Wu"])) @ params["Wd"], state
+
+    def output_type(self, it: InputType) -> InputType:
+        if it.kind == "rnn":
+            return InputType.recurrent(self.nOut,
+                                       it.dims.get("timesteps", -1))
+        return InputType.feedForward(self.nOut)
+
+
+@jax.custom_vjp
+def _logits(h, w):
+    """``h @ w`` with float32 logits from operands in ``h``'s dtype (the
+    master ``w`` is cast here). The backward pass rounds the logits'
+    cotangent to that dtype for its two products, as every other layer's
+    does; ``w``'s gradient is accumulated and handed back in float32."""
+    return jnp.dot(h, w.astype(h.dtype), preferred_element_type=jnp.float32)
+
+
+def _logits_fwd(h, w):
+    return _logits(h, w), (h, w)
+
+
+def _logits_bwd(res, g):
+    h, w = res
+    g = g.astype(h.dtype)
+    dh = jnp.dot(g, w.astype(h.dtype).T)
+    dw = jnp.einsum("...d,...v->dv", h, g,
+                    preferred_element_type=jnp.float32)
+    return dh, dw.astype(w.dtype)
+
+
+_logits.defvjp(_logits_fwd, _logits_bwd)
+
+
+class LoopedLMOutputLayer(BaseOutputLayer):
+    """Language-model head over the passes of a :class:`~deeplearning4j_
+    tpu.nn.graph.LoopVertex`, with the exit-weighted loss of looped
+    language models (Zhu et al. 2025, arXiv:2510.25741, first-stage
+    objective). After pass t: logits ``z_t = h_t W`` and an exit gate
+    ``lambda_t = sigmoid(h_t . gate_w + gate_b)``; a token's exit
+    distribution is ``p_t = lambda_t prod_{j<t}(1 - lambda_j)``, the last
+    pass taking what is left; the loss is the mean over positions of
+    ``sum_t p_t CE(z_t, y) - beta H(p)``.
+
+    Labels are INTEGER token ids [N, T]. The loss is worked out from the
+    layer's input, never from probabilities: log-softmax from logits, one
+    pass's float32 [T, nOut] logits alive at a time (each pass's head is
+    rematerialised in the backward pass). Its state carries the batch
+    means of ``p_t`` and ``CE(z_t, y)`` of the last step, for the gauges
+    ``dl4j_loop_exit_mass`` / ``dl4j_loop_pass_loss``. ``apply`` (the
+    inference forward) gives the last pass's logits. Fed by an ordinary
+    layer it is a one-pass head with plain cross-entropy."""
+
+    input_kind = None
+    loss_from_input = True
+
+    def __init__(self, nOut=None, beta: float = 0.1, **kw):
+        super().__init__(lossFunction="sparse_mcxent", nOut=nOut, **kw)
+        self.beta = float(beta)
+        self.n_passes = 1
+
+    def infer_nin(self, it: InputType):
+        self.nIn = _sequence_size(it)
+        self.n_passes = int(it.dims.get("passes", 1))
+
+    def param_shapes(self):
+        if not self.nIn or not self.nOut:
+            return {}
+        return {"W": (self.nIn, self.nOut), "gate_w": (self.nIn,),
+                "gate_b": (1,)}
+
+    def initialize(self, key):
+        k1, k2 = jax.random.split(key)
+        zeros = jnp.zeros((self.n_passes,), jnp.float32)
+        return ({"W": _initialize((self.nIn, self.nOut), self.weight_init,
+                                  k1),
+                 "gate_w": _initialize((self.nIn, 1), self.weight_init,
+                                       k2)[:, 0],
+                 "gate_b": jnp.zeros((1,), jnp.float32)},
+                {"exit_mass": zeros, "pass_loss": zeros})
+
+    def apply(self, params, state, x, train, key):
+        h = x[-1] if isinstance(x, tuple) else x
+        return _logits(_feature_last(self, h), params["W"]), state
+
+    def _pass_head(self, h, w, gate_w, gate_b, labels):
+        """(cross-entropy, gate logit) of one pass, [N, T] float32 each."""
+        z = _logits(h, w)
+        ce = jax.nn.logsumexp(z, axis=-1) - jnp.take_along_axis(
+            z, labels[..., None], axis=-1)[..., 0]
+        gate = jnp.einsum("ntd,d->nt", h.astype(jnp.float32),
+                          gate_w.astype(jnp.float32)) + gate_b[0]
+        return ce, gate
+
+    def loss_from(self, params, x, labels, mask=None):
+        """``(loss, state)`` from the layer's input: every pass's hidden
+        states (a tuple off a LoopVertex) or one array."""
+        passes = x if isinstance(x, tuple) else (x,)
+        labels = labels.astype(jnp.int32)
+        head = jax.checkpoint(self._pass_head)
+        ces, gates = [], []
+        for t, h in enumerate(passes):
+            with jax.named_scope(_stepprogram.pass_scope(t + 1)), \
+                    jax.named_scope(_stepprogram.HEAD_LOSS_SCOPE):
+                ce, gate = head(_feature_last(self, h), params["W"],
+                                params["gate_w"], params["gate_b"], labels)
+            ces.append(ce)
+            gates.append(gate)
+        with jax.named_scope(_stepprogram.HEAD_LOSS_SCOPE):
+            ce = jnp.stack(ces)                         # [P, N, T]
+            log_p = exit_log_distribution(jnp.stack(gates))
+            p = jnp.exp(log_p)
+            per_token = jnp.sum(p * ce, axis=0) \
+                + self.beta * jnp.sum(p * log_p, axis=0)
+            if mask is None:
+                weight = jnp.full(per_token.shape, 1.0 / per_token.size)
+            else:
+                m = mask.astype(jnp.float32)
+                weight = m / jnp.maximum(jnp.sum(m), 1.0)
+            loss = jnp.sum(per_token * weight)
+            state = {"exit_mass": jnp.sum(p * weight, axis=(1, 2)),
+                     "pass_loss": jnp.sum(ce * weight, axis=(1, 2))}
+        return loss, jax.lax.stop_gradient(state)
+
+    def compute_loss(self, labels, preds, mask=None):
+        raise ValueError(
+            "LoopedLMOutputLayer works its loss out from its input "
+            "(loss_from), not from predictions")
+
+    def output_type(self, it: InputType) -> InputType:
+        return InputType.recurrent(self.nOut, it.dims.get("timesteps", -1))
+
+
+def exit_log_distribution(gate_logits):
+    """``log p_t`` over the leading (pass) axis from the gates' logits:
+    ``p_t = lambda_t prod_{j<t}(1 - lambda_j)`` for t < P, the last pass
+    taking ``prod_{j<P}(1 - lambda_j)``, so the p_t sum to one. In logs,
+    from ``log_sigmoid`` alone: nothing under- or overflows."""
+    log_stay = jax.nn.log_sigmoid(-gate_logits)         # log(1 - lambda)
+    log_exit = jax.nn.log_sigmoid(gate_logits)
+    before = jnp.cumsum(log_stay, axis=0) - log_stay    # sum over j < t
+    return jnp.concatenate([(log_exit + before)[:-1], before[-1:]], axis=0)
+
+
+#: layers that compute on [N, T, C] (see ``layout_step``)
+SEQUENCE_LAST = (RMSNorm, CausalSelfAttentionLayer, GatedMLP,
+                 LoopedLMOutputLayer)
+
+for _cls in SEQUENCE_LAST:
+    _LAYER_CLASSES[_cls.__name__] = _cls

@@ -97,7 +97,8 @@ def layer_flop_model(conf) -> List[Tuple[str, str, int]]:
     OR a graph config. Returns ``[(layer_name, op_kind, flops), ...]``
     in forward order; layers whose InputType propagation failed report
     0 FLOPs rather than raising (attribution degrades, never breaks)."""
-    from deeplearning4j_tpu.analysis.distribution import _approx_flops
+    from deeplearning4j_tpu.analysis.distribution import (_approx_flops,
+                                                          loop_steps)
     rows: List[Tuple[str, str, int]] = []
     if hasattr(conf, "graph_inputs"):            # ComputationGraph config
         types = getattr(conf, "types", {}) or {}
@@ -107,7 +108,8 @@ def layer_flop_model(conf) -> List[Tuple[str, str, int]]:
             it = types.get(node.inputs[0]) if node.inputs else None
             out = types.get(node.name)
             try:
-                f = _approx_flops(node.obj, it, out)
+                # a looped layer runs ``steps`` times a forward pass
+                f = _approx_flops(node.obj, it, out) * loop_steps(conf, node)
             except Exception:
                 f = 0
             rows.append((node.name, op_kind(node.obj), int(f)))

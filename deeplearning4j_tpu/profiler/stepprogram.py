@@ -14,7 +14,12 @@ Pallas kernels carry a ``name=`` (``dl4j_layer_norm``, …).
 
 :func:`parse` turns a compiled module's text into ``{instruction name:
 Entry(phase, layer, kernel, mixed)}`` — a pure function of the text,
-jax-free. A reader joins it to a trace by the instruction's name.
+jax-free. A reader joins it to a trace by the instruction's name. An
+entry also says which pass of a ``LoopVertex`` the instruction runs in
+(:func:`pass_scope`; the loop is unrolled, so no instruction serves two
+passes), whether it belongs to the attention core or to the heads and
+loss of a looped model (:data:`PARTS`), and whether it is forward work a
+rematerialised stretch runs again in the backward pass.
 
 The map of a running fit is made outside every timed stretch. While
 instrumentation is active the fit loops :func:`note` each step function
@@ -41,7 +46,23 @@ UPDATER_SCOPE = "dl4j_updater"
 LOSS_SCOPE = "dl4j_loss"
 AUGMENT_SCOPE = "dl4j_augment"
 
+#: scopes inside a layer's own: the attention core (scores, softmax,
+#: weighted sum) and the heads, gates and loss of a looped model's passes
+ATTN_CORE_SCOPE = "dl4j_attn_core"
+HEAD_LOSS_SCOPE = "dl4j_head_loss"
+PARTS = {ATTN_CORE_SCOPE: "attn_core", HEAD_LOSS_SCOPE: "head_loss"}
+#: what JAX names the forward ops a ``jax.checkpoint`` runs again in the
+#: backward pass
+REMAT_MARK = "rematted_computation"
+
 PHASES = ("forward", "backward", "updater")
+
+
+def pass_scope(t: int) -> str:
+    """The scope every op of pass ``t`` (from 1) of a LoopVertex runs
+    under, its head and loss too: the loop is unrolled, so each pass has
+    instructions of its own and the map can tell them apart."""
+    return f"dl4j_ut{int(t)}"
 
 
 class Entry(NamedTuple):
@@ -51,6 +72,10 @@ class Entry(NamedTuple):
     kernel: Optional[str]       # the Pallas kernel's name= of a custom-call
     mixed: bool                 # a fusion whose instructions disagree on
     #                             the phase (weight gradient + Adam, …)
+    loop_pass: Optional[int] = None     # pass of a LoopVertex, from 1
+    part: Optional[str] = None  # attn_core | head_loss (see PARTS)
+    remat: bool = False         # forward work run again in the backward
+    #                             pass (a rematerialised stretch)
 
 
 _OTHER = Entry("other", None, None, False)
@@ -58,6 +83,8 @@ _OTHER = Entry("other", None, None, False)
 _SCOPE = re.compile(
     r"dl4j_(?:L\d+_[A-Za-z0-9_.\-]+|updater|loss|augment)")
 _KERNEL = re.compile(r"(dl4j_[A-Za-z0-9_]+)/pallas_call")
+_PASS = re.compile(r"dl4j_ut(\d+)")
+_PART = re.compile("|".join(PARTS))
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
 _OPCODE = re.compile(r" ([a-z][a-z0-9\-]*)\(")
@@ -84,6 +111,17 @@ def classify(op_name: str):
     if "jvp(" in name or layer is not None:
         return "forward", layer
     return "other", None
+
+
+def marks(op_name: str):
+    """``(loop_pass, part, remat)`` of one ``op_name``: the pass of a
+    LoopVertex it runs in, whether it is the attention core or a head
+    with its loss, and whether it is rematerialised forward work."""
+    name = op_name.partition(";")[0]
+    ut = _PASS.search(name)
+    part = _PART.search(name)
+    return (int(ut.group(1)) if ut else None,
+            PARTS[part.group(0)] if part else None, REMAT_MARK in name)
 
 
 def module_name(hlo_text: str) -> Optional[str]:
@@ -123,6 +161,11 @@ def _own(rest: str):
     return classify(m.group(1)) if m else ("other", None)
 
 
+def _own_marks(rest: str):
+    m = _OP_NAME.search(rest)
+    return marks(m.group(1)) if m else (None, None, False)
+
+
 #: instructions that do a fusion's heavy work; the elementwise rest rides
 #: along with whatever they read and write
 _LEADS = ("convolution", "dot")
@@ -147,18 +190,52 @@ def _fusion(body, rest: str) -> Entry:
         phase, layer = _own(inner)
         if phase not in PHASES:
             continue
+        mine = (phase, layer) + _own_marks(inner)
         if op in _LEADS and lead is None:
-            lead = (phase, layer)
+            lead = mine
         elif op in _REDUCTIONS and reduction is None:
-            reduction = (phase, layer)
+            reduction = mine
         if latest is None or PHASES.index(phase) > PHASES.index(latest[0]) \
                 or (phase == latest[0] and latest[1] is None):
-            latest = (phase, layer)
+            latest = mine
     if latest is None:
         phase, layer = _own(rest)       # the fusion's own name, if any
-        return Entry(phase, layer, None, False)
-    phase, layer = lead or reduction or latest
-    return Entry(phase, layer, None, phase != latest[0])
+        return Entry(phase, layer, None, False, *_own_marks(rest))
+    # the pass, part and remat mark are those of the instruction that
+    # gives the fusion its phase and layer: its matmul, else its
+    # reduction, else its latest
+    phase, layer, loop_pass, part, remat = lead or reduction or latest
+    return Entry(phase, layer, None, phase != latest[0], loop_pass, part,
+                 remat)
+
+
+#: what the compiler adds to move data for another instruction and gives
+#: no name of its own: asynchronous copies and slices into on-chip memory,
+#: layout copies, bitcasts
+_MOVES = ("copy-start", "copy-done", "slice-start", "slice-done", "copy",
+          "bitcast", "reshape", "transpose")
+_OPERAND = re.compile(r"%([\w.\-]+)")
+
+
+def _adopt_moves(instructions, out: Dict[str, Entry]) -> None:
+    """An unnamed data movement works for the instruction that reads what
+    it moved: it takes that instruction's phase, layer and marks (a
+    ``-start`` through its ``-done``). Thousands of them a step prefetch
+    operands for a looped stack's fusions; without this they are
+    ``other`` and no layer's time."""
+    first_user: Dict[str, str] = {}
+    for name, _op, rest, _root in instructions:
+        for operand in _OPERAND.findall(rest.partition(", metadata=")[0]):
+            first_user.setdefault(operand, name)
+    for name, op, _rest, _root in instructions:
+        if op not in _MOVES or out.get(name) is not _OTHER:
+            continue
+        user, hops = first_user.get(name), 0
+        while user is not None and out.get(user) is _OTHER and hops < 4:
+            user, hops = first_user.get(user), hops + 1
+        found = out.get(user)
+        if found is not None and found is not _OTHER:
+            out[name] = found._replace(kernel=None, mixed=False)
 
 
 def parse(hlo_text: str) -> Dict[str, Entry]:
@@ -166,7 +243,9 @@ def parse(hlo_text: str) -> Dict[str, Entry]:
     runs as an op of its own: those of the entry computation and of the
     computations it reaches through ``while``, ``call``, ``conditional``
     and asynchronous wrappers (a megastep's scan body). A fusion's called
-    computation is read for its phase, not listed."""
+    computation is read for its phase, not listed. The compiler's own
+    unnamed data movements adopt their reader's entry
+    (:func:`_adopt_moves`)."""
     comps, entry = _split(hlo_text)
     out: Dict[str, Entry] = {}
     seen, todo = set(), [entry]
@@ -186,7 +265,8 @@ def parse(hlo_text: str) -> Dict[str, Entry]:
             if op == "custom-call":
                 k = _KERNEL.search(rest)
                 kernel = k.group(1) if k else None
-            out[name] = Entry(phase, layer, kernel, False) \
+            out[name] = Entry(phase, layer, kernel, False,
+                              *_own_marks(rest)) \
                 if (phase != "other" or kernel) else _OTHER
             todo.extend(c for key, c in called.items()
                         if key != "to_apply" or op == "call")
@@ -194,6 +274,7 @@ def parse(hlo_text: str) -> Dict[str, Entry]:
             if branches:
                 todo.extend(b.strip().lstrip("%")
                             for b in branches.group(1).split(","))
+        _adopt_moves(comps[comp], out)
     return out
 
 

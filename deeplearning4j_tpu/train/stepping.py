@@ -66,6 +66,21 @@ TRAIN_H2D_BYTES = _prof.get_registry().counter(
     "dl4j_train_h2d_bytes_total",
     "Host bytes stage_batch placed on the device inside the fit loop "
     "(arrays already on the device are not counted)")
+# Token ids handed to a step: what a language model's throughput counts.
+TRAIN_TOKENS = _prof.get_registry().counter(
+    "dl4j_train_tokens_total",
+    "Token ids (elements of integer feature batches) stage_batch handed "
+    "to a train dispatch inside the fit loop")
+# What a looped language model's head read of its passes at the last
+# step of a fit (nn.layers.LoopedLMOutputLayer keeps them as its state).
+LOOP_EXIT_MASS = _prof.get_registry().gauge(
+    "dl4j_loop_exit_mass",
+    "Batch mean of the exit distribution's mass on each pass of a looped "
+    "model at the last step of the last fit", labelnames=("pass",))
+LOOP_PASS_LOSS = _prof.get_registry().gauge(
+    "dl4j_loop_pass_loss",
+    "Batch mean cross-entropy of each pass's logits of a looped model at "
+    "the last step of the last fit", labelnames=("pass",))
 _STEP_SECONDS = _prof.get_registry().histogram(
     "dl4j_train_step_seconds",
     "Host time to enqueue one compiled train dispatch (1 or K steps); "
@@ -177,7 +192,24 @@ def epoch_span(model):
                 {"epoch": model._epoch})
 
 
-def stage_batch(model, a, mega: bool = False):
+def publish_loop_gauges(model) -> None:
+    """``dl4j_loop_exit_mass`` / ``dl4j_loop_pass_loss`` from the state the
+    last step left in a graph's looped heads. One device-to-host read, so
+    only while instrumentation is active and only once a ``fit`` call has
+    dispatched its last step: it adds no sync inside the loop."""
+    if not _prof.instrumentation_active():
+        return
+    for state in getattr(model, "_states", {}).values():
+        if not isinstance(state, dict) or "exit_mass" not in state:
+            continue
+        mass, loss = jax.device_get((state["exit_mass"],
+                                     state["pass_loss"]))
+        for t in range(len(mass)):
+            LOOP_EXIT_MASS.labels(str(t + 1)).set(float(mass[t]))
+            LOOP_PASS_LOSS.labels(str(t + 1)).set(float(loss[t]))
+
+
+def stage_batch(model, a, mega: bool = False, features: bool = False):
     """Batch staging for the fit functions: plain ``jnp.asarray`` — or,
     when a :class:`~deeplearning4j_tpu.distributed.gspmd.
     ShardedTrainingPlan` is attached, ``device_put`` per the plan's
@@ -186,11 +218,16 @@ def stage_batch(model, a, mega: bool = False):
     model/seq axes). A no-op copy-wise for arrays a DevicePrefetcher
     already placed with the same sharding. While instrumentation is
     active the bytes of every host array placed count into
-    ``dl4j_train_h2d_bytes_total``."""
+    ``dl4j_train_h2d_bytes_total``, and the elements of an integer
+    ``features`` batch (token ids) into ``dl4j_train_tokens_total``."""
     if a is None:
         return None
-    if not isinstance(a, jax.Array) and _prof.instrumentation_active():
-        TRAIN_H2D_BYTES.inc(int(getattr(a, "nbytes", 0)))
+    if _prof.instrumentation_active():
+        if not isinstance(a, jax.Array):
+            TRAIN_H2D_BYTES.inc(int(getattr(a, "nbytes", 0)))
+        if features and np.issubdtype(a.dtype, np.integer) \
+                and a.dtype != np.uint8:
+            TRAIN_TOKENS.inc(int(a.size))
     plan = getattr(model, "_sharding_plan", None)
     if plan is None:
         return jnp.asarray(a)

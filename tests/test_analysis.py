@@ -503,7 +503,7 @@ class TestCli:
         from deeplearning4j_tpu.analysis.__main__ import main
         assert main(["--zoo"]) == 0
         out = capsys.readouterr().out
-        assert "16 model(s) linted: 16 clean" in out
+        assert "17 model(s) linted: 17 clean" in out
 
     def test_single_model_by_name(self, capsys):
         from deeplearning4j_tpu.analysis.__main__ import main
@@ -820,7 +820,7 @@ class TestCliMesh:
         from deeplearning4j_tpu.analysis.__main__ import main
         # --zero: see test_zoo_clean_under_data8_mesh (W109 otherwise)
         assert main(["--zoo", "--mesh", "data=8", "--zero"]) == 0
-        assert "16 model(s) linted: 16 clean" in capsys.readouterr().out
+        assert "17 model(s) linted: 17 clean" in capsys.readouterr().out
 
     def test_mesh_flag_fails_bad_batch(self, capsys):
         from deeplearning4j_tpu.analysis.__main__ import main

@@ -165,3 +165,21 @@ def test_fused_epilogue_rides_in_the_conv_fusions(one_chip, shape, alpha):
     moved = re.findall(
         r"= %s\S* (?:copy|reshape|transpose)\(" % re.escape(activation), entry)
     assert not moved, moved
+
+
+# ------------------------------------------------ the causal attention core
+def test_causal_attention_compiles_for_v5e_without_a_kernel(one_chip):
+    """The looped language model's attention core at the benchmark cell's
+    shape, forward and backward: plain matmuls in query blocks, no
+    ``custom-call`` of the program's (PR 28's chip probe of five
+    implementations chose it), and no [T, T] tensor for any head: the
+    widest score block is 256 query rows by 4,096 keys."""
+    from deeplearning4j_tpu.ops import attention as attention_ops
+    shape = (1, 4096, 16, 128)
+    specs = [jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)] * 3
+    grad = jax.grad(lambda q, k, v: jnp.sum(attention_ops.causal_attention(
+        q, k, v).astype(jnp.float32)), argnums=(0, 1, 2))
+    text = jax.jit(grad).lower(*specs).compile().as_text()
+    assert "tpu_custom_call" not in text
+    assert not re.search(r"4096,4096\]", text)
+    assert "rematted_computation" in text

@@ -1,0 +1,544 @@
+"""The loop construct (``LoopVertex``), the feature-last sequence layers and
+``zoo.Ouro`` on the CPU at a tiny size: d = 64, 4 heads of 16, F = 176,
+V = 512, 2 layers, S = 32, T = 4 and T = 1, in float32, against the
+benchmark's plain reference (``chipbench/configs/ouro-2.6b-l6-bf16/
+reference.py``) through the harness's own ``compare``."""
+
+import copy
+import importlib.util
+import json
+import os
+
+import jax
+import jax.numpy as jnp
+import numpy as np
+import pytest
+
+from chipbench import compare, refnn
+from chipbench.weights import make_weights
+from deeplearning4j_tpu import profiler
+from deeplearning4j_tpu.data.dataset import DataSet
+from deeplearning4j_tpu.models import zoo
+from deeplearning4j_tpu.nn import layers as L
+from deeplearning4j_tpu.nn.config import InputType, NeuralNetConfiguration
+from deeplearning4j_tpu.nn.graph import (ComputationGraph,
+                                         ComputationGraphConfiguration,
+                                         ElementWiseVertex, LoopVertex,
+                                         PassVertex)
+from deeplearning4j_tpu.ops import attention as attention_ops
+from deeplearning4j_tpu.profiler import stepprogram
+from deeplearning4j_tpu.train import stepping, updaters
+
+ROOT = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+CFG_DIR = os.path.join(ROOT, "chipbench", "configs", "ouro-2.6b-l6-bf16")
+TINY = dict(num_layers=2, hidden_size=64, num_attention_heads=4, head_dim=16,
+            intermediate_size=176, vocab_size=512, seq_len=32)
+SEED = 2 ** 31 + 17
+
+
+def _load(name):
+    spec = importlib.util.spec_from_file_location(
+        "ouro_" + name, os.path.join(CFG_DIR, name + ".py"))
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod
+
+
+MODEL, REFERENCE = _load("model"), _load("reference")
+
+
+def tiny_cfg(passes=4):
+    cfg = json.load(open(os.path.join(CFG_DIR, "config.json")))
+    cfg.update(TINY, total_ut_steps=passes)
+    return cfg
+
+
+def tokens(cfg, n_batches=3, batch=2, seed=0):
+    rng = np.random.default_rng(seed)
+    rows = [rng.integers(0, cfg["vocab_size"], (batch, cfg["seq_len"] + 1),
+                         dtype=np.int32) for _ in range(n_batches)]
+    return [(r[:, :-1].copy(), r[:, 1:].copy()) for r in rows]
+
+
+def tiny_net(passes=4, **kw):
+    cfg = tiny_cfg(passes)
+    return zoo.Ouro(num_layers=2, hidden_size=64, num_heads=4, head_dim=16,
+                    intermediate_size=176, vocab_size=512,
+                    total_ut_steps=passes, seq_len=32, **kw), cfg
+
+
+# ------------------------------------------------ program against reference
+@pytest.mark.parametrize("passes", [4, 1])
+def test_fit_agrees_with_the_plain_reference_in_float32(passes):
+    """Three losses, the first gradients and the parameters' changes of
+    ``net.fit`` against ``refnn.train_steps`` over ``reference.py``, from
+    the same seeded weights and batches."""
+    cfg = tiny_cfg(passes)
+    spec = MODEL.param_spec(cfg)
+    net = MODEL.build(cfg, make_weights(spec, SEED))
+    batches = tokens(cfg)
+    losses, first_m = [], None
+    for x, y in batches:
+        net.fit(DataSet(x, y))
+        losses.append(float(net.score()))
+        if first_m is None:
+            first_m = jax.device_get(MODEL.read_leaves(net, "m"))
+    beta1 = cfg["updater"]["beta1"]
+    start = make_weights(spec, SEED)
+    after = MODEL.read_leaves(net, "params")
+    program = {
+        "losses": losses,
+        "first_grads": {k: v / (1 - beta1) for k, v in first_m.items()},
+        "change_norms": {k: float(jnp.linalg.norm(after[k] - start[k]))
+                         for k in start}}
+    hp = {k: v for k, v in cfg["updater"].items() if k != "kind"}
+    ref = refnn.train_steps(REFERENCE.make_loss(cfg), make_weights(spec, SEED),
+                            batches, hp)
+    ref["change_norms"] = {k: float(jnp.linalg.norm(ref["params"][k]
+                                                    - start[k]))
+                           for k in start}
+    ref["first_grads"] = jax.device_get(ref["first_grads"])
+    nums = compare.numbers(program, ref)
+    assert nums["loss1_gap"] < 1e-5 and nums["loss3_gap"] < 1e-3, nums
+    assert nums["grad_gap"] < 1e-4 and nums["graddir_gap"] < 1e-4, nums
+    assert nums["change_gap"] < 1e-2, nums
+
+
+# ------------------------------------------------------- one set of weights
+def test_a_looped_weight_exists_once_and_its_gradient_sums_its_uses():
+    zoo_model, cfg = tiny_net(4)
+    net = zoo_model.init()
+    n_layer = MODEL.layer_matmul_params(cfg) + 4 * 64
+    assert net.numParams() == 2 * 512 * 64 + 2 * n_layer + 64 + 64 + 1
+    assert int(net.params().shape[0]) == net.numParams()
+    assert "l0_attn (CausalSelfAttentionLayer)" in net.summary()
+    assert net.summary().count("l0_attn (") == 1
+    x, y = tokens(cfg, 1)[0]
+    ins, labels = {"tokens": jnp.asarray(x)}, [jnp.asarray(y)]
+    key = jax.random.PRNGKey(0)
+
+    def loss(params):
+        return net._loss_and_reg(params, net._states, ins, labels, True, key,
+                                 None, None)[0]
+    whole = jax.grad(loss)(net._params)["l1_mlp"]["Wd"]
+
+    # the same graph with the body written out once a pass: four copies of
+    # every layer, each holding the looped layer's weights
+    unrolled = _unrolled_copy(net, 4)
+    params4 = {name: net._params[name.split("@")[0]]
+               for name in unrolled._params}
+
+    def loss4(params):
+        return unrolled._loss_and_reg(params, unrolled._states, ins, labels,
+                                      True, key, None, None)[0]
+    assert float(loss4(params4)) == pytest.approx(float(loss(net._params)),
+                                                  rel=1e-6)
+    per_use = jax.grad(loss4)(params4)
+    uses = [per_use[f"l1_mlp@{t}"]["Wd"] for t in range(4)]
+    assert all(float(jnp.linalg.norm(u)) > 0 for u in uses)
+    np.testing.assert_allclose(whole, sum(uses), rtol=2e-4, atol=1e-7)
+
+
+def _unrolled_copy(net, passes):
+    """``net``'s graph with no loop: the body's nodes repeated a pass, as
+    ``name@t``, the head reading the passes through a tuple vertex."""
+    from deeplearning4j_tpu.nn.graph import GraphVertex
+
+    class Passes(GraphVertex):
+        def apply(self, *xs):
+            return tuple(xs)
+
+        def output_type(self, *its):
+            return InputType(its[0].kind, **{**its[0].dims,
+                                             "passes": len(its)})
+    conf = net.conf
+    g = NeuralNetConfiguration.Builder().seed(conf.base.seed) \
+        .updater(conf.base.updater).graphBuilder()
+    g.addInputs("tokens")
+    g.setInputTypes(conf.input_types["tokens"])
+    g.addLayer("embed", copy.deepcopy(conf.node_by_name["embed"].obj),
+               "tokens")
+    carried, outs = "embed", []
+    for t in range(passes):
+        rename = lambda r: carried if r == "ut" else f"{r}@{t}"  # noqa: E731
+        for node in conf.loop_bodies["ut"]:
+            ins = [rename(r) for r in node.inputs]
+            if node.kind == "layer":
+                g.addLayer(f"{node.name}@{t}", copy.deepcopy(node.obj), *ins)
+            else:
+                g.addVertex(f"{node.name}@{t}", copy.deepcopy(node.obj), *ins)
+        carried = f"fnorm@{t}"
+        outs.append(carried)
+    g.addVertex("passes", Passes(), *outs)
+    g.addLayer("lm", copy.deepcopy(conf.node_by_name["lm"].obj), "passes")
+    g.setOutputs("lm")
+    out = ComputationGraph(g.build()).init()
+    out._states["lm"] = net._states["lm"]
+    return out
+
+
+def test_one_pass_is_the_same_layers_stacked_plainly_bit_for_bit():
+    zoo_model, cfg = tiny_net(1)
+    looped = zoo_model.init()
+    plain = _unrolled_copy(looped, 1)
+    for name in plain._params:
+        plain._params[name] = jax.tree_util.tree_map(
+            jnp.array, looped._params[name.split("@")[0]])
+    for x, y in tokens(cfg, 3):
+        looped.fit(DataSet(x, y))
+        plain.fit(DataSet(x, y))
+        assert float(looped.score()) == float(plain.score())
+    for name in plain._params:
+        for leaf, a in plain._params[name].items():
+            np.testing.assert_array_equal(
+                a, looped._params[name.split("@")[0]][leaf])
+
+
+def test_rematerialised_and_plain_step_give_the_same_values():
+    zoo_model, cfg = tiny_net(4)
+    net = zoo_model.init()
+    x, y = tokens(cfg, 1)[0]
+    ins, labels = {"tokens": jnp.asarray(x)}, [jnp.asarray(y)]
+    key = jax.random.PRNGKey(3)
+
+    def loss(params, remat):
+        return net._loss_and_reg(params, net._states, ins, labels, True, key,
+                                 None, None, remat=remat)[0]
+    plain = jax.jit(jax.value_and_grad(lambda p: loss(p, False)))(net._params)
+    remat = jax.jit(jax.value_and_grad(lambda p: loss(p, True)))(net._params)
+    assert float(plain[0]) == pytest.approx(float(remat[0]), rel=1e-6)
+    for a, b in zip(jax.tree_util.tree_leaves(plain[1]),
+                    jax.tree_util.tree_leaves(remat[1])):
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-7)
+    # and the train step asks for it: its program runs forward work again
+    text = jax.jit(jax.grad(lambda p: loss(p, True))).lower(
+        net._params).compile().as_text()
+    assert stepprogram.REMAT_MARK in text
+    text = jax.jit(jax.grad(lambda p: loss(p, False))).lower(
+        net._params).compile().as_text()
+    assert "/" + stepprogram.REMAT_MARK + "/dl4j_L" not in text
+
+
+def test_the_body_is_cut_where_one_value_goes_on():
+    net = tiny_net(4)[0].conf_builder()
+    segs = [[n.name for n in seg] for seg in net.conf.loop_segments["ut"]]
+    assert segs == [["l0_n1", "l0_attn", "l0_n2", "l0_add1"],
+                    ["l0_n3", "l0_mlp", "l0_n4", "l0_add2"],
+                    ["l1_n1", "l1_attn", "l1_n2", "l1_add1"],
+                    ["l1_n3", "l1_mlp", "l1_n4", "l1_add2"], ["fnorm"]]
+    names = [n.name for n in net.conf.topo]
+    assert names.index("ut") < names.index("l0_n1") < names.index("fnorm") \
+        < names.index("lm")
+    assert net.conf.types["ut"].dims["passes"] == 4
+    assert net.conf.types["fnorm"] == InputType.recurrent(64, 32)
+
+
+# ------------------------------------------------------------ serialisation
+def test_json_round_trip_keeps_the_loop():
+    net = tiny_net(4)[0].init()
+    text = net.conf.to_json()
+    conf = ComputationGraphConfiguration.from_json(text)
+    assert conf.to_json() == text
+    assert [n.name for n in conf.topo] == [n.name for n in net.conf.topo]
+    assert conf.node_by_name["ut"].obj.steps == 4
+    assert conf.node_by_name["ut"].obj.output == "fnorm"
+    assert conf.node_by_name["l1_mlp"].loop == "ut"
+    assert isinstance(conf.node_by_name["l0_attn"].obj,
+                      L.CausalSelfAttentionLayer)
+    assert conf.node_by_name["l0_attn"].obj.rope_theta == 1e6
+    again = ComputationGraph(conf).init()
+    again._params = net._params
+    cfg = tiny_cfg()
+    x, y = tokens(cfg, 1)[0]
+    assert again.score(DataSet(x, y)) == net.score(DataSet(x, y))
+
+
+def test_save_and_load_keep_weights_updater_state_and_loss(tmp_path):
+    zoo_model, cfg = tiny_net(4)
+    net = zoo_model.init()
+    batches = tokens(cfg, 3)
+    net.fit(DataSet(*batches[0]))
+    path = str(tmp_path / "ouro.zip")
+    net.save(path)
+    loaded = ComputationGraph.load(path)
+    assert loaded.numParams() == net.numParams()
+    net.fit(DataSet(*batches[1]))
+    loaded.fit(DataSet(*batches[1]))
+    assert float(loaded.score()) == pytest.approx(float(net.score()),
+                                                  rel=1e-6)
+
+
+def test_a_sharding_rule_sees_a_looped_weight_once():
+    """``ShardedTrainingPlan`` names a leaf ``<node>/<param>``: a looped
+    layer is one node, so one regex match shards all its uses; the
+    updater's state follows the same tree. (``nn/transfer.py`` freezes by
+    layer index in a MultiLayerNetwork and has no graph API to see.)"""
+    from jax.sharding import PartitionSpec as P
+    from deeplearning4j_tpu.distributed.gspmd import ShardedTrainingPlan
+    from deeplearning4j_tpu.parallel.mesh import DeviceMesh
+    net = tiny_net(4)[0].init()
+    net._ensure_opt_state()
+    plan = ShardedTrainingPlan(
+        DeviceMesh.create(data=2, model=4),
+        rules={r"^l\d+_attn/W[qkv]$": (None, "model"),
+               r"^l\d+_mlp/Wd$": ("model", None)})
+    sh = plan.param_shardings(net)
+    assert sh["l1_attn"]["Wq"].spec == P(None, "model")
+    assert sh["l1_attn"]["Wo"].spec == P()
+    assert sh["l0_mlp"]["Wd"].spec == P("model", None)
+    assert sh["lm"]["W"].spec == P()
+    assert sorted(sh) == sorted(net._params)
+    opt = plan.opt_shardings(net)
+    assert opt["l1_attn"]["Wq"]["m"].spec == P(None, "model")
+    leaves = jax.tree_util.tree_leaves(net._params)
+    assert sum(int(np.prod(a.shape)) for a in leaves) == net.numParams()
+
+
+# ------------------------------------------------------------- the builder
+def _builder():
+    return NeuralNetConfiguration.Builder().updater(updaters.Adam(1e-3)) \
+        .graphBuilder().addInputs("x") \
+        .setInputTypes(InputType.recurrent(8, 5))
+
+
+def test_a_loop_has_to_be_closed_and_cannot_nest():
+    g = _builder().beginLoop("loop", "x", steps=2)
+    with pytest.raises(ValueError, match="never closed"):
+        g.build()
+    with pytest.raises(ValueError, match="do not nest"):
+        g.beginLoop("inner", "loop", steps=2)
+    with pytest.raises(ValueError, match="no loop is open"):
+        _builder().endLoop("x")
+    with pytest.raises(ValueError, match="steps must be >= 1"):
+        LoopVertex(0)
+
+
+def test_a_pass_has_to_hand_on_the_type_it_took():
+    g = _builder().beginLoop("loop", "x", steps=2) \
+        .addLayer("wide", L.GatedMLP(nOut=16, nHidden=12), "loop") \
+        .endLoop("wide") \
+        .addLayer("out", L.LoopedLMOutputLayer(nOut=4), "loop") \
+        .setOutputs("out")
+    with pytest.raises(ValueError, match="hand on the type it took"):
+        g.build()
+
+
+def test_nothing_outside_reads_a_body_node_but_through_the_loop():
+    g = _builder().beginLoop("loop", "x", steps=2) \
+        .addLayer("mlp", L.GatedMLP(nHidden=12), "loop").endLoop("mlp") \
+        .addLayer("out", L.LoopedLMOutputLayer(nOut=4), "mlp") \
+        .setOutputs("out")
+    with pytest.raises(ValueError, match="unresolved"):
+        g.build()
+
+
+def test_pass_vertex_hands_one_pass_to_an_ordinary_layer():
+    g = _builder().beginLoop("loop", "x", steps=3) \
+        .addLayer("mlp", L.GatedMLP(nHidden=12), "loop") \
+        .addVertex("add", ElementWiseVertex("Add"), "loop", "mlp") \
+        .endLoop("add") \
+        .addVertex("last", PassVertex(-1), "loop") \
+        .addLayer("out", L.RnnOutputLayer(nOut=4, lossFunction="mcxent"),
+                  "last").setOutputs("out")
+    net = ComputationGraph(g.build()).init()
+    x = np.random.default_rng(0).normal(size=(2, 8, 5)).astype(np.float32)
+    out = net.output(x)
+    assert out.shape == (2, 4, 5)
+    # by hand: three times h <- h + mlp(h), feature-last
+    p = net._params["mlp"]
+    h = jnp.swapaxes(jnp.asarray(x), 1, 2)
+    for _ in range(3):
+        h = h + (jax.nn.silu(h @ p["Wg"]) * (h @ p["Wu"])) @ p["Wd"]
+    z = h @ net._params["out"]["W"] + net._params["out"]["b"]
+    np.testing.assert_allclose(out, jnp.swapaxes(jax.nn.softmax(z), 1, 2),
+                               rtol=1e-5, atol=1e-6)
+    with pytest.raises(ValueError, match="one activation a"):
+        net.feedForward(x)
+
+
+# --------------------------------------------------------- hand-written cases
+def test_rotary_embedding_against_a_hand_written_case():
+    x = jnp.arange(2 * 3 * 1 * 4, dtype=jnp.float32).reshape(1, 3, 2, 4)[
+        :, :, :1] + 1.0                                  # [1, 3, 1, 4]
+    out = np.asarray(attention_ops.rotary_embedding(x, theta=100.0))
+    for pos in range(3):
+        a, b, c, d = np.asarray(x)[0, pos, 0]
+        # pairs (0, 2) and (1, 3); angles pos * 100^(0) and pos * 100^(-1/2)
+        t0, t1 = pos * 1.0, pos * 0.1
+        want = [a * np.cos(t0) - c * np.sin(t0), b * np.cos(t1) - d * np.sin(t1),
+                c * np.cos(t0) + a * np.sin(t0), d * np.cos(t1) + b * np.sin(t1)]
+        np.testing.assert_allclose(out[0, pos, 0], want, rtol=1e-5, atol=1e-6)
+    # a rotation: norms kept, position 0 untouched
+    np.testing.assert_allclose(np.linalg.norm(out, axis=-1),
+                               np.linalg.norm(np.asarray(x), axis=-1),
+                               rtol=1e-6)
+    np.testing.assert_array_equal(out[0, 0], np.asarray(x)[0, 0])
+    ref = REFERENCE.rope(x[0], 100.0)
+    np.testing.assert_allclose(out[0], ref, rtol=1e-6, atol=1e-6)
+
+
+@pytest.mark.parametrize("block", [4, 16])
+def test_causal_attention_masks_the_future(block, monkeypatch):
+    monkeypatch.setattr(attention_ops, "CAUSAL_QUERY_BLOCK", block)
+    rng = np.random.default_rng(1)
+    q, k, v = (jnp.asarray(rng.normal(size=(1, 16, 2, 8)), jnp.float32)
+               for _ in range(3))
+    out = attention_ops.causal_attention(q, k, v)
+    # by hand, one head and one row at a time
+    for h in range(2):
+        for t in range(16):
+            s = np.asarray(q[0, t, h]) @ np.asarray(k[0, :t + 1, h]).T / 8 ** 0.5
+            w = np.exp(s - s.max())
+            w /= w.sum()
+            np.testing.assert_allclose(out[0, t, h],
+                                       w @ np.asarray(v[0, :t + 1, h]),
+                                       rtol=1e-4, atol=1e-5)
+    # a later token changes nothing before it
+    k2 = k.at[0, 9].set(100.0)
+    out2 = attention_ops.causal_attention(q, k2, v)
+    np.testing.assert_array_equal(out[0, :9], out2[0, :9])
+    assert not np.allclose(out[0, 9:], out2[0, 9:])
+    # the same values and gradients with and without its own remat
+    g1 = jax.grad(lambda q: jnp.sum(attention_ops.causal_attention(
+        q, k, v) ** 2))(q)
+    g2 = jax.grad(lambda q: jnp.sum(attention_ops.causal_attention(
+        q, k, v, remat=False) ** 2))(q)
+    np.testing.assert_allclose(g1, g2, rtol=1e-5, atol=1e-6)
+
+
+def test_exit_distribution_sums_to_one_and_matches_the_formula():
+    gates = jnp.asarray(np.random.default_rng(2).normal(size=(4, 3, 5)) * 3,
+                        jnp.float32)
+    p = np.exp(np.asarray(L.exit_log_distribution(gates)))
+    np.testing.assert_allclose(p.sum(axis=0), 1.0, rtol=1e-6)
+    lam = 1 / (1 + np.exp(-np.asarray(gates, np.float64)))
+    want = [lam[0], lam[1] * (1 - lam[0]),
+            lam[2] * (1 - lam[0]) * (1 - lam[1]),
+            (1 - lam[0]) * (1 - lam[1]) * (1 - lam[2])]
+    np.testing.assert_allclose(p, np.stack(want), rtol=1e-5, atol=1e-7)
+    np.testing.assert_allclose(REFERENCE.exit_distribution(jnp.asarray(lam)),
+                               np.stack(want), rtol=1e-5, atol=1e-7)
+    # one pass: everything exits there; saturated gates stay finite
+    assert np.exp(np.asarray(L.exit_log_distribution(gates[:1]))).min() == 1.0
+    far = L.exit_log_distribution(jnp.full((4, 1), 200.0))
+    assert np.isfinite(np.asarray(far)[0]).all()
+
+
+def test_the_output_layer_takes_integer_labels_and_never_keeps_two_logits():
+    layer = L.LoopedLMOutputLayer(nOut=16, beta=0.1)
+    layer.set_defaults(NeuralNetConfiguration())
+    layer.infer_nin(InputType("rnn", size=8, timesteps=4, passes=2))
+    params, state = layer.initialize(jax.random.PRNGKey(0))
+    assert set(state) == {"exit_mass", "pass_loss"} \
+        and state["exit_mass"].shape == (2,)
+    rng = np.random.default_rng(0)
+    h = tuple(jnp.asarray(rng.normal(size=(3, 4, 8)), jnp.float32)
+              for _ in range(2))
+    y = jnp.asarray(rng.integers(0, 16, (3, 4)), jnp.int32)
+    loss, aux = layer.loss_from(params, h, y)
+    ces, lams = [], []
+    for a in h:
+        logp = jax.nn.log_softmax(a @ params["W"])
+        ces.append(-jnp.take_along_axis(logp, y[..., None], -1)[..., 0])
+        lams.append(jax.nn.sigmoid(a @ params["gate_w"] + params["gate_b"][0]))
+    p = jnp.stack([lams[0], 1 - lams[0]])
+    want = jnp.mean(p[0] * ces[0] + p[1] * ces[1]
+                    + 0.1 * jnp.sum(p * jnp.log(p), axis=0))
+    assert float(loss) == pytest.approx(float(want), rel=1e-5)
+    np.testing.assert_allclose(aux["exit_mass"], p.mean(axis=(1, 2)),
+                               rtol=1e-5)
+    np.testing.assert_allclose(aux["pass_loss"],
+                               [float(c.mean()) for c in ces], rtol=1e-5)
+    # a masked position carries no loss
+    mask = jnp.ones((3, 4)).at[0, 0].set(0.0)
+    masked, _ = layer.loss_from(params, h, y, mask=mask)
+    y2 = y.at[0, 0].set((int(y[0, 0]) + 1) % 16)
+    assert float(layer.loss_from(params, h, y2, mask=mask)[0]) \
+        == pytest.approx(float(masked), rel=1e-6)
+    # under bf16 the logits stay float32 and the master head float32
+    hb = tuple(a.astype(jnp.bfloat16) for a in h)
+    grads = jax.grad(lambda p: layer.loss_from(p, hb, y)[0])(params)
+    assert grads["W"].dtype == jnp.float32
+    assert layer.apply(params, state, hb, False, None)[0].dtype == jnp.float32
+    with pytest.raises(ValueError, match="from its input"):
+        layer.compute_loss(y, None)
+
+
+def test_a_sequence_layer_refuses_the_public_layout():
+    norm = L.RMSNorm()
+    norm.infer_nin(InputType.recurrent(8, 5))
+    params, _ = norm.initialize(None)
+    with pytest.raises(ValueError, match="computes feature-last"):
+        norm.apply(params, {}, jnp.zeros((2, 8, 5)), False, None)
+    out, _ = norm.apply(params, {}, jnp.full((2, 5, 8), 3.0), False, None)
+    np.testing.assert_allclose(out, 1.0, rtol=1e-5)
+    with pytest.raises(ValueError, match="needs nHidden"):
+        L.GatedMLP()
+    attn = L.CausalSelfAttentionLayer(nHeads=3)
+    with pytest.raises(ValueError, match="does not divide"):
+        attn.infer_nin(InputType.recurrent(8, 5))
+    with pytest.raises(TypeError, match="did you mean 'ropeTheta'"):
+        L.CausalSelfAttentionLayer(ropeTeta=1.0)
+
+
+# ------------------------------------------------------------ the instruments
+def test_the_step_program_says_pass_part_and_remat():
+    net = tiny_net(4)[0].init()
+    cfg = tiny_cfg()
+    profiler.set_profiling_mode("basic")
+    try:
+        stepprogram.clear()
+        tokens_before = stepping.TRAIN_TOKENS.value
+        net.fit(DataSet(*tokens(cfg, 1)[0]))
+        assert stepping.TRAIN_TOKENS.value - tokens_before == 2 * 32
+        mass = [stepping.LOOP_EXIT_MASS.labels(str(t)).value
+                for t in range(1, 5)]
+        assert sum(mass) == pytest.approx(1.0, rel=1e-5)
+        assert stepping.LOOP_PASS_LOSS.labels("4").value > 0
+        maps = stepprogram.maps()
+    finally:
+        profiler.set_profiling_mode(None)
+        stepprogram.clear()
+    entries = [e for m in maps.values() for e in m.values()]
+    passes = {e.loop_pass for e in entries}
+    assert {1, 2, 3, 4} <= passes
+    parts = {e.part for e in entries}
+    assert {"attn_core", "head_loss"} <= parts
+    assert any(e.remat and e.phase == "backward" for e in entries)
+    assert not any(e.remat and e.phase == "forward" for e in entries)
+    attn = [e for e in entries if e.part == "attn_core"]
+    assert all(e.layer and "_attn" in e.layer and e.loop_pass for e in attn)
+    heads = [e for e in entries if e.part == "head_loss"]
+    assert all(e.layer == stepprogram.LOSS_SCOPE for e in heads)
+
+
+def test_marks_of_an_op_name():
+    name = ("jit(step)/transpose(jvp(dl4j_ut3))/jvp(dl4j_ut3)/checkpoint/"
+            "rematted_computation/dl4j_L7_l0_attn/dl4j_attn_core/dot_general")
+    assert stepprogram.marks(name) == (3, "attn_core", True)
+    assert stepprogram.classify(name) == ("backward", "dl4j_L7_l0_attn")
+    assert stepprogram.marks("jit(step)/jvp(dl4j_L1_conv)/conv") \
+        == (None, None, False)
+    assert stepprogram.marks(
+        "jit(step)/jvp(dl4j_loss)/dl4j_ut1/dl4j_head_loss/dot_general") \
+        == (1, "head_loss", False)
+    assert stepprogram.Entry("forward", "dl4j_L1_conv", None, False) \
+        == stepprogram.Entry("forward", "dl4j_L1_conv", None, False, None,
+                             None, False)
+
+
+def test_the_flop_models_know_the_looped_layers():
+    from deeplearning4j_tpu.analysis import graphir
+    from deeplearning4j_tpu.profiler import devicetime
+    cfg = tiny_cfg()
+    net = tiny_net(4)[0].conf_builder()
+    ir = graphir.from_graph(net.conf, batch_size=1)
+    want = MODEL.flops_per_sample(cfg) \
+        + 24 * 0     # nothing else multiplies
+    # the static model counts the whole square of the attention core, the
+    # benchmark's the causal half it requires
+    square = MODEL.layer_applications(cfg) * MODEL.attention_flops(cfg)
+    assert ir.total_flops() == pytest.approx(want + square, rel=1e-6)
+    rows = {name: f for name, _op, f in devicetime.layer_flop_model(net.conf)}
+    assert rows["l0_mlp"] == 4 * 2 * 32 * 3 * 64 * 176
+    assert rows["lm"] == 4 * 2 * 32 * 64 * 512

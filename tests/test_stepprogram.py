@@ -119,7 +119,8 @@ def test_fusion(case):
     body, want = FUSIONS[case]
     got = sp.parse(_module("\n".join(body)))
     assert got["fusion.7"] == want
-    assert got["copy.1"] == sp.Entry("other", None, None, False)
+    # the compiler's unnamed copy works for the fusion that reads it (PR 28)
+    assert got["copy.1"] == want._replace(mixed=False)
     assert "convolution.1" not in got     # a fusion's inside is not listed
     assert sp.module_name(_module("")) == "jit_step"
 
@@ -242,3 +243,36 @@ def test_v5e_resnet50_excerpt(name):
         got = sp.parse(f.read())
     assert set(got) == set(V5E)
     assert got[name] == V5E[name]
+
+
+def test_unnamed_data_movements_adopt_their_reader():
+    """The compiler's asynchronous copies and slices carry no ``op_name``:
+    a ``-start`` adopts, through its ``-done``, the entry of the fusion
+    that reads what it moved; one nobody listed reads stays ``other``."""
+    meta = (', metadata={op_name="jit(step)/transpose(jvp(dl4j_ut2))/'
+            'jvp(dl4j_ut2)/checkpoint/rematted_computation/dl4j_L3_attn/'
+            'dl4j_attn_core/dot_general"}')
+    text = f"""HloModule jit_step, is_scheduled=true
+
+%fused_computation.1 (p0: f32[8,8], p1: f32[8,8]) -> f32[8,8] {{
+  %p0 = f32[8,8]{{1,0}} parameter(0)
+  %p1 = f32[8,8]{{1,0}} parameter(1)
+  ROOT %dot.1 = f32[8,8]{{1,0}} dot(%p0, %p1){meta}
+}}
+
+ENTRY %main.1 (a: f32[8,8], b: f32[8,8]) -> f32[8,8] {{
+  %a = f32[8,8]{{1,0}} parameter(0)
+  %b = f32[8,8]{{1,0}} parameter(1)
+  %slice-start.1 = (f32[8,8]{{1,0}}, f32[8,8]{{1,0}}, u32[]) slice-start(%a)
+  %slice-done.1 = f32[8,8]{{1,0}} slice-done(%slice-start.1)
+  %copy-start.9 = (f32[8,8]{{1,0}}, f32[8,8]{{1,0}}, u32[]) copy-start(%b)
+  %copy-done.9 = f32[8,8]{{1,0}} copy-done(%copy-start.9)
+  ROOT %fusion.7 = f32[8,8]{{1,0}} fusion(%slice-done.1, %b), kind=kOutput, calls=%fused_computation.1
+}}
+"""
+    got = sp.parse(text)
+    want = sp.Entry("backward", "dl4j_L3_attn", None, False, 2, "attn_core",
+                    True)
+    assert got["fusion.7"] == want
+    assert got["slice-done.1"] == got["slice-start.1"] == want
+    assert got["copy-done.9"].phase == got["copy-start.9"].phase == "other"

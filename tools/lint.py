@@ -472,7 +472,8 @@ def run_cost(chip: str = "tpu-v4") -> int:
     failed = checked = 0
     for name, cls in zoo.ZOO_MODELS.items():
         try:
-            report = analyze(cls().conf_builder(), cost=CostSpec(chip=chip),
+            report = analyze(cls.for_cost_gate().conf_builder(),
+                             cost=CostSpec(chip=chip),
                              suppress=suppress)
         except ValueError as e:
             # a typo'd code in [tool.dl4j.cost] suppress must be a clean
