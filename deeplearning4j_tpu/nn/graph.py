@@ -15,7 +15,6 @@ Multiple inputs and multiple outputs (MultiDataSet) are supported.
 
 from __future__ import annotations
 
-import contextlib
 from typing import Any, Dict, List, Optional
 
 import jax
@@ -756,11 +755,9 @@ class ComputationGraph:
             def stretch(p_seg, s_seg, x, key):
                 local, local_fmt, ns = dict(env), dict(fmt), {}
                 local[src] = x
-                with L.rematerialised_stretch() if checkpointed \
-                        else contextlib.nullcontext():
-                    for n in seg:
-                        key = apply_node(n, p_seg, s_seg, local, local_fmt,
-                                         ns, key)
+                for n in seg:
+                    key = apply_node(n, p_seg, s_seg, local, local_fmt,
+                                     ns, key)
                 fmt.update({k: local_fmt[k] for k in names})
                 return local[names[-1]], ns, key
 

@@ -15,7 +15,6 @@ the RETAIN probability, applied to the layer's input.
 
 from __future__ import annotations
 
-import contextlib
 import difflib
 import inspect
 from typing import Any, Dict, Optional, Tuple
@@ -2472,22 +2471,6 @@ class TimeDistributed(Layer):
 # reference's [N, C, T] once at each boundary, as it does for NHWC), so
 # every matmul contracts the minor axis and no layer transposes.
 
-_REMAT_STRETCHES = [0]   # depth of rematerialised_stretch() being traced
-
-
-@contextlib.contextmanager
-def rematerialised_stretch():
-    """Entered by a forward while it traces layers inside a stretch that
-    is rematerialised as a whole (a LoopVertex body in a train step): a
-    layer that would rematerialise a part of itself (the attention core)
-    then leaves that to the stretch."""
-    _REMAT_STRETCHES[0] += 1
-    try:
-        yield
-    finally:
-        _REMAT_STRETCHES[0] -= 1
-
-
 def _feature_last(layer, x):
     """The layers below are handed [N, T, C] by the graph's forwards; a
     caller that hands them the public [N, C, T] gets told, not garbage."""
@@ -2550,9 +2533,9 @@ class CausalSelfAttentionLayer(Layer):
     rotary embedding on q and k over the whole head
     (``ops.attention.rotary_embedding``, base ``ropeTheta``),
     ``softmax(q k^T / sqrt(headSize) + causal mask) v``, then ``Wo``. The
-    attention core (``ops.attention.causal_attention``) is rematerialised
-    in the backward pass, by itself or with the stretch of a loop's body
-    it stands in: no [T, T] tensor is kept for it."""
+    attention core (``ops.attention.causal_attention``) has a backward of
+    its own that keeps the output and the row log-sum-exp: no [T, T]
+    tensor is kept for it, in a rematerialised stretch or outside one."""
 
     input_kind = "rnn"
 
@@ -2600,8 +2583,7 @@ class CausalSelfAttentionLayer(Layer):
         q = attention_ops.rotary_embedding(q, self.rope_theta)
         k = attention_ops.rotary_embedding(k, self.rope_theta)
         with jax.named_scope(_stepprogram.ATTN_CORE_SCOPE):
-            o = attention_ops.causal_attention(
-                q, k, v, remat=not _REMAT_STRETCHES[0])
+            o = attention_ops.causal_attention(q, k, v)
         return o.reshape(N, T, H * hs) @ params["Wo"], state
 
     def output_type(self, it: InputType) -> InputType:

@@ -14,10 +14,13 @@ modern [B, T, H, D]; the reference-layout wrappers live at the bottom.
 
 from __future__ import annotations
 
+import functools
 
 import jax
 import jax.numpy as jnp
 from jax import lax
+
+from deeplearning4j_tpu import profiler as _prof
 
 
 def dot_product_attention(q, k, v, *, mask=None, scaled: bool = True,
@@ -168,46 +171,188 @@ def rotary_embedding(x, theta: float = 10000.0):
 #: query rows a block of :func:`causal_attention` takes. A block reads only
 #: the keys at or before its last row, so of the square above the diagonal
 #: it computes 1/(2*blocks). Measured on a v5e at [1, 4096, 16, 128] bf16,
-#: forward + rematerialised forward + backward of one call (PR 28): the
-#: whole square 22.5 ms, blocks of 2,048 17.5, 1,024 13.7, 512 8.2, 256
-#: 5.7; this repo's Pallas flash forward with its blockwise backward 9.8,
-#: jax's Pallas TPU flash attention 19.7. So plain matmuls in blocks of
-#: 256, and no kernel, whatever the backend.
-CAUSAL_QUERY_BLOCK = 256
+#: forward + rematerialised forward + backward of one call (PR 30; the
+#: probe is PR 28's), heads a group from :data:`CAUSAL_SCORE_BYTES`:
+#:
+#:     rows a block    128     256     512    1,024   autodiff, 256 rows
+#:     heads a group    16       8       4       2    (the path replaced)
+#:     ms a call       5.42    3.31    3.30    3.53        6.74
+#:
+#: 512 and 256 tie; 512 makes half as many blocks to compile.
+CAUSAL_QUERY_BLOCK = 512
+
+#: bytes of the float32 scores [B, heads of a group, block, keys] the core
+#: has in flight at once: the heads are taken in the largest groups whose
+#: scores fit, one group and one block after the other, and the compiler
+#: then keeps a block's scores, probabilities and the dk/dv accumulators
+#: on chip instead of in HBM. Same probe, 512 rows: 16 MiB (2 heads)
+#: 3.34 ms, 32 MiB (4) 3.30, 64 MiB (8) 4.17; all 16 heads at 256 rows
+#: (64 MiB) 5.84, with the barrier between blocks or without.
+CAUSAL_SCORE_BYTES = 32 << 20
+
+_CORE_LOWERED = _prof.get_registry().counter(
+    "dl4j_attn_core_lowered_total",
+    "Traces of ops.attention.causal_attention (one a lowering of each "
+    "call site, not one a step) by the path its shapes took: query "
+    "blocks, or one block because T is no multiple of the block",
+    labelnames=("path",))
+
+_QK, _PV, _PTX = "bqhd,bkhd->bhqk", "bhqk,bkhd->bqhd", "bhqk,bqhd->bkhd"
 
 
-def _causal_block(q, k, v, first_row: int):
-    """Rows ``first_row..`` of ``q`` against the keys ``k`` (all at or
-    before the block's last row): float32 scores and softmax, the
-    probabilities rounded to ``v``'s dtype for the weighted sum."""
-    D = q.shape[-1]
-    s = jnp.einsum("bqhd,bkhd->bhqk", q, k,
-                   preferred_element_type=jnp.float32) * (D ** -0.5)
-    rows = first_row + jnp.arange(q.shape[1])
-    s = jnp.where(rows[:, None] >= jnp.arange(k.shape[1])[None, :],
-                  s, jnp.float32(-1e30))
-    p = jax.nn.softmax(s, axis=-1).astype(v.dtype)
-    return jnp.einsum("bhqk,bkhd->bqhd", p, v)
-
-
-def _causal_blocks(q, k, v):
-    T = q.shape[1]
+def _causal_plan(B, T, H):
+    """``(block, heads a group)`` for a call's shapes: one path, whose
+    block and group counts follow from what it is handed."""
     blk = CAUSAL_QUERY_BLOCK if T % CAUSAL_QUERY_BLOCK == 0 else T
-    outs = [_causal_block(q[:, i:i + blk], k[:, :i + blk], v[:, :i + blk], i)
-            for i in range(0, T, blk)]
-    return outs[0] if len(outs) == 1 else jnp.concatenate(outs, axis=1)
+    fit = [g for g in range(1, H + 1)
+           if H % g == 0 and 4 * B * g * blk * T <= CAUSAL_SCORE_BYTES]
+    return blk, max(fit, default=1)
 
 
-def causal_attention(q, k, v, *, remat: bool = True):
+def _then(nxt, done):
+    """Both unchanged, but ``nxt`` exists only once ``done`` does: the
+    compiler may not start the next block before the last has finished,
+    so one block's [q, k] tensors are alive at a time and stay on chip."""
+    return lax.optimization_barrier((nxt, done))
+
+
+def _rows(x, i, e, axis=1):
+    return lax.slice_in_dim(x, i, e, axis=axis)
+
+
+def _scores(q, k, first_row):
+    """float32 ``q k^T / sqrt(D)`` of the query rows ``first_row..``
+    against the keys ``0..``, the keys after a row at -1e30. The mask
+    rides in the product's own fusion over the whole block: cut to the
+    diagonal tile it costs a concatenation of the scores (v5e: 11.1 ms
+    against 5.8 for the probe above)."""
+    s = jnp.einsum(_QK, q, k, preferred_element_type=jnp.float32) \
+        * (q.shape[-1] ** -0.5)
+    rows = first_row + jnp.arange(q.shape[1])
+    return jnp.where(rows[:, None] >= jnp.arange(k.shape[1])[None, :],
+                     s, jnp.float32(-1e30))
+
+
+def _over_head_groups(fn, hg, args, head_axes, out_axes):
+    """``fn`` on groups of ``hg`` heads, one after the other."""
+    H = args[0].shape[2]
+    if hg == H:
+        return fn(*args)
+    parts = []
+    for h in range(0, H, hg):
+        xs = [_rows(x, h, h + hg, ax) for x, ax in zip(args, head_axes)]
+        if parts:
+            xs, parts[-1] = _then(xs, parts[-1])
+        parts.append(fn(*xs))
+    return tuple(jnp.concatenate([p[n] for p in parts], ax)
+                 for n, ax in enumerate(out_axes))
+
+
+# The two functions below are jitted for what a jit shares, not for a
+# dispatch: a step calls them once a head group, a layer application, a
+# pass and a phase (96 times each in a step of 4 passes of 6 layers), and
+# JAX then traces and lowers each once and calls it by name; XLA inlines
+# the calls, each under its caller's scopes, so the compiled step and its
+# map are what they would be written out (PR 30: set-up 83 s against the
+# parent's 38 with every block of every call traced anew).
+@functools.partial(jax.jit, static_argnums=3)
+def _fwd_heads(q, k, v, blk):
+    """``(O, lse)`` of these heads: the output and the float32 row
+    log-sum-exp [B, H, T] of the scaled, masked scores, a block of ``blk``
+    query rows after the other; nothing of [T, T] leaves it."""
+    outs, lses = [], []
+    for i in range(0, q.shape[1], blk):
+        e = i + blk
+        qi = _rows(q, i, e)
+        if outs:
+            qi, outs[-1] = _then(qi, outs[-1])
+        s = _scores(qi, _rows(k, 0, e), i)
+        m = jnp.max(s, axis=-1)
+        p = jnp.exp(s - m[..., None])
+        l = jnp.sum(p, axis=-1)
+        o = jnp.einsum(_PV, p.astype(v.dtype), _rows(v, 0, e),
+                       preferred_element_type=jnp.float32)
+        outs.append((o / jnp.swapaxes(l, 1, 2)[..., None]).astype(q.dtype))
+        lses.append(m + jnp.log(l))
+    return jnp.concatenate(outs, 1), jnp.concatenate(lses, -1)
+
+
+@functools.partial(jax.jit, static_argnums=6)
+def _bwd_heads(q, k, v, o, lse, do, blk):
+    """``(dq, dk, dv)`` of these heads, FlashAttention's backward (Dao et
+    al. 2022, algorithm 4) in plain matmuls: ``p`` again from the scores
+    and ``lse``, ``delta = rowsum(dO * O)`` over [T, D], ``ds = p * (dO
+    v^T - delta) / sqrt(D)``; bf16 operands, float32 products, ``dk`` and
+    ``dv`` summed in float32 over the query blocks and rounded once."""
+    scale = q.shape[-1] ** -0.5
+    delta = jnp.swapaxes(jnp.sum(
+        do.astype(jnp.float32) * o.astype(jnp.float32), -1), 1, 2)
+    dk = jnp.zeros(k.shape, jnp.float32)
+    dv = jnp.zeros(v.shape, jnp.float32)
+    dqs = []
+    for i in range(0, q.shape[1], blk):
+        e = i + blk
+        qi, doi, ki, vi = (_rows(q, i, e), _rows(do, i, e),
+                           _rows(k, 0, e), _rows(v, 0, e))
+        if dqs:
+            (qi, doi), (dqs[-1], dk, dv) = _then(
+                (qi, doi), (dqs[-1], dk, dv))
+        p = jnp.exp(_scores(qi, ki, i) - _rows(lse, i, e, 2)[..., None])
+        dvi = jnp.einsum(_PTX, p.astype(v.dtype), doi,
+                         preferred_element_type=jnp.float32)
+        dp = jnp.einsum(_QK, doi, vi, preferred_element_type=jnp.float32)
+        ds = (p * (dp - _rows(delta, i, e, 2)[..., None]) * scale) \
+            .astype(q.dtype)
+        dqs.append(jnp.einsum(_PV, ds, ki, preferred_element_type=jnp.float32)
+                   .astype(q.dtype))
+        dki = jnp.einsum(_PTX, ds, qi, preferred_element_type=jnp.float32)
+        dk = lax.dynamic_update_slice_in_dim(dk, _rows(dk, 0, e) + dki, 0, 1)
+        dv = lax.dynamic_update_slice_in_dim(dv, _rows(dv, 0, e) + dvi, 0, 1)
+    return jnp.concatenate(dqs, 1), dk.astype(k.dtype), dv.astype(v.dtype)
+
+
+def _causal_fwd(q, k, v):
+    """``(O, lse)`` of every head, a group after the other."""
+    blk, hg = _causal_plan(*q.shape[:3])
+    return _over_head_groups(lambda *xs: _fwd_heads(*xs, blk), hg,
+                             (q, k, v), (2, 2, 2), (2, 1))
+
+
+def _causal_bwd(res, do):
+    """``(dq, dk, dv)`` from what the forward kept and ``dO``."""
+    blk, hg = _causal_plan(*res[0].shape[:3])
+    return _over_head_groups(lambda *xs: _bwd_heads(*xs, blk), hg,
+                             (*res, do), (2, 2, 2, 2, 1, 2), (2, 2, 2))
+
+
+@jax.custom_vjp
+def _causal_core(q, k, v):
+    return _causal_fwd(q, k, v)[0]
+
+
+def _causal_core_fwd(q, k, v):
+    o, lse = _causal_fwd(q, k, v)
+    return o, (q, k, v, o, lse)
+
+
+_causal_core.defvjp(_causal_core_fwd, _causal_bwd)
+
+
+def causal_attention(q, k, v):
     """Causal scaled dot-product attention for a training step, [B, T, H,
-    D] each. Plain XLA matmuls over query blocks of
-    :data:`CAUSAL_QUERY_BLOCK` rows, each against the keys up to its own
-    last row. ``remat``: rematerialise the call in the backward pass, so
-    that no [T, T] probability tensor outlives it; a caller that already
-    runs inside a rematerialised stretch passes False, or the core would
-    be computed a third time for nothing."""
-    fn = jax.checkpoint(_causal_blocks) if remat else _causal_blocks
-    return fn(q, k, v)
+    D] each: a forward and a backward written by hand (``jax.custom_vjp``)
+    in plain XLA matmuls over query blocks of :data:`CAUSAL_QUERY_BLOCK`
+    rows, each against the keys up to its own last row, the heads in
+    groups sized by :data:`CAUSAL_SCORE_BYTES`; a ``T`` that is no
+    multiple of the block runs the same pair as one block. What the
+    backward keeps is ``q, k, v``, the output and the float32 row
+    log-sum-exp, so no [T, T] tensor outlives the call, inside a
+    rematerialised stretch or outside one. Scores, softmax statistics
+    and every accumulator are float32; the probabilities are rounded
+    once, to ``v``'s dtype, before a product."""
+    blk, _ = _causal_plan(*q.shape[:3])
+    _CORE_LOWERED.labels("blocked" if blk < q.shape[1] else "single").inc()
+    return _causal_core(q, k, v)
 
 
 # --------------------------------------------------- reference-layout shims

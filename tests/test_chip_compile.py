@@ -171,15 +171,25 @@ def test_fused_epilogue_rides_in_the_conv_fusions(one_chip, shape, alpha):
 def test_causal_attention_compiles_for_v5e_without_a_kernel(one_chip):
     """The looped language model's attention core at the benchmark cell's
     shape, forward and backward: plain matmuls in query blocks, no
-    ``custom-call`` of the program's (PR 28's chip probe of five
-    implementations chose it), and no [T, T] tensor for any head: the
-    widest score block is 256 query rows by 4,096 keys."""
+    ``custom-call`` of the program's (the chip probes of PR 28 and PR 30
+    chose it), no [T, T] tensor for any head (the widest score block is
+    512 query rows by 4,096 keys of four heads), and a backward of its own
+    that runs the forward's scores again from the row log-sum-exp instead
+    of rematerialising the call."""
     from deeplearning4j_tpu.ops import attention as attention_ops
     shape = (1, 4096, 16, 128)
+    assert attention_ops._causal_plan(*shape[:3]) == (512, 4)
     specs = [jax.ShapeDtypeStruct(shape, jnp.bfloat16, sharding=one_chip)] * 3
     grad = jax.grad(lambda q, k, v: jnp.sum(attention_ops.causal_attention(
         q, k, v).astype(jnp.float32)), argnums=(0, 1, 2))
     text = jax.jit(grad).lower(*specs).compile().as_text()
     assert "tpu_custom_call" not in text
     assert not re.search(r"4096,4096\]", text)
-    assert "rematted_computation" in text
+    assert "rematted_computation" not in text
+    # what the head groups and the barriers between blocks buy: every
+    # float32 [q, k] tensor that passes between two fusions stays on chip
+    # (memory space S(1)); none is written to HBM
+    entry = text[text.index("\nENTRY "):]
+    scores = re.findall(r"= f32\[4,512,\d+\]\{([^}]*)\}", entry)
+    assert scores and all("S(1)" in layout for layout in scores), \
+        [layout for layout in scores if "S(1)" not in layout][:3]
