@@ -47,10 +47,6 @@ probe — throughput vs p99 + shed rates, plus the ISSUE-12 ingress
 section: wire-path p50/p99 + shed rate vs in-process submit at the
 same load, per-batch D2H bytes full-logits vs results-only (asserted),
 and the W111 registry-roll lint check — into ``detail.serving``;
-``--cold-start`` folds ``benchmarks/probe_cold_start.py`` — fresh-
-process first-dispatch seconds with the persistent compile cache off
-vs. populated for fit / resume / serving warmup, with the
-zero-disk-miss warm pin asserted — into ``detail.cold_start``;
 ``--device-timing`` folds ``benchmarks/probe_device_timing.py`` — the
 ISSUE-14 bridge checks: non-empty per-layer device-time MFU attribution
 matching the analyzer FLOP model, fused-epilogue bit-closeness (fp32)
@@ -854,15 +850,6 @@ def bench_device_timing(quick: bool = False):
                       ["--quick"] if quick else [], timeout=900)
 
 
-def bench_cold_start(quick: bool = False):
-    """Cold-start probe (benchmarks/probe_cold_start.py): fresh-process
-    first-dispatch latency with the persistent compile cache off vs.
-    populated, across fit, resume, and serving warmup. The probe itself
-    asserts zero disk-miss compiles for the warm fit/serving runs."""
-    return _run_probe("probe_cold_start.py",
-                      ["--quick"] if quick else [], timeout=1800)
-
-
 def bench_obs(quick: bool = False):
     """Observability-plane cost probe (benchmarks/probe_obs_overhead.py):
     tracecontext / flightrec / SLO-engine fit columns and the serve-path
@@ -1039,7 +1026,6 @@ def _aggregate(draws, primary):
 
 #: flag -> (detail key, probe): each selects a probe-only run (docstring)
 _PROBES = {"--serving": ("serving", bench_serving),
-           "--cold-start": ("cold_start", bench_cold_start),
            "--device-timing": ("device_timing", bench_device_timing),
            "--obs": ("obs_overhead", bench_obs),
            "--lifecycle": ("lifecycle", bench_lifecycle)}

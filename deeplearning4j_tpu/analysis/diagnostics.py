@@ -114,7 +114,7 @@ DIAGNOSTIC_CODES = {
                  "active version serves warm), so post-roll traffic "
                  "XLA-compiles under live load",
     "DL4J-W112": "serving warmup without a persistent compile cache: no "
-                 "DL4J_TPU_COMPILE_CACHE_DIR / compilecache.configure() "
+                 "JAX_COMPILATION_CACHE_DIR / place_jax_compile_cache() "
                  "directory is set (or the directory is unwritable), so "
                  "every fresh process, rollout, and hot-swap staging pays "
                  "full XLA compile instead of a disk hit",

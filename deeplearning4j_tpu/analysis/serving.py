@@ -21,11 +21,11 @@ same contract as the rest of ``analysis``):
   buckets — the first post-roll request at an unwarmed (bucket, shape)
   XLA-compiles under live traffic, exactly the cold-start the zero-drop
   hot-swap exists to avoid.
-- ``DL4J-W112``: a serving/registry warmup running WITHOUT a persistent
-  compile cache (no ``DL4J_TPU_COMPILE_CACHE_DIR`` /
-  ``nn.compilecache.configure()`` directory, or an unwritable one) —
-  every fresh process, rollout, and hot-swap staging pays full XLA
-  compile where a populated cache would deserialize from disk. Checked
+- ``DL4J-W112``: a serving/registry warmup running WITHOUT JAX's
+  persistent compilation cache (no ``JAX_COMPILATION_CACHE_DIR`` and no
+  ``utils.environment.place_jax_compile_cache()``, or an unwritable
+  directory) — every fresh process, rollout, and hot-swap staging pays
+  full XLA compile where a populated cache would read from disk. Checked
   only when the lint runs on behalf of an actual ``warmup()``
   (``check_cache=True``): a pure-static ``validate()`` stays silent so
   config linting is environment-independent.
@@ -91,21 +91,21 @@ def _activation_bytes_per_example(conf, shapes, itemsize: int) -> float:
 
 
 def lint_compile_cache(context: str = "serving warmup") -> List[Diagnostic]:
-    """The DL4J-W112 check: is a persistent compile cache configured and
-    writable? jax-free (``nn.compilecache``'s config half imports no
-    accelerator stack)."""
-    from deeplearning4j_tpu.nn.compilecache import ENV_DIR, cache_dir_status
-    directory, writable = cache_dir_status()
+    """The DL4J-W112 check: is JAX's persistent compilation cache placed
+    and writable? jax-free (``utils.environment`` answers)."""
+    from deeplearning4j_tpu.utils.environment import jax_compile_cache_status
+    directory, writable = jax_compile_cache_status()
     if directory is None:
         return [Diagnostic(
             "DL4J-W112", Severity.WARNING, context,
             "no persistent compile cache is configured — every fresh "
             "process, rollout, and hot-swap staging pays full XLA "
             "compile for programs an earlier run already compiled",
-            fix_hint=f"set {ENV_DIR}=/path/shared/by/your/fleet (or call "
-                     "nn.compilecache.configure(dir)) so warmup "
-                     "deserializes previously-seen (model, bucket, mesh, "
-                     "policy) programs from disk")]
+            fix_hint="set JAX_COMPILATION_CACHE_DIR=/path/shared/by/your/"
+                     "fleet (or call utils.environment."
+                     "place_jax_compile_cache() before the first compile) "
+                     "so warmup reads previously-seen (model, bucket, "
+                     "mesh, policy) programs from disk")]
     if not writable:
         return [Diagnostic(
             "DL4J-W112", Severity.WARNING, context,
@@ -114,7 +114,7 @@ def lint_compile_cache(context: str = "serving warmup") -> List[Diagnostic]:
             "rollouts on new (model, bucket, mesh, policy) tuples still "
             "pay full compile",
             fix_hint="fix the directory permissions (or point "
-                     f"{ENV_DIR} at a writable path)")]
+                     "JAX_COMPILATION_CACHE_DIR at a writable path)")]
     return []
 
 

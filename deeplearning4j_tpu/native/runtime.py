@@ -21,7 +21,6 @@ C ABI requires jax (jax is used here only as a convenient StableHLO
 from __future__ import annotations
 
 import ctypes
-import hashlib
 import os
 import subprocess
 import time
@@ -260,14 +259,6 @@ class NativeRuntime:
     def __init__(self, handle: int, plugin_path: str):
         self._h = handle
         self.plugin_path = plugin_path
-        # persistent-tier bookkeeping: keys already resolved against the
-        # disk store this process (hit OR miss) — repeat compiles of the
-        # same program go straight to dl4j_compile's in-process cache
-        # instead of re-reading/re-hashing the disk entry every call;
-        # disk-deserialized executables are memoized here because the C
-        # in-process cache never saw their program bytes
-        self._disk_seen: set = set()
-        self._deser_memo: dict = {}
 
     @classmethod
     def create(cls, plugin_path: str = None,
@@ -325,102 +316,15 @@ class NativeRuntime:
         return {"size": int(size), "hits": int(hits.value),
                 "misses": int(misses.value)}
 
-    def _disk_cache_key(self, program: bytes, fmt: str, opts: bytes):
-        """Persistent-cache key for a native compile: content-addressed
-        over the StableHLO/HLO bytes + serialized compile options, with
-        the plugin path and platform standing in for the mesh/runtime
-        half of the key (nn.compilecache adds the format/version gate)."""
-        from deeplearning4j_tpu.nn import compilecache as _cc
-        return _cc.content_key(
-            "native:compile", program,
-            key_parts=(fmt, hashlib.sha256(opts).hexdigest(),
-                       os.path.basename(self.plugin_path),
-                       self.platform_name))
-
-    def _try_deserialize(self, blob: bytes):
-        """Load a persisted PJRT executable through the OPTIONAL
-        ``dl4j_executable_deserialize`` C entry point. Returns a handle
-        or None — older builds of libdl4j_tpu_native.so (no
-        serialization support) and load failures both degrade to a
-        fresh compile, never an error."""
-        lib = _lib()
-        fn = getattr(lib, "dl4j_executable_deserialize", None)
-        if fn is None:
-            return None
-        fn.restype = ctypes.c_void_p
-        fn.argtypes = [ctypes.c_void_p, ctypes.c_char_p, ctypes.c_int64,
-                       ctypes.c_char_p, ctypes.c_size_t]
-        err = ctypes.create_string_buffer(2048)
-        return fn(self._h, blob, len(blob), err, len(err)) or None
-
-    def _try_serialize(self, handle) -> Optional[bytes]:
-        """Serialize a compiled executable through the OPTIONAL
-        ``dl4j_executable_serialize`` C entry point (None when the
-        loaded library predates it)."""
-        lib = _lib()
-        fn = getattr(lib, "dl4j_executable_serialize", None)
-        if fn is None:
-            return None
-        fn.restype = ctypes.c_int64
-        fn.argtypes = [ctypes.c_void_p, ctypes.POINTER(ctypes.c_void_p),
-                       ctypes.c_char_p, ctypes.c_size_t]
-        out = ctypes.c_void_p()
-        err = ctypes.create_string_buffer(2048)
-        n = fn(handle, ctypes.byref(out), err, len(err))
-        if n <= 0 or not out:
-            return None
-        try:
-            return ctypes.string_at(out, n)
-        finally:
-            free = getattr(lib, "dl4j_free_buffer", None)
-            if free is not None:
-                free.argtypes = [ctypes.c_void_p]
-                free(out)
-
     def compile(self, program, fmt: str = "mlir",
                 compile_options: bytes = None) -> NativeExecutable:
         """Compile StableHLO MLIR text/bytecode (or serialized HLO proto
         with fmt='hlo'); cached by (program, options) content hash in
-        the in-process executable cache, and — when the persistent
-        compile cache (nn.compilecache) is configured AND the native
-        library exposes the optional serialize/deserialize entry points
-        — by the shared on-disk store, so a fresh process skips the
-        PJRT compile for previously-seen programs."""
-        from deeplearning4j_tpu.nn import compilecache as _cc
+        the in-process executable cache."""
         if isinstance(program, str):
             program = program.encode()
         opts = compile_options if compile_options is not None \
             else _default_compile_options()
-        disk = _cc.disk_cache()
-        key = None
-        if disk is not None:
-            try:
-                key = self._disk_cache_key(program, fmt, opts)
-                memo = self._deser_memo.get(key)
-                if memo is not None and memo._h:
-                    # repeat compile of a disk-loaded program: the C
-                    # in-process cache never saw its bytes, so the memo
-                    # IS its in-process tier (one shared executable,
-                    # like a C-cache hit)
-                    _M_CACHE_HITS.inc()
-                    return memo
-                if key in self._disk_seen:
-                    key = None      # already resolved (miss or released
-                                    # memo): take the in-process C path
-                else:
-                    blob = disk.get(key)
-                    if blob is not None:
-                        t0 = time.perf_counter()
-                        h = self._try_deserialize(blob)
-                        if h is not None:
-                            _M_CACHE_HITS.inc()
-                            _cc.note_disk_hit(time.perf_counter() - t0)
-                            exe = NativeExecutable(self, h, True)
-                            self._disk_seen.add(key)
-                            self._deser_memo[key] = exe
-                            return exe
-            except Exception:       # the disk tier is an accelerant only
-                key = None
         hit = ctypes.c_int(0)
         err = ctypes.create_string_buffer(4096)
         with _prof.trace_span("native:compile", fmt=fmt,
@@ -434,31 +338,9 @@ class NativeRuntime:
             raise NativeRuntimeError(err.value.decode() or "compile failed")
         if hit.value:
             _M_CACHE_HITS.inc()
-            if key is not None:
-                # the C cache had it but the disk tier did not (we only
-                # reach here with a non-None key after a disk miss this
-                # call): mark resolved so repeats skip the disk read,
-                # and backfill the entry for OTHER processes
-                self._disk_seen.add(key)
-                blob = self._try_serialize(h)
-                if blob:
-                    try:
-                        disk.put(key, blob, scope="native:compile")
-                    except OSError:
-                        pass
         else:
             _M_CACHE_MISSES.inc()
             _M_COMPILE_SECONDS.observe(dt)
-            _cc.note_cold_compile(dt)
-            if key is not None:
-                _cc.note_disk_miss()
-                self._disk_seen.add(key)    # resolved: repeats take the
-                blob = self._try_serialize(h)   # in-process C cache
-                if blob:
-                    try:
-                        disk.put(key, blob, scope="native:compile")
-                    except OSError:
-                        pass
             # recompile-churn seam: each fresh program body this client
             # compiles is a distinct signature (steady-state training
             # should converge on a handful)

@@ -1,65 +1,37 @@
-"""Persistent/AOT compilation cache + the unified warmup API — kill
-cold start.
+"""AOT compilation ahead of the first dispatch + the unified warmup API.
 
-Every fresh process pays full XLA compile on its first dispatch; that
-cost is exactly why preemption resume (train.resilience), elastic
-shrink re-warm (parallel.elastic), serving bucket-ladder warmup
-(serving.server), and registry hot-swap staging (serving.registry) are
-the expensive moments at scale. TVM and PyGraph (PAPERS.md) both show
-ahead-of-time graph compilation/capture amortizing compile cost across
-runs — this module is that layer for the whole stack:
+Every fresh process pays an XLA compile on its first dispatch; that is
+why preemption resume (train.resilience), elastic shrink re-warm
+(parallel.elastic), serving bucket-ladder warmup (serving.server) and
+registry hot-swap staging (serving.registry) are the expensive moments
+at scale. Two things make them cheap, and only the first lives here:
 
-- :class:`DiskCompileCache` — a content-addressed on-disk store of
-  serialized XLA executables. The key is a SHA-256 over (scope,
-  lowered StableHLO text — which already embeds the graph structure,
-  input signature/bucket shape, mesh/sharding annotations, and the
-  PrecisionPolicy's traced casts — explicit key parts like the model
-  fingerprint and policy signature, and the jax/jaxlib/backend
-  versions). Corrupt entries are QUARANTINED (renamed aside, never
-  trusted); version-mismatched entries are ignored and rewritten.
-  Writes are atomic (temp file + ``os.replace``, the PR-5 checkpoint
-  pattern), so concurrent writers — many processes warming the same
-  model — race safely: last identical write wins.
-- :class:`CachedDispatch` — a ``jax.jit`` drop-in that sits behind the
-  networks' existing signature-keyed step caches. Until the persistent
-  cache is enabled (or :meth:`CachedDispatch.warm` is called) it
-  delegates straight to the jitted function — zero behavioural change.
-  With a cache dir configured it goes AOT: ``lower()`` the program,
-  content-address it, ``deserialize`` from disk on a hit (warm) or
-  ``compile()`` + persist on a miss (cold). ``warm()`` compiles WITHOUT
-  executing — warmup never touches model state.
+- :class:`CachedDispatch` — a ``jax.jit`` drop-in behind the networks'
+  signature-keyed step caches. Until :meth:`CachedDispatch.warm` is
+  called it delegates straight to the jitted function. ``warm()``
+  lowers and compiles a signature WITHOUT executing (warmup never
+  touches model state) and keeps the executable in an in-process table.
 - :func:`warmup` — the ONE entry point fit, resume, shrink, and
   serving all call: ``warmup(model, [((32, 784), (32, 10))])`` AOT-
   compiles the train step (megastep with ``steps_per_dispatch=K``),
   ``warmup(model, [(8, 3, 32, 32)])`` the inference forward, and
   ``warmup(server, [(4,)])`` delegates to the serving bucket-ladder
-  warmup. A registry hot-swap on a previously-seen (model, bucket,
-  mesh, policy) tuple therefore hits disk instead of recompiling.
+  warmup.
 
-Enable with ``configure("/path/to/cache")`` or the
-``DL4J_TPU_COMPILE_CACHE_DIR`` environment variable (read lazily, so
-tests and launchers can set it before the first compile).
+The persistent cache — what makes a compile in a SECOND process cheap —
+is JAX's own, under ``jit`` and ``lower().compile()`` alike;
+``utils.environment`` places it and answers where it is.
 
-Metrics: ``dl4j_compile_cache_{hits,misses,evictions}_total{scope=
-disk|memory}``, ``dl4j_compile_cache_quarantined_total``, and
-``dl4j_compile_seconds{state=cold|warm}`` (cold = real XLA compile,
-warm = disk-hit deserialize). ``bench.py --cold-start`` measures the
-end-to-end effect: first-dispatch latency of a fresh process with the
-cache off vs. populated, across fit, resume, and serving warmup.
+Metrics: ``dl4j_compile_cache_{hits,misses}_total{scope="memory"}`` and
+``dl4j_compile_seconds{state="cold"}`` (an AOT compile as this process
+saw it: seconds where JAX's persistent cache missed, a fraction of one
+where it hit).
 
-IMPORTANT: jax-free at module scope — ``analysis/serving.py`` consults
-:func:`cache_dir_status` for the DL4J-W112 lint from environments with
-no accelerator stack (the jax-blocked subprocess pin covers ``nn``'s
-static half). jax loads lazily, only on the compile path.
+jax-free at module scope; jax loads lazily, on the compile path.
 """
 
 from __future__ import annotations
 
-import hashlib
-import json
-import os
-import pickle
-import threading
 import time
 import warnings
 from typing import Optional
@@ -69,416 +41,47 @@ from deeplearning4j_tpu.profiler.metrics import get_registry
 _REG = get_registry()
 CACHE_HITS = _REG.counter(
     "dl4j_compile_cache_hits_total",
-    "Compile-cache hits by tier: memory = an already-AOT-compiled "
-    "executable served a dispatch, disk = a fresh program was "
-    "deserialized from the persistent store instead of compiled",
+    "Dispatches served by an already-AOT-compiled executable of this "
+    "process (scope=memory)",
     labelnames=("scope",))
 CACHE_MISSES = _REG.counter(
     "dl4j_compile_cache_misses_total",
-    "Compile-cache misses by tier: memory = first sight of a dispatch "
-    "signature in this process, disk = the persistent store had no "
-    "entry (a real XLA compile followed)",
+    "First sight of a dispatch signature on the AOT path in this "
+    "process (scope=memory): an AOT compile followed",
     labelnames=("scope",))
-CACHE_EVICTIONS = _REG.counter(
-    "dl4j_compile_cache_evictions_total",
-    "Entries evicted from a compile-cache tier (disk: LRU past "
-    "max_entries; memory: never — programs live with their model)",
-    labelnames=("scope",))
-CACHE_QUARANTINED = _REG.counter(
-    "dl4j_compile_cache_quarantined_total",
-    "Corrupt persistent-cache entries (bad magic/header/checksum) "
-    "renamed aside at read time instead of trusted")
 COMPILE_SECONDS = _REG.histogram(
     "dl4j_compile_seconds",
-    "Program acquisition latency split by state: cold = real XLA "
-    "compile, warm = deserialize of a persistent-cache hit",
+    "AOT program acquisition latency (state=cold: lower().compile(), "
+    "whether or not JAX's persistent cache served it)",
     labelnames=("state",),
     buckets=(0.001, 0.005, 0.01, 0.05, 0.1, 0.25, 0.5, 1.0, 2.5, 5.0,
              10.0, 30.0, 60.0))
 
 # prebound children: the memory-hit increment sits on the dispatch hot path
 _HITS_MEM = CACHE_HITS.labels(scope="memory")
-_HITS_DISK = CACHE_HITS.labels(scope="disk")
 _MISS_MEM = CACHE_MISSES.labels(scope="memory")
-_MISS_DISK = CACHE_MISSES.labels(scope="disk")
-_EVICT_DISK = CACHE_EVICTIONS.labels(scope="disk")
-# registered so the series exists even though memory entries never evict
-CACHE_EVICTIONS.labels(scope="memory")
 _COLD = COMPILE_SECONDS.labels(state="cold")
-_WARM = COMPILE_SECONDS.labels(state="warm")
 
-ENV_DIR = "DL4J_TPU_COMPILE_CACHE_DIR"
-ENV_MAX_ENTRIES = "DL4J_TPU_COMPILE_CACHE_MAX_ENTRIES"
-
-_UNSET = object()
-_LOCK = threading.RLock()
-_CONFIGURED_DIR = _UNSET            # explicit configure() overrides the env
-_CONFIGURED_MAX: Optional[int] = None
-_DISK: Optional["DiskCompileCache"] = None
-
-#: per-process aggregates for cache_stats() / the cold-start probe —
-#: plain ints mutated under the GIL (single += per event)
+#: per-process aggregates for cache_stats() — plain numbers mutated
+#: under the GIL (single += per event)
 _STATS = {"memory_hits": 0, "memory_misses": 0,
-          "disk_hits": 0, "disk_misses": 0,
-          "cold_seconds": 0.0, "warm_seconds": 0.0,
-          "cold_compiles": 0, "warm_loads": 0}
-
-
-def configure(directory: Optional[str], max_entries: Optional[int] = None
-              ) -> None:
-    """Set (or clear, with ``None``) the persistent cache directory for
-    this process, overriding ``DL4J_TPU_COMPILE_CACHE_DIR``. Call with
-    the sentinel-free default to re-enable env resolution:
-    ``configure(os.environ.get(ENV_DIR))``."""
-    global _CONFIGURED_DIR, _CONFIGURED_MAX, _DISK
-    with _LOCK:
-        _CONFIGURED_DIR = directory
-        _CONFIGURED_MAX = max_entries
-        _DISK = None                     # rebuilt lazily at the new path
-
-
-def reset_configuration() -> None:
-    """Drop the explicit configure() override (env resolution returns)."""
-    global _CONFIGURED_DIR, _CONFIGURED_MAX, _DISK
-    with _LOCK:
-        _CONFIGURED_DIR = _UNSET
-        _CONFIGURED_MAX = None
-        _DISK = None
-
-
-def cache_dir() -> Optional[str]:
-    """The resolved persistent-cache directory (explicit configure()
-    wins, else the env var), or None when the disk tier is disabled."""
-    with _LOCK:
-        if _CONFIGURED_DIR is not _UNSET:
-            return _CONFIGURED_DIR
-    return os.environ.get(ENV_DIR) or None
-
-
-def cache_dir_status():
-    """(directory, writable) — what the DL4J-W112 serving lint checks:
-    ``(None, False)`` means no persistent cache is configured and every
-    fresh process/rollout pays full XLA compile. jax-free."""
-    d = cache_dir()
-    if d is None:
-        return None, False
-    try:
-        os.makedirs(d, exist_ok=True)
-        probe = os.path.join(d, f".wprobe_{os.getpid()}_{threading.get_ident()}")
-        with open(probe, "w") as f:
-            f.write("w")
-        os.remove(probe)
-        return d, True
-    except OSError:
-        return d, False
-
-
-_DISK_WARNED: set = set()
-
-
-def disk_cache() -> Optional["DiskCompileCache"]:
-    """The process-wide disk tier at the resolved directory (None when
-    disabled OR the directory cannot be created — an unusable cache
-    degrades to no cache, never to a failed dispatch; the W112 lint is
-    what surfaces the misconfiguration). Rebuilt when configure()
-    changes the path."""
-    global _DISK
-    d = cache_dir()
-    if d is None:
-        return None
-    with _LOCK:
-        if _DISK is None or _DISK.dir != d:
-            max_entries = _CONFIGURED_MAX
-            if max_entries is None:
-                max_entries = int(os.environ.get(ENV_MAX_ENTRIES, "512"))
-            try:
-                _DISK = DiskCompileCache(d, max_entries=max_entries)
-            except OSError as e:
-                if d not in _DISK_WARNED:
-                    _DISK_WARNED.add(d)
-                    warnings.warn(
-                        f"persistent compile cache at {d!r} unusable "
-                        f"({e}) — running without the disk tier "
-                        "(DL4J-W112 territory)", stacklevel=2)
-                return None
-        return _DISK
+          "cold_seconds": 0.0, "cold_compiles": 0}
 
 
 def cache_stats() -> dict:
-    """Per-process snapshot: tier hit/miss counts, compile-seconds split
-    cold/warm, and the disk store's entry count. The cross-process pin
-    asserts ``disk.misses == 0`` and ``compile_seconds.cold == 0`` for a
-    second fresh process over previously-seen keys."""
-    disk = None
-    d = cache_dir()
-    if d is not None and os.path.isdir(d):
-        disk = disk_cache()
+    """Per-process snapshot: in-process table hit/miss counts and the
+    AOT compiles this process ran, with their seconds."""
     return {
         "memory": {"hits": _STATS["memory_hits"],
                    "misses": _STATS["memory_misses"]},
-        "disk": {"enabled": d is not None,
-                 "dir": d,
-                 "hits": _STATS["disk_hits"],
-                 "misses": _STATS["disk_misses"],
-                 "entries": disk.entry_count() if disk is not None else 0},
         "compile_seconds": {"cold": _STATS["cold_seconds"],
-                            "warm": _STATS["warm_seconds"],
-                            "cold_compiles": _STATS["cold_compiles"],
-                            "warm_loads": _STATS["warm_loads"]},
+                            "cold_compiles": _STATS["cold_compiles"]},
     }
 
 
 def reset_stats() -> None:
     for k in _STATS:
         _STATS[k] = 0.0 if k.endswith("seconds") else 0
-
-
-# --------------------------------------------- shared event accounting
-# ONE bookkeeping path for both tiers' consumers (CachedDispatch and the
-# native runtime's disk seam): the Prometheus series and the per-process
-# cache_stats() aggregates — which the cold-start probe and the
-# cross-process pins read — can never disagree.
-def note_disk_hit(seconds: float) -> None:
-    _STATS["disk_hits"] += 1
-    _STATS["warm_seconds"] += seconds
-    _STATS["warm_loads"] += 1
-    _HITS_DISK.inc()
-    _WARM.observe(seconds)
-
-
-def note_disk_miss() -> None:
-    _STATS["disk_misses"] += 1
-    _MISS_DISK.inc()
-
-
-def note_cold_compile(seconds: float) -> None:
-    _STATS["cold_seconds"] += seconds
-    _STATS["cold_compiles"] += 1
-    _COLD.observe(seconds)
-
-
-# ------------------------------------------------------------------- keys
-_RUNTIME_FP = None
-
-
-def runtime_fingerprint() -> str:
-    """jax/jaxlib/backend identity baked into every key: an executable
-    serialized by one runtime must never be loaded by another."""
-    global _RUNTIME_FP
-    if _RUNTIME_FP is None:
-        import jax
-        import jaxlib
-        _RUNTIME_FP = (f"jax={jax.__version__};jaxlib={jaxlib.__version__};"
-                       f"backend={jax.default_backend()}")
-    return _RUNTIME_FP
-
-
-def content_key(scope: str, content: bytes, key_parts=()) -> str:
-    """SHA-256 hex over (runtime fingerprint, scope, explicit key parts,
-    program content). The content is the lowered StableHLO text, so the
-    graph fingerprint, input signature/bucket shape, mesh sharding
-    annotations, and precision-policy casts are all content-addressed;
-    ``key_parts`` (model fingerprint, policy signature, ...) add
-    defense-in-depth namespacing and observability."""
-    h = hashlib.sha256()
-    h.update(runtime_fingerprint().encode())
-    h.update(b"\x00" + scope.encode() + b"\x00")
-    h.update(repr(tuple(key_parts)).encode())
-    h.update(b"\x00")
-    h.update(content)
-    return h.hexdigest()
-
-
-# -------------------------------------------------------------- disk tier
-_MAGIC = b"DL4JCC1\n"
-_FORMAT = 1
-
-
-class DiskCompileCache:
-    """Content-addressed store of serialized executables (module doc).
-
-    One entry = one file ``cc_<sha256>.bin``: magic line, one JSON
-    header line (format, runtime fingerprint, payload SHA-256, scope,
-    creation time), then the pickled serialized-executable payload.
-    Readers validate magic + header + checksum; corrupt entries are
-    quarantined (renamed ``quarantine_cc_...``), version-mismatched
-    ones ignored (the caller recompiles and overwrites). Writes are
-    atomic: temp file + ``os.replace``.
-    """
-
-    def __init__(self, directory: str, max_entries: int = 512):
-        self.dir = directory
-        self.max_entries = int(max_entries)
-        os.makedirs(directory, exist_ok=True)
-
-    def _path(self, key: str) -> str:
-        return os.path.join(self.dir, f"cc_{key}.bin")
-
-    def entry_count(self) -> int:
-        try:
-            return sum(1 for n in os.listdir(self.dir)
-                       if n.startswith("cc_") and n.endswith(".bin"))
-        except OSError:
-            return 0
-
-    # ------------------------------------------------------------- read
-    def get(self, key: str) -> Optional[bytes]:
-        """Payload bytes for ``key``, or None (absent, version-
-        mismatched, transiently unreadable, or quarantined-corrupt).
-        Does NOT touch the hit/miss counters — :class:`CachedDispatch`
-        owns those."""
-        path = self._path(key)
-        try:
-            with open(path, "rb") as f:
-                magic = f.read(len(_MAGIC))
-                if magic != _MAGIC:
-                    raise ValueError(f"bad magic {magic!r}")
-                header = json.loads(f.readline().decode())
-                payload = f.read()
-        except FileNotFoundError:
-            return None
-        except OSError:
-            # an I/O error (EIO, a stale NFS handle, momentary EACCES on
-            # a fleet-shared dir) is NOT evidence of corruption — miss
-            # now, retry next time; only content damage quarantines
-            return None
-        except (ValueError, UnicodeDecodeError) as e:
-            self._quarantine(path, str(e))
-            return None
-        if header.get("format") != _FORMAT \
-                or header.get("runtime") != runtime_fingerprint():
-            # stale jax/jaxlib/backend (or format) — ignored, and the
-            # caller's fresh compile overwrites it in place
-            return None
-        digest = hashlib.sha256(payload).hexdigest()
-        if digest != header.get("sha256"):
-            self._quarantine(
-                path, f"payload checksum mismatch (header "
-                      f"{str(header.get('sha256'))[:12]}..., actual "
-                      f"{digest[:12]}...)")
-            return None
-        try:                # LRU clock for eviction ordering
-            os.utime(path, None)
-        except OSError:
-            pass
-        return payload
-
-    # ------------------------------------------------------------ write
-    def put(self, key: str, payload: bytes, scope: str = "") -> str:
-        """Atomic write (temp + ``os.replace``): a crash mid-write can
-        never leave a half-entry under the real name, and concurrent
-        writers of the same key land whole either way."""
-        path = self._path(key)
-        header = {"format": _FORMAT, "runtime": runtime_fingerprint(),
-                  "sha256": hashlib.sha256(payload).hexdigest(),
-                  "scope": scope, "created": time.time()}
-        tmp = os.path.join(
-            self.dir, f".tmp_cc_{key[:16]}_{os.getpid()}_"
-                      f"{threading.get_ident()}")
-        try:
-            with open(tmp, "wb") as f:
-                f.write(_MAGIC)
-                f.write(json.dumps(header).encode() + b"\n")
-                f.write(payload)
-            os.replace(tmp, path)
-        except BaseException:
-            # a failed (or interrupted) write must not orphan the temp
-            # file in a long-lived fleet-shared directory
-            try:
-                os.remove(tmp)
-            except OSError:
-                pass
-            raise
-        self._evict()
-        return path
-
-    #: temp files older than this are considered abandoned by a killed
-    #: writer and swept by _evict (a live write takes milliseconds)
-    _TMP_MAX_AGE_S = 3600.0
-
-    #: entries younger than this are NEVER evicted, whatever the entry
-    #: count (the multi-host grace window): on a fleet-shared directory
-    #: another host may have just written an entry it has not dispatched
-    #: yet — its mtime is its only defense against a neighbor's LRU
-    #: pass, and an autotuning sweep multiplying entries must not let
-    #: host A's churn delete host B's seconds-old executable
-    _EVICT_GRACE_S = 300.0
-
-    def _evict(self) -> None:
-        """Best-effort LRU over the shared directory — correct under
-        concurrent multi-host writers WITHOUT any cross-host lock.
-
-        Scoring is mtime-based: ``get()`` touches entries on every hit
-        (the LRU clock), so the oldest mtime is the coldest entry on
-        ANY host.  Every filesystem call tolerates losing a race — a
-        file another evictor removed first, an entry vanishing between
-        ``listdir`` and ``getmtime`` — by skipping, never by aborting
-        the sweep; and entries inside the grace window are left alone
-        even when the directory is over capacity (capacity recovers on
-        a later pass once they age; deleting fresh entries would break
-        the writer that has not loaded them yet)."""
-        try:
-            names = os.listdir(self.dir)
-        except OSError:
-            return
-        # wall clock on purpose: it is compared against file MTIMES,
-        # which are wall-clock too (monotonic would be wrong here)
-        now = time.time()
-        entries = []
-        for n in names:
-            p = os.path.join(self.dir, n)
-            if n.startswith(".tmp_cc_"):
-                try:
-                    age = now - os.path.getmtime(p)  # dl4j: noqa=W210
-                    if age > self._TMP_MAX_AGE_S:
-                        os.remove(p)    # a crashed writer's orphan
-                except OSError:
-                    pass
-                continue
-            if n.startswith("cc_") and n.endswith(".bin"):
-                try:
-                    entries.append((os.path.getmtime(p), n))
-                except OSError:
-                    continue        # concurrently evicted/quarantined
-        entries.sort()              # oldest mtime (coldest) first
-        excess = len(entries) - max(1, self.max_entries)
-        for mtime, name in entries:
-            if excess <= 0:
-                break
-            if now - mtime < self._EVICT_GRACE_S:  # dl4j: noqa=W210
-                break       # sorted: everything after is younger still
-            try:
-                os.remove(os.path.join(self.dir, name))
-                _EVICT_DISK.inc()
-            except OSError:
-                pass        # a concurrent evictor got it first — the
-                            # entry is gone either way, count it
-            excess -= 1
-
-    def _quarantine(self, path: str, reason: str) -> None:
-        dst = os.path.join(os.path.dirname(path),
-                           "quarantine_" + os.path.basename(path))
-        try:
-            os.replace(path, dst)
-        except OSError:
-            return
-        CACHE_QUARANTINED.inc()
-        warnings.warn(
-            f"compile cache: quarantined corrupt entry {path}: {reason}",
-            stacklevel=3)
-
-
-# -------------------------------------------------- serialized executables
-def _serialize_executable(compiled) -> bytes:
-    from jax.experimental import serialize_executable as se
-    payload, in_tree, out_tree = se.serialize(compiled)
-    return pickle.dumps((payload, in_tree, out_tree), protocol=4)
-
-
-def _deserialize_executable(blob: bytes):
-    from jax.experimental import serialize_executable as se
-    payload, in_tree, out_tree = pickle.loads(blob)
-    return se.deserialize_and_load(payload, in_tree, out_tree)
 
 
 # --------------------------------------------------------- cached dispatch
@@ -501,18 +104,16 @@ def _leaf_signature(a):
 
 
 class CachedDispatch:
-    """``jax.jit`` drop-in backed by the two-tier compile cache.
+    """``jax.jit`` drop-in with an in-process table of AOT-compiled
+    signatures.
 
-    Construction jits ``fn`` exactly as before. ``__call__`` delegates
-    straight to that jit until the AOT path is engaged (persistent
-    cache configured, or :meth:`warm` used) — the default behaviour is
-    byte-identical to plain ``jax.jit``. On the AOT path each concrete
-    call signature maps to one compiled executable held in ``_compiled``
-    (the memory tier); acquisition lowers the program, content-
-    addresses the StableHLO, and either deserializes a disk hit (warm)
-    or compiles + persists (cold). Any failure in the AOT machinery
-    falls back to the plain jit with a single warning — the cache is an
-    accelerant, never a correctness dependency.
+    Construction jits ``fn``. ``__call__`` delegates straight to that
+    jit until :meth:`warm` has engaged the AOT path — the default
+    behaviour is byte-identical to plain ``jax.jit``. On the AOT path
+    each concrete call signature maps to one compiled executable held in
+    ``_compiled``; a signature seen first at dispatch is lowered and
+    compiled there. Any failure in the AOT machinery falls back to the
+    plain jit with a single warning — never a failed dispatch.
 
     Cost note: the AOT path computes a Python-side signature (flatten +
     per-leaf shape/dtype/sharding) on every call, replacing jit's C++
@@ -520,17 +121,16 @@ class CachedDispatch:
     tree is keyed deliberately: the parallel wrapper swaps params to
     mesh-replicated shardings without busting the outer step caches, so
     keying only on the data leaves would silently reuse an executable
-    compiled for the wrong placement. Deployments that never enable the
-    persistent cache never pay this — the disabled path IS plain jit.
+    compiled for the wrong placement. A program that was never warmed
+    never pays this — that path IS plain jit.
     """
 
-    __slots__ = ("_jit", "scope", "key_parts", "_compiled", "_warned")
+    __slots__ = ("_jit", "scope", "_compiled", "_warned")
 
-    def __init__(self, fn, scope: str, key_parts=(), donate_argnums=()):
+    def __init__(self, fn, scope: str, donate_argnums=()):
         import jax
         self._jit = jax.jit(fn, donate_argnums=donate_argnums)
-        self.scope = scope
-        self.key_parts = tuple(key_parts)
+        self.scope = scope                  # names the program in warnings
         self._compiled = {}
         self._warned = False
 
@@ -541,9 +141,9 @@ class CachedDispatch:
         return (treedef, tuple(_leaf_signature(a) for a in leaves))
 
     def __call__(self, *args):
-        if not self._compiled and disk_cache() is None:
-            return self._jit(*args)     # cache disabled, never warmed:
-        sig = self._signature(args)     # the pre-existing fast path
+        if not self._compiled:
+            return self._jit(*args)     # never warmed: plain jit
+        sig = self._signature(args)
         exe = self._compiled.get(sig)
         if exe is _AOT_FAILED:
             return self._jit(*args)     # known-bad signature: permanent
@@ -564,9 +164,9 @@ class CachedDispatch:
         return exe(*args)
 
     def warm(self, *args) -> "CachedDispatch":
-        """AOT-compile (or load from disk) the program for this argument
-        signature WITHOUT executing it — model/optimizer state is never
-        touched, donation consumes nothing."""
+        """AOT-compile the program for this argument signature WITHOUT
+        executing it — model/optimizer state is never touched, donation
+        consumes nothing."""
         sig = self._signature(args)
         if sig not in self._compiled:
             if self._acquire(args, sig) is None:
@@ -592,30 +192,6 @@ class CachedDispatch:
         except Exception as e:
             self._warn_once("AOT lowering", e)
             return None
-        disk = disk_cache()
-        key = None
-        if disk is not None:
-            try:
-                text = lowered.as_text()
-                key = content_key(self.scope, text.encode(), self.key_parts)
-                blob = disk.get(key)
-            except Exception as e:
-                self._warn_once("persistent-cache lookup", e)
-                disk, blob = None, None
-            if blob is not None:
-                try:
-                    t0 = time.perf_counter()
-                    exe = _deserialize_executable(blob)
-                    note_disk_hit(time.perf_counter() - t0)
-                    self._compiled[sig] = exe
-                    return exe
-                except Exception as e:
-                    # checksum-valid but unloadable (e.g. an executable
-                    # from a subtly different device topology): recompile
-                    # and overwrite — never fail the dispatch
-                    self._warn_once("persistent-cache deserialize", e)
-            if disk is not None:
-                note_disk_miss()
         try:
             t0 = time.perf_counter()
             exe = lowered.compile()
@@ -623,33 +199,16 @@ class CachedDispatch:
         except Exception as e:
             self._warn_once("AOT compile", e)
             return None
-        note_cold_compile(dt)
-        if disk is not None and key is not None:
-            try:
-                disk.put(key, _serialize_executable(exe), scope=self.scope)
-            except Exception as e:
-                self._warn_once("persistent-cache write", e)
+        _STATS["cold_seconds"] += dt
+        _STATS["cold_compiles"] += 1
+        _COLD.observe(dt)
         self._compiled[sig] = exe
         return exe
 
 
-def cached_dispatch(fn, scope: str, key_parts=(), donate_argnums=()
-                    ) -> CachedDispatch:
+def cached_dispatch(fn, scope: str, donate_argnums=()) -> CachedDispatch:
     """The seam the networks' step caches call instead of ``jax.jit``."""
-    return CachedDispatch(fn, scope, key_parts=key_parts,
-                          donate_argnums=donate_argnums)
-
-
-def model_fingerprint(model) -> str:
-    """Stable cross-process identity of a model's architecture: SHA-256
-    of the configuration JSON when the config serializes, else a
-    process-local id (disables cross-process sharing for that model but
-    keeps in-process AOT correct)."""
-    conf = getattr(model, "conf", model)
-    try:
-        return hashlib.sha256(conf.to_json().encode()).hexdigest()[:16]
-    except Exception:
-        return f"pid{os.getpid()}-id{id(conf):x}"
+    return CachedDispatch(fn, scope, donate_argnums=donate_argnums)
 
 
 # ----------------------------------------------------------------- warmup
@@ -691,9 +250,8 @@ def warmup(target, shapes, *, mesh=None, policy=None,
     (model, mesh, backend) BEFORE compiling, so the warmed programs are
     the ones the tuned fit/serve path will dispatch — the plan's
     ``steps_per_dispatch`` also takes over when the caller left the
-    default.  Nothing executes: warmup populates the compile caches —
-    and, when the persistent cache is configured, the on-disk store —
-    without touching model/optimizer state."""
+    default.  Nothing executes: warmup compiles (through JAX's persistent
+    cache where one is placed) without touching model/optimizer state."""
     import numpy as np
     if hasattr(target, "buckets") and hasattr(target, "submit"):
         # a ModelServer: its ladder warmup is already the serving-side

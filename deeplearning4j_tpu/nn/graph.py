@@ -825,9 +825,8 @@ class ComputationGraph:
                 outs, _ = self._forward(params, states, ins, False, key)
                 return outs
             # behind the compile-cache seam — see MultiLayerNetwork.
-            # _jit_forward (serving warmup / persistent disk tier)
-            self._fwd_cache = _cc.cached_dispatch(
-                fwd, "graph:forward", key_parts=self._compile_key_parts(0))
+            # _jit_forward (serving warmup)
+            self._fwd_cache = _cc.cached_dispatch(fwd, "graph:forward")
         return self._fwd_cache
 
     def _warm_forward(self, x) -> "ComputationGraph":
@@ -1020,11 +1019,9 @@ class ComputationGraph:
         if steps > 1:
             return _cc.cached_dispatch(
                 _stepping.scan_megastep(step, 4), "graph:megastep",
-                key_parts=self._compile_key_parts(steps),
                 donate_argnums=(0, 1, 2, 3))
-        return _cc.cached_dispatch(
-            step, "graph:train_step", key_parts=self._compile_key_parts(1),
-            donate_argnums=(0, 1, 2, 3))
+        return _cc.cached_dispatch(step, "graph:train_step",
+                                   donate_argnums=(0, 1, 2, 3))
 
     def _make_dynamic_train_step(self, steps: int, with_lmasks: bool):
         """Train step under ``PrecisionPolicy(loss_scale="dynamic")`` —
@@ -1072,11 +1069,9 @@ class ComputationGraph:
         if steps > 1:
             return _cc.cached_dispatch(
                 _stepping.scan_megastep(step, 5), "graph:megastep",
-                key_parts=self._compile_key_parts(steps),
                 donate_argnums=(0, 1, 2, 3, 4))
-        return _cc.cached_dispatch(
-            step, "graph:train_step", key_parts=self._compile_key_parts(1),
-            donate_argnums=(0, 1, 2, 3, 4))
+        return _cc.cached_dispatch(step, "graph:train_step",
+                                   donate_argnums=(0, 1, 2, 3, 4))
 
     def _step_for(self, sig, steps: int, n_labels: int):
         """(compiled step, dummy mask list) for one mask signature ×
@@ -1091,21 +1086,6 @@ class ComputationGraph:
         if sig not in self._train_step_cache:
             self._train_step_cache[sig] = self._make_train_step(sig)
         return self._train_step_cache[sig], [jnp.zeros((1,))] * n_labels
-
-    def _compile_key_parts(self, steps: int = 1):
-        """Persistent-cache key parts — see MultiLayerNetwork."""
-        pol = self._precision
-        aug = self._augment
-        fp = getattr(self, "_conf_fingerprint", None)
-        if fp is None:
-            fp = self._conf_fingerprint = _cc.model_fingerprint(self)
-        plan = self._sharding_plan
-        return (fp,
-                pol.signature() if pol is not None else None,
-                aug.signature() if aug is not None else None,
-                steps, self._compute_layout,
-                self._fuse_epilogues,
-                plan.signature() if plan is not None else None)
 
     def _dynamic_scaling(self) -> bool:
         pol = self._precision
@@ -1166,7 +1146,6 @@ class ComputationGraph:
         self._compute_layout = fmt
         # recorded on the config too, so save/load round-trips the seam
         self.conf.base.compute_layout = fmt
-        self._conf_fingerprint = None    # config JSON changed
         L.stamp_layout([n.obj for n in self.conf.topo if n.kind == "layer"],
                        fmt)
         return self

@@ -391,10 +391,8 @@ class MultiLayerNetwork:
                 return self._forward(params, states, x, False, key)
             # behind the compile-cache seam: serving warmup (bucketed
             # shapes, possibly under a mesh context) AOT-compiles this
-            # program and the persistent cache makes a later process's
-            # warmup a disk hit instead of an XLA compile
-            self._fwd_cache = _cc.cached_dispatch(
-                fwd, "mln:forward", key_parts=self._compile_key_parts(0))
+            # program ahead of the first request
+            self._fwd_cache = _cc.cached_dispatch(fwd, "mln:forward")
         return self._fwd_cache
 
     def _warm_forward(self, x) -> "MultiLayerNetwork":
@@ -537,15 +535,13 @@ class MultiLayerNetwork:
         # donate params/states/opt_state/t: consumed and replaced each step,
         # so dependent dispatches queue without a host round trip. The jit sits
         # behind the compile-cache seam (nn.compilecache): plain jit
-        # dispatch until the persistent/AOT cache is engaged.
+        # dispatch until a warmup engages the AOT path.
         if steps > 1:
             return _cc.cached_dispatch(
                 _stepping.scan_megastep(step, 4), "mln:megastep",
-                key_parts=self._compile_key_parts(steps),
                 donate_argnums=(0, 1, 2, 3))
-        return _cc.cached_dispatch(
-            step, "mln:train_step", key_parts=self._compile_key_parts(1),
-            donate_argnums=(0, 1, 2, 3))
+        return _cc.cached_dispatch(step, "mln:train_step",
+                                   donate_argnums=(0, 1, 2, 3))
 
     def _make_dynamic_train_step(self, steps: int, with_fmask: bool,
                                  with_lmask: bool):
@@ -615,29 +611,9 @@ class MultiLayerNetwork:
         if steps > 1:
             return _cc.cached_dispatch(
                 _stepping.scan_megastep(step, 5), "mln:megastep",
-                key_parts=self._compile_key_parts(steps),
                 donate_argnums=(0, 1, 2, 3, 4))
-        return _cc.cached_dispatch(
-            step, "mln:train_step", key_parts=self._compile_key_parts(1),
-            donate_argnums=(0, 1, 2, 3, 4))
-
-    def _compile_key_parts(self, steps: int = 1):
-        """Explicit persistent-cache key parts next to the content hash:
-        model architecture fingerprint, precision-policy and augmentation
-        signatures, frozen set, and the dispatch's K."""
-        pol = self._precision
-        aug = self._augment
-        fp = getattr(self, "_conf_fingerprint", None)
-        if fp is None:
-            fp = self._conf_fingerprint = _cc.model_fingerprint(self)
-        plan = self._sharding_plan
-        return (fp,
-                pol.signature() if pol is not None else None,
-                aug.signature() if aug is not None else None,
-                tuple(sorted(getattr(self, "_frozen_layers", None) or ())),
-                steps, self._compute_layout,
-                self._fuse_epilogues,
-                plan.signature() if plan is not None else None)
+        return _cc.cached_dispatch(step, "mln:train_step",
+                                   donate_argnums=(0, 1, 2, 3, 4))
 
     def _dynamic_scaling(self) -> bool:
         pol = self._precision
@@ -709,10 +685,6 @@ class MultiLayerNetwork:
         # (the per-layer stamps alone would deserialize into an NCHW
         # forward feeding NHWC-stamped layers)
         self.conf.base.compute_layout = fmt
-        # the config JSON changed: recompute the persistent-cache
-        # fingerprint so a fresh process hashing the saved config lands
-        # on the same disk keys
-        self._conf_fingerprint = None
         L.stamp_layout(self.layers, fmt)
         return self
 
@@ -1379,11 +1351,9 @@ class MultiLayerNetwork:
         # and replaced (states is read-only here — the segment threads
         # seg_states instead, which retrace-safely starts as a list of
         # None). Behind the compile-cache seam like every other compiled
-        # step, so AOT warmup and the persistent cache apply.
-        return _cc.cached_dispatch(
-            step, "mln:tbptt_step",
-            key_parts=self._compile_key_parts(1) + ("tbptt", with_lmask),
-            donate_argnums=donate)
+        # step, so AOT warmup applies.
+        return _cc.cached_dispatch(step, "mln:tbptt_step",
+                                   donate_argnums=donate)
 
     def _fit_one_tbptt(self, ds: DataSet, seg_states):
         """One TBPTT segment: like _fit_one but threading initial RNN state

@@ -63,6 +63,7 @@ import numpy as np
 
 from deeplearning4j_tpu import profiler as _prof
 from deeplearning4j_tpu.parallel.mesh import DeviceMesh
+from deeplearning4j_tpu.utils.environment import jax_compile_cache_status
 
 logger = logging.getLogger("deeplearning4j_tpu")
 
@@ -663,9 +664,9 @@ def _shrink_and_resume(wrapper, model, session, iterator,
                 stacklevel=2)
     wrapper.mesh = new_mesh
     # 6. survivor-mesh warmup through the unified compile-cache seam:
-    #    with the persistent cache configured, a survivor layout any
-    #    earlier run (or process) already compiled deserializes from
-    #    disk, so the post-shrink first dispatch is a read, not an XLA
+    #    where JAX's persistent cache is placed, a survivor layout any
+    #    earlier run (or process) already compiled is read from disk,
+    #    so the post-shrink first dispatch is a read, not an XLA
     #    compile. Best-effort — a warm miss just compiles as before.
     _warm_survivor_mesh(wrapper, model, session, new_mesh,
                         steps_per_dispatch)
@@ -682,12 +683,11 @@ def _warm_survivor_mesh(wrapper, model, session, new_mesh: DeviceMesh,
     """AOT-warm the train step for the shrunk layout (module step 6):
     rebuild a zero batch from the checkpoint-recorded batch signature,
     pad + stage it exactly like the dispatch loop will (wrapper._pad +
-    _mesh_placement), and compile WITHOUT executing. Gated on the
-    persistent cache being configured — without it the first post-shrink
+    _mesh_placement), and compile WITHOUT executing. Gated on JAX's
+    persistent cache being placed — without it the first post-shrink
     dispatch compiles under the watchdog's warmup leniency exactly as
     before. Never raises: recovery must not die warming."""
-    from deeplearning4j_tpu.nn import compilecache as _cc
-    if _cc.cache_dir() is None:
+    if jax_compile_cache_status()[0] is None:
         return
     sig = getattr(session, "_last_batch_sig", None)
     if not sig:

@@ -62,8 +62,8 @@ class ParallelWrapper:
         the train step/megastep, bare feature shapes warm the forward.
         Batch dims are padded up to a multiple of the data-axis width
         exactly like ``fit`` pads real batches, so the warmed program IS
-        the dispatched one. With a persistent cache dir configured, a
-        fresh process warms from disk (zero cold compiles)."""
+        the dispatched one. Where JAX's persistent cache is placed, a
+        fresh process warms from disk."""
         from deeplearning4j_tpu.nn import compilecache as _cc
         model = self.model
         if not model._initialized:
@@ -170,12 +170,6 @@ class ParallelWrapper:
             # see incompatible devices; _ensure_clock rebuilds it (fresh,
             # uncommitted) from _iteration on the first sharded step
             model._t_dev = None
-            from deeplearning4j_tpu.nn import compilecache as _cc
-            # auto-warm the first sharded batch signature when the
-            # persistent cache is engaged (PR-13 carried remainder: the
-            # plain replication path now flows through the same seam the
-            # elastic shrink re-warm uses)
-            warm_first = _cc.cache_dir() is not None
             from deeplearning4j_tpu.train.resilience import fit_scope
             with fit_scope(session, model, epochs) as n_epochs:
                 for e in range(n_epochs):
@@ -192,20 +186,7 @@ class ParallelWrapper:
                         stream = session.wrap_batches(pulls()) \
                             if session is not None else pulls()
                         for ds in stream:
-                            sds = self._shard(ds)
-                            if warm_first:
-                                # replication-path warmup through the
-                                # compile-cache seam: the first sharded
-                                # signature AOT-compiles (or loads from
-                                # the persistent disk tier) before the
-                                # dispatch, which then hits the warmed
-                                # executable — zero extra compiles
-                                warm_first = False
-                                model._warm_dispatch(
-                                    sds.features, sds.labels,
-                                    fmask=sds.features_mask,
-                                    lmask=sds.labels_mask)
-                            model._fit_one(sds)
+                            model._fit_one(self._shard(ds))
                     model._epoch += 1
                     if session is not None:
                         session.on_epoch_end()

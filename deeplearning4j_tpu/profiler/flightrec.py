@@ -17,7 +17,8 @@ always:
   trigger: ``events.json`` (the ring), ``trace.json`` (the process
   tracer's recent spans — Perfetto-loadable), ``metrics.txt`` (full
   registry exposition), ``config.json`` (backend/device/topology,
-  compile-cache status + stats + runtime fingerprint, pid/python), and
+  where JAX's compile cache is placed + the in-process compile
+  counters, pid/python), and
   ``reason.txt`` (trigger type, message, traceback). Dumps are
   rate-limited per reason and **never raise** — a recorder failure must
   not mask the crash it is documenting.
@@ -110,11 +111,12 @@ class FlightRecorder:
                      "argv": list(sys.argv)}
         try:
             from deeplearning4j_tpu.nn import compilecache as _cc
+            from deeplearning4j_tpu.utils.environment import \
+                jax_compile_cache_status
+            directory, writable = jax_compile_cache_status()
             cfg["compile_cache"] = {
-                "dir": _cc.cache_dir(),
-                "status": _jsonable(_cc.cache_dir_status()),
+                "dir": directory, "writable": writable,
                 "stats": _jsonable(_cc.cache_stats()),
-                "runtime_fingerprint": _cc.runtime_fingerprint(),
             }
         except Exception as e:                      # pragma: no cover
             cfg["compile_cache"] = {"error": repr(e)}

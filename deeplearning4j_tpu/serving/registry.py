@@ -14,11 +14,10 @@ The swap protocol:
 
 1. ``load("m", model_v2, version=2, shapes=[(4,)])`` builds v2's server
    on the same mesh and ``warmup()``s every bucket x shape — v1 keeps
-   taking 100% of the traffic while v2 compiles. With the persistent
-   compile cache configured (``nn.compilecache``), a hot-swap onto a
-   previously-seen (model, bucket, mesh, policy) tuple deserializes
-   each program from disk instead of recompiling — the staging window
-   shrinks from compile-seconds to read-seconds, and warmup without a
+   taking 100% of the traffic while v2 compiles. With JAX's persistent
+   compile cache placed (``utils.environment``), a hot-swap onto a
+   previously-seen (model, bucket, mesh, policy) tuple reads each
+   program from disk instead of recompiling, and warmup without a
    cache dir warns ``DL4J-W112``.
 2. ``roll("m")`` lints the plan (``DL4J-W111`` when v2's warmed shapes
    do not cover what v1 serves), then atomically moves the route

@@ -215,8 +215,7 @@ def _make_head(head):
     """Compile a results-only post-processing head: the device->host
     copy then moves the head's (small) output instead of full logits.
     Heads sit behind the compile-cache seam (nn.compilecache) like the
-    forwards, so a warmed process's heads deserialize from the
-    persistent cache too."""
+    forwards, so warmup AOT-compiles them too."""
     if head is None:
         return None
     from deeplearning4j_tpu.nn import compilecache as _cc
@@ -227,17 +226,15 @@ def _make_head(head):
     if isinstance(head, (tuple, list)) and tuple(head)[0] == "top_k":
         k = int(tuple(head)[1])
         return _cc.cached_dispatch(lambda y: jax.lax.top_k(y, k),
-                                   "serving:head", key_parts=("top_k", k))
+                                   "serving:head")
     if head == "argmax":
         return _cc.cached_dispatch(lambda y: jnp.argmax(y, axis=-1),
-                                   "serving:head", key_parts=("argmax",))
+                                   "serving:head")
     if head == "softmax":
         return _cc.cached_dispatch(lambda y: jax.nn.softmax(y, axis=-1),
-                                   "serving:head", key_parts=("softmax",))
+                                   "serving:head")
     if callable(head):
-        return _cc.cached_dispatch(
-            head, "serving:head",
-            key_parts=("callable", getattr(head, "__qualname__", "?")))
+        return _cc.cached_dispatch(head, "serving:head")
     raise ValueError(
         f"unknown head {head!r} (expected 'argmax', 'softmax', "
         "'top_k[:k]', or a callable)")
@@ -717,8 +714,9 @@ class ModelServer:
         shortfall refuses to warm."""
         shapes = [tuple(int(d) for d in s) for s in shapes]
         # check_cache: warmup is the moment the cold-start bill lands, so
-        # DL4J-W112 (no/unwritable persistent compile cache — every
-        # rollout pays full compile) fires here, not on static validate()
+        # DL4J-W112 (JAX's persistent compile cache not placed, or not
+        # writable — every rollout pays full compile) fires here, not on
+        # static validate()
         report = self.validate(shapes=shapes, check_cache=True, cost=cost)
         if strict:
             report.raise_if_errors()

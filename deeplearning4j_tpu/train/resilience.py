@@ -78,7 +78,8 @@ from deeplearning4j_tpu import profiler as _prof
 from deeplearning4j_tpu.data.dataset import (DataSetIterator,
                                              RetryingDataSetIterator)
 from deeplearning4j_tpu.utils.concurrent import ErrorLatch
-from deeplearning4j_tpu.utils.environment import NumericsPanicError
+from deeplearning4j_tpu.utils.environment import (NumericsPanicError,
+                                                  jax_compile_cache_status)
 
 logger = logging.getLogger("deeplearning4j_tpu")
 
@@ -759,10 +760,10 @@ class TrainingSession:
         return True
 
     def warm_after_resume(self, steps_per_dispatch: int = 1) -> bool:
-        """Kill the resume cold start: when the persistent compile cache
-        is configured (nn.compilecache), AOT-warm the train step for the
-        batch signature the restored checkpoint recorded — a previously-
-        seen (model, shapes, policy) tuple deserializes from disk
+        """Kill the resume cold start: where JAX's persistent compile
+        cache is placed (utils.environment), AOT-warm the train step for
+        the batch signature the restored checkpoint recorded — a
+        previously-seen (model, shapes, policy) tuple is read from disk
         instead of paying the first-dispatch XLA compile. Fit loops call
         this right after ``begin_session`` (they know the dispatch K).
         Best-effort and gated OFF when no cache dir is configured, so
@@ -770,7 +771,7 @@ class TrainingSession:
         if not self.resumed:
             return False
         from deeplearning4j_tpu.nn import compilecache as _cc
-        if _cc.cache_dir() is None:
+        if jax_compile_cache_status()[0] is None:
             return False
         sig = ((self.restored.get("extra") or {}).get("resilience")
                or {}).get("batch_signature")

@@ -16,8 +16,8 @@ The three pieces (ISSUE 17):
   measurement is spent, recording each prune's reason on the
   :class:`TuningReport`).
 - :mod:`~deeplearning4j_tpu.tune.records` — the persistent
-  :class:`TuningRecord` store, keyed like the compile cache (model
-  fingerprint x mesh x backend x jax version), consulted by
+  :class:`TuningRecord` store, keyed by (model fingerprint x mesh x
+  backend x jax version), consulted by
   ``fit(tune="auto")``, ``warmup(tuned=True)``, and the serving
   registry.
 

@@ -4,10 +4,7 @@ zoo architecture on the local backend and persist the winning plan.
 The record lands in the store (``--dir`` / ``DL4J_TPU_TUNE_DIR``) under
 the (model fingerprint, mesh, backend, jax version) key, where a later
 process's ``fit(tune="auto")`` / ``warmup(tuned=True)`` / registry load
-picks it up.  Configure the persistent compile cache (``--cache-dir`` /
-``DL4J_TPU_COMPILE_CACHE_DIR``) and every candidate the search compiles
-is AOT-cached too — the tuned fresh-process cold start then pays zero
-XLA compiles (record + compile cache both hit).
+picks it up.
 """
 
 from __future__ import annotations
@@ -44,9 +41,6 @@ def _build_parser() -> argparse.ArgumentParser:
     p.add_argument("--dir", default=None,
                    help="tuning-record directory (default: "
                         "$DL4J_TPU_TUNE_DIR or the user cache)")
-    p.add_argument("--cache-dir", default=None,
-                   help="persistent compile-cache directory (makes every "
-                        "candidate AOT-cached and revisits near-free)")
     p.add_argument("--peak-tflops", type=float, default=None,
                    help="accelerator peak FLOP/s (in TFLOP/s) for the "
                         "MFU estimate")
@@ -78,12 +72,9 @@ def main(argv=None) -> int:
     args = _build_parser().parse_args(argv)
     reg_name, cls = _resolve_model(args.model)
 
-    from deeplearning4j_tpu.nn import compilecache as _cc
     from deeplearning4j_tpu.tune import driver, records
     if args.dir is not None:
         records.configure(args.dir)
-    if args.cache_dir is not None:
-        _cc.configure(args.cache_dir)
 
     import numpy as np
     zoo_kw = {"seed": 11}

@@ -6,9 +6,8 @@ Search shape, per the TVM loop (PAPERS.md): a cheap EXPLORE pass
 REFINEMENT walk (single-axis mutations of the incumbent, axis order
 seeded by ``DeviceTimeTable.top_offenders`` so conv-dominated profiles
 try the layout/fusion seams first).  Every trial dispatches through the
-networks' normal ``CachedDispatch`` seam, so with the persistent
-compile cache configured each candidate is AOT-cached the first time it
-is seen and near-free to revisit — in this process or the next.
+networks' normal ``CachedDispatch`` seam, so where JAX's persistent
+compile cache is placed a candidate seen before compiles from disk.
 
 The winner is gated by a LOSS-PARITY guard (the PR-14 bench machinery:
 same-seed loss curves, deltas bounded at 10% of curve scale) before it

@@ -4,12 +4,9 @@ Winning :class:`~deeplearning4j_tpu.tune.space.TuningPlan`\\ s are
 durable artifacts, not one-off bench settings (the TensorFlow-Serving
 saved-model discipline): one record file per (model architecture
 fingerprint x mesh x backend x jax version) key, written atomically,
-checksummed, and quarantined on content damage — the exact discipline
-``nn.compilecache.DiskCompileCache`` uses for serialized executables,
-so the two stores can share a fleet filesystem and the same failure
-model.  A record that survives :func:`lookup` is what
-``fit(tune="auto")`` / ``warmup(tuned=True)`` / ``ModelRegistry.load
-(tuned=True)`` auto-apply.
+checksummed, and quarantined on content damage.  A record that survives
+:func:`lookup` is what ``fit(tune="auto")`` / ``warmup(tuned=True)`` /
+``ModelRegistry.load(tuned=True)`` auto-apply.
 
 Layout of a record file (``tr_<sha256>.json``)::
 
@@ -17,11 +14,10 @@ Layout of a record file (``tr_<sha256>.json``)::
     {"format": 1, "sha256": <payload sha>, "created": <ts>}\\n
     <record JSON payload>
 
-Key facts mirrored from the compile cache: an OSError on read is a
-transient miss (stale NFS handles on a fleet share are not corruption);
-a bad magic / truncated header / checksum mismatch renames the file to
-``quarantine_*`` so one damaged entry can never wedge every process
-that maps to it.
+Failure model: an OSError on read is a transient miss (stale NFS
+handles on a fleet share are not corruption); a bad magic / truncated
+header / checksum mismatch renames the file to ``quarantine_*`` so one
+damaged entry can never wedge every process that maps to it.
 """
 
 from __future__ import annotations
@@ -138,8 +134,7 @@ def _jax_version() -> str:
 def record_key(model_fp: str, mesh=None, backend: Optional[str] = None
                ) -> str:
     """SHA-256 key over (model fingerprint, mesh signature, backend,
-    jax version) — the compile cache's key shape, minus the per-program
-    content hash: ONE best plan per deployment context."""
+    jax version): ONE best plan per deployment context."""
     parts = (str(model_fp), mesh_signature(mesh), _backend(backend),
              _jax_version())
     return hashlib.sha256("|".join(parts).encode()).hexdigest()
@@ -165,14 +160,14 @@ def model_fingerprint(model) -> str:
     """Stable identity of the model ARCHITECTURE: the config JSON hashed
     with the tunable-seam keys scrubbed at every depth, so a plan's
     ``apply()`` (which stamps ``compute_layout``/``data_format`` into
-    the config) is fingerprint-neutral.  Falls back to the compile
-    cache's raw fingerprint when the config does not serialize."""
-    from deeplearning4j_tpu.nn import compilecache as _cc
+    the config) is fingerprint-neutral.  Falls back to a process-local
+    id when the config does not serialize (no cross-process sharing for
+    that model)."""
     conf = getattr(model, "conf", model)
     try:
         cfg = _scrub_seams(json.loads(conf.to_json()))
     except Exception:
-        return _cc.model_fingerprint(model)
+        return f"pid{os.getpid()}-id{id(conf):x}"
     return hashlib.sha256(
         json.dumps(cfg, sort_keys=True).encode()).hexdigest()[:16]
 
@@ -250,10 +245,10 @@ def _quarantine(path: str, reason: str) -> None:
 
 def put(record: TuningRecord) -> Optional[str]:
     """Atomically persist ``record`` under its deployment key (temp +
-    ``os.replace`` — same crash/concurrent-writer guarantees as the
-    compile cache).  Returns the path, or None when the store is
-    disabled/unwritable (a tuning run must never die on a read-only
-    share)."""
+    ``os.replace``: a crash mid-write leaves no half-record, concurrent
+    writers land whole either way).  Returns the path, or None when the
+    store is disabled/unwritable (a tuning run must never die on a
+    read-only share)."""
     d = record_dir(create=True)
     if d is None:
         if not _ENABLED:
@@ -307,7 +302,7 @@ def lookup(model, mesh=None, backend: Optional[str] = None
         return None
     except OSError:
         # transient I/O on a fleet share is NOT corruption — miss now,
-        # retry next process (compile-cache discipline)
+        # retry next process
         return None
     except (ValueError, UnicodeDecodeError) as e:
         _quarantine(path, str(e))

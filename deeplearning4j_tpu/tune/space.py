@@ -5,9 +5,8 @@ knob the repo grew — conv compute layout (PR 14), fused epilogues
 (PR 14), ``steps_per_dispatch`` megasteps (PR 2), mixed precision
 (PR 11), prefetch depth, serving bucket ladders (PR 7/12), sharding
 plans (PR 15) — is already a *seam*: a setter whose change busts the
-compiled-step caches exactly once and whose value is part of the
-persistent compile-cache key. A :class:`TuningPlan` is one point in the
-cross product of those seams; a :class:`TuningSpace` enumerates the
+compiled-step caches exactly once. A :class:`TuningPlan` is one point in
+the cross product of those seams; a :class:`TuningSpace` enumerates the
 points deterministically so a search driver (``tune.driver``) can walk
 them and a record store (``tune.records``) can persist the winner under
 a stable :meth:`TuningPlan.signature`.

@@ -14,6 +14,7 @@ registry is introspectable (``Environment.describe()``).
 from __future__ import annotations
 
 import os
+import sys
 import threading
 from dataclasses import dataclass, field, fields
 from typing import Any
@@ -108,13 +109,43 @@ def place_jax_compile_cache() -> str:
     nothing is changed. Unset: ``<checkout>/.jax_cache``. Called by the
     entry points (``chip_smoke.py``, ``bench.py``) before their first
     compile, never at package import — the test suite must not fill the
-    checkout. (``nn/compilecache.py`` is a different, private store.)"""
+    checkout."""
     placed = os.environ.get("JAX_COMPILATION_CACHE_DIR")
     if placed:
         return placed
     import jax
     jax.config.update("jax_compilation_cache_dir", DEFAULT_JAX_CACHE_DIR)
     return DEFAULT_JAX_CACHE_DIR
+
+
+def jax_compile_cache_status():
+    """``(directory | None, writable)``: is JAX's persistent compilation
+    cache placed, where, and can this process write there. The one
+    reader of ``JAX_COMPILATION_CACHE_DIR`` and ``jax.config.
+    jax_compilation_cache_dir`` in the package: the W112 lint, the
+    warm-ahead gates (resume, elastic shrink) and the flight recorder
+    all ask here. jax-free: once jax is imported its config is the
+    answer (jax read the variable at import, a later ``jax.config.
+    update`` wins, and ``jax_enable_compilation_cache=False`` means no
+    cache); before that, the variable jax will read. Touches nothing:
+    writability is that of the directory, or of its nearest existing
+    ancestor (JAX creates the directory at its first write); a URL
+    (``gs://...``) is taken on trust."""
+    config = getattr(sys.modules.get("jax"), "config", None)
+    if config is None:
+        d = os.environ.get("JAX_COMPILATION_CACHE_DIR")
+    elif config.jax_enable_compilation_cache:
+        d = config.jax_compilation_cache_dir
+    else:
+        d = None
+    if not d:
+        return None, False
+    if "://" in d:
+        return d, True
+    at = os.path.abspath(d)
+    while not os.path.exists(at) and os.path.dirname(at) != at:
+        at = os.path.dirname(at)
+    return d, os.path.isdir(at) and os.access(at, os.W_OK | os.X_OK)
 
 
 class NumericsPanicError(ArithmeticError):

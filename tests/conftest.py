@@ -108,6 +108,23 @@ def devices():
     return jax.devices()
 
 
+@pytest.fixture
+def place_jax_cache():
+    """``place(directory | None)`` points jax's own config at a persistent
+    cache directory for one test: what ``utils.environment.
+    jax_compile_cache_status()`` reads once jax is imported. jax looked
+    at ``JAX_COMPILATION_CACHE_DIR`` at import, so a variable set now
+    would place nothing. This process's compiles still write nothing
+    there (jax decided at its first compile whether it uses a cache)."""
+    before = jax.config.jax_compilation_cache_dir
+
+    def place(directory):
+        jax.config.update("jax_compilation_cache_dir",
+                          None if directory is None else str(directory))
+    yield place
+    jax.config.update("jax_compilation_cache_dir", before)
+
+
 @pytest.fixture(autouse=True)
 def _fixed_seed():
     from deeplearning4j_tpu.linalg import factory

@@ -63,3 +63,45 @@ def test_cache_defaults_to_one_path_in_the_checkout(monkeypatch):
         assert jax.config.jax_compilation_cache_dir == first
     finally:    # the suite must not fill the checkout
         jax.config.update("jax_compilation_cache_dir", before)
+
+
+@pytest.mark.parametrize("case", ["variable_before_import",
+                                  "variable_after_import", "config",
+                                  "switched_off", "neither", "url"])
+def test_one_answer_to_where_the_cache_is(case, monkeypatch, tmp_path,
+                                          place_jax_cache):
+    """``jax_compile_cache_status()`` is what W112, the warm-ahead gates
+    and the flight recorder ask. It names what jax uses: jax's own config
+    once jax is imported, the variable jax will read before that."""
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR", raising=False)
+    place_jax_cache(None)
+    status = environment.jax_compile_cache_status
+    if case == "variable_before_import":
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        monkeypatch.delitem(sys.modules, "jax")
+        assert status() == (str(tmp_path), True)
+    elif case == "variable_after_import":   # jax never saw it
+        monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+        assert status() == (None, False)
+    elif case == "config":
+        # not there yet (JAX makes it at its first write), under a
+        # directory that can be written; then under a plain file
+        place_jax_cache(tmp_path / "made" / "later")
+        assert status() == (str(tmp_path / "made" / "later"), True)
+        (tmp_path / "file").write_text("x")
+        place_jax_cache(tmp_path / "file" / "cache")
+        assert status() == (str(tmp_path / "file" / "cache"), False)
+    elif case == "switched_off":
+        place_jax_cache(tmp_path)
+        jax.config.update("jax_enable_compilation_cache", False)
+        try:
+            assert status() == (None, False)
+        finally:
+            jax.config.update("jax_enable_compilation_cache", True)
+        assert status() == (str(tmp_path), True)
+    elif case == "neither":
+        assert status() == (None, False)
+    else:       # a bucket cannot be asked from here: taken on trust
+        place_jax_cache("gs://b/cache")
+        assert status() == ("gs://b/cache", True)
+    assert not os.path.exists(tmp_path / "made")    # asked, not touched

@@ -1,23 +1,19 @@
-"""Persistent/AOT compilation cache + unified warmup (ISSUE 13).
+"""AOT compilation ahead of dispatch + unified warmup, under JAX's own
+persistent cache.
 
-Pins, per tier:
+Pins:
 
-- DiskCompileCache: atomic write/read roundtrip, corruption QUARANTINE
-  (never trusted, renamed aside), version-mismatch = ignored+rewritten,
-  LRU eviction past max_entries.
-- CachedDispatch: plain-jit passthrough when disabled, AOT warm()
-  compiles without executing, in-process disk reuse across instances,
-  graceful fallback when serialization breaks.
-- THE cross-process pin: a second fresh process reports disk misses==0
-  and ZERO cold compile seconds for the same (model, shapes, policy)
-  across fit, resume (checkpoint-recorded batch signature), and
-  serving bucket warmup.
-- Key busting: a policy or mesh/sharding change maps to different
-  entries (no false sharing).
-- The existing zero-steady-state-recompile pins stay green with the
-  persistent cache enabled (megastep, serving buckets, precision
-  re-attach).
-- Concurrent writers race safely (``-m races``).
+- CachedDispatch: plain-jit passthrough until warmed, AOT warm()
+  compiles without executing, shardings are part of a signature.
+- THE cross-process pin: a second fresh process sharing a
+  ``JAX_COMPILATION_CACHE_DIR`` writes no new entry, sees hits, and ends
+  bit-identical — across fit, graph fit, megastep, serving warmup,
+  resume and an 8-device GSPMD fit; another PrecisionPolicy does write
+  new entries (no false sharing).
+- The existing zero-steady-state-recompile pins stay green on the AOT
+  path (megastep, serving buckets, precision re-attach).
+- The warm-ahead gates (resume, elastic shrink) open only where a
+  persistent cache is placed.
 - DL4J-W112: serving warmup without a (writable) persistent cache dir.
 """
 
@@ -25,7 +21,6 @@ import json
 import os
 import subprocess
 import sys
-import threading
 import warnings
 
 import numpy as np
@@ -46,14 +41,21 @@ from deeplearning4j_tpu.serving.server import ModelServer
 
 
 @pytest.fixture(autouse=True)
-def _clean_cache_config():
-    """Every test starts with the cache disabled and zeroed stats, and
-    cannot leak its configuration into the rest of the suite."""
-    cc.configure(None)
+def _clean_cache_state(place_jax_cache):
+    """Every test starts with no persistent cache placed and zeroed
+    stats, whatever the environment the suite runs in."""
+    place_jax_cache(None)
     cc.reset_stats()
     yield
-    cc.reset_configuration()
     cc.reset_stats()
+
+
+@pytest.fixture
+def placed_cache(place_jax_cache, tmp_path):
+    """A placed persistent cache as the package sees it (the gates and
+    W112 ask ``utils.environment``)."""
+    place_jax_cache(tmp_path)
+    return str(tmp_path)
 
 
 def _mlp_conf(seed=7, hidden=16):
@@ -90,181 +92,6 @@ def _iterator(seed=0, n=48, batch=8):
     return ListDataSetIterator(_data(n, seed), batch_size=batch)
 
 
-# ------------------------------------------------------------- disk store
-class TestDiskStore:
-    def test_roundtrip(self, tmp_path):
-        store = cc.DiskCompileCache(str(tmp_path))
-        key = cc.content_key("t", b"program-bytes", ("part",))
-        assert store.get(key) is None
-        store.put(key, b"payload", scope="t")
-        assert store.get(key) == b"payload"
-        assert store.entry_count() == 1
-
-    def test_corrupt_entry_quarantined(self, tmp_path):
-        store = cc.DiskCompileCache(str(tmp_path))
-        key = cc.content_key("t", b"p", ())
-        path = store.put(key, b"payload")
-        with open(path, "r+b") as f:          # flip payload bytes: the
-            f.seek(-3, os.SEEK_END)           # header checksum must catch
-            f.write(b"zzz")
-        with pytest.warns(UserWarning, match="quarantined corrupt"):
-            assert store.get(key) is None
-        assert not os.path.exists(path)
-        quarantined = [n for n in os.listdir(tmp_path)
-                       if n.startswith("quarantine_")]
-        assert len(quarantined) == 1
-        # a rewrite restores the entry
-        store.put(key, b"payload")
-        assert store.get(key) == b"payload"
-
-    def test_truncated_entry_quarantined(self, tmp_path):
-        store = cc.DiskCompileCache(str(tmp_path))
-        key = cc.content_key("t", b"p2", ())
-        path = store.put(key, b"payload-bytes")
-        with open(path, "wb") as f:
-            f.write(b"DL4")                  # not even the magic survives
-        with pytest.warns(UserWarning, match="quarantined"):
-            assert store.get(key) is None
-
-    def test_version_mismatch_ignored_and_rewritten(self, tmp_path):
-        store = cc.DiskCompileCache(str(tmp_path))
-        key = cc.content_key("t", b"p3", ())
-        path = store.put(key, b"payload")
-        # doctor the header to an older runtime: ignored, NOT quarantined
-        with open(path, "rb") as f:
-            f.readline()
-            header = json.loads(f.readline().decode())
-            payload = f.read()
-        header["runtime"] = "jax=0.0.1;jaxlib=0.0.1;backend=cpu"
-        with open(path, "wb") as f:
-            f.write(b"DL4JCC1\n")
-            f.write(json.dumps(header).encode() + b"\n")
-            f.write(payload)
-        assert store.get(key) is None
-        assert os.path.exists(path)           # still there — and a fresh
-        store.put(key, b"payload")            # put overwrites it in place
-        assert store.get(key) == b"payload"
-
-    def test_eviction_lru(self, tmp_path):
-        store = cc.DiskCompileCache(str(tmp_path), max_entries=3)
-        keys = [cc.content_key("t", f"p{i}".encode(), ()) for i in range(5)]
-        for i, k in enumerate(keys):
-            store.put(k, b"x")
-            os.utime(store._path(k), (1000 + i, 1000 + i))
-        store.put(keys[0], b"x")              # refresh + trigger evict
-        assert store.entry_count() == 3
-
-    def test_eviction_grace_window(self, tmp_path):
-        """ISSUE 17 satellite: entries younger than the grace window are
-        never evicted even over capacity — a concurrent multi-host
-        writer may not have loaded its own fresh entry yet."""
-        store = cc.DiskCompileCache(str(tmp_path), max_entries=2)
-        keys = [cc.content_key("t", f"g{i}".encode(), ()) for i in range(4)]
-        for k in keys:
-            store.put(k, b"x")
-        # every put triggered _evict, but all 4 entries are fresh
-        assert store.entry_count() == 4
-        for k in keys[:2]:                    # age the two oldest
-            os.utime(store._path(k), (1000, 1000))
-        store._evict()
-        assert store.entry_count() == 2
-        assert store.get(keys[3]) == b"x"     # fresh survivors intact
-        assert store.get(keys[2]) == b"x"
-        assert store.get(keys[0]) is None
-
-    def test_eviction_survives_vanishing_entry(self, tmp_path, monkeypatch):
-        """An entry vanishing between listdir and getmtime (another
-        host's evictor won the race) is skipped — the sweep still
-        removes the remaining cold excess instead of aborting."""
-        store = cc.DiskCompileCache(str(tmp_path), max_entries=1)
-        keys = [cc.content_key("t", f"v{i}".encode(), ()) for i in range(3)]
-        for k in keys:                        # all fresh: grace-protected
-            store.put(k, b"x")
-        for i, k in enumerate(keys):          # now age them together
-            os.utime(store._path(k), (1000 + i, 1000 + i))
-        ghost = store._path(keys[1])
-        real_getmtime = os.path.getmtime
-
-        def getmtime(p):
-            if p == ghost:
-                raise OSError("vanished")
-            return real_getmtime(p)
-        monkeypatch.setattr(cc.os.path, "getmtime", getmtime)
-        store._evict()                        # sees 2 entries, excess 1
-        monkeypatch.undo()
-        assert store.entry_count() == 2       # oldest visible one removed
-        assert store.get(keys[0]) is None
-
-    def test_eviction_survives_concurrent_remove(self, tmp_path,
-                                                 monkeypatch):
-        """os.remove losing a race with another evictor (entry already
-        gone) still counts toward the excess and the sweep continues."""
-        store = cc.DiskCompileCache(str(tmp_path), max_entries=1)
-        keys = [cc.content_key("t", f"r{i}".encode(), ()) for i in range(3)]
-        for k in keys:                        # all fresh: grace-protected
-            store.put(k, b"x")
-        for i, k in enumerate(keys):          # now age them together
-            os.utime(store._path(k), (1000 + i, 1000 + i))
-        real_remove = os.remove
-        raced = []
-
-        def remove(p):
-            real_remove(p)                    # the "other evictor" won...
-            if not raced:
-                raced.append(p)
-                raise OSError("already gone")  # ...so ours sees ENOENT
-        monkeypatch.setattr(cc.os, "remove", remove)
-        store._evict()
-        monkeypatch.undo()
-        assert raced                          # the race actually happened
-        assert store.entry_count() == 1
-        assert store.get(keys[2]) == b"x"
-
-    def test_concurrent_put_same_key_atomic(self, tmp_path):
-        store = cc.DiskCompileCache(str(tmp_path))
-        key = cc.content_key("t", b"race", ())
-        payload = b"P" * 4096
-        errors = []
-        barrier = threading.Barrier(4)
-
-        def writer():
-            try:
-                barrier.wait()
-                for _ in range(20):
-                    store.put(key, payload)
-                    got = store.get(key)
-                    assert got == payload
-            except BaseException as e:          # noqa: B017
-                errors.append(e)
-        threads = [threading.Thread(target=writer) for _ in range(4)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        assert not errors
-        assert store.get(key) == payload
-
-    def test_cache_dir_status(self, tmp_path):
-        assert cc.cache_dir_status() == (None, False)
-        cc.configure(str(tmp_path))
-        d, writable = cc.cache_dir_status()
-        assert d == str(tmp_path) and writable
-        # unwritable: a path whose "parent" is a regular file (chmod
-        # tricks don't work under root, which CI may run as)
-        blocker = tmp_path / "blocker"
-        blocker.write_text("x")
-        cc.configure(str(blocker / "sub"))
-        d, writable = cc.cache_dir_status()
-        assert not writable
-
-    def test_env_var_resolution(self, tmp_path, monkeypatch):
-        cc.reset_configuration()
-        monkeypatch.setenv(cc.ENV_DIR, str(tmp_path))
-        assert cc.cache_dir() == str(tmp_path)
-        cc.configure(None)                    # explicit disable wins
-        assert cc.cache_dir() is None
-
-
 # -------------------------------------------------------- cached dispatch
 class TestCachedDispatch:
     def test_passthrough_when_disabled(self):
@@ -278,8 +105,7 @@ class TestCachedDispatch:
         assert float(out[0]) == 2.0
         assert d.warmed_signatures() == 0     # plain jit path, no AOT
 
-    def test_warm_compiles_without_executing(self, tmp_path):
-        cc.configure(str(tmp_path))
+    def test_warm_compiles_without_executing(self):
         executed = []
 
         def f(x):
@@ -288,63 +114,40 @@ class TestCachedDispatch:
         d = cc.cached_dispatch(f, "test:warm")
         d.warm(jnp.zeros((4,)))
         assert d.warmed_signatures() == 1
-        stats = cc.cache_stats()
-        assert stats["compile_seconds"]["cold_compiles"] == 1
-        assert stats["disk"]["entries"] == 1
+        assert cc.cache_stats()["compile_seconds"]["cold_compiles"] == 1
+        assert executed == [1]
         # the call now hits the memory tier
         cc.reset_stats()
         assert float(d(jnp.ones((4,)))[0]) == 2.0
         assert cc.cache_stats()["memory"]["hits"] == 1
 
-    def test_disk_reuse_across_instances(self, tmp_path):
-        cc.configure(str(tmp_path))
+    def test_aot_failure_falls_back_for_good(self):
+        """A signature whose AOT acquisition fails is served by the
+        plain jit from then on: one warning, no lowering per dispatch."""
+        d = cc.cached_dispatch(lambda x: x - 1, "test:fb")
+        d.warm(jnp.zeros((2,)))               # engages the AOT path
+        jit, lowerings = d._jit, []
 
-        def f(x):
-            return jnp.dot(x, x.T)
-        cc.cached_dispatch(f, "test:reuse").warm(jnp.zeros((8, 8)))
-        cc.reset_stats()
-        d2 = cc.cached_dispatch(f, "test:reuse")
-        d2.warm(jnp.zeros((8, 8)))
-        s = cc.cache_stats()
-        assert s["disk"]["hits"] == 1 and s["disk"]["misses"] == 0
-        assert s["compile_seconds"]["cold_compiles"] == 0
-        assert s["compile_seconds"]["warm_loads"] == 1
-        out = d2(jnp.full((8, 8), 2.0))
-        assert float(np.asarray(out)[0, 0]) == pytest.approx(32.0)
+        class Refusing:
+            def lower(self, *args):
+                lowerings.append(1)
+                raise RuntimeError("injected lowering failure")
 
-    def test_key_parts_bust(self, tmp_path):
-        cc.configure(str(tmp_path))
-
-        def f(x):
-            return x * 3
-        cc.cached_dispatch(f, "test:kp", key_parts=("a",)).warm(
-            jnp.zeros((2,)))
-        cc.reset_stats()
-        cc.cached_dispatch(f, "test:kp", key_parts=("b",)).warm(
-            jnp.zeros((2,)))
-        s = cc.cache_stats()                  # different key part: a miss
-        assert s["disk"]["misses"] == 1 and s["disk"]["hits"] == 0
-
-    def test_serialize_failure_falls_back(self, tmp_path, monkeypatch):
-        cc.configure(str(tmp_path))
-
-        def boom(exe):
-            raise RuntimeError("injected serialize failure")
-        monkeypatch.setattr(cc, "_serialize_executable", boom)
-
-        def f(x):
-            return x - 1
-        d = cc.cached_dispatch(f, "test:fb")
-        with pytest.warns(UserWarning, match="persistent-cache write"):
-            out = d(jnp.ones((2,)))
+            def __call__(self, *args):
+                return jit(*args)
+        d._jit = Refusing()
+        with pytest.warns(UserWarning, match="AOT lowering failed"):
+            out = d(jnp.ones((3,)))           # a new signature
         assert float(out[0]) == 0.0           # dispatch survived
-        assert cc.cache_stats()["disk"]["entries"] == 0
+        with warnings.catch_warnings():
+            warnings.simplefilter("error")    # and stays silent
+            assert float(d(jnp.ones((3,)))[0]) == 0.0
+        assert lowerings == [1] and d.warmed_signatures() == 1
 
-    def test_sharding_in_signature(self, tmp_path):
+    def test_sharding_in_signature(self):
         from deeplearning4j_tpu.parallel.mesh import DeviceMesh
         if len(jax.devices()) < 2:
             pytest.skip("needs multi-device")
-        cc.configure(str(tmp_path))
 
         def f(x):
             return x * 2
@@ -362,89 +165,61 @@ class TestCachedDispatch:
 
 # ------------------------------------------------------------ model paths
 class TestModelIntegration:
-    def test_fit_bit_exact_with_cache(self, tmp_path):
+    def test_fit_bit_exact_with_cache(self):
+        """A fit dispatched through AOT-compiled executables ends where
+        a plain-jit fit ends."""
         ds = _data()
         base = MultiLayerNetwork(_mlp_conf()).init()
         base.fit(ds, epochs=3)
-        cc.configure(str(tmp_path))
         cached = MultiLayerNetwork(_mlp_conf()).init()
+        cc.warmup(cached, [((16, 8), (16, 3))])
         cached.fit(ds, epochs=3)
         assert np.array_equal(np.asarray(base.params()),
                               np.asarray(cached.params()))
-        assert cc.cache_stats()["disk"]["entries"] >= 1
-
-    def test_fit_from_disk_bit_exact(self, tmp_path):
-        """An executable DESERIALIZED from the store trains bit-exactly
-        like a freshly compiled one."""
-        ds = _data()
-        cc.configure(str(tmp_path))
-        a = MultiLayerNetwork(_mlp_conf()).init()
-        a.fit(ds, epochs=2)                   # populates the store
-        cc.reset_stats()
-        b = MultiLayerNetwork(_mlp_conf()).init()
-        b.fit(ds, epochs=2)                   # deserializes
         s = cc.cache_stats()
-        assert s["disk"]["hits"] >= 1
-        assert s["compile_seconds"]["cold_compiles"] == 0
-        assert np.array_equal(np.asarray(a.params()), np.asarray(b.params()))
+        assert s["compile_seconds"]["cold_compiles"] == 1
+        assert s["memory"]["hits"] == 3
 
-    def test_megastep_with_cache_bit_exact(self, tmp_path):
+    def test_megastep_with_cache_bit_exact(self):
         batches = [_data(8, seed=i) for i in range(4)]
         base = MultiLayerNetwork(_mlp_conf()).init()
         base.fit(list(batches), epochs=1, steps_per_dispatch=2)
-        cc.configure(str(tmp_path))
         cached = MultiLayerNetwork(_mlp_conf()).init()
+        cc.warmup(cached, [((8, 8), (8, 3))], steps_per_dispatch=2)
         cached.fit(list(batches), epochs=1, steps_per_dispatch=2)
         assert np.array_equal(np.asarray(base.params()),
                               np.asarray(cached.params()))
+        assert cc.cache_stats()["memory"]["hits"] == 2
 
-    def test_graph_fit_with_cache(self, tmp_path):
+    def test_graph_fit_with_cache(self):
         ds = _data()
         base = ComputationGraph(_graph_conf()).init()
         base.fit(ds, epochs=2)
-        cc.configure(str(tmp_path))
         cached = ComputationGraph(_graph_conf()).init()
+        cc.warmup(cached, [((16, 8), (16, 3))])
         cached.fit(ds, epochs=2)
         lb = [np.asarray(v) for v in jax.tree_util.tree_leaves(base._params)]
         lc = [np.asarray(v)
               for v in jax.tree_util.tree_leaves(cached._params)]
         assert all(np.array_equal(x, y) for x, y in zip(lb, lc))
-        cc.reset_stats()
-        g2 = ComputationGraph(_graph_conf()).init()
-        g2.fit(ds, epochs=1)
-        assert cc.cache_stats()["disk"]["hits"] >= 1
+        assert cc.cache_stats()["memory"]["hits"] == 2
 
-    def test_policy_change_busts_key(self, tmp_path):
-        """Key busting: a different PrecisionPolicy must not reuse the
-        fp32 executable (and vice versa)."""
-        ds = _data()
-        cc.configure(str(tmp_path))
-        MultiLayerNetwork(_mlp_conf()).init().fit(ds, epochs=1)
-        cc.reset_stats()
-        MultiLayerNetwork(_mlp_conf()).init().fit(ds, epochs=1,
-                                                  precision="bf16")
-        s = cc.cache_stats()
-        assert s["disk"]["misses"] >= 1       # bf16 = new program
-        cc.reset_stats()
-        MultiLayerNetwork(_mlp_conf()).init().fit(ds, epochs=1,
-                                                  precision="bf16")
-        s = cc.cache_stats()                  # second bf16 fit = disk hit
-        assert s["disk"]["misses"] == 0 and s["disk"]["hits"] >= 1
-
-    def test_zero_steady_state_recompiles_with_cache(self, tmp_path):
-        """The churn-detector pin with the persistent cache enabled:
-        20 steps of steady-state fit = ONE signature at the fit site."""
-        cc.configure(str(tmp_path))
+    def test_zero_steady_state_recompiles_with_cache(self):
+        """The churn-detector pin on the AOT path: 20 steps of
+        steady-state fit = ONE signature at the fit site, one compile."""
         det = get_churn_detector()
         net = MultiLayerNetwork(_mlp_conf()).init()
+        cc.warmup(net, [((16, 8), (16, 3))])
         before = det.signature_count("MultiLayerNetwork.fit", owner=net)
         for _ in range(20):
             net.fit(_data(), epochs=1)
         assert det.signature_count("MultiLayerNetwork.fit",
                                    owner=net) - before == 1
+        s = cc.cache_stats()
+        assert s["compile_seconds"]["cold_compiles"] == 1
+        assert s["memory"]["misses"] == 0 and s["memory"]["hits"] == 20
 
-    def test_warmup_api_forward_and_train(self, tmp_path):
-        cc.configure(str(tmp_path))
+    def test_warmup_api_forward_and_train(self):
         net = MultiLayerNetwork(_mlp_conf()).init()
         cc.warmup(net, [((16, 8), (16, 3)), (16, 8)])
         s = cc.cache_stats()
@@ -460,8 +235,7 @@ class TestModelIntegration:
         net2 = MultiLayerNetwork(_mlp_conf()).init()
         assert np.array_equal(p_before, np.asarray(net2.params()))
 
-    def test_warmup_megastep(self, tmp_path):
-        cc.configure(str(tmp_path))
+    def test_warmup_megastep(self):
         net = MultiLayerNetwork(_mlp_conf()).init()
         cc.warmup(net, [((8, 8), (8, 3))], steps_per_dispatch=2)
         cc.reset_stats()
@@ -469,8 +243,7 @@ class TestModelIntegration:
                 steps_per_dispatch=2)
         assert cc.cache_stats()["compile_seconds"]["cold_compiles"] == 0
 
-    def test_warmup_graph(self, tmp_path):
-        cc.configure(str(tmp_path))
+    def test_warmup_graph(self):
         g = ComputationGraph(_graph_conf()).init()
         cc.warmup(g, [((16, 8), (16, 3)), (16, 8)])
         cc.reset_stats()
@@ -483,8 +256,7 @@ class TestModelIntegration:
         with pytest.raises(ValueError, match="warmup shape spec"):
             cc.warmup(net, [((1, 2), (3, 4), (5, 6))])
 
-    def test_warmup_delegates_to_server(self, tmp_path):
-        cc.configure(str(tmp_path))
+    def test_warmup_delegates_to_server(self):
         net = MultiLayerNetwork(_mlp_conf()).init()
         sv = ModelServer(net, batch_limit=8, name="cc-deleg")
         try:
@@ -498,62 +270,27 @@ class TestModelIntegration:
 
 # ---------------------------------------------------------------- serving
 class TestServingCache:
-    def test_serving_warmup_zero_recompiles_with_cache(self, tmp_path):
-        cc.configure(str(tmp_path))
+    def test_serving_warmup_zero_recompiles_with_cache(self):
+        """A server over a model whose forward is on the AOT path: the
+        ladder warmup compiles each bucket ahead, and traffic is served
+        by those executables."""
         net = MultiLayerNetwork(_mlp_conf()).init()
+        cc.warmup(net, [(8, 8)])              # engages the AOT path
         sv = ModelServer(net, batch_limit=8, name="cc-srv1")
         try:
             with warnings.catch_warnings():
                 warnings.simplefilter("ignore")
                 sv.warmup([(8,)])
+            cc.reset_stats()
             out = sv.output(np.random.RandomState(0)
                             .randn(4, 8).astype(np.float32))
             assert out.shape == (4, 3)
             assert sv.recompiles_after_warmup() == 0
+            s = cc.cache_stats()
+            assert s["compile_seconds"]["cold_compiles"] == 0
+            assert s["memory"]["hits"] >= 1 and s["memory"]["misses"] == 0
         finally:
             sv.close()
-
-    def test_second_server_warmup_hits_disk(self, tmp_path):
-        """The registry hot-swap staging scenario in miniature: warming
-        a NEW server over a previously-seen (model, bucket, mesh)
-        performs zero cold compiles."""
-        cc.configure(str(tmp_path))
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore")
-            sv1 = ModelServer(MultiLayerNetwork(_mlp_conf()).init(),
-                              batch_limit=8, name="cc-srv2")
-            sv1.warmup([(8,)])
-            sv1.close()
-            cc.reset_stats()
-            sv2 = ModelServer(MultiLayerNetwork(_mlp_conf()).init(),
-                              batch_limit=8, name="cc-srv3")
-            sv2.warmup([(8,)])
-        try:
-            s = cc.cache_stats()
-            assert s["compile_seconds"]["cold_compiles"] == 0
-            assert s["disk"]["misses"] == 0 and s["disk"]["hits"] >= 1
-            assert sv2.recompiles_after_warmup() == 0
-        finally:
-            sv2.close()
-
-    def test_registry_load_staging_hits_disk(self, tmp_path):
-        from deeplearning4j_tpu.serving.registry import ModelRegistry
-        cc.configure(str(tmp_path))
-        with warnings.catch_warnings():
-            warnings.simplefilter("ignore")
-            reg = ModelRegistry(batch_limit=8)
-            reg.load("m", MultiLayerNetwork(_mlp_conf()).init(),
-                     shapes=[(8,)])
-            cc.reset_stats()
-            # v2 of the same architecture: AOT staging = pure disk reads
-            reg.load("m", MultiLayerNetwork(_mlp_conf()).init())
-            reg.roll("m")
-        try:
-            s = cc.cache_stats()
-            assert s["compile_seconds"]["cold_compiles"] == 0
-            assert s["disk"]["misses"] == 0 and s["disk"]["hits"] >= 1
-        finally:
-            reg.close()
 
 
 # ----------------------------------------------------------------- resume
@@ -571,9 +308,12 @@ class TestResumeWarmup:
         assert sig["features"] == [[16, 8], "float32"]
         assert sig["labels"] == [[16, 3], "float32"]
 
-    def test_resume_warms_from_recorded_signature(self, tmp_path):
+    def test_resume_warms_from_recorded_signature(self, tmp_path,
+                                                  placed_cache):
+        """Where a persistent cache is placed, the resumed fit compiles
+        its step from the checkpoint's batch signature BEFORE the first
+        dispatch, and every dispatch is served by that executable."""
         from deeplearning4j_tpu.train.resilience import CheckpointConfig
-        cc.configure(str(tmp_path / "cache"))
         ck = str(tmp_path / "ck")
         a = MultiLayerNetwork(_mlp_conf()).init()
         a.fit([_data(), _data(16, 1)], epochs=1,
@@ -584,8 +324,8 @@ class TestResumeWarmup:
         b.fit([_data(), _data(16, 1)], epochs=2,
               checkpoint=CheckpointConfig(ck, resume=True))
         s = cc.cache_stats()
-        assert s["compile_seconds"]["cold_compiles"] == 0
-        assert s["disk"]["hits"] >= 1 and s["disk"]["misses"] == 0
+        assert s["compile_seconds"]["cold_compiles"] == 1
+        assert s["memory"]["hits"] >= 1 and s["memory"]["misses"] == 0
 
     def test_resume_warm_noop_without_cache(self, tmp_path):
         """No cache dir configured -> warm_after_resume is a no-op and
@@ -604,21 +344,21 @@ class TestResumeWarmup:
                     checkpoint=CheckpointConfig(ck, resume=True))
         assert np.array_equal(np.asarray(full.params()),
                               np.asarray(resumed.params()))
+        assert cc.cache_stats()["compile_seconds"]["cold_compiles"] == 0
 
 
 # ---------------------------------------------------------------- elastic
 class TestElasticWarm:
-    def test_survivor_mesh_warm_populates_cache(self, tmp_path):
+    def test_survivor_mesh_warm_populates_cache(self, placed_cache):
         """The shrink path's warm seam: given a checkpoint-recorded
         batch signature, the survivor-mesh megastep is AOT-compiled
         (padded + sharded like the dispatch loop stages it) without
-        touching model state, and a repeat warm is a disk hit."""
+        touching model state, and a repeat warm compiles nothing."""
         import types
         from deeplearning4j_tpu.parallel.elastic import _warm_survivor_mesh
         from deeplearning4j_tpu.parallel.wrapper import ParallelWrapper
         if len(jax.devices()) < 2:
             pytest.skip("needs multi-device")
-        cc.configure(str(tmp_path))
         net = MultiLayerNetwork(_mlp_conf()).init()
         wrapper = ParallelWrapper(net)
         session = types.SimpleNamespace(_last_batch_sig={
@@ -626,17 +366,12 @@ class TestElasticWarm:
             "labels": [[16, 3], "float32"]})
         p_before = np.asarray(net.params())
         _warm_survivor_mesh(wrapper, net, session, wrapper.mesh, k=2)
-        s = cc.cache_stats()
-        assert s["compile_seconds"]["cold_compiles"] == 1
+        assert cc.cache_stats()["compile_seconds"]["cold_compiles"] == 1
         assert np.array_equal(p_before, np.asarray(net.params()))
-        # a later process/mesh-twin warms from disk
-        cc.reset_stats()
-        net2 = MultiLayerNetwork(_mlp_conf()).init()
-        _warm_survivor_mesh(ParallelWrapper(net2), net2, session,
-                            wrapper.mesh, k=2)
-        s = cc.cache_stats()
-        assert s["compile_seconds"]["cold_compiles"] == 0
-        assert s["disk"]["hits"] == 1
+        assert [d.warmed_signatures()
+                for d in net._megastep_cache.values()] == [1]
+        _warm_survivor_mesh(wrapper, net, session, wrapper.mesh, k=2)
+        assert cc.cache_stats()["compile_seconds"]["cold_compiles"] == 1
 
     def test_survivor_warm_noop_without_cache(self):
         import types
@@ -652,107 +387,137 @@ class TestElasticWarm:
 
 
 # ---------------------------------------------------------- cross-process
+# One case of the script = one fresh process, over this file's own models
+# and data. JAX's persistent cache is placed by the variable alone; the
+# two thresholds are lowered so that programs this small are kept at all.
 _XPROC = r"""
-import json, sys, warnings
+import json, os, sys, warnings
 warnings.simplefilter("ignore")
+import jax
+jax.config.update("jax_persistent_cache_min_compile_time_secs", 0)
+jax.config.update("jax_persistent_cache_min_entry_size_bytes", -1)
+hits = []
+jax.monitoring.register_event_listener(
+    lambda event, **kw: hits.append(1)
+    if event == "/jax/compilation_cache/cache_hits" else None)
 import numpy as np
-from deeplearning4j_tpu.nn.config import NeuralNetConfiguration, InputType
-from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
-from deeplearning4j_tpu.nn import compilecache as cc
-from deeplearning4j_tpu.nn.layers import DenseLayer, OutputLayer
-from deeplearning4j_tpu.train.updaters import Adam
-from deeplearning4j_tpu.data.dataset import DataSet
-from deeplearning4j_tpu.serving.server import ModelServer
+from test_compilecache import (ComputationGraph, MultiLayerNetwork, cc,
+                               _data, _graph_conf, _iterator, _mlp_conf)
 
-cc.configure(sys.argv[1])
-conf = (NeuralNetConfiguration.Builder().seed(7).updater(Adam(0.01))
-        .weightInit("xavier").list()
-        .layer(DenseLayer(nOut=16, activation="relu"))
-        .layer(OutputLayer(nOut=3, lossFunction="mcxent",
-                           activation="softmax"))
-        .setInputType(InputType.feedForward(8)).build())
-net = MultiLayerNetwork(conf).init()
-rng = np.random.RandomState(0)
-ds = DataSet(rng.randn(16, 8).astype(np.float32),
-             np.eye(3, dtype=np.float32)[rng.randint(0, 3, 16)])
-net.fit(ds, epochs=2)
-sv = ModelServer(net, batch_limit=8, name="xproc")
-sv.warmup([(8,)])
-sv.close()
-print("PARAMS0=%.9e" % float(np.asarray(net.params())[0]))
-print(json.dumps(cc.cache_stats()))
+case, work = sys.argv[1], sys.argv[2]
+
+
+def mln():
+    return MultiLayerNetwork(_mlp_conf()).init()
+
+
+def leaves(net):
+    return np.concatenate([np.asarray(v).ravel() for v in
+                           jax.tree_util.tree_leaves(net._params)])
+
+
+if case == "mln_fit":
+    net = mln()
+    net.fit(_data(), epochs=2)
+    out = leaves(net)
+elif case == "policy_bf16":
+    net = mln()
+    net.fit(_data(), epochs=2, precision="bf16")
+    out = leaves(net)
+elif case == "graph_fit":
+    net = ComputationGraph(_graph_conf()).init()
+    net.fit(_data(), epochs=2)
+    out = leaves(net)
+elif case == "megastep_k4":
+    net = mln()
+    net.fit([_data(8, seed=i) for i in range(4)], epochs=1,
+            steps_per_dispatch=4)
+    out = leaves(net)
+elif case == "serving_warmup":
+    from deeplearning4j_tpu.serving.server import ModelServer
+    sv = ModelServer(mln(), batch_limit=8, name="xproc")
+    # the variable this process started under is a placed cache
+    assert "DL4J-W112" not in sv.validate(check_cache=True).codes()
+    sv.warmup([(8,)])
+    out = np.asarray(sv.output(_data(4).features))
+    sv.close()
+elif case in ("resume_kill", "resume_warm"):
+    from deeplearning4j_tpu.faults import FaultPlan
+    from deeplearning4j_tpu.train.resilience import CheckpointConfig
+    ck = os.path.join(work, "ck")
+    net = mln()
+    if case == "resume_kill":       # the uninterrupted run is the answer
+        net.fit(_iterator(), epochs=1)
+        mln().fit(_iterator(), epochs=1,
+                  checkpoint=CheckpointConfig(ck, every_steps=1),
+                  faults=FaultPlan(preempt_at_step=2))
+    else:
+        net.fit(_iterator(), epochs=1,
+                checkpoint=CheckpointConfig(ck, resume=True))
+    out = leaves(net)
+elif case == "gspmd_fit_8dev":
+    from deeplearning4j_tpu.data.dataset import ListDataSetIterator
+    from deeplearning4j_tpu.distributed import (GSPMDTrainer,
+                                                ShardedTrainingPlan)
+    from deeplearning4j_tpu.parallel.mesh import DeviceMesh
+    assert len(jax.devices()) == 8
+    net = mln()
+    GSPMDTrainer(net, ShardedTrainingPlan(DeviceMesh.data_parallel())).fit(
+        ListDataSetIterator(_data(48), 16), epochs=2)
+    out = leaves(net)
+else:
+    raise SystemExit(f"unknown case {case!r}")
+print(json.dumps({"hits": len(hits),
+                  "aot_compiles":
+                      cc.cache_stats()["compile_seconds"]["cold_compiles"],
+                  "out": out.astype(np.float64).tolist()}))
 """
 
 
-def _run_xproc(cache_dir):
+def _run_xproc(case, work):
     env = dict(os.environ)
     env["JAX_PLATFORMS"] = "cpu"
-    env["PYTHONPATH"] = (os.path.dirname(os.path.dirname(
-        os.path.abspath(__file__))) + os.pathsep + env.get("PYTHONPATH", ""))
-    env.pop("DL4J_TPU_COMPILE_CACHE_DIR", None)
-    proc = subprocess.run([sys.executable, "-c", _XPROC, cache_dir],
+    env["JAX_COMPILATION_CACHE_DIR"] = os.path.join(work, "jax_cache")
+    tests = os.path.dirname(os.path.abspath(__file__))  # this file's makers
+    env["PYTHONPATH"] = os.pathsep.join(
+        [os.path.dirname(tests), tests, env.get("PYTHONPATH", "")])
+    proc = subprocess.run([sys.executable, "-c", _XPROC, case, work],
                           capture_output=True, text=True, timeout=600,
                           env=env)
     assert proc.returncode == 0, proc.stderr[-2000:]
-    lines = proc.stdout.strip().splitlines()
-    return lines[-2], json.loads(lines[-1])
+    entries = set(os.listdir(env["JAX_COMPILATION_CACHE_DIR"]))
+    return json.loads(proc.stdout.strip().splitlines()[-1]), entries
 
 
-class TestCrossProcess:
-    def test_second_process_zero_misses_and_no_cold_compiles(self, tmp_path):
-        """THE acceptance pin: fit + serving warmup in a fresh process
-        over a populated cache report disk misses==0 and materially
-        lower compile seconds (zero cold compiles), bit-identical
-        training included."""
-        d = str(tmp_path)
-        p1, s1 = _run_xproc(d)
-        assert s1["disk"]["misses"] >= 1          # first process populated
-        assert s1["compile_seconds"]["cold"] > 0
-        p2, s2 = _run_xproc(d)
-        assert s2["disk"]["misses"] == 0
-        assert s2["disk"]["hits"] >= 2            # train step + forward
-        assert s2["compile_seconds"]["cold"] == 0.0
-        assert s2["compile_seconds"]["warm"] < s1["compile_seconds"]["cold"]
-        assert p1 == p2                           # cached exe = same math
-
-
-# ------------------------------------------------------------------ races
-@pytest.mark.races
-class TestConcurrentWriters:
-    def test_many_threads_one_key(self, tmp_path):
-        """N threads AOT-compile the same program into one store
-        concurrently: no corruption, every call correct, exactly one
-        final entry readable."""
-        cc.configure(str(tmp_path))
-        errors = []
-        barrier = threading.Barrier(6)
-
-        def work(i):
-            try:
-                def f(x):
-                    return x * 2 + 1
-                d = cc.cached_dispatch(f, "races:onekey")
-                barrier.wait()
-                out = d(jnp.full((4,), float(i)))
-                assert float(out[0]) == 2.0 * i + 1
-            except BaseException as e:              # noqa: B017
-                errors.append(e)
-        threads = [threading.Thread(target=work, args=(i,))
-                   for i in range(6)]
-        for t in threads:
-            t.start()
-        for t in threads:
-            t.join()
-        assert not errors
-        disk = cc.disk_cache()
-        assert disk.entry_count() == 1
-        # and the surviving entry is loadable
-        cc.reset_stats()
-
-        def f(x):
-            return x * 2 + 1
-        cc.cached_dispatch(f, "races:onekey").warm(jnp.zeros((4,)))
-        assert cc.cache_stats()["disk"]["hits"] == 1
+@pytest.mark.parametrize("first,second", [
+    ("mln_fit", "mln_fit"),
+    ("graph_fit", "graph_fit"),
+    ("megastep_k4", "megastep_k4"),
+    ("serving_warmup", "serving_warmup"),
+    # killed after a checkpoint, resumed (and warmed ahead) by the second
+    ("resume_kill", "resume_warm"),
+    # the mesh on which a private store of serialized executables once
+    # handed back an executable for the wrong number of shards
+    ("gspmd_fit_8dev", "gspmd_fit_8dev"),
+    ("mln_fit", "policy_bf16"),
+], ids=["mln_fit", "graph_fit", "megastep_k4", "serving_warmup",
+        "resume_warm", "gspmd_fit_8dev", "policy_change"])
+def test_second_process_compiles_from_jax_cache(first, second, tmp_path):
+    """THE cross-process pin: a second fresh process sharing the first's
+    ``JAX_COMPILATION_CACHE_DIR`` writes NO new entry, sees hits, and
+    ends bit-identical. Another PrecisionPolicy is another program and
+    does write (no false sharing)."""
+    r1, e1 = _run_xproc(first, str(tmp_path))
+    assert e1                                 # the first process populated
+    r2, e2 = _run_xproc(second, str(tmp_path))
+    assert r2["hits"] >= 1
+    if second == "policy_bf16":
+        assert e2 - e1 and r2["out"] != r1["out"]
+        return
+    assert e2 == e1
+    assert r2["out"] == r1["out"]             # cached exe = same math
+    if second == "resume_warm":               # the gate opened: the step
+        assert r2["aot_compiles"] == 1        # was compiled ahead, once
 
 
 # ------------------------------------------------------------------- W112
@@ -769,8 +534,7 @@ class TestW112:
         finally:
             sv.close()
 
-    def test_warmup_with_cache_no_w112(self, tmp_path):
-        cc.configure(str(tmp_path))
+    def test_warmup_with_cache_no_w112(self, placed_cache):
         sv = self._server()
         try:
             with warnings.catch_warnings(record=True) as caught:
@@ -780,10 +544,10 @@ class TestW112:
         finally:
             sv.close()
 
-    def test_unwritable_dir_warns_w112(self, tmp_path):
+    def test_unwritable_dir_warns_w112(self, tmp_path, place_jax_cache):
         blocker = tmp_path / "blocker"          # file-as-parent: root-proof
         blocker.write_text("x")
-        cc.configure(str(blocker / "cache"))
+        place_jax_cache(blocker / "cache")
         sv = self._server()
         try:
             with pytest.warns(UserWarning, match="writable"):
@@ -801,12 +565,25 @@ class TestW112:
         finally:
             sv.close()
 
-    def test_lint_compile_cache_direct(self, tmp_path):
+    def test_lint_compile_cache_direct(self, tmp_path, place_jax_cache):
         from deeplearning4j_tpu.analysis import lint_compile_cache
         diags = lint_compile_cache()
         assert diags and diags[0].code == "DL4J-W112"
-        cc.configure(str(tmp_path))
+        assert "JAX_COMPILATION_CACHE_DIR" in diags[0].fix_hint
+        assert "place_jax_compile_cache" in diags[0].fix_hint
+        place_jax_cache(tmp_path)
         assert lint_compile_cache() == []
+
+    def test_placed_but_switched_off_warns_w112(self, placed_cache):
+        """A directory jax was told not to use is no cache."""
+        from deeplearning4j_tpu.analysis import lint_compile_cache
+        assert lint_compile_cache() == []
+        jax.config.update("jax_enable_compilation_cache", False)
+        try:
+            diags = lint_compile_cache()
+        finally:
+            jax.config.update("jax_enable_compilation_cache", True)
+        assert [d.code for d in diags] == ["DL4J-W112"]
 
     def test_w112_suppressible(self):
         sv = self._server()
@@ -821,6 +598,7 @@ class TestW112:
     def test_w112_documented(self):
         from deeplearning4j_tpu.analysis.diagnostics import DIAGNOSTIC_CODES
         assert "DL4J-W112" in DIAGNOSTIC_CODES
+        assert "JAX_COMPILATION_CACHE_DIR" in DIAGNOSTIC_CODES["DL4J-W112"]
 
 
 # ------------------------------------------------------- tracer streaming
@@ -912,19 +690,25 @@ class TestDynamicLossScaling:
     def test_no_overflow_equals_static_bit_exact(self):
         """With no overflow and growth disabled, dynamic(init=S) ==
         static(S) bit-exactly — the automaton is pure bookkeeping until
-        something overflows."""
+        something overflows. Checked op by op as well as compiled."""
         from deeplearning4j_tpu.nn.precision import PrecisionPolicy
         ds = _data()
-        dyn = MultiLayerNetwork(_mlp_conf()).init()
-        dyn.fit(ds, epochs=3, precision=PrecisionPolicy(
-            "fp16", loss_scale="dynamic", loss_scale_init=2.0 ** 10,
-            growth_interval=10 ** 9))
-        st = MultiLayerNetwork(_mlp_conf()).init()
-        st.fit(ds, epochs=3, precision=PrecisionPolicy(
-            "fp16", loss_scale=2.0 ** 10))
-        assert np.array_equal(np.asarray(dyn.params()),
-                              np.asarray(st.params()))
-        assert dyn.current_loss_scale() == 2.0 ** 10
+
+        def pair():
+            dyn = MultiLayerNetwork(_mlp_conf()).init()
+            dyn.fit(ds, epochs=3, precision=PrecisionPolicy(
+                "fp16", loss_scale="dynamic", loss_scale_init=2.0 ** 10,
+                growth_interval=10 ** 9))
+            st = MultiLayerNetwork(_mlp_conf()).init()
+            st.fit(ds, epochs=3, precision=PrecisionPolicy(
+                "fp16", loss_scale=2.0 ** 10))
+            assert dyn.current_loss_scale() == 2.0 ** 10
+            return np.asarray(dyn.params()), np.asarray(st.params())
+        with jax.disable_jit():
+            dyn, st = pair()
+        assert np.array_equal(dyn, st)
+        dyn, st = pair()
+        assert np.array_equal(dyn, st)
 
     def test_overflow_skips_update_and_backs_off(self):
         """An absurd init scale overflows the fp16 backward: the step's
@@ -1013,15 +797,14 @@ class TestDynamicLossScaling:
                               np.asarray(res.params()))
         assert res.current_loss_scale() == full.current_loss_scale()
 
-    def test_policy_reattach_keeps_programs(self, tmp_path):
+    def test_policy_reattach_keeps_programs(self):
         """Equal dynamic policy re-attach keeps the compiled step (zero
-        recompiles); a changed one busts it — with the persistent cache
-        enabled."""
+        recompiles); a changed one busts it — on the AOT path."""
         from deeplearning4j_tpu.nn.precision import PrecisionPolicy
-        cc.configure(str(tmp_path))
         net = MultiLayerNetwork(_mlp_conf()).init()
         pol = PrecisionPolicy("fp16", loss_scale="dynamic",
                               loss_scale_init=2.0 ** 10)
+        cc.warmup(net, [((16, 8), (16, 3))], policy=pol)
         net.fit(_data(), epochs=1, precision=pol)
         step = net._train_step_cache[(False, False)]
         net.setPrecisionPolicy(PrecisionPolicy(

@@ -2,7 +2,6 @@
 layout seam, the fused BN+activation epilogues, the Rotate/Resize device
 augment kernels, and the ParallelWrapper replication-path warmup."""
 
-import tempfile
 
 import numpy as np
 import pytest
@@ -601,36 +600,32 @@ class TestWrapperWarmup:
         from deeplearning4j_tpu.nn import compilecache as cc
         from deeplearning4j_tpu.parallel.mesh import DeviceMesh
         from deeplearning4j_tpu.parallel.wrapper import ParallelWrapper
-        d = tempfile.mkdtemp()
-        cc.configure(d)
-        try:
-            net = conv_fixture(hw=8)
-            x, y = small_data(hw=8, n=16)
-            w = ParallelWrapper(net, DeviceMesh.create(data=8))
-            w.warmup([((16, 3, 8, 8), (16, 4))])
-            cold = cc.cache_stats()["compile_seconds"]["cold_compiles"]
-            assert cold >= 1
-            w.fit(ListDataSetIterator(DataSet(x, y), batch_size=16),
-                  epochs=1)
-            assert cc.cache_stats()["compile_seconds"]["cold_compiles"] \
-                == cold
-        finally:
-            cc.reset_configuration()
+        net = conv_fixture(hw=8)
+        x, y = small_data(hw=8, n=16)
+        w = ParallelWrapper(net, DeviceMesh.create(data=8))
+        before = cc.cache_stats()["compile_seconds"]["cold_compiles"]
+        w.warmup([((16, 3, 8, 8), (16, 4))])
+        cold = cc.cache_stats()["compile_seconds"]["cold_compiles"]
+        assert cold >= before + 1
+        w.fit(ListDataSetIterator(DataSet(x, y), batch_size=16),
+              epochs=1)
+        assert cc.cache_stats()["compile_seconds"]["cold_compiles"] \
+            == cold
 
     def test_warmup_pads_ragged_batch(self):
         from deeplearning4j_tpu.nn import compilecache as cc
         from deeplearning4j_tpu.parallel.mesh import DeviceMesh
         from deeplearning4j_tpu.parallel.wrapper import ParallelWrapper
-        d = tempfile.mkdtemp()
-        cc.configure(d)
-        try:
-            net = conv_fixture(hw=8)
-            w = ParallelWrapper(net, DeviceMesh.create(data=8))
-            # batch 12 pads to 16 (the fit-path _pad rule)
-            w.warmup([((12, 3, 8, 8), (12, 4))])
-            assert cc.cache_stats()["compile_seconds"]["cold_compiles"] >= 1
-        finally:
-            cc.reset_configuration()
+        net = conv_fixture(hw=8)
+        w = ParallelWrapper(net, DeviceMesh.create(data=8))
+        before = cc.cache_stats()["compile_seconds"]["cold_compiles"]
+        # batch 12 pads to 16 (the fit-path _pad rule)
+        w.warmup([((12, 3, 8, 8), (12, 4))])
+        assert cc.cache_stats()["compile_seconds"]["cold_compiles"] \
+            >= before + 1
+        assert net._train_step_cache and all(
+            d.warmed_signatures() == 1
+            for d in net._train_step_cache.values())
 
     def test_megastep_warmup_rejects_bare_shapes(self):
         from deeplearning4j_tpu.parallel.mesh import DeviceMesh

@@ -448,13 +448,17 @@ class _FlakyOutputModel:
         self.base = base
         self._fail = fail
         self._sleep = sleep
+        self.stall_over = threading.Event()     # a stalled call returned
 
     def output(self, x):
         if self._fail > 0:
             self._fail -= 1
             if self._sleep:
                 time.sleep(self._sleep)
-                return self.base.output(x)
+                try:
+                    return self.base.output(x)
+                finally:
+                    self.stall_over.set()
             raise RuntimeError("injected replica failure")
         return self.base.output(x)
 
@@ -501,11 +505,17 @@ class TestParallelInferenceRobustness:
     def test_timed_out_replica_retried(self, devices8):
         net = self._net()
         x = np.random.RandomState(1).randn(2, 4).astype(np.float32)
-        net.output(x)   # pre-compile so the timeout only measures the stall
+        mesh = DeviceMesh.data_parallel()
+        # pre-compile the program the replicas run (the entered mesh is
+        # part of its key), so the timeout only measures the stall: a
+        # retry that still had to compile overran a short timeout under
+        # a loaded machine, and the stall outlasts the timeout fourfold
+        with mesh:
+            net.output(x)
         before = _INFERENCE_REPLICA_FAILURES.value
-        pi = ParallelInference(_FlakyOutputModel(net, fail=1, sleep=0.6),
-                               DeviceMesh.data_parallel(), max_retries=2,
-                               replica_timeout=0.2)
+        flaky = _FlakyOutputModel(net, fail=1, sleep=2.0)
+        pi = ParallelInference(flaky, mesh, max_retries=2,
+                               replica_timeout=0.5)
         pi._watchdog._lenient = 0       # compile already done above
         try:
             with pytest.warns(UserWarning, match="replica failure"):
@@ -515,7 +525,8 @@ class TestParallelInferenceRobustness:
             assert _INFERENCE_REPLICA_FAILURES.value >= before + 1
         finally:
             pi.shutdown()
-            time.sleep(0.5)     # let the abandoned forward finish cleanly
+            # let the abandoned forward finish cleanly
+            assert flaky.stall_over.wait(30)
 
     def test_tensor_parallel_mesh_not_flattened(self, devices8):
         # a TP serving mesh cannot drop devices (each holds a shard):

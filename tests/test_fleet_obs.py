@@ -542,6 +542,20 @@ class TestFlightRecorder:
         # ...but a different reason still dumps
         assert rec.dump("dead_peer") is not None
 
+    def test_bundle_says_where_the_compile_cache_is(self, tmp_path,
+                                                    place_jax_cache):
+        """config.json records the one answer ``utils.environment`` gives
+        (directory, writable) and the in-process compile counters."""
+        place_jax_cache(tmp_path / "jc")
+        rec = FlightRecorder(capacity=4, directory=str(tmp_path),
+                             min_dump_interval=0.0)
+        config = json.loads(
+            (Path(rec.dump("r")) / "config.json").read_text())
+        cache = config["compile_cache"]
+        assert cache["dir"] == str(tmp_path / "jc")
+        assert cache["writable"] is True
+        assert set(cache["stats"]) == {"memory", "compile_seconds"}
+
     def test_dump_never_raises(self):
         rec = FlightRecorder(capacity=4, min_dump_interval=0.0)
         rec.record("x")

@@ -488,17 +488,28 @@ class TestHotSwap:
                 handles.append(h)
                 return h
 
-            replay = threading.Thread(
-                target=lambda: load.replay(submit, (NIN,), rng_seed=5))
+            rolled = threading.Event()
+            passes = []
+
+            def sustain():      # traffic never stops until the roll has
+                while True:     # landed, then one more pass on the new route
+                    last = rolled.is_set()
+                    passes.append(load.replay(submit, (NIN,),
+                                              rng_seed=5 + len(passes)))
+                    if last:
+                        return
+
+            replay = threading.Thread(target=sustain)
             replay.start()
             # v2 warms its whole ladder while v1 carries the load, then
             # the route rolls atomically mid-replay
             reg.load("m", net2)             # inherits v1's warm shapes
             prev = reg.roll("m")
+            rolled.set()
             assert prev == 1
-            replay.join(60.0)
+            replay.join(120.0)
             assert not replay.is_alive()
-            assert len(handles) == len(load)
+            assert len(handles) == len(load) * len(passes)
 
             # zero drops, exactly-once, exactly-one-version
             v1 = v2 = 0
@@ -755,13 +766,18 @@ class TestIngressDrain:
             "                           activation='softmax'))\n"
             "        .setInputType(InputType.feedForward(4)).build())\n"
             "net = MultiLayerNetwork(conf).init()\n"
+            "dispatched = threading.Event()\n"
             "class Slow:\n"
             "    def output(self, x):\n"
+            "        if not warming:\n"
+            "            dispatched.set()\n"
             "        time.sleep(0.1)\n"
             "        return net.output(x)\n"
+            "warming = True\n"
             "sv = ModelServer(Slow(), batch_limit=1, max_queue=64,\n"
             "                 coalesce_ms=0.0, preemption=True)\n"
             "sv.warmup([(4,)])\n"
+            "warming = False\n"
             "ing = HttpIngress(sv, port=0).start()\n"
             "body = json.dumps({'instances': [[0.0, 0.0, 0.0, 0.0]]})\\\n"
             "    .encode()\n"
@@ -778,7 +794,13 @@ class TestIngressDrain:
             "threads = [threading.Thread(target=one) for _ in range(16)]\n"
             "for t in threads:\n"
             "    t.start()\n"
-            "time.sleep(0.25)  # some dispatched, most still queued\n"
+            "# one dispatched and at least one queued behind it: the\n"
+            "# state the pin is about, waited for and not slept for\n"
+            "assert dispatched.wait(60)\n"
+            "limit = time.monotonic() + 60\n"
+            "while sv.queue_depth() < 1 and time.monotonic() < limit:\n"
+            "    time.sleep(0.005)\n"
+            "assert sv.queue_depth() >= 1\n"
             "os.kill(os.getpid(), 15)  # SIGTERM mid-load\n"
             "for t in threads:\n"
             "    t.join(90)\n"

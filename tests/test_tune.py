@@ -492,14 +492,13 @@ class TestCLI:
     def test_cli_tunes_persists_and_fresh_process_applies(self, tmp_path):
         """The ISSUE-17 acceptance path: the CLI search finds a plan no
         worse than the default, persists it, and a FRESH process's
-        ``fit(tune="auto")`` applies it with zero cold compiles (tuning
-        record + disk compile cache both hit)."""
-        rdir, cdir = str(tmp_path / "records"), str(tmp_path / "cc")
+        ``fit(tune="auto")`` applies it."""
+        rdir = str(tmp_path / "records")
         env = dict(os.environ, JAX_PLATFORMS="cpu")
         proc = _run_cli(["lenet", "--budget", "8", "--batch", "4",
                          "--hw", "32", "--classes", "10", "--reps", "1",
                          "--steps", "2", "--dir", rdir,
-                         "--cache-dir", cdir, "--no-parity", "--json"],
+                         "--no-parity", "--json"],
                         env)
         assert proc.returncode == 0, proc.stderr[-2000:]
         payload = json.loads(proc.stdout)
@@ -509,20 +508,17 @@ class TestCLI:
         assert payload["speedup"] >= 1.0
         assert payload["persisted"] is True
         assert any(n.startswith("tr_") for n in os.listdir(rdir))
-        assert any(n.startswith("cc_") for n in os.listdir(cdir))
 
         script = tmp_path / "fresh_apply.py"
         script.write_text(f"""
 import numpy as np
 import sys
 sys.path.insert(0, {REPO!r})
-from deeplearning4j_tpu.nn import compilecache as cc
 from deeplearning4j_tpu.tune import records
 from deeplearning4j_tpu.models.zoo import LeNet
 from deeplearning4j_tpu.data.dataset import DataSet
 
 records.configure({rdir!r})
-cc.configure({cdir!r})
 net = LeNet(seed=11, num_classes=10, input_shape=(3, 32, 32)).init()
 plan = records.best_plan(net)
 assert plan is not None, "fresh process found no tuning record"
@@ -532,9 +528,6 @@ y = np.eye(10, dtype=np.float32)[rng.randint(0, 10, 4)]
 batches = [DataSet(x, y)] * max(1, plan.steps_per_dispatch)
 net.fit(batches, tune="auto")
 assert net._compute_layout == plan.compute_layout
-stats = cc.cache_stats()
-assert stats["compile_seconds"]["cold_compiles"] == 0, stats
-assert stats["disk"]["hits"] >= 1, stats
 print("FRESH-OK", plan.signature())
 """)
         proc2 = subprocess.run([sys.executable, str(script)], cwd=REPO,
@@ -549,14 +542,13 @@ print("FRESH-OK", plan.signature())
         """The headline acceptance run: ``python -m
         deeplearning4j_tpu.tune resnet50 --budget 20`` (CPU-sized
         input) finds a measurably faster plan and persists it."""
-        rdir, cdir = str(tmp_path / "records"), str(tmp_path / "cc")
+        rdir = str(tmp_path / "records")
         env = dict(os.environ, JAX_PLATFORMS="cpu")
         proc = subprocess.run(
             [sys.executable, "-m", "deeplearning4j_tpu.tune", "resnet50",
              "--budget", "20", "--batch", "2", "--hw", "32",
              "--classes", "10", "--reps", "1", "--steps", "2",
-             "--dir", rdir, "--cache-dir", cdir, "--no-parity",
-             "--json"],
+             "--dir", rdir, "--no-parity", "--json"],
             cwd=REPO, env=env, capture_output=True, text=True,
             timeout=3600)
         assert proc.returncode == 0, proc.stderr[-2000:]
