@@ -929,9 +929,9 @@ class ComputationGraph:
             for i, (ol, out) in enumerate(zip(out_layers, outs)):
                 lm = lmasks[i] if lmasks is not None else None
                 if ol.name in heads:
-                    # the layer works its loss out from its input (one
-                    # pass's logits alive at a time) and hands its
-                    # per-pass readings on as its state
+                    # the layer works its loss out from its input (a
+                    # vocabulary block's logits alive at a time) and hands
+                    # its per-pass readings on as its state
                     l, new_states[ol.name] = ol.loss_from(
                         *heads[ol.name], labels[i], mask=lm)
                     loss = loss + l

@@ -16,6 +16,7 @@ the RETAIN probability, applied to the layer's input.
 from __future__ import annotations
 
 import difflib
+import functools
 import inspect
 from typing import Any, Dict, Optional, Tuple
 
@@ -23,6 +24,7 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from deeplearning4j_tpu import profiler as _prof
 from deeplearning4j_tpu.autodiff.samediff import _initialize
 from deeplearning4j_tpu.nn.config import InputType
 from deeplearning4j_tpu.ops import activations as act
@@ -2661,6 +2663,161 @@ def _logits_bwd(res, g):
 _logits.defvjp(_logits_fwd, _logits_bwd)
 
 
+#: bytes of the float32 logits [N, T, block] that :func:`blocked_cross_
+#: entropy` has in flight at once: the vocabulary is taken in blocks of the
+#: largest power of two of columns whose logits fit, one block and one pass
+#: after the other, and the compiler then keeps a block's logits,
+#: probabilities and their rounded cotangent on chip instead of in HBM
+#: (the finding of ``ops.attention.CAUSAL_SCORE_BYTES``, on the one tensor
+#: of a language model's step that is larger than any score block).
+#: Measured on a v5e, forward + backward of four passes [1, 4096, 2048]
+#: bf16 under a float32 head [2048, 49152], ms a call (PR 32; the
+#: checkpointed autodiff path replaced: 112.9, a pass alone 28.2):
+#:
+#:     bytes a block        8 MiB   16 MiB   32 MiB   64 MiB
+#:     columns a block        512    1,024    2,048    4,096
+#:     one call, 4 passes              77.4     78.5     79.2
+#:     a pass alone          21.0     20.4     20.2     20.9
+#:
+#: flat, since at every width the 16 products run at 82-97% of the MXU's
+#: peak; 32 MiB makes half as many blocks to compile as 16.
+HEAD_LOGIT_BYTES = 32 << 20
+
+_HEAD_LOWERED = _prof.get_registry().counter(
+    "dl4j_head_loss_lowered_total",
+    "Traces of nn.layers.blocked_cross_entropy (one a lowering of each "
+    "call site, not one a step) by the path its shapes took: vocabulary "
+    "blocks, or one block because nOut is no multiple of the block",
+    labelnames=("path",))
+
+
+def _head_block(rows, n_out):
+    """Columns a vocabulary block takes for ``rows`` positions: one path,
+    whose block count follows from what it is handed."""
+    fit = max(HEAD_LOGIT_BYTES // (4 * rows), 1)
+    blk = 1 << (fit.bit_length() - 1)
+    return blk if blk < n_out and n_out % blk == 0 else n_out
+
+
+def _block_logits(h, w, labels, v0, blk):
+    """``(w block, logits, label mask)`` of the columns ``v0..``: float32
+    ``h @ w[:, v0:v0 + blk]`` from operands in ``h``'s dtype, as
+    :func:`_logits` gives them (the slice and the cast of the master ``w``
+    ride in the product's fusion), and where a row's label is."""
+    wb = jax.lax.dynamic_slice_in_dim(w, v0, blk, axis=1).astype(h.dtype)
+    z = jnp.einsum("ntd,dv->ntv", h, wb, preferred_element_type=jnp.float32)
+    return wb, z, (labels - v0)[..., None] == jnp.arange(blk)
+
+
+# The two functions below are jitted for what a jit shares, not for a
+# dispatch (as ``ops.attention._fwd_heads`` is): a step calls each once a
+# block and a pass (96 times at 4 passes of 24 blocks) with the block's
+# first column as a value, JAX traces and lowers each once, and XLA
+# inlines the calls under their callers' scopes and folds the column in
+# (warm ``setup_s`` 37.0 s against the parent's 34.1 with every block
+# traced anew, PR 32). Neither loops on the device: a ``while`` in a step
+# is listed beside its body's ops and a reader of op time counts it twice.
+@functools.partial(jax.jit, static_argnums=4)
+def _ce_fwd_block(h, w, labels, v0, blk):
+    """A block's float32 row log-sum-exp and the label's logit where the
+    block holds it (0 elsewhere), [N, T] each."""
+    _, z, hit = _block_logits(h, w, labels, v0, blk)
+    return (jax.nn.logsumexp(z, axis=-1),
+            jnp.sum(jnp.where(hit, z, 0.0), axis=-1))
+
+
+@functools.partial(jax.jit, static_argnums=8)
+def _ce_bwd_block(h, w, labels, lse, g, dh, dw, v0, blk):
+    """``dh`` and ``dw`` with a block's share added: the block's logits
+    again, ``dz = (exp(z - lse) - onehot) g`` rounded to ``h``'s dtype for
+    its two products exactly as :func:`_logits_bwd` rounds it, each
+    product's fusion adding into its float32 accumulator (``dw``'s columns
+    ``v0..`` in place)."""
+    wb, z, hit = _block_logits(h, w, labels, v0, blk)
+    dz = ((jnp.exp(z - lse[..., None]) - hit) * g[..., None]).astype(h.dtype)
+    dwb = jax.lax.dynamic_slice_in_dim(dw, v0, blk, axis=1) + jnp.einsum(
+        "ntd,ntv->dv", h, dz, preferred_element_type=jnp.float32)
+    return (dh + jnp.einsum("ntv,dv->ntd", dz, wb,
+                            preferred_element_type=jnp.float32),
+            jax.lax.dynamic_update_slice_in_dim(dw, dwb, v0, axis=1))
+
+
+def _ce_fwd(hs, w, labels, blk):
+    """``(ce, lse)`` [P, N, T] float32 of the passes ``hs``, a block and a
+    pass after the other; nothing of [T, nOut] leaves a block."""
+    parts = []
+    for t, h in enumerate(hs):
+        with jax.named_scope(_stepprogram.pass_scope(t + 1)):
+            for v0 in range(0, w.shape[1], blk):
+                if parts:
+                    # the next block starts once the last has finished:
+                    # one block's [T, block] tensors are alive at a time
+                    h, parts[-1] = attention_ops._then(h, parts[-1])
+                parts.append(_ce_fwd_block(h, w, labels, v0, blk))
+    ces, lses, n = [], [], w.shape[1] // blk
+    for t in range(len(hs)):
+        with jax.named_scope(_stepprogram.pass_scope(t + 1)):
+            mine = parts[t * n:(t + 1) * n]
+            lses.append(jax.nn.logsumexp(
+                jnp.stack([lse for lse, _ in mine]), axis=0))
+            ces.append(lses[-1] - sum(picked for _, picked in mine))
+    return jnp.stack(ces), jnp.stack(lses)
+
+
+def _ce_bwd(hs, w, labels, lses, gs, blk):
+    """``(dhs, dw)`` from the cotangents ``gs`` [P, N, T] of the
+    cross-entropies: a pass's ``dh`` summed over the blocks in float32 and
+    rounded once, ``dw`` summed over blocks and passes in ONE float32
+    buffer."""
+    dw = jnp.zeros(w.shape, jnp.float32)
+    dhs = []
+    for t, h in enumerate(hs):
+        dh = jnp.zeros(h.shape, jnp.float32)
+        with jax.named_scope(_stepprogram.pass_scope(t + 1)):
+            for v0 in range(0, w.shape[1], blk):
+                if t or v0:
+                    h, (dh, dw) = attention_ops._then(h, (dh, dw))
+                dh, dw = _ce_bwd_block(h, w, labels, lses[t], gs[t], dh, dw,
+                                       v0, blk)
+        dhs.append(dh.astype(h.dtype))
+    return tuple(dhs), dw.astype(w.dtype)
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _blocked_ce(hs, w, labels, blk):
+    return _ce_fwd(hs, w, labels, blk)[0]
+
+
+def _blocked_ce_fwd(hs, w, labels, blk):
+    ce, lse = _ce_fwd(hs, w, labels, blk)
+    return ce, (hs, w, labels, lse)
+
+
+def _blocked_ce_bwd(blk, res, g):
+    return _ce_bwd(*res, g, blk) + (None,)
+
+
+_blocked_ce.defvjp(_blocked_ce_fwd, _blocked_ce_bwd)
+
+
+def blocked_cross_entropy(hs, w, labels):
+    """Cross-entropy [P, N, T] float32 of the logits ``h @ w`` of every
+    pass ``h`` [N, T, nIn] in the tuple ``hs`` against the INTEGER
+    ``labels`` [N, T], under the master head ``w`` [nIn, nOut]: a forward
+    and a backward written by hand (``jax.custom_vjp``) over vocabulary
+    blocks sized by :data:`HEAD_LOGIT_BYTES`, each pass's ops under its
+    ``dl4j_ut<t>`` scope; an ``nOut`` that is no multiple of the block
+    runs the same pair as one block. What the backward keeps is ``hs``,
+    ``w``, the labels and the float32 row log-sum-exp [P, N, T], and it
+    runs a block's product again next to the two that consume it, so no
+    [T, nOut] tensor outlives a block and ``w``'s gradient is one buffer
+    for all passes. Logits, log-sum-exp and loss are float32 from operands
+    in ``h``'s dtype; ``w``'s gradient is float32."""
+    blk = _head_block(labels.size, w.shape[1])
+    _HEAD_LOWERED.labels("blocked" if blk < w.shape[1] else "single").inc()
+    return _blocked_ce(tuple(hs), w, labels, blk)
+
+
 class LoopedLMOutputLayer(BaseOutputLayer):
     """Language-model head over the passes of a :class:`~deeplearning4j_
     tpu.nn.graph.LoopVertex`, with the exit-weighted loss of looped
@@ -2672,9 +2829,11 @@ class LoopedLMOutputLayer(BaseOutputLayer):
     ``sum_t p_t CE(z_t, y) - beta H(p)``.
 
     Labels are INTEGER token ids [N, T]. The loss is worked out from the
-    layer's input, never from probabilities: log-softmax from logits, one
-    pass's float32 [T, nOut] logits alive at a time (each pass's head is
-    rematerialised in the backward pass). Its state carries the batch
+    layer's input, never from probabilities, by :func:`blocked_cross_
+    entropy`: float32 logits a vocabulary block at a time, of which the
+    backward pass keeps only the row log-sum-exp [P, N, T] beside the
+    hidden states, the head and the labels, and runs a block's product
+    again where it needs it. Its state carries the batch
     means of ``p_t`` and ``CE(z_t, y)`` of the last step, for the gauges
     ``dl4j_loop_exit_mass`` / ``dl4j_loop_pass_loss``. ``apply`` (the
     inference forward) gives the last pass's logits. Fed by an ordinary
@@ -2712,31 +2871,21 @@ class LoopedLMOutputLayer(BaseOutputLayer):
         h = x[-1] if isinstance(x, tuple) else x
         return _logits(_feature_last(self, h), params["W"]), state
 
-    def _pass_head(self, h, w, gate_w, gate_b, labels):
-        """(cross-entropy, gate logit) of one pass, [N, T] float32 each."""
-        z = _logits(h, w)
-        ce = jax.nn.logsumexp(z, axis=-1) - jnp.take_along_axis(
-            z, labels[..., None], axis=-1)[..., 0]
-        gate = jnp.einsum("ntd,d->nt", h.astype(jnp.float32),
-                          gate_w.astype(jnp.float32)) + gate_b[0]
-        return ce, gate
-
     def loss_from(self, params, x, labels, mask=None):
         """``(loss, state)`` from the layer's input: every pass's hidden
         states (a tuple off a LoopVertex) or one array."""
-        passes = x if isinstance(x, tuple) else (x,)
+        passes = tuple(_feature_last(self, h)
+                       for h in (x if isinstance(x, tuple) else (x,)))
         labels = labels.astype(jnp.int32)
-        head = jax.checkpoint(self._pass_head)
-        ces, gates = [], []
-        for t, h in enumerate(passes):
-            with jax.named_scope(_stepprogram.pass_scope(t + 1)), \
-                    jax.named_scope(_stepprogram.HEAD_LOSS_SCOPE):
-                ce, gate = head(_feature_last(self, h), params["W"],
-                                params["gate_w"], params["gate_b"], labels)
-            ces.append(ce)
-            gates.append(gate)
         with jax.named_scope(_stepprogram.HEAD_LOSS_SCOPE):
-            ce = jnp.stack(ces)                         # [P, N, T]
+            ce = blocked_cross_entropy(passes, params["W"], labels)
+            gates = []
+            for t, h in enumerate(passes):
+                with jax.named_scope(_stepprogram.pass_scope(t + 1)):
+                    gates.append(
+                        jnp.einsum("ntd,d->nt", h.astype(jnp.float32),
+                                   params["gate_w"].astype(jnp.float32))
+                        + params["gate_b"][0])
             log_p = exit_log_distribution(jnp.stack(gates))
             p = jnp.exp(log_p)
             per_token = jnp.sum(p * ce, axis=0) \

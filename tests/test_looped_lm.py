@@ -545,6 +545,85 @@ def test_the_output_layer_takes_integer_labels_and_never_keeps_two_logits():
         layer.compute_loss(y, None)
 
 
+def _plain_cross_entropy(hs, w, labels):
+    """Float32 ``log_softmax`` of every pass's whole [T, nOut] logits."""
+    logp = jax.nn.log_softmax(jnp.einsum(
+        "pntd,dv->pntv", jnp.stack(hs).astype(jnp.float32), w,
+        precision="highest"))
+    return -jnp.take_along_axis(logp, labels[None, ..., None], -1)[..., 0]
+
+
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("n_out, block, path", [
+    (48, 16, "blocked"), (48, 64, "single"), (40, 16, "single")])
+def test_the_blocked_head_against_a_plain_float32_log_softmax(
+        n_out, block, path, dtype, monkeypatch):
+    """``ce``, every pass's ``dh`` and the summed ``dW`` of the
+    hand-written pair against autodiff of the plain log-softmax: a
+    vocabulary of three blocks, of one, and one that is no multiple of the
+    block, two passes; the counter says which path, once a traced call."""
+    P, N, T, D = 2, 2, 6, 8
+    monkeypatch.setattr(L, "HEAD_LOGIT_BYTES", 4 * N * T * block)
+    assert L._head_block(N * T, n_out) == (block if path == "blocked"
+                                           else n_out)
+    rng = np.random.default_rng(n_out + block)
+    hs = tuple(jnp.asarray(rng.normal(size=(N, T, D)), dtype)
+               for _ in range(P))
+    w = jnp.asarray(rng.normal(size=(D, n_out)), jnp.float32)
+    y = jnp.asarray(rng.integers(0, n_out, (N, T)), jnp.int32)
+    g = jnp.asarray(rng.normal(size=(P, N, T)), jnp.float32)
+    before = {p: L._HEAD_LOWERED.labels(p).value
+              for p in ("blocked", "single")}
+
+    def both(fn, hs, w, y, g):
+        ce, vjp = jax.vjp(lambda hs, w: fn(hs, w, y), hs, w)
+        return (ce,) + vjp(g)
+    fn = jax.jit(lambda *a: both(L.blocked_cross_entropy, *a))
+    ce, dhs, dw = fn(hs, w, y, g)
+    fn(hs, w, y, g)                 # a second call traces nothing
+    other = "single" if path == "blocked" else "blocked"
+    assert L._HEAD_LOWERED.labels(path).value == before[path] + 1
+    assert L._HEAD_LOWERED.labels(other).value == before[other]
+    assert ce.dtype == dw.dtype == jnp.float32 and ce.shape == (P, N, T)
+    assert all(dh.dtype == h.dtype for dh, h in zip(dhs, hs))
+    want_ce, want_dhs, want_dw = jax.jit(
+        lambda *a: both(_plain_cross_entropy, *a))(hs, w, y, g)
+    # bfloat16: the operands of the three products are rounded, as the
+    # path this pair replaced rounded them
+    tol = dict(rtol=1e-5, atol=1e-5) if dtype == "float32" \
+        else dict(rtol=3e-2, atol=6e-2)
+    for got, ref in zip((ce, dw) + dhs, (want_ce, want_dw) + want_dhs):
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(ref, np.float32), **tol)
+
+
+def test_the_blocked_head_passes_check_grads_and_keeps_nothing_of_the_logits():
+    from jax.test_util import check_grads
+    rng = np.random.default_rng(5)
+    P, N, T, D, V = 2, 1, 6, 4, 24
+    hs = tuple(jnp.asarray(rng.normal(size=(N, T, D)), jnp.float32)
+               for _ in range(P))
+    w = jnp.asarray(rng.normal(size=(D, V)), jnp.float32)
+    y = jnp.asarray(rng.integers(0, V, (N, T)), jnp.int32)
+    for block in (8, 32):       # three blocks, and one
+        with pytest.MonkeyPatch.context() as mp:
+            mp.setattr(L, "HEAD_LOGIT_BYTES", 4 * N * T * block)
+            check_grads(lambda hs, w: L.blocked_cross_entropy(hs, w, y),
+                        (hs, w), order=1, modes=("rev",), atol=1e-2,
+                        rtol=1e-2, eps=1e-3)
+    # what the backward keeps: each pass's h, W, the labels and the row
+    # log-sum-exp of every pass
+    T, V = 16, 64
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(L, "HEAD_LOGIT_BYTES", 4 * T * 16)
+        kept = jax.tree_util.tree_leaves(jax.vjp(
+            lambda hs, w: L.blocked_cross_entropy(
+                hs, w, jnp.zeros((1, T), jnp.int32)),
+            (jnp.zeros((1, T, D)),) * P, jnp.zeros((D, V)))[1])
+    assert sorted(a.size for a in kept) == [T, P * T, T * D, T * D, D * V]
+    assert all(a.size < T * V for a in kept)
+
+
 def test_a_sequence_layer_refuses_the_public_layout():
     norm = L.RMSNorm()
     norm.infer_nin(InputType.recurrent(8, 5))
@@ -650,6 +729,66 @@ def test_the_map_marks_the_cores_backward_rule(block, path, monkeypatch):
     assert seen.pop("backward") in (16 * blocks, 20 * blocks)
     assert seen == {"forward": 8 * blocks, "remat": 8 * blocks,
                     "rule_only": 8 * blocks}, seen
+
+
+def test_the_map_marks_the_heads_backward_rule(monkeypatch):
+    """The head's cross-entropy is a forward/backward pair written by hand
+    over vocabulary blocks: every op of it must stand in the map as the
+    heads', in its pass; the rule's ops, the block's product run again
+    among them, are backward work and none is rematerialised (nothing is
+    left for a checkpoint to run again), while the stack's stretches still
+    are; and the counter reads ``blocked`` once, for the one call site."""
+    monkeypatch.setattr(L, "HEAD_LOGIT_BYTES", 4 * 2 * 32 * 128)
+    assert L._head_block(2 * 32, 512) == 128
+    texts = []
+    parse = stepprogram.parse
+    monkeypatch.setattr(stepprogram, "parse",
+                        lambda text: texts.append(text) or parse(text))
+    lowered = {p: L._HEAD_LOWERED.labels(p).value
+               for p in ("blocked", "single")}
+    net = tiny_net(2)[0].init()
+    profiler.set_profiling_mode("basic")
+    try:
+        stepprogram.clear()
+        net.fit(DataSet(*tokens(tiny_cfg(2), 1)[0]))
+        maps = stepprogram.maps()
+    finally:
+        profiler.set_profiling_mode(None)
+        stepprogram.clear()
+    assert L._HEAD_LOWERED.labels("blocked").value == lowered["blocked"] + 1
+    assert L._HEAD_LOWERED.labels("single").value == lowered["single"]
+    (entries,), (text,) = maps.values(), texts
+    seen = {"forward": 0, "backward": 0, "products": 0}
+    for line in text.split("\n"):
+        name, op = stepprogram._INSTRUCTION.match(line), \
+            stepprogram._OP_NAME.search(line)
+        if not name or not op or name.group(1) not in entries:
+            continue
+        op = op.group(1).partition(";")[0]
+        rule = "jit(_ce_bwd_block)" in op
+        if not rule and "jit(_ce_fwd_block)" not in op:
+            continue
+        entry = entries[name.group(1)]
+        assert entry.part == "head_loss" \
+            and entry.layer == stepprogram.LOSS_SCOPE, line
+        # (a product knows its pass; the label's mask is the same in every
+        # pass, so the compiler may share it, and the stacking of the
+        # passes' results is no pass's)
+        if " dot(" in line:
+            assert entry.loop_pass == int(
+                op[op.index("dl4j_ut") + len("dl4j_ut")]), line
+        assert (entry.phase, entry.remat) == (
+            "backward" if rule else "forward", False), line
+        seen["backward" if rule else "forward"] += 1
+        seen["products"] += rule and " dot(" in line
+    # two passes of four blocks: the rule's three products a block, of
+    # which the compiler may fold some into fusions
+    assert seen["forward"] >= 8 and seen["backward"] >= 16, seen
+    assert 8 <= seen["products"] <= 24, seen
+    heads = [e for e in entries.values() if e.part == "head_loss"]
+    assert heads and not any(e.remat for e in heads)
+    assert any(e.remat and e.phase == "backward" and e.part != "head_loss"
+               for e in entries.values())
 
 
 def test_marks_of_an_op_name():
