@@ -460,6 +460,9 @@ def _approx_flops(layer, it, out_it) -> int:
             return int(hook())
         except Exception:
             return 0
+    declared = getattr(layer, "forward_flops", None)
+    if declared is not None:    # the layer counts itself for this input
+        return int(declared(it))
     if type(layer).__name__.startswith("Embedding"):
         return 0             # a gather: its [V, d] table is no matmul
     shapes = getattr(layer, "param_shapes", lambda: {})()
@@ -622,8 +625,9 @@ def _propagate_graph_types(conf) -> Dict[str, Tuple]:
     loops = {}          # LoopVertex name -> every pass's type, outside it
     for n in _graph_order_all(conf, nodes):
         in_types = loop_aware_inputs(n, types, loops)
-        if any(t is None for t in in_types) or not in_types:
-            continue
+        if any(t is None for t in in_types) or \
+                (not in_types and n.kind == "layer"):
+            continue        # (a vertex without inputs: the step's labels)
         try:
             if is_loop(n):
                 types[n.name] = in_types[0]

@@ -17,17 +17,24 @@ from typing import Tuple
 
 from deeplearning4j_tpu.nn.config import InputType, NeuralNetConfiguration
 from deeplearning4j_tpu.nn.graph import (ComputationGraph, ElementWiseVertex,
-                                         MergeVertex)
+                                         LabelsVertex, MergeVertex)
 from deeplearning4j_tpu.nn.layers import (ActivationLayer, BatchNormalization,
                                           CausalSelfAttentionLayer,
                                           ConvolutionLayer, DenseLayer,
                                           DropoutLayer,
                                           EmbeddingSequenceLayer, GatedMLP,
                                           GlobalPoolingLayer,
+                                          HyperConnectionIn,
+                                          HyperConnectionOut,
+                                          HyperConnectionRead,
+                                          HyperConnectionWrite,
+                                          LatentAttentionLayer,
                                           LocalResponseNormalization,
                                           LoopedLMOutputLayer, LSTM,
+                                          MTPJoinLayer, MTPLMOutputLayer,
                                           OutputLayer, RMSNorm, RnnOutputLayer,
                                           SeparableConvolution2D,
+                                          SparseExpertsLayer,
                                           SubsamplingLayer, Upsampling2D)
 from deeplearning4j_tpu.nn.multilayer import MultiLayerNetwork
 from deeplearning4j_tpu.train import updaters
@@ -984,6 +991,147 @@ class Ouro(ZooModel):
         return ComputationGraph(g.build())
 
 
+
+class Xing4(ZooModel):
+    """Xing4.0-29B-A4B (XingChen-AGI 2026; defaults: its config.json), a
+    DeepSeek-V3-style sparse decoder under manifold-constrained
+    hyper-connections: token embedding copied into ``hc_mult`` residual
+    streams; ``first_k_dense`` leading layers of latent attention and a
+    dense SwiGLU MLP, then ``num_layers - first_k_dense`` of latent
+    attention and sparse experts (a sigmoid-scored, bias-selected router
+    over ``n_routed_experts``, ``num_experts_per_tok`` selected, a shared
+    expert); every sub-block reads one mix of the streams and writes back
+    through per-token maps whose stream-to-stream part is Sinkhorn-
+    projected (arXiv:2512.24880); the streams summed, a final norm, an
+    untied head; ``num_nextn_predict_layers`` multi-token-prediction
+    modules (arXiv:2412.19437 §2.2), each one expert layer fed the main
+    hidden states joined with the next token's embedding, sharing
+    embedding, final norm and head with the main model (``tiedWith``).
+    ``held_experts`` lists the routed experts THIS chip holds (default:
+    all): one chip's share under expert parallelism, routed over all,
+    the absent experts' part left out; ``keep_selected`` rows of each
+    expert layer's last selection are kept in its state
+    (``SparseExpertsLayer(keepSelected=...)``). The plain stack is rematerialised
+    a sub-block at a time in a train step (``rematerializeStack``).
+    Trains on ``DataSet(int32 tokens [N, T], int32 next tokens [N, T])``.
+
+    The published 40 layers hold 29 B parameters, which no chip trains
+    alone; the cost gates judge the cut the benchmark runs on one chip
+    (``chipbench/configs/xing4.0-29b-a4b-l5-bf16``: one dense and four
+    expert layers, 8 of 64 experts, an eighth of the vocabulary)."""
+
+    cost_gate_kwargs = {"num_layers": 5, "first_k_dense": 1,
+                        "held_experts": list(range(8)),
+                        "vocab_size": 16384}
+
+    def __init__(self, num_layers: int = 40, first_k_dense: int = 2,
+                 hidden_size: int = 3584, num_heads: int = 32,
+                 q_lora_rank: int = 768, kv_lora_rank: int = 512,
+                 qk_nope_head_dim: int = 128, qk_rope_head_dim: int = 64,
+                 v_head_dim: int = 128, intermediate_size: int = 9216,
+                 moe_intermediate_size: int = 1024,
+                 n_routed_experts: int = 64, held_experts=None,
+                 num_experts_per_tok: int = 4,
+                 routed_scaling_factor: float = 2.0, hc_mult: int = 4,
+                 hc_sinkhorn_iters: int = 20, hc_eps: float = 1e-6,
+                 mhc_h_res_clamp=(-30.0, 30.0), rms_norm_eps: float = 1e-6,
+                 rope_theta: float = 10000.0, rope_scaling: dict = None,
+                 vocab_size: int = 131072, seq_len: int = 4096,
+                 num_nextn_predict_layers: int = 1, mtp_weight: float = 0.3,
+                 keep_selected: int = 0, **kw):
+        self.num_layers, self.first_k_dense = int(num_layers), \
+            int(first_k_dense)
+        self.hidden_size, self.num_heads = int(hidden_size), int(num_heads)
+        self.attention = dict(
+            nHeads=self.num_heads, qLoraRank=q_lora_rank,
+            kvLoraRank=kv_lora_rank, qkNopeHeadDim=qk_nope_head_dim,
+            qkRopeHeadDim=qk_rope_head_dim, vHeadDim=v_head_dim,
+            ropeTheta=rope_theta, eps=rms_norm_eps,
+            ropeScaling=rope_scaling if rope_scaling is not None else dict(
+                factor=64, original_max_position_embeddings=4096,
+                beta_fast=32, beta_slow=1, mscale=1, mscale_all_dim=1))
+        self.intermediate_size = int(intermediate_size)
+        self.experts = dict(
+            nExperts=n_routed_experts, nExpertsPerTok=num_experts_per_tok,
+            nHidden=moe_intermediate_size, heldExperts=held_experts,
+            routedScalingFactor=routed_scaling_factor,
+            keepSelected=keep_selected)
+        self.streams = dict(nStreams=hc_mult, eps=hc_eps)
+        self.write = dict(sinkhornIters=hc_sinkhorn_iters,
+                          clampMin=mhc_h_res_clamp[0],
+                          clampMax=mhc_h_res_clamp[1], **self.streams)
+        self.rms_norm_eps = rms_norm_eps
+        self.vocab_size, self.seq_len = int(vocab_size), int(seq_len)
+        self.n_mtp, self.mtp_weight = int(num_nextn_predict_layers), \
+            float(mtp_weight)
+        kw.setdefault("updater", updaters.Adam(3e-4, beta2=0.95))
+        super().__init__(num_classes=vocab_size, **kw)
+
+    def default_input_shape(self):
+        return (self.vocab_size, self.seq_len)
+
+    def _layer(self, g, p, h, dense: bool):
+        """One decoder layer on the streams ``h``; returns its output's
+        name. A sub-block is read, norm, the block, write."""
+        norm = lambda: RMSNorm(eps=self.rms_norm_eps)   # noqa: E731
+        block = (GatedMLP(nHidden=self.intermediate_size) if dense
+                 else SparseExpertsLayer(**self.experts))
+        for tag, layer in (("1", LatentAttentionLayer(**self.attention)),
+                           ("2", block)):
+            name = "attn" if tag == "1" else ("mlp" if dense else "moe")
+            g.addLayer(p + "hr" + tag, HyperConnectionRead(**self.streams),
+                       h)
+            g.addLayer(p + "n" + tag, norm(), p + "hr" + tag)
+            g.addLayer(p + name, layer, p + "n" + tag)
+            g.addLayer(p + "hw" + tag, HyperConnectionWrite(**self.write),
+                       h, p + name)
+            h = p + "hw" + tag
+        return h
+
+    def conf_builder(self) -> ComputationGraph:
+        vocab, seq_len = self.input_shape
+        g = (NeuralNetConfiguration.Builder()
+             .seed(self.seed).updater(self.updater).weightInit("xavier")
+             .graphBuilder())
+        g.addInputs("tokens")
+        g.setInputTypes(InputType.recurrent(vocab, seq_len))
+        g.rematerializeStack()
+        g.addLayer("embed", EmbeddingSequenceLayer(nOut=self.hidden_size),
+                   "tokens")
+        g.addLayer("hc_in", HyperConnectionIn(**self.streams), "embed")
+        h = "hc_in"
+        for i in range(self.num_layers):
+            h = self._layer(g, f"l{i}_", h, dense=i < self.first_k_dense)
+        g.addLayer("hc_out", HyperConnectionOut(**self.streams), h)
+        g.addLayer("fnorm", RMSNorm(eps=self.rms_norm_eps), "hc_out")
+        heads, prev = ["fnorm"], "hc_out"
+        if self.n_mtp:
+            g.addVertex("next", LabelsVertex(0))
+        for d in range(1, self.n_mtp + 1):
+            # module d reads token i + d's embedding at position i: the
+            # labels moved d - 1 places left (d = 1: as they come)
+            if d > 1:
+                raise ValueError("Xing4: one multi-token-prediction module "
+                                 "is what the published model has")
+            p = "mtp_" if self.n_mtp == 1 else f"mtp{d}_"
+            g.addLayer(p + "embed", EmbeddingSequenceLayer(
+                nOut=self.hidden_size, tiedWith="embed"), "next")
+            g.addLayer(p + "join", MTPJoinLayer(eps=self.rms_norm_eps),
+                       prev, p + "embed")
+            g.addLayer(p + "hc_in", HyperConnectionIn(**self.streams),
+                       p + "join")
+            h = self._layer(g, p, p + "hc_in", dense=False)
+            g.addLayer(p + "hc_out", HyperConnectionOut(**self.streams), h)
+            g.addLayer(p + "fnorm", RMSNorm(eps=self.rms_norm_eps,
+                                            tiedWith="fnorm"), p + "hc_out")
+            heads.append(p + "fnorm")
+            prev = p + "hc_out"
+        g.addLayer("lm", MTPLMOutputLayer(nOut=vocab,
+                                          mtpWeight=self.mtp_weight), *heads)
+        g.setOutputs("lm")
+        return ComputationGraph(g.build())
+
+
 #: Name -> class registry of every shipped architecture (ref:
 #: ZooModel.select-by-name in the reference's zoo). The analysis CLI's
 #: ``--zoo`` mode lints each of these; ``all_zoo_models()`` instantiates
@@ -992,7 +1140,7 @@ ZOO_MODELS = {cls.__name__: cls for cls in
               (LeNet, SimpleCNN, AlexNet, VGG16, VGG19, ResNet50,
                Darknet19, SqueezeNet, UNet, Xception, FaceNetNN4Small2,
                TextGenerationLSTM, TinyYOLO, YOLO2, InceptionResNetV1,
-               NASNet, Ouro)}
+               NASNet, Ouro, Xing4)}
 
 
 def all_zoo_models():

@@ -295,11 +295,31 @@ class PassVertex(GraphVertex):
                                      if k != "passes"})
 
 
+class LabelsVertex(GraphVertex):
+    """The labels of graph output ``index`` as a value nodes can read: a
+    multi-token-prediction module is fed the embedding of the NEXT token,
+    which a train step has as its label. It takes no input. Outside a
+    train step (``output()``) it has no value, and every node that needs
+    it is left out of the forward pass."""
+
+    def __init__(self, index: int = 0):
+        self.index = int(index)
+
+    def apply(self, *inputs):
+        raise NotImplementedError(
+            "a LabelsVertex is filled in by ComputationGraph._forward "
+            "from the step's labels; it has no apply of its own")
+
+    def output_type(self, *its: InputType) -> InputType:
+        return its[0] if its else InputType.recurrent(1)
+
+
 _VERTEX_CLASSES = {c.__name__: c for c in
                    [MergeVertex, ElementWiseVertex, SubsetVertex,
                     DotProductVertex, L2NormalizeVertex, ScaleVertex,
                     ShiftVertex, StackVertex, UnstackVertex,
-                    PreprocessorVertex, LoopVertex, PassVertex]}
+                    PreprocessorVertex, LoopVertex, PassVertex,
+                    LabelsVertex]}
 
 
 class _GraphNode:
@@ -322,6 +342,16 @@ class GraphBuilder:
         self.graph_outputs: List[str] = []
         self.input_types: Dict[str, InputType] = {}
         self._loop: Optional[str] = None    # the loop being built, if any
+        self.remat_stack = False
+
+    def rematerializeStack(self, on: bool = True):
+        """Ask the train step to rematerialise the graph OUTSIDE its loops
+        a stretch at a time too (``ComputationGraphConfiguration.
+        _stack_stretches``): a deep plain stack then keeps one activation
+        a stretch instead of every layer's internals. Off by default: a
+        graph that does not ask compiles the step it always did."""
+        self.remat_stack = bool(on)
+        return self
 
     def addInputs(self, *names):
         self.graph_inputs.extend(names)
@@ -399,8 +429,16 @@ class ComputationGraphConfiguration:
         self.graph_inputs = builder.graph_inputs
         self.graph_outputs = builder.graph_outputs
         self.input_types = builder.input_types
+        self.remat_stack = bool(getattr(builder, "remat_stack", False))
         self.preprocessors: Dict[str, Any] = {}
         self.node_by_name = {n.name: n for n in self.nodes}
+        # a layer whose ``tiedWith`` names another node of this graph has
+        # no parameters of its own: it is applied with that node's (an
+        # embedding or a norm used at two places; the gradient is the sum)
+        self.param_owner = {
+            n.name: (n.obj.tied_with if n.kind == "layer" and getattr(
+                n.obj, "tied_with", None) in self.node_by_name else n.name)
+            for n in self.nodes}
         self._toposort()
         if self.input_types:
             self._propagate_types()
@@ -460,6 +498,35 @@ class ComputationGraphConfiguration:
             raise ValueError(f"nodes {stray} belong to a loop the graph "
                              f"does not have")
         self.topo = order
+        self.stack_stretches = self._stack_stretches(
+            top, self.graph_inputs, self.graph_outputs) \
+            if self.remat_stack else None
+
+    @staticmethod
+    def _stack_stretches(top, inputs, outputs):
+        """The nodes outside every loop cut into the stretches a train
+        step rematerialises one at a time (``rematerializeStack``): after
+        a node where no later cut would have fewer values alive (made so
+        far and still read later). A residual block, or a sub-block
+        between a hyper-connection's read and write, is one stretch; a
+        value that lives long (a hidden state a second head reads) raises
+        the count for every cut it passes and forbids none. The output
+        layers at the end are no stretch: they work the loss out."""
+        n_body = len(top)
+        while n_body and top[n_body - 1].name in outputs:
+            n_body -= 1
+        made, alive = set(inputs), []
+        for i, node in enumerate(top[:n_body]):
+            made.add(node.name)
+            later = {r for b in top[i + 1:] for r in b.inputs}
+            alive.append(len(made & later))
+        stretches, cur = [], []
+        for i, node in enumerate(top[:n_body]):
+            cur.append(node)
+            if alive[i] <= min(alive[i:]):
+                stretches.append(cur)
+                cur = []
+        return stretches + [[n] for n in top[n_body:]]
 
     @staticmethod
     def _segments(loop, body, out):
@@ -498,6 +565,8 @@ class ComputationGraphConfiguration:
                     in_types[0] = pre.output_type(in_types[0])
                 layer.set_defaults(self.base)
                 layer.infer_nin(in_types[0])
+                if hasattr(layer, "set_input_count"):
+                    layer.set_input_count(len(in_types))
                 types[node.name] = layer.output_type(in_types[0])
             elif isinstance(node.obj, LoopVertex):
                 # inside the body the loop's name is the carried value
@@ -522,6 +591,7 @@ class ComputationGraphConfiguration:
             "inputs": self.graph_inputs,
             "outputs": self.graph_outputs,
             "input_types": {k: v.to_config() for k, v in self.input_types.items()},
+            **({"remat_stack": True} if self.remat_stack else {}),
             "nodes": [{"name": n.name, "kind": n.kind,
                        "inputs": n.inputs, "conf": n.obj.to_config(),
                        **({"loop": n.loop} if n.loop else {})}
@@ -546,6 +616,7 @@ class ComputationGraphConfiguration:
                 b.addVertex(nd["name"], cls.from_config(nd["conf"]), *nd["inputs"])
         b._loop = None
         b.setOutputs(*d["outputs"])
+        b.rematerializeStack(d.get("remat_stack", False))
         return ComputationGraphConfiguration(b)
 
 
@@ -598,7 +669,8 @@ class ComputationGraph:
             if node.kind == "layer":
                 key, sub = jax.random.split(key)
                 p, s = node.obj.initialize(sub)
-                self._params[node.name] = p
+                tied = self.conf.param_owner[node.name] != node.name
+                self._params[node.name] = {} if tied else p
                 self._states[node.name] = s
         self._opt_state = None
         self._train_step_cache = {}
@@ -619,17 +691,24 @@ class ComputationGraph:
         return L.compute_dtype_of(self.conf.base.dtype)
 
     def _forward(self, params, states, inputs: Dict[str, Any], train, key,
-                 fmask=None, remat: bool = False, heads: Dict = None):
+                 fmask=None, remat: bool = False, heads: Dict = None,
+                 labels: List = None):
         """One forward pass over ``conf.topo``. ``remat``: the train step
         asks for each single-entry, single-exit stretch of a loop's body
         (``conf.loop_segments``; of a loop of more than one pass) to be
         rematerialised in the backward pass, so a looped stack keeps one
         activation a stretch and pass
         instead of every layer's internals ``steps`` times over; a graph
-        without a loop compiles the same program either way. ``heads``:
+        without a loop compiles the same program either way, unless it
+        asked for its plain stack to be rematerialised too
+        (``GraphBuilder.rematerializeStack``: then every stretch of
+        ``conf.stack_stretches`` is). ``heads``:
         a dict the loss wants filled with ``{output name: (cast params,
         input)}`` for output layers that compute their loss from their
-        input (``loss_from_input``) and are then not applied."""
+        input (``loss_from_input``) and are then not applied. ``labels``:
+        the step's labels, for a :class:`LabelsVertex`; without them such
+        a vertex has no value, and neither has any node that reads it
+        (``None`` in place of an output that needs one)."""
         cdt = self._compute_dtype()
         nhwc = self._compute_layout == "NHWC"
         plan = self._ensure_epilogue_plan() if self._fuse_epilogues else {}
@@ -649,6 +728,7 @@ class ComputationGraph:
         # (bit-identical to the unfused conv, see L.conv_bias_add)
         biased: Dict[str, Any] = {}
         index_of = {n.name: i for i, n in enumerate(self.conf.topo)}
+        owner = self.conf.param_owner
 
         def read(env, name, consumer=None):
             if name in biased:
@@ -661,6 +741,23 @@ class ComputationGraph:
         def apply_node(node, params, states, env, fmt, new_states, key):
             """Run one node: fills ``env``, ``fmt`` and ``new_states``
             under the node's name and returns the key to go on with."""
+            if isinstance(node.obj, LabelsVertex):
+                env[node.name] = None if labels is None \
+                    else labels[node.obj.index]
+                fmt[node.name] = False
+                return key
+            takes = getattr(node.obj, "n_inputs", 1) \
+                if node.kind == "layer" else 1
+            gone = [env[i] is None for i in node.inputs]
+            if gone[0] or (any(gone) and takes is not None):
+                # a value only a train step has (see LabelsVertex)
+                env[node.name], fmt[node.name] = None, False
+                if node.kind == "layer":
+                    new_states[node.name] = states[node.name]
+                return key
+            if takes != 1:
+                return apply_joint(node, params, states, env, fmt,
+                                   new_states, key)
             if node.name in fused_act:
                 # folded into its BN's scale_shift_act epilogue; keep the
                 # RNG stream identical to the unfused forward
@@ -683,7 +780,7 @@ class ComputationGraph:
                         x = self.conf.preprocessors[node.name](x)
                     x, cur_nhwc = L.layout_step(node.obj, x, cur_nhwc, nhwc,
                                                 sequences=True)
-                    p = params[node.name]
+                    p = params[owner[node.name]]
                     if cdt is not None:
                         p, x = L.policy_cast(node.obj, p, x, cdt)
                     key, sub = jax.random.split(key)
@@ -746,6 +843,38 @@ class ComputationGraph:
             env[node.name] = out
             return key
 
+        def apply_joint(node, params, states, env, fmt, new_states, key):
+            """A layer that takes several inputs (``n_inputs``; those a
+            head finds present where it takes any number): each input
+            turned and cast as a lone one would be, the layer handed the
+            tuple."""
+            with jax.named_scope(_devicetime.scope_name(index_of[node.name],
+                                                        node.name)):
+                p, xs, last = params[owner[node.name]], [], False
+                for i in node.inputs:
+                    if env[i] is None:
+                        continue
+                    x, last = L.layout_step(node.obj, read(env, i),
+                                            fmt[i], nhwc, sequences=True)
+                    if cdt is not None:
+                        cast, x = L.policy_cast(node.obj, p, x, cdt)
+                    xs.append(x)
+                if cdt is not None:
+                    p = cast
+                key, sub = jax.random.split(key)
+                if heads is not None and \
+                        getattr(node.obj, "loss_from_input", False):
+                    heads[node.name] = (p, tuple(xs))
+                    out, ns = None, states[node.name]
+                else:
+                    out, ns = node.obj.apply(p, states[node.name],
+                                             tuple(xs), train, sub)
+                new_states[node.name] = ns
+                fmt[node.name] = last and \
+                    getattr(out, "ndim", 0) == L.rank_of(xs[0])
+            env[node.name] = out
+            return key
+
         def run_segment(seg, src, env, fmt, new_states, key, checkpointed):
             """One stretch of a loop's body, from the one value ``src``
             that enters it to its last node's output; ``checkpointed``:
@@ -791,15 +920,54 @@ class ComputationGraph:
             env[node.name], fmt[node.name] = tuple(passes), cur
             return key
 
+        def run_stretch(seg, outs, key):
+            """One stretch of the plain stack (``conf.stack_stretches``),
+            rematerialised in the backward pass: what it reads from
+            before it goes in, what is read after it (``outs``) comes
+            out, and nothing in between is kept."""
+            names = [n.name for n in seg]
+            srcs = sorted({i for n in seg for i in n.inputs} - set(names))
+
+            def stretch(p_seg, s_seg, xs, key):
+                local, local_fmt, ns = dict(env), dict(fmt), {}
+                local.update(xs)
+                for n in seg:
+                    key = apply_node(n, p_seg, s_seg, local, local_fmt, ns,
+                                     key)
+                fmt.update({k: local_fmt[k] for k in names})
+                return {k: local[k] for k in outs}, ns, key
+
+            made, ns, key = jax.checkpoint(stretch)(
+                {k: params[k] for k in {owner[n] for n in names}
+                 if k in params},
+                {k: states[k] for k in names if k in states},
+                {k: env[k] for k in srcs}, key)
+            env.update(made)
+            new_states.update(ns)
+            return key
+
         new_states = {}
-        for node in self.conf.topo:
-            if node.loop is not None:
-                continue                # run by its loop, every pass
-            if isinstance(node.obj, LoopVertex):
-                key = run_loop(node, key)
-            else:
-                key = apply_node(node, params, states, env, fmt, new_states,
-                                 key)
+        # the nodes outside every loop, one by one or, where the graph
+        # asked and a train step runs, a rematerialised stretch at a time
+        # (a loop rematerialises its own body; a head works the loss out)
+        stretches = self.conf.stack_stretches \
+            if remat and not self._fuse_epilogues else None
+        units = stretches or [[n] for n in self.conf.topo if n.loop is None]
+        needed, read_later = set(self.conf.graph_outputs), []
+        for seg in reversed(units):
+            read_later.append(sorted({n.name for n in seg} & needed))
+            needed |= {i for n in seg for i in n.inputs}
+        for seg, outs in zip(units, reversed(read_later)):
+            if stretches and not any(
+                    isinstance(n.obj, LoopVertex)
+                    or n.name in self.conf.graph_outputs for n in seg):
+                key = run_stretch(seg, outs, key)
+                continue
+            for node in seg:
+                key = run_loop(node, key) \
+                    if isinstance(node.obj, LoopVertex) else \
+                    apply_node(node, params, states, env, fmt, new_states,
+                               key)
         return [L.to_public(read(env, o)) if fmt.get(o) else read(env, o)
                 for o in self.conf.graph_outputs], new_states
 
@@ -873,6 +1041,11 @@ class ComputationGraph:
             raise ValueError(
                 "feedForward: a node in a loop's body has one activation a "
                 "pass; read the passes with output() through a PassVertex")
+        if any(getattr(n.obj, "n_inputs", 1) != 1
+               or isinstance(n.obj, LabelsVertex) for n in self.conf.topo):
+            raise ValueError(
+                "feedForward: this graph has layers of several inputs or "
+                "reads its labels in the forward pass; use output()")
         ins = self._as_input_dict(inputs)
         env = dict(ins)
         key = jax.random.PRNGKey(0)
@@ -922,7 +1095,8 @@ class ComputationGraph:
                       fmask, lmasks: Optional[List], remat: bool = False):
         heads: Dict[str, Any] = {}
         outs, new_states = self._forward(params, states, ins, train, key,
-                                         fmask, remat=remat, heads=heads)
+                                         fmask, remat=remat, heads=heads,
+                                         labels=labels)
         with jax.named_scope(_stepprogram.LOSS_SCOPE):
             out_layers = self._output_layers()
             loss = 0.0

@@ -18,6 +18,7 @@ from __future__ import annotations
 import difflib
 import functools
 import inspect
+import math
 from typing import Any, Dict, Optional, Tuple
 
 import jax
@@ -1629,9 +1630,14 @@ def policy_cast(layer, params, x, compute_dt):
         # and never pays a float conversion (data/pipeline.py)
         x = x.astype(compute_dt)
     if params:
-        params = jax.tree_util.tree_map(
-            lambda a: a.astype(compute_dt)
-            if getattr(a, "dtype", None) == jnp.float32 else a, params)
+        # a layer's ``fp32_leaves`` stay masters (a router, a norm's gain
+        # inside an attention layer): the layer casts where it uses them
+        keep = getattr(layer, "fp32_leaves", ())
+        cast = lambda a: a.astype(compute_dt) \
+            if getattr(a, "dtype", None) == jnp.float32 else a  # noqa: E731
+        params = jax.tree_util.tree_map(cast, params) if not keep else {
+            k: v if k in keep else jax.tree_util.tree_map(cast, v)
+            for k, v in params.items()}
     return params, x
 
 
@@ -2920,9 +2926,692 @@ def exit_log_distribution(gate_logits):
     return jnp.concatenate([(log_exit + before)[:-1], before[-1:]], axis=0)
 
 
+# ---------------------------------- hyper-connections, latent attention,
+# ---------------------------------- sparse experts, multi-token prediction
+# The blocks of a DeepSeek-V3-style sparse decoder under manifold-
+# constrained hyper-connections (Xie et al. 2025, arXiv:2512.24880, over
+# Zhu et al. 2024, arXiv:2409.19606): the residual stream is ``nStreams``
+# copies of the width, held feature-last as [N, T, nStreams * C]; every
+# sub-block reads one [N, T, C] mix of them and writes its result back
+# through per-token maps. A layer that takes several inputs says so with
+# ``n_inputs`` and is handed a tuple.
+
+_HIGHEST = jax.lax.Precision.HIGHEST
+
+
+def _rms(x, gain, eps):
+    """RMS norm with a float32 gain, statistics in float32, the result in
+    ``x``'s dtype (what :class:`RMSNorm` computes, inside another layer)."""
+    return norm_ops.rms_norm(x.astype(jnp.float32), gain.astype(jnp.float32),
+                             eps=eps).astype(x.dtype)
+
+
+def _steps(it) -> int:
+    """Positions a sequence input has (1 where it declares none): what a
+    layer's ``forward_flops(it)`` multiplies its per-token products by;
+    ``analysis.distribution._approx_flops`` asks a layer that has one."""
+    t = int(getattr(it, "dims", {}).get("timesteps", -1) or -1) \
+        if it is not None else -1
+    return t if t > 0 else 1
+
+
+def sinkhorn(m, iters: int, eps: float, row_axis: int = -1,
+             col_axis: int = -2):
+    """``iters`` rounds of "divide each row by its sum, then each column
+    by its" of a positive ``m`` (+ ``eps`` in every divisor; a row runs
+    along ``row_axis``, a column along ``col_axis``): the Sinkhorn-Knopp
+    projection towards the doubly stochastic matrices. The columns sum to
+    one after the last round, the rows nearly."""
+    for _ in range(int(iters)):
+        m = m / (jnp.sum(m, axis=row_axis, keepdims=True) + eps)
+        m = m / (jnp.sum(m, axis=col_axis, keepdims=True) + eps)
+    return m
+
+
+def _stream_maps(x, phis, eps):
+    """``[(x~ phi)^T for phi in phis]``, float32 [k, N, T] each (a map's
+    numbers lead, the tokens lie on the lanes: a [N, T, 4, 4] tensor
+    would be padded 64-fold on the chip), ``x~`` the RMS norm without a
+    gain of the streams ``x`` [N, T, F] and ``phi`` float32 [F, k]; the
+    products at float32's precision whatever ``x``'s dtype. Streams in
+    bfloat16 are exact in it, so three bfloat16 pieces of ``phi`` side by
+    side give the float32 product in ONE pass of the MXU (their columns,
+    3 x 24 at most, fit one 128-lane tile), where ``Precision.HIGHEST``
+    would take six over a float32 copy of the streams. The norm's divisor
+    is applied after the product."""
+    f32 = jnp.float32
+    P = jnp.concatenate([p.astype(f32) for p in phis], axis=1)
+    K = P.shape[1]
+    if x.dtype == jnp.bfloat16:
+        hi = P.astype(jnp.bfloat16)
+        mid = jax.lax.stop_gradient(P - hi.astype(f32)).astype(jnp.bfloat16)
+        lo = jax.lax.stop_gradient(
+            P - hi.astype(f32) - mid.astype(f32)).astype(jnp.bfloat16)
+        z = jnp.dot(x, jnp.concatenate([hi, mid, lo], axis=1),
+                    preferred_element_type=f32)
+        z = z[..., :K] + z[..., K:2 * K] + z[..., 2 * K:]
+    else:
+        z = jnp.dot(x.astype(f32), P, precision=_HIGHEST)
+    x32 = x.astype(f32)
+    inv = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1) + eps)      # [N, T]
+    return jnp.split(jnp.moveaxis(z, -1, 0) * inv,
+                     np.cumsum([p.shape[1] for p in phis])[:-1].tolist())
+
+
+class _HyperConnection(Layer):
+    """What the four hyper-connection layers share: the stream count, a
+    float32 island (streams come and go in the compute dtype, the maps
+    and the mixing are float32), the feature-last layout. Stream ``i`` is
+    the features ``i * C .. (i + 1) * C`` of [N, T, n * C]."""
+
+    input_kind = None
+    fp32_params = True
+
+    def __init__(self, nStreams: int = 4, eps: float = 1e-6, **kw):
+        super().__init__(**kw)
+        self.n_streams = int(nStreams)
+        self.eps = float(eps)
+
+    def mxu_lane_dims(self):
+        return []
+
+    def _width(self, it: InputType) -> int:
+        size = _sequence_size(it)
+        if size % self.n_streams:
+            raise ValueError(
+                f"{type(self).__name__} '{self.name}': {size} features do "
+                f"not divide into nStreams={self.n_streams}")
+        return size // self.n_streams
+
+    def infer_nin(self, it: InputType):
+        self.nIn = self.nOut = _sequence_size(it)
+
+    def output_type(self, it: InputType) -> InputType:
+        return InputType.recurrent(self.nOut, it.dims.get("timesteps", -1))
+
+    def forward_flops(self, it) -> int:
+        """The maps' products and the mixing, a token: thin next to any
+        sub-block."""
+        maps = sum(math.prod(shape) for shape in self.param_shapes().values()
+                   if len(shape) == 2 and shape[0] == self.nIn)
+        return _steps(it) * 2 * (maps + self.n_streams * max(
+            self.nIn or 0, self.nOut or 0))
+
+    def _streams(self, x):
+        """The ``n`` streams of [N, T, n * C], float32 [N, T, C] each."""
+        C = x.shape[-1] // self.n_streams
+        return [x[..., i * C:(i + 1) * C].astype(jnp.float32)
+                for i in range(self.n_streams)]
+
+
+class HyperConnectionIn(_HyperConnection):
+    """[N, T, C] -> ``nStreams`` copies side by side, [N, T, n * C]."""
+
+    has_params = False
+
+    def infer_nin(self, it: InputType):
+        self.nIn = _sequence_size(it)
+        self.nOut = self.nIn * self.n_streams
+
+    def apply(self, params, state, x, train, key):
+        x = _feature_last(self, x)
+        with jax.named_scope(_stepprogram.MHC_SCOPE):
+            return jnp.tile(x, (1, 1, self.n_streams)), state
+
+
+class HyperConnectionOut(_HyperConnection):
+    """The streams summed, [N, T, n * C] -> [N, T, C]."""
+
+    has_params = False
+
+    def infer_nin(self, it: InputType):
+        self.nIn = _sequence_size(it)
+        self.nOut = self._width(it)
+
+    def apply(self, params, state, x, train, key):
+        x = _feature_last(self, x)
+        with jax.named_scope(_stepprogram.MHC_SCOPE):
+            return sum(self._streams(x)).astype(x.dtype), state
+
+
+class HyperConnectionRead(_HyperConnection):
+    """A sub-block's input under hyper-connections: ``u = H_pre X`` with
+    ``H_pre = sigmoid(alpha_pre * (x~ phi_pre) + b_pre)`` [N, T, n], ``x~``
+    the RMS norm (no gain) of the flattened streams; one weight a stream
+    and a token."""
+
+    def infer_nin(self, it: InputType):
+        self.nIn = _sequence_size(it)
+        self.nOut = self._width(it)
+
+    def param_shapes(self):
+        if not self.nIn:
+            return {}
+        return {"phi_pre": (self.nIn, self.n_streams), "alpha_pre": (1,),
+                "b_pre": (self.n_streams,)}
+
+    def initialize(self, key):
+        n = self.n_streams
+        return {"phi_pre": _initialize((self.nIn, n), self.weight_init, key),
+                "alpha_pre": jnp.full((1,), 0.01, jnp.float32),
+                "b_pre": jnp.zeros((n,), jnp.float32)}, {}
+
+    def apply(self, params, state, x, train, key):
+        x = _feature_last(self, x)
+        with jax.named_scope(_stepprogram.MHC_SCOPE):
+            (z,) = _stream_maps(x, [params["phi_pre"]], self.eps)
+            h_pre = jax.nn.sigmoid(params["alpha_pre"][0] * z
+                                   + params["b_pre"][:, None, None])
+            u = sum(h_pre[i][..., None] * xi
+                    for i, xi in enumerate(self._streams(x)))
+            return u.astype(x.dtype), state
+
+
+class HyperConnectionWrite(_HyperConnection):
+    """A sub-block's output written back: inputs ``(X, y)``, ``X' = H_res X
+    + H_post^T y`` with ``H_post = 2 sigmoid(alpha_post * (x~ phi_post) +
+    b_post)`` [N, T, n] and ``H_res = sinkhorn(exp(clamp(alpha_res * mat(x~
+    phi_res) + b_res)))`` [N, T, n, n], the manifold constraint of
+    arXiv:2512.24880: a (nearly) doubly stochastic mixing of the streams,
+    so that neither a forward signal nor a gradient grows through the
+    depth. ``x~`` is of the streams BEFORE the sub-block, as the read's."""
+
+    n_inputs = 2
+
+    def __init__(self, sinkhornIters: int = 20, clampMin: float = -30.0,
+                 clampMax: float = 30.0, **kw):
+        super().__init__(**kw)
+        self.sinkhorn_iters = int(sinkhornIters)
+        self.clamp = (float(clampMin), float(clampMax))
+
+    def param_shapes(self):
+        if not self.nIn:
+            return {}
+        n = self.n_streams
+        return {"phi_post": (self.nIn, n), "phi_res": (self.nIn, n * n),
+                "alpha_post": (1,), "alpha_res": (1,), "b_post": (n,),
+                "b_res": (n, n)}
+
+    def initialize(self, key):
+        n = self.n_streams
+        k1, k2 = jax.random.split(key)
+        small = lambda: jnp.full((1,), 0.01, jnp.float32)  # noqa: E731
+        return {"phi_post": _initialize((self.nIn, n), self.weight_init, k1),
+                "phi_res": _initialize((self.nIn, n * n), self.weight_init,
+                                       k2),
+                "alpha_post": small(), "alpha_res": small(),
+                "b_post": jnp.zeros((n,), jnp.float32),
+                # H_res opens at the identity (nearly: exp(3) to 1)
+                "b_res": 3.0 * jnp.eye(n, dtype=jnp.float32)}, {}
+
+    def _maps(self, params, x):
+        """``(H_post [n, N, T], H_res [n, n, N, T])`` of the streams
+        ``x`` [N, T, n * C]: the maps' numbers lead."""
+        n = self.n_streams
+        post, res = _stream_maps(x, [params["phi_post"], params["phi_res"]],
+                                 self.eps)
+        h_post = 2.0 * jax.nn.sigmoid(params["alpha_post"][0] * post
+                                      + params["b_post"][:, None, None])
+        res = params["alpha_res"][0] * res.reshape((n, n) + res.shape[1:]) \
+            + params["b_res"][:, :, None, None]
+        return h_post, sinkhorn(jnp.exp(jnp.clip(res, *self.clamp)),
+                                self.sinkhorn_iters, self.eps,
+                                row_axis=1, col_axis=0)
+
+    def maps(self, params, x):
+        """``(H_post [N, T, n], H_res [N, T, n, n])``: a token leads."""
+        h_post, h_res = self._maps(params, x)
+        return jnp.moveaxis(h_post, 0, -1), \
+            jnp.moveaxis(jnp.moveaxis(h_res, 0, -1), 0, -1)
+
+    def apply(self, params, state, x, train, key):
+        x, y = x
+        x = _feature_last(self, x)
+        with jax.named_scope(_stepprogram.MHC_SCOPE):
+            xs, y32 = self._streams(x), y.astype(jnp.float32)
+            h_post, h_res = self._maps(params, x)
+            mixed = [h_post[i][..., None] * y32
+                     + sum(h_res[i, j][..., None] * xj
+                           for j, xj in enumerate(xs))
+                     for i in range(self.n_streams)]
+            return jnp.concatenate(mixed, axis=-1).astype(x.dtype), state
+
+
+class LatentAttentionLayer(Layer):
+    """Multi-head latent attention (DeepSeek-V2, arXiv:2405.04434 §2.1):
+    queries through a ``qLoraRank`` bottleneck with an RMS norm in it,
+    keys and values through ONE ``kvLoraRank`` latent a token (normed)
+    plus one rotary key of ``qkRopeHeadDim`` shared by all heads; a head's
+    query and key are ``[nope | rope]`` (``qkNopeHeadDim + qkRopeHeadDim``),
+    its value ``vHeadDim``. Rotary positions on the rope parts only,
+    rotate-half pairs, YaRN frequencies where ``ropeScaling`` gives them;
+    the scores' scale is ``mscale^2 / sqrt(qk head size)`` with YaRN's
+    ``mscale`` (``mscale_all_dim``). No bias. Training computes the keys
+    and values of every head from the latent (the cache-free form); the
+    core is :func:`ops.attention.causal_attention`."""
+
+    input_kind = "rnn"
+    fp32_leaves = ("q_gain", "kv_gain")
+
+    def __init__(self, nOut=None, nHeads: int = 1, qLoraRank: int = None,
+                 kvLoraRank: int = None, qkNopeHeadDim: int = None,
+                 qkRopeHeadDim: int = None, vHeadDim: int = None,
+                 ropeTheta: float = 10000.0, ropeScaling: dict = None,
+                 eps: float = 1e-6, **kw):
+        super().__init__(nOut=nOut, **kw)
+        self.n_heads = int(nHeads)
+        self.q_lora_rank, self.kv_lora_rank = int(qLoraRank), int(kvLoraRank)
+        self.qk_nope, self.qk_rope = int(qkNopeHeadDim), int(qkRopeHeadDim)
+        self.v_head = int(vHeadDim)
+        self.rope_theta = float(ropeTheta)
+        self.rope_scaling = dict(ropeScaling) if ropeScaling else None
+        self.eps = float(eps)
+        if self.qk_rope % 2:
+            raise ValueError(f"LatentAttentionLayer: rotary positions need "
+                             f"an even qkRopeHeadDim, got {self.qk_rope}")
+
+    def infer_nin(self, it: InputType):
+        super().infer_nin(it)
+        if self.nOut is None:
+            self.nOut = self.nIn
+
+    def mxu_lane_dims(self):
+        return [self.n_heads * (self.qk_nope + self.qk_rope), self.nOut]
+
+    def param_shapes(self):
+        if not self.nIn or not self.nOut:
+            return {}
+        H, ql, kvl = self.n_heads, self.q_lora_rank, self.kv_lora_rank
+        return {"Wqa": (self.nIn, ql), "q_gain": (ql,),
+                "Wqb": (ql, H * (self.qk_nope + self.qk_rope)),
+                "Wkva": (self.nIn, kvl + self.qk_rope), "kv_gain": (kvl,),
+                "Wkvb": (kvl, H * (self.qk_nope + self.v_head)),
+                "Wo": (H * self.v_head, self.nOut)}
+
+    def initialize(self, key):
+        out = {}
+        for name, shape in self.param_shapes().items():
+            key, sub = jax.random.split(key)
+            out[name] = jnp.ones(shape, jnp.float32) if len(shape) == 1 \
+                else _initialize(shape, self.weight_init, sub)
+        return out, {}
+
+    def forward_flops(self, it) -> int:
+        """The five projections and the whole square of the core (what a
+        plain lowering executes; the benchmark's requirement counts the
+        causal half)."""
+        t = _steps(it)
+        proj = sum(math.prod(s) for s in self.param_shapes().values()
+                   if len(s) == 2)
+        return 2 * t * proj + 2 * t * t * self.n_heads * (
+            self.qk_nope + self.qk_rope + self.v_head)
+
+    def inv_freq(self):
+        """The rope part's inverse frequencies, and the softmax scale."""
+        rs = self.rope_scaling
+        d = self.qk_nope + self.qk_rope
+        if not rs:
+            return None, d ** -0.5
+        inv = attention_ops.yarn_inv_freq(
+            self.qk_rope, self.rope_theta, rs["factor"],
+            rs["original_max_position_embeddings"],
+            rs.get("beta_fast", 32), rs.get("beta_slow", 1))
+        m = attention_ops.yarn_mscale(rs["factor"],
+                                      rs.get("mscale_all_dim", 0) or 0)
+        return inv, m * m * d ** -0.5
+
+    def apply(self, params, state, x, train, key):
+        x = self._maybe_dropout(_feature_last(self, x), train, key)
+        N, T, H = x.shape[0], x.shape[1], self.n_heads
+        dn, dr, dv = self.qk_nope, self.qk_rope, self.v_head
+        kvl = self.kv_lora_rank
+        inv, scale = self.inv_freq()
+        q = (_rms(x @ params["Wqa"], params["q_gain"], self.eps)
+             @ params["Wqb"]).reshape(N, T, H, dn + dr)
+        ckv = x @ params["Wkva"]
+        kv = (_rms(ckv[..., :kvl], params["kv_gain"], self.eps)
+              @ params["Wkvb"]).reshape(N, T, H, dn + dv)
+        rot = functools.partial(attention_ops.rotary_embedding,
+                                theta=self.rope_theta, inv_freq=inv)
+        k_rope = rot(ckv[..., kvl:].reshape(N, T, 1, dr))
+        q = jnp.concatenate([q[..., :dn], rot(q[..., dn:])], -1)
+        k = jnp.concatenate(
+            [kv[..., :dn], jnp.broadcast_to(k_rope, (N, T, H, dr))], -1)
+        with jax.named_scope(_stepprogram.ATTN_CORE_SCOPE):
+            o = attention_ops.causal_attention(q, k, kv[..., dn:],
+                                               scale=scale)
+        return o.reshape(N, T, H * dv) @ params["Wo"], state
+
+    def output_type(self, it: InputType) -> InputType:
+        return InputType.recurrent(self.nOut, it.dims.get("timesteps", -1))
+
+
+_MOE_LOWERED = _prof.get_registry().counter(
+    "dl4j_moe_lowered_total",
+    "Traces of nn.layers.SparseExpertsLayer's routed path (one a lowering "
+    "of each call site, not one a step) by what runs the grouped products "
+    "over the experts held",
+    labelnames=("path",))
+
+
+@jax.custom_vjp
+def _dispatch(x, order, inv, held):
+    """Rows of ``x`` [M, C] laid out a routed (token, expert) pair a row
+    in ``order`` (pair ``j`` is token ``j // k``): [M * k, C]. A gather
+    both ways: the backward brings the pairs' cotangents back in token
+    order (``inv``, the inverse permutation), drops those of pairs no held
+    expert saw (``held`` [M, k]: whatever the grouped product left in such
+    a row is nobody's gradient) and sums a token's ``k``."""
+    return jnp.take(x, order // held.shape[1], axis=0)
+
+
+def _dispatch_fwd(x, order, inv, held):
+    return _dispatch(x, order, inv, held), (inv, held)
+
+
+def _dispatch_bwd(res, g):
+    inv, held = res
+    back = jnp.take(g, inv, axis=0).reshape(held.shape + g.shape[1:])
+    dx = jnp.sum(jnp.where(held[..., None], back, 0).astype(jnp.float32),
+                 axis=1).astype(g.dtype)
+    return dx, None, None, None
+
+
+_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+
+
+@jax.custom_vjp
+def _unpermute(y, order, inv):
+    """``y[inv]``: sorted pair rows back in token order; its backward is
+    the gather ``g[order]``, never a scatter."""
+    return jnp.take(y, inv, axis=0)
+
+
+_unpermute.defvjp(lambda y, order, inv: (jnp.take(y, inv, axis=0), order),
+                  lambda order, g: (jnp.take(g, order, axis=0), None, None))
+
+
+class SparseExpertsLayer(Layer):
+    """A sparse-expert feed-forward layer that is TOLD which experts it
+    holds (DeepSeek-V3's routing, arXiv:2412.19437 §2.1.2): a router over
+    all ``nExperts`` scores a token ``s = sigmoid(x Wr)`` in float32, the
+    ``nExpertsPerTok`` largest of ``s + select_bias`` are selected (the
+    bias, a layer STATE with no gradient, enters the selection only), the
+    gates are ``routedScalingFactor * s_i / sum_selected s_j`` (the sum
+    over ALL selected experts, held here or not), and the output is
+    ``shared(x) + sum_{i selected and held} g_i E_i(x)``, every expert and
+    the one shared expert a SwiGLU MLP of inner width ``nHidden``.
+    ``heldExperts`` lists the ids held (default: all): the chip's share
+    under expert parallelism. What the absent experts would add is left
+    out; no code stands in for their exchange.
+
+    No token is dropped: the selected (token, expert) pairs are sorted by
+    held expert into ``tokens * nExpertsPerTok`` rows (the most any
+    routing can send here) and the held experts' products are grouped
+    matrix products over that one buffer (``jax.lax.ragged_dot``, group
+    sizes the experts' loads), under ``dl4j_moe_experts`` inside
+    ``dl4j_moe``. The state carries the loads of the last step
+    (``expert_load`` [held]) for the gauges ``dl4j_moe_expert_load`` /
+    ``dl4j_moe_held_pairs``, and with ``keepSelected=rows`` the ids it
+    selected for the first ``rows`` tokens (``selected`` [rows, k] int32,
+    -1 beyond the tokens): which experts a token takes is a discrete
+    choice that rounding moves (the k-th and the next score of 64 lie
+    close), so a comparison with a float32 reference has the reference
+    follow the program's choice and judges the choice by its margin."""
+
+    input_kind = None
+    fp32_leaves = ("Wr",)
+
+    def __init__(self, nOut=None, nExperts: int = None,
+                 nExpertsPerTok: int = 1, nHidden: int = None,
+                 heldExperts=None, routedScalingFactor: float = 1.0,
+                 keepSelected: int = 0, **kw):
+        super().__init__(nOut=nOut, activation="swish", **kw)
+        self.keep_selected = int(keepSelected)
+        if not nExperts or not nHidden:
+            raise ValueError("SparseExpertsLayer needs nExperts, the "
+                             "router's width, and nHidden, an expert's")
+        self.n_experts, self.top_k = int(nExperts), int(nExpertsPerTok)
+        self.n_hidden = int(nHidden)
+        self.held = [int(e) for e in (range(self.n_experts)
+                                      if heldExperts is None
+                                      else heldExperts)]
+        if len(set(self.held)) != len(self.held) or not all(
+                0 <= e < self.n_experts for e in self.held):
+            raise ValueError(f"SparseExpertsLayer: heldExperts must be "
+                             f"distinct ids below {self.n_experts}, got "
+                             f"{self.held}")
+        self.scaling = float(routedScalingFactor)
+
+    def infer_nin(self, it: InputType):
+        self.nIn = _sequence_size(it)
+        if self.nOut is None:
+            self.nOut = self.nIn
+
+    def mxu_lane_dims(self):
+        return [self.n_hidden, self.nOut]
+
+    def param_shapes(self):
+        if not self.nIn or not self.nOut:
+            return {}
+        E, F = len(self.held), self.n_hidden
+        return {"Wr": (self.nIn, self.n_experts),
+                "Eg": (E, self.nIn, F), "Eu": (E, self.nIn, F),
+                "Ed": (E, F, self.nOut),
+                "Sg": (self.nIn, F), "Su": (self.nIn, F),
+                "Sd": (F, self.nOut)}
+
+    def initialize(self, key):
+        out = {}
+        for name, shape in self.param_shapes().items():
+            key, sub = jax.random.split(key)
+            if len(shape) == 3:     # an expert a row: each its own fan
+                out[name] = jnp.stack([
+                    _initialize(shape[1:], self.weight_init, k)
+                    for k in jax.random.split(sub, shape[0])])
+            else:
+                out[name] = _initialize(shape, self.weight_init, sub)
+        state = {"select_bias": jnp.zeros((self.n_experts,), jnp.float32),
+                 "expert_load": jnp.zeros((len(self.held),), jnp.float32)}
+        if self.keep_selected:
+            state["selected"] = jnp.full((self.keep_selected, self.top_k),
+                                         -1, jnp.int32)
+        return out, state
+
+    def forward_flops(self, it) -> int:
+        """Router, the shared expert, and the routed products at the load
+        uniform routing gives the experts held: ``nExpertsPerTok * held /
+        nExperts`` experts a token."""
+        per_expert = 3 * self.nIn * self.n_hidden
+        share = self.top_k * len(self.held) / self.n_experts
+        return int(_steps(it) * 2 * (
+            self.nIn * self.n_experts
+            + per_expert * (1 + share)))
+
+    def route(self, x32, wr, select_bias):
+        """``(selected ids [M, k], gates [M, k])`` of float32 tokens."""
+        s = jax.nn.sigmoid(jnp.dot(x32, wr.astype(jnp.float32),
+                                   precision=_HIGHEST))
+        _, sel = jax.lax.top_k(s + select_bias, self.top_k)
+        picked = jnp.take_along_axis(s, sel, axis=-1)
+        return sel, picked / jnp.sum(picked, axis=-1, keepdims=True) \
+            * self.scaling
+
+    def apply(self, params, state, x, train, key):
+        x = self._maybe_dropout(_feature_last(self, x), train, key)
+        f = act.get(self.activation)
+        xf = x.reshape(-1, x.shape[-1])
+        M, k, E = xf.shape[0], self.top_k, len(self.held)
+        with jax.named_scope(_stepprogram.MOE_SCOPE):
+            sel, gate = self.route(xf.astype(jnp.float32), params["Wr"],
+                                   state["select_bias"])
+            # a selected expert's row among the held ones; E = not held
+            local = jnp.full((self.n_experts,), E, jnp.int32).at[
+                jnp.asarray(self.held, jnp.int32)].set(
+                    jnp.arange(E, dtype=jnp.int32))[sel]
+            held = local < E
+            order = jnp.argsort(local.reshape(-1), stable=True)
+            inv = jnp.argsort(order)
+            load = jnp.sum(local.reshape(-1, 1) == jnp.arange(E),
+                           axis=0, dtype=jnp.int32)
+            rows = _dispatch(xf, order, inv, held)
+            with jax.named_scope(_stepprogram.MOE_EXPERTS_SCOPE):
+                _MOE_LOWERED.labels("ragged_dot").inc()
+                h = f(jax.lax.ragged_dot(rows, params["Eg"], load)) \
+                    * jax.lax.ragged_dot(rows, params["Eu"], load)
+                ys = jax.lax.ragged_dot(h, params["Ed"], load)
+            ys = _unpermute(ys, order, inv).reshape(M, k, -1)
+            routed = jnp.sum(jnp.where(held[..., None], ys, 0)
+                             * gate[..., None].astype(ys.dtype), axis=1)
+        out = routed + (f(xf @ params["Sg"]) * (xf @ params["Su"])) \
+            @ params["Sd"]
+        new_state = {"select_bias": state["select_bias"],
+                     "expert_load": jax.lax.stop_gradient(
+                         load.astype(jnp.float32))}
+        if self.keep_selected:
+            rows = self.keep_selected
+            new_state["selected"] = jnp.pad(
+                sel[:rows].astype(jnp.int32),
+                ((0, max(rows - M, 0)), (0, 0)), constant_values=-1)
+        return out.reshape(x.shape[:-1] + (self.nOut,)).astype(x.dtype), \
+            new_state
+
+    def output_type(self, it: InputType) -> InputType:
+        if it.kind == "rnn":
+            return InputType.recurrent(self.nOut,
+                                       it.dims.get("timesteps", -1))
+        return InputType.feedForward(self.nOut)
+
+
+class MTPJoinLayer(Layer):
+    """Where a multi-token-prediction module starts (DeepSeek-V3,
+    arXiv:2412.19437 §2.2): inputs ``(h, e)``, the main model's hidden
+    states before its final norm and the embedding of the NEXT token;
+    ``[RMSNorm(h) ; RMSNorm(e)] W`` with ``W`` [2 C, C], each norm with a
+    gain of its own."""
+
+    input_kind = None
+    n_inputs = 2
+    fp32_leaves = ("h_gain", "e_gain")
+
+    def __init__(self, nOut=None, eps: float = 1e-6, **kw):
+        super().__init__(nOut=nOut, **kw)
+        self.eps = float(eps)
+
+    def infer_nin(self, it: InputType):
+        self.nIn = _sequence_size(it)
+        if self.nOut is None:
+            self.nOut = self.nIn
+
+    def param_shapes(self):
+        if not self.nIn or not self.nOut:
+            return {}
+        return {"h_gain": (self.nIn,), "e_gain": (self.nIn,),
+                "W": (2 * self.nIn, self.nOut)}
+
+    def initialize(self, key):
+        ones = lambda: jnp.ones((self.nIn,), jnp.float32)   # noqa: E731
+        return {"h_gain": ones(), "e_gain": ones(),
+                "W": _initialize((2 * self.nIn, self.nOut),
+                                 self.weight_init, key)}, {}
+
+    def apply(self, params, state, x, train, key):
+        h, e = (_feature_last(self, a) for a in x)
+        joined = jnp.concatenate(
+            [_rms(h, params["h_gain"], self.eps),
+             _rms(e, params["e_gain"], self.eps).astype(h.dtype)], -1)
+        return joined @ params["W"], state
+
+    def output_type(self, it: InputType) -> InputType:
+        return InputType.recurrent(self.nOut, it.dims.get("timesteps", -1))
+
+
+class MTPLMOutputLayer(BaseOutputLayer):
+    """A language-model head shared by the main model and its
+    multi-token-prediction modules (DeepSeek-V3, arXiv:2412.19437 §2.2):
+    inputs ``(h_0, h_1, ..)``, the main model's normed hidden states and
+    each module's; module ``d``'s state at position ``i`` has seen token
+    ``i + d`` and predicts token ``i + d + 1``. With the labels ``y_i`` =
+    token ``i + 1`` the loss is ``CE(h_0 W, y) + mtpWeight * sum_d
+    CE(h_d[i] W, y[i + d])``, each a mean over its own unmasked positions
+    (a module's last ``d`` have no label). One head ``W`` for all:
+    :func:`blocked_cross_entropy` over the inputs as its passes, a
+    module's states moved ``d`` places right so that every pass meets the
+    same labels. Labels are INTEGER ids [N, T]; the state carries each
+    head's loss of the last step (``dl4j_lm_loss``). ``apply`` gives the
+    main model's logits. Fed one array it is a plain head."""
+
+    input_kind = None
+    loss_from_input = True
+    n_inputs = None             # as many as the graph wires in
+
+    def __init__(self, nOut=None, mtpWeight: float = 0.3, **kw):
+        super().__init__(lossFunction="sparse_mcxent", nOut=nOut, **kw)
+        self.mtp_weight = float(mtpWeight)
+        self.n_heads = 1
+
+    def infer_nin(self, it: InputType):
+        self.nIn = _sequence_size(it)
+
+    def set_input_count(self, n: int):
+        """The graph says how many heads' inputs it wired in."""
+        self.n_heads = int(n)
+
+    def forward_flops(self, it) -> int:
+        return _steps(it) * 2 * (self.nIn or 0) * (self.nOut or 0) \
+            * self.n_heads
+
+    def param_shapes(self):
+        if not self.nIn or not self.nOut:
+            return {}
+        return {"W": (self.nIn, self.nOut)}
+
+    def initialize(self, key):
+        return ({"W": _initialize((self.nIn, self.nOut), self.weight_init,
+                                  key)},
+                {"head_loss": jnp.zeros((self.n_heads,), jnp.float32)})
+
+    def apply(self, params, state, x, train, key):
+        h = x[0] if isinstance(x, tuple) else x
+        return _logits(_feature_last(self, h), params["W"]), state
+
+    def loss_from(self, params, x, labels, mask=None):
+        """``(loss, state)`` from the heads' inputs."""
+        hs = tuple(_feature_last(self, h)
+                   for h in (x if isinstance(x, tuple) else (x,)))
+        labels = labels.astype(jnp.int32)
+        T = labels.shape[1]
+        with jax.named_scope(_stepprogram.HEAD_LOSS_SCOPE):
+            ce = blocked_cross_entropy(
+                tuple(h if d == 0 else jnp.roll(h, d, axis=1)
+                      for d, h in enumerate(hs)), params["W"], labels)
+            m = jnp.ones(labels.shape, jnp.float32) if mask is None \
+                else mask.astype(jnp.float32)
+            losses = []
+            for d in range(len(hs)):
+                md = m * (jnp.arange(T) >= d)
+                losses.append(jnp.sum(ce[d] * md)
+                              / jnp.maximum(jnp.sum(md), 1.0))
+            losses = jnp.stack(losses)
+            loss = losses[0] + self.mtp_weight * jnp.sum(losses[1:])
+        return loss, {"head_loss": jax.lax.stop_gradient(losses)}
+
+    def compute_loss(self, labels, preds, mask=None):
+        raise ValueError(
+            "MTPLMOutputLayer works its loss out from its inputs "
+            "(loss_from), not from predictions")
+
+    def output_type(self, it: InputType) -> InputType:
+        return InputType.recurrent(self.nOut, it.dims.get("timesteps", -1))
+
+
+
 #: layers that compute on [N, T, C] (see ``layout_step``)
 SEQUENCE_LAST = (RMSNorm, CausalSelfAttentionLayer, GatedMLP,
-                 LoopedLMOutputLayer)
+                 LoopedLMOutputLayer, HyperConnectionIn, HyperConnectionOut,
+                 HyperConnectionRead, HyperConnectionWrite,
+                 LatentAttentionLayer, SparseExpertsLayer, MTPJoinLayer,
+                 MTPLMOutputLayer)
 
 for _cls in SEQUENCE_LAST:
     _LAYER_CLASSES[_cls.__name__] = _cls

@@ -149,16 +149,51 @@ def _flash_attention_scan(q, k, v, *, mask=None, is_causal: bool = False,
 
 
 # ------------------------------------------- causal training attention
-def rotary_embedding(x, theta: float = 10000.0):
+def yarn_inv_freq(dim: int, theta: float, factor: float,
+                  original_max_position: int, beta_fast: float = 32.0,
+                  beta_slow: float = 1.0):
+    """YaRN's inverse frequencies [dim/2] (Peng et al. 2023,
+    arXiv:2309.00071, as DeepSeek-V2's rotary embedding computes them):
+    the pair ``i`` turns at ``theta^(-2i/dim)``, divided by ``factor``
+    where its wavelength is longer than the original context allows
+    ``beta_slow`` turns of, unchanged where it makes more than
+    ``beta_fast``, a linear ramp over the pairs between. Host arithmetic
+    (numpy, float64 rounded once): a constant of the program."""
+    import numpy as np
+    half = dim // 2
+    base = np.float64(theta) ** (np.arange(half, dtype=np.float64)
+                                 * 2.0 / dim)
+
+    def correction_dim(turns):
+        return dim * np.log(original_max_position / (turns * 2.0 * np.pi)) \
+            / (2.0 * np.log(np.float64(theta)))
+    low = max(int(np.floor(correction_dim(beta_fast))), 0)
+    high = min(int(np.ceil(correction_dim(beta_slow))), dim - 1)
+    ramp = np.clip((np.arange(half, dtype=np.float64) - low)
+                   / max(high - low, 1e-3), 0.0, 1.0)
+    inv = (1.0 / (factor * base)) * ramp + (1.0 / base) * (1.0 - ramp)
+    return inv.astype(np.float32)
+
+
+def yarn_mscale(factor: float, mscale: float = 1.0) -> float:
+    """YaRN's attention temperature ``0.1 * mscale * ln(factor) + 1`` (1
+    for ``factor`` <= 1); a softmax scale takes its square."""
+    import math
+    return 1.0 if factor <= 1 else 0.1 * mscale * math.log(factor) + 1.0
+
+
+def rotary_embedding(x, theta: float = 10000.0, inv_freq=None):
     """Rotary position embedding over the whole head size of ``x``
     [B, T, H, D] at positions 0..T-1: rotate-half pairs ``(i, i + D/2)``,
     angle ``pos * theta^(-2i/D)`` (Su et al. 2021, as the released
-    language models apply it). Angles and the rotation are float32; the
-    result has ``x``'s dtype."""
+    language models apply it), or ``pos * inv_freq[i]`` where the caller
+    hands the D/2 inverse frequencies in (:func:`yarn_inv_freq`). Angles
+    and the rotation are float32; the result has ``x``'s dtype."""
     T, D = x.shape[1], x.shape[-1]
     half = D // 2
     inv = jnp.exp(jnp.arange(half, dtype=jnp.float32)
-                  * (-2.0 * jnp.log(jnp.float32(theta)) / D))
+                  * (-2.0 * jnp.log(jnp.float32(theta)) / D)) \
+        if inv_freq is None else jnp.asarray(inv_freq, jnp.float32)
     ang = jnp.arange(T, dtype=jnp.float32)[:, None] * inv[None, :]
     cos = jnp.cos(ang)[None, :, None, :]
     sin = jnp.sin(ang)[None, :, None, :]
@@ -220,14 +255,14 @@ def _rows(x, i, e, axis=1):
     return lax.slice_in_dim(x, i, e, axis=axis)
 
 
-def _scores(q, k, first_row):
-    """float32 ``q k^T / sqrt(D)`` of the query rows ``first_row..``
+def _scores(q, k, first_row, scale=None):
+    """float32 ``q k^T * scale`` (``1 / sqrt(D)`` unless handed in) of the query rows ``first_row..``
     against the keys ``0..``, the keys after a row at -1e30. The mask
     rides in the product's own fusion over the whole block: cut to the
     diagonal tile it costs a concatenation of the scores (v5e: 11.1 ms
     against 5.8 for the probe above)."""
     s = jnp.einsum(_QK, q, k, preferred_element_type=jnp.float32) \
-        * (q.shape[-1] ** -0.5)
+        * (q.shape[-1] ** -0.5 if scale is None else scale)
     rows = first_row + jnp.arange(q.shape[1])
     return jnp.where(rows[:, None] >= jnp.arange(k.shape[1])[None, :],
                      s, jnp.float32(-1e30))
@@ -255,8 +290,8 @@ def _over_head_groups(fn, hg, args, head_axes, out_axes):
 # the calls, each under its caller's scopes, so the compiled step and its
 # map are what they would be written out (PR 30: set-up 83 s against the
 # parent's 38 with every block of every call traced anew).
-@functools.partial(jax.jit, static_argnums=3)
-def _fwd_heads(q, k, v, blk):
+@functools.partial(jax.jit, static_argnums=(3, 4))
+def _fwd_heads(q, k, v, blk, scale=None):
     """``(O, lse)`` of these heads: the output and the float32 row
     log-sum-exp [B, H, T] of the scaled, masked scores, a block of ``blk``
     query rows after the other; nothing of [T, T] leaves it."""
@@ -266,7 +301,7 @@ def _fwd_heads(q, k, v, blk):
         qi = _rows(q, i, e)
         if outs:
             qi, outs[-1] = _then(qi, outs[-1])
-        s = _scores(qi, _rows(k, 0, e), i)
+        s = _scores(qi, _rows(k, 0, e), i, scale)
         m = jnp.max(s, axis=-1)
         p = jnp.exp(s - m[..., None])
         l = jnp.sum(p, axis=-1)
@@ -277,14 +312,15 @@ def _fwd_heads(q, k, v, blk):
     return jnp.concatenate(outs, 1), jnp.concatenate(lses, -1)
 
 
-@functools.partial(jax.jit, static_argnums=6)
-def _bwd_heads(q, k, v, o, lse, do, blk):
+@functools.partial(jax.jit, static_argnums=(6, 7))
+def _bwd_heads(q, k, v, o, lse, do, blk, scale=None):
     """``(dq, dk, dv)`` of these heads, FlashAttention's backward (Dao et
     al. 2022, algorithm 4) in plain matmuls: ``p`` again from the scores
     and ``lse``, ``delta = rowsum(dO * O)`` over [T, D], ``ds = p * (dO
     v^T - delta) / sqrt(D)``; bf16 operands, float32 products, ``dk`` and
     ``dv`` summed in float32 over the query blocks and rounded once."""
-    scale = q.shape[-1] ** -0.5
+    softmax_scale, scale = scale, \
+        (q.shape[-1] ** -0.5 if scale is None else scale)
     delta = jnp.swapaxes(jnp.sum(
         do.astype(jnp.float32) * o.astype(jnp.float32), -1), 1, 2)
     dk = jnp.zeros(k.shape, jnp.float32)
@@ -297,7 +333,8 @@ def _bwd_heads(q, k, v, o, lse, do, blk):
         if dqs:
             (qi, doi), (dqs[-1], dk, dv) = _then(
                 (qi, doi), (dqs[-1], dk, dv))
-        p = jnp.exp(_scores(qi, ki, i) - _rows(lse, i, e, 2)[..., None])
+        p = jnp.exp(_scores(qi, ki, i, softmax_scale)
+                    - _rows(lse, i, e, 2)[..., None])
         dvi = jnp.einsum(_PTX, p.astype(v.dtype), doi,
                          preferred_element_type=jnp.float32)
         dp = jnp.einsum(_QK, doi, vi, preferred_element_type=jnp.float32)
@@ -311,36 +348,39 @@ def _bwd_heads(q, k, v, o, lse, do, blk):
     return jnp.concatenate(dqs, 1), dk.astype(k.dtype), dv.astype(v.dtype)
 
 
-def _causal_fwd(q, k, v):
+def _causal_fwd(q, k, v, scale=None):
     """``(O, lse)`` of every head, a group after the other."""
     blk, hg = _causal_plan(*q.shape[:3])
-    return _over_head_groups(lambda *xs: _fwd_heads(*xs, blk), hg,
+    return _over_head_groups(lambda *xs: _fwd_heads(*xs, blk, scale), hg,
                              (q, k, v), (2, 2, 2), (2, 1))
 
 
-def _causal_bwd(res, do):
+def _causal_bwd(scale, res, do):
     """``(dq, dk, dv)`` from what the forward kept and ``dO``."""
     blk, hg = _causal_plan(*res[0].shape[:3])
-    return _over_head_groups(lambda *xs: _bwd_heads(*xs, blk), hg,
+    return _over_head_groups(lambda *xs: _bwd_heads(*xs, blk, scale), hg,
                              (*res, do), (2, 2, 2, 2, 1, 2), (2, 2, 2))
 
 
-@jax.custom_vjp
-def _causal_core(q, k, v):
-    return _causal_fwd(q, k, v)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
+def _causal_core(q, k, v, scale):
+    return _causal_fwd(q, k, v, scale)[0]
 
 
-def _causal_core_fwd(q, k, v):
-    o, lse = _causal_fwd(q, k, v)
+def _causal_core_fwd(q, k, v, scale):
+    o, lse = _causal_fwd(q, k, v, scale)
     return o, (q, k, v, o, lse)
 
 
 _causal_core.defvjp(_causal_core_fwd, _causal_bwd)
 
 
-def causal_attention(q, k, v):
-    """Causal scaled dot-product attention for a training step, [B, T, H,
-    D] each: a forward and a backward written by hand (``jax.custom_vjp``)
+def causal_attention(q, k, v, scale: float = None):
+    """Causal scaled dot-product attention for a training step, ``q`` and
+    ``k`` [B, T, H, D], ``v`` [B, T, H, Dv] (latent attention's value heads
+    are narrower than its query/key heads; the output has ``v``'s), the
+    scores times ``scale`` (``1 / sqrt(D)`` unless handed in: YaRN's
+    temperature rides in it): a forward and a backward written by hand (``jax.custom_vjp``)
     in plain XLA matmuls over query blocks of :data:`CAUSAL_QUERY_BLOCK`
     rows, each against the keys up to its own last row, the heads in
     groups sized by :data:`CAUSAL_SCORE_BYTES`; a ``T`` that is no
@@ -352,7 +392,7 @@ def causal_attention(q, k, v):
     once, to ``v``'s dtype, before a product."""
     blk, _ = _causal_plan(*q.shape[:3])
     _CORE_LOWERED.labels("blocked" if blk < q.shape[1] else "single").inc()
-    return _causal_core(q, k, v)
+    return _causal_core(q, k, v, None if scale is None else float(scale))
 
 
 # --------------------------------------------------- reference-layout shims

@@ -50,7 +50,16 @@ AUGMENT_SCOPE = "dl4j_augment"
 #: weighted sum) and the heads, gates and loss of a looped model's passes
 ATTN_CORE_SCOPE = "dl4j_attn_core"
 HEAD_LOSS_SCOPE = "dl4j_head_loss"
-PARTS = {ATTN_CORE_SCOPE: "attn_core", HEAD_LOSS_SCOPE: "head_loss"}
+#: the hyper-connection read and write around a sub-block (stream norm,
+#: maps, Sinkhorn, mixing); a sparse-expert layer's routed path (router,
+#: top-k, dispatch, combine) and, inside it, the grouped products over the
+#: experts held
+MHC_SCOPE = "dl4j_mhc"
+MOE_SCOPE = "dl4j_moe"
+MOE_EXPERTS_SCOPE = "dl4j_moe_experts"
+PARTS = {ATTN_CORE_SCOPE: "attn_core", HEAD_LOSS_SCOPE: "head_loss",
+         MHC_SCOPE: "mhc", MOE_SCOPE: "moe",
+         MOE_EXPERTS_SCOPE: "moe_experts"}
 #: what JAX names the forward ops a ``jax.checkpoint`` runs again in the
 #: backward pass
 REMAT_MARK = "rematted_computation"
@@ -73,7 +82,7 @@ class Entry(NamedTuple):
     mixed: bool                 # a fusion whose instructions disagree on
     #                             the phase (weight gradient + Adam, …)
     loop_pass: Optional[int] = None     # pass of a LoopVertex, from 1
-    part: Optional[str] = None  # attn_core | head_loss (see PARTS)
+    part: Optional[str] = None  # attn_core | head_loss | mhc | … (PARTS)
     remat: bool = False         # forward work run again in the backward
     #                             pass (a rematerialised stretch)
 
@@ -84,7 +93,8 @@ _SCOPE = re.compile(
     r"dl4j_(?:L\d+_[A-Za-z0-9_.\-]+|updater|loss|augment)")
 _KERNEL = re.compile(r"(dl4j_[A-Za-z0-9_]+)/pallas_call")
 _PASS = re.compile(r"dl4j_ut(\d+)")
-_PART = re.compile("|".join(PARTS))
+# the longest name first (``dl4j_moe_experts`` before ``dl4j_moe``)
+_PART = re.compile("|".join(sorted(PARTS, key=len, reverse=True)))
 _COMPUTATION = re.compile(r"^(?:ENTRY )?%?([\w.\-]+) \(.*\{\s*$")
 _INSTRUCTION = re.compile(r"^\s+(?:ROOT )?%?([\w.\-]+) = ")
 _OPCODE = re.compile(r" ([a-z][a-z0-9\-]*)\(")
@@ -119,9 +129,9 @@ def marks(op_name: str):
     with its loss, and whether it is rematerialised forward work."""
     name = op_name.partition(";")[0]
     ut = _PASS.search(name)
-    part = _PART.search(name)
+    part = _PART.findall(name)      # scopes nest: the innermost speaks
     return (int(ut.group(1)) if ut else None,
-            PARTS[part.group(0)] if part else None, REMAT_MARK in name)
+            PARTS[part[-1]] if part else None, REMAT_MARK in name)
 
 
 def module_name(hlo_text: str) -> Optional[str]:
@@ -217,16 +227,22 @@ _MOVES = ("copy-start", "copy-done", "slice-start", "slice-done", "copy",
 _OPERAND = re.compile(r"%([\w.\-]+)")
 
 
+def _first_users(instructions) -> Dict[str, str]:
+    """``{instruction: the first instruction that reads it}``."""
+    first_user: Dict[str, str] = {}
+    for name, _op, rest, _root in instructions:
+        for operand in _OPERAND.findall(rest.partition(", metadata=")[0]):
+            first_user.setdefault(operand, name)
+    return first_user
+
+
 def _adopt_moves(instructions, out: Dict[str, Entry]) -> None:
     """An unnamed data movement works for the instruction that reads what
     it moved: it takes that instruction's phase, layer and marks (a
     ``-start`` through its ``-done``). Thousands of them a step prefetch
     operands for a looped stack's fusions; without this they are
     ``other`` and no layer's time."""
-    first_user: Dict[str, str] = {}
-    for name, _op, rest, _root in instructions:
-        for operand in _OPERAND.findall(rest.partition(", metadata=")[0]):
-            first_user.setdefault(operand, name)
+    first_user = _first_users(instructions)
     for name, op, _rest, _root in instructions:
         if op not in _MOVES or out.get(name) is not _OTHER:
             continue
@@ -236,6 +252,41 @@ def _adopt_moves(instructions, out: Dict[str, Entry]) -> None:
         found = out.get(user)
         if found is not None and found is not _OTHER:
             out[name] = found._replace(kernel=None, mixed=False)
+
+
+#: kernels the compiler writes in the place of an instruction it rewrote,
+#: by the name it gives them, and the part they are: a grouped matrix
+#: product (``jax.lax.ragged_dot``) becomes ``ragged-dot-<mode>.<n>``
+#: custom-calls whose metadata keeps nothing of the scopes it was traced
+#: under
+_COMPILER_KERNELS = {"ragged-dot": "moe_experts"}
+
+
+def _adopt_kernels(instructions, out: Dict[str, Entry]) -> None:
+    """A kernel of the compiler's own (:data:`_COMPILER_KERNELS`) carries
+    no scope: it takes the layer and marks of one of its operands'
+    producers or of its first reader (the rows it multiplies were
+    gathered in its layer) and the part its name stands for. Like a
+    fusion it can only run once its latest input exists: of those it
+    takes the latest phase, and rematerialised only if all are (a weight
+    gradient reads rows the backward pass made again AND a cotangent)."""
+    first_user = _first_users(instructions)
+    for name, op, rest, _root in instructions:
+        part = next((p for k, p in _COMPILER_KERNELS.items()
+                     if name.startswith(k)), None)
+        if op != "custom-call" or part is None:
+            continue
+        operands = _OPERAND.findall(
+            rest.partition(", custom_call_target=")[0])
+        near = [out[o] for o in operands + [first_user.get(name)]
+                if o in out and out[o] is not _OTHER
+                and out[o].phase in PHASES[:2]]
+        if near:
+            found = max(near, key=lambda e: (PHASES.index(e.phase),
+                                             not e.remat))
+            out[name] = found._replace(kernel=None, mixed=False, part=part)
+        else:
+            out[name] = Entry("other", None, None, False, None, part, False)
 
 
 def parse(hlo_text: str) -> Dict[str, Entry]:
@@ -275,6 +326,7 @@ def parse(hlo_text: str) -> Dict[str, Entry]:
                 todo.extend(b.strip().lstrip("%")
                             for b in branches.group(1).split(","))
         _adopt_moves(comps[comp], out)
+        _adopt_kernels(comps[comp], out)
     return out
 
 

@@ -81,6 +81,24 @@ LOOP_PASS_LOSS = _prof.get_registry().gauge(
     "dl4j_loop_pass_loss",
     "Batch mean cross-entropy of each pass's logits of a looped model at "
     "the last step of the last fit", labelnames=("pass",))
+# What a sparse-expert layer and a shared language-model head read at
+# the last step of a fit (their states: nn.layers.SparseExpertsLayer,
+# MTPLMOutputLayer).
+MOE_HELD_PAIRS = _prof.get_registry().gauge(
+    "dl4j_moe_held_pairs",
+    "Routed (token, expert) pairs that met an expert held here, a "
+    "sparse-expert layer, at the last step of the last fit",
+    labelnames=("layer",))
+MOE_EXPERT_LOAD = _prof.get_registry().gauge(
+    "dl4j_moe_expert_load",
+    "Routed pairs at each expert held here (its id among all the "
+    "router's) at the last step of the last fit",
+    labelnames=("layer", "expert"))
+LM_LOSS = _prof.get_registry().gauge(
+    "dl4j_lm_loss",
+    "Mean cross-entropy of the main head and of each multi-token-"
+    "prediction head at the last step of the last fit",
+    labelnames=("head",))
 _STEP_SECONDS = _prof.get_registry().histogram(
     "dl4j_train_step_seconds",
     "Host time to enqueue one compiled train dispatch (1 or K steps); "
@@ -194,13 +212,27 @@ def epoch_span(model):
 
 def publish_loop_gauges(model) -> None:
     """``dl4j_loop_exit_mass`` / ``dl4j_loop_pass_loss`` from the state the
-    last step left in a graph's looped heads. One device-to-host read, so
+    last step left in a graph's looped heads, and ``dl4j_moe_held_pairs``
+    / ``dl4j_moe_expert_load`` / ``dl4j_lm_loss`` from its sparse-expert
+    layers' and shared heads'. One device-to-host read, so
     only while instrumentation is active and only once a ``fit`` call has
     dispatched its last step: it adds no sync inside the loop."""
     if not _prof.instrumentation_active():
         return
-    for state in getattr(model, "_states", {}).values():
-        if not isinstance(state, dict) or "exit_mass" not in state:
+    for name, state in getattr(model, "_states", {}).items():
+        if not isinstance(state, dict):
+            continue
+        if "expert_load" in state:
+            load = jax.device_get(state["expert_load"])
+            held = model.conf.node_by_name[name].obj.held
+            MOE_HELD_PAIRS.labels(name).set(float(load.sum()))
+            for e, n in zip(held, load):
+                MOE_EXPERT_LOAD.labels(name, str(e)).set(float(n))
+        if "head_loss" in state:
+            for d, loss in enumerate(jax.device_get(state["head_loss"])):
+                LM_LOSS.labels("main" if d == 0 else "mtp" if d == 1
+                               else f"mtp{d}").set(float(loss))
+        if "exit_mass" not in state:
             continue
         mass, loss = jax.device_get((state["exit_mass"],
                                      state["pass_loss"]))

@@ -459,7 +459,7 @@ class TestCliCost:
         from deeplearning4j_tpu.analysis.__main__ import main
         assert main(["--zoo", "--mesh", "data=8", "--cost",
                      "--chip", "tpu-v4"]) == 0
-        assert "17 model(s) linted: 17 clean" in capsys.readouterr().out
+        assert "18 model(s) linted: 18 clean" in capsys.readouterr().out
 
     def test_chip_implies_cost_and_validates(self, capsys):
         from deeplearning4j_tpu.analysis.__main__ import main
