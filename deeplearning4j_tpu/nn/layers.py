@@ -2968,41 +2968,288 @@ def sinkhorn(m, iters: int, eps: float, row_axis: int = -1,
     return m
 
 
-def _stream_maps(x, phis, eps):
-    """``[(x~ phi)^T for phi in phis]``, float32 [k, N, T] each (a map's
-    numbers lead, the tokens lie on the lanes: a [N, T, 4, 4] tensor
+def _split3(a):
+    """Three bfloat16 pieces of a float32 ``a`` that sum to it at float32's
+    precision (8 + 8 + 8 bits of mantissa): side by side in ONE bfloat16
+    product against an operand that is exact in bfloat16 they give the
+    float32 product in one pass of the MXU (3 x 24 columns at most fit
+    one 128-lane tile), where ``Precision.HIGHEST`` takes six over float32
+    copies of both operands."""
+    f32, bf16 = jnp.float32, jnp.bfloat16
+    hi = a.astype(bf16)
+    mid = (a - hi.astype(f32)).astype(bf16)
+    lo = (a - hi.astype(f32) - mid.astype(f32)).astype(bf16)
+    return hi, mid, lo
+
+
+def _stream_slices(x, n):
+    """The ``n`` streams of [N, T, n * C] in ``x``'s dtype, [N, T, C] each:
+    a slice is converted inside the fusion that uses it."""
+    C = x.shape[-1] // n
+    return [x[..., i * C:(i + 1) * C] for i in range(n)]
+
+
+def _stream_products(x, phi, eps):
+    """``((x~ phi)^T [K, N, T], the norm's divisor [N, T])``, float32 (a
+    map's numbers lead, the tokens lie on the lanes: a [N, T, 4, 4] tensor
     would be padded 64-fold on the chip), ``x~`` the RMS norm without a
-    gain of the streams ``x`` [N, T, F] and ``phi`` float32 [F, k]; the
-    products at float32's precision whatever ``x``'s dtype. Streams in
-    bfloat16 are exact in it, so three bfloat16 pieces of ``phi`` side by
-    side give the float32 product in ONE pass of the MXU (their columns,
-    3 x 24 at most, fit one 128-lane tile), where ``Precision.HIGHEST``
-    would take six over a float32 copy of the streams. The norm's divisor
-    is applied after the product."""
+    gain of the streams ``x`` [N, T, F] and ``phi`` float32 [F, K]; the
+    product at float32's precision whatever ``x``'s dtype (bfloat16
+    streams are exact in it: :func:`_split3`). The divisor is applied
+    after the product. Two passes over the streams; what the backward
+    rules keep of them is these two small results."""
     f32 = jnp.float32
-    P = jnp.concatenate([p.astype(f32) for p in phis], axis=1)
-    K = P.shape[1]
+    K = phi.shape[1]
     if x.dtype == jnp.bfloat16:
-        hi = P.astype(jnp.bfloat16)
-        mid = jax.lax.stop_gradient(P - hi.astype(f32)).astype(jnp.bfloat16)
-        lo = jax.lax.stop_gradient(
-            P - hi.astype(f32) - mid.astype(f32)).astype(jnp.bfloat16)
-        z = jnp.dot(x, jnp.concatenate([hi, mid, lo], axis=1),
+        z = jnp.dot(x, jnp.concatenate(_split3(phi.astype(f32)), axis=1),
                     preferred_element_type=f32)
         z = z[..., :K] + z[..., K:2 * K] + z[..., 2 * K:]
     else:
-        z = jnp.dot(x.astype(f32), P, precision=_HIGHEST)
+        z = jnp.dot(x.astype(f32), phi.astype(f32), precision=_HIGHEST)
     x32 = x.astype(f32)
     inv = jax.lax.rsqrt(jnp.mean(x32 * x32, axis=-1) + eps)      # [N, T]
-    return jnp.split(jnp.moveaxis(z, -1, 0) * inv,
-                     np.cumsum([p.shape[1] for p in phis])[:-1].tolist())
+    return jnp.moveaxis(z, -1, 0) * inv, inv
+
+
+def _stream_products_bwd(x, phi, zt, inv, dzt):
+    """From the cotangent ``dzt`` [K, N, T] of :func:`_stream_products`'
+    first result: ``phi``'s gradient ``x~^T dzt`` float32 [F, K] (one pass
+    over the streams, on the MXU; float32's precision in one pass of
+    bfloat16 streams, the cotangent in :func:`_split3`'s pieces), and
+    what the streams' cotangent gets from the maps, as the two factors
+    the caller's ONE pass that writes it needs: ``dz = dzt / divisor``
+    [K, N, T], to be multiplied by ``phi^T``, and the divisor's own
+    ``c`` [N, T], to be multiplied by ``x``."""
+    f32 = jnp.float32
+    K = phi.shape[1]
+    dz = dzt * inv
+    c = jnp.sum(dzt * zt, axis=0) * inv * inv * (-1.0 / x.shape[-1])
+    cols = jnp.moveaxis(dz, 0, -1)                            # [N, T, K]
+    if x.dtype == jnp.bfloat16:
+        dphi = jnp.einsum("ntf,ntk->fk", x,
+                          jnp.concatenate(_split3(cols), axis=-1),
+                          preferred_element_type=f32)
+        dphi = dphi[:, :K] + dphi[:, K:2 * K] + dphi[:, 2 * K:]
+    else:
+        dphi = jnp.einsum("ntf,ntk->fk", x.astype(f32), cols,
+                          precision=_HIGHEST)
+    return dphi, dz, c
+
+
+def _stream_maps(x, phis, eps):
+    """``[(x~ phi)^T for phi in phis]``, float32 [k, N, T] each:
+    :func:`_stream_products` of the ``phis`` [F, k] side by side. The
+    layers' rules call that one; this is for :meth:`HyperConnectionWrite.
+    maps` and whoever wants the maps alone (plain ``jax.numpy``: autodiff
+    derives its gradient, ``phi``'s rounded to bfloat16 under bfloat16
+    streams)."""
+    zt, _ = _stream_products(x, jnp.concatenate(phis, axis=1), eps)
+    return jnp.split(zt, np.cumsum([p.shape[1] for p in phis])[:-1].tolist())
+
+
+_MHC_LOWERED = _prof.get_registry().counter(
+    "dl4j_mhc_lowered_total",
+    "Traces of a hyper-connection read or write (one a lowering of each "
+    "call site, not one a step) by the path it took: the hand-written "
+    "forward/backward pair, for every dtype and shape",
+    labelnames=("path",))
+
+
+# The read and the write are each a ``jax.custom_vjp`` pair (as
+# ``ops.attention.causal_attention`` and :func:`blocked_cross_entropy`
+# are). What is written by hand is every pass over a stream-sized tensor:
+# slices of ``X``, ``y`` and the cotangents are read in their own dtype and
+# converted inside the fusion that uses them, every multiply, sum and
+# per-token reduction is float32, a result is rounded once on its way out
+# (but for ``H_res^T dX'``, which the write's backward hands to its last
+# product rounded: see :func:`_write_bwd`). What autodiff still derives is
+# the small tensors' part, ONE function of [k, N, T] arrays a layer
+# (``_read_maps`` / ``_write_maps``: sigmoid, clamp, exp, Sinkhorn's
+# rounds, ``alpha``, ``b``) by ``jax.vjp`` inside the backward rule. What
+# a rule keeps is ``X``, ``y``, the parameters, the maps before their
+# activation [k, N, T] and the norm's divisor [N, T]: in a rematerialised
+# stretch the forward rule is run again for those two (the products and
+# the sum of squares; the write's ``X'`` and its Sinkhorn are dead there)
+# and the backward rule runs the small function once, under its
+# ``jax.vjp``. The four rule bodies are jitted for what a jit shares, not
+# for a dispatch (as ``_ce_fwd_block``): a step's 24 layers have two
+# shapes, JAX traces and lowers each body once, XLA inlines the calls
+# under their callers' scopes.
+def _read_maps(zt, alpha, b):
+    """``H_pre`` [n, N, T]."""
+    return jax.nn.sigmoid(alpha[0] * zt + b[:, None, None])
+
+
+@functools.partial(jax.jit, static_argnums=4)
+def _read_fwd(x, phi, alpha, b, eps):
+    """``(u, (maps before the sigmoid, divisor))``: products, sum of
+    squares and ONE pass that reads ``X`` and writes ``u``."""
+    zt, inv = _stream_products(x, phi, eps)
+    h = _read_maps(zt, alpha, b)
+    u = sum(h[i][..., None] * xi.astype(jnp.float32)
+            for i, xi in enumerate(_stream_slices(x, phi.shape[1])))
+    return u.astype(x.dtype), (zt, inv)
+
+
+@functools.partial(jax.jit, static_argnums=7)
+def _read_bwd(x, phi, alpha, b, zt, inv, du, eps):
+    """Cotangents of ``(x, phi, alpha, b)``. (a) one pass over ``du`` and
+    ``X`` gives ``dH_pre[i] = sum_C du x_i``; (b) the small function's
+    ``jax.vjp``; (c) ``phi``'s gradient; (d) one pass that writes ``dX =
+    H_pre du + the maps' two terms``: the read's product has one column a
+    stream, so ``dz phi^T`` is that many multiply-adds an element and
+    rides in the pass (the write's has ``n + n * n`` and goes to the
+    MXU)."""
+    f32 = jnp.float32
+    xs, du32 = _stream_slices(x, phi.shape[1]), du.astype(f32)
+    h, back = jax.vjp(_read_maps, zt, alpha, b)
+    dzt, dalpha, db = back(jnp.stack(
+        [jnp.sum(du32 * xi.astype(f32), axis=-1) for xi in xs]))
+    dphi, dz, c = _stream_products_bwd(x, phi, zt, inv, dzt)
+    phi_t = phi.astype(f32).T                                 # [n, F]
+    C = du.shape[-1]
+    dx = jnp.concatenate(
+        [h[j][..., None] * du32 + c[..., None] * xj.astype(f32)
+         + sum(dz[k][..., None] * phi_t[k, j * C:(j + 1) * C]
+               for k in range(phi.shape[1]))
+         for j, xj in enumerate(xs)], axis=-1)
+    return dx.astype(x.dtype), dphi.astype(phi.dtype), dalpha, db
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(4,))
+def _hc_read(x, phi, alpha, b, eps):
+    return _read_fwd(x, phi, alpha, b, eps)[0]
+
+
+def _hc_read_fwd(x, phi, alpha, b, eps):
+    u, kept = _read_fwd(x, phi, alpha, b, eps)
+    return u, (x, phi, alpha, b, *kept)
+
+
+def _hc_read_bwd(eps, res, du):
+    return _read_bwd(*res, du, eps)
+
+
+_hc_read.defvjp(_hc_read_fwd, _hc_read_bwd)
+
+
+def _write_maps(post, res, p, iters, eps, clamp):
+    """``(H_post [n, N, T], H_res [n, n, N, T])`` from the maps before
+    their activation (``post`` [n, N, T], ``res`` [n, n, N, T]: the maps'
+    numbers lead) and the write's small parameters ``p``."""
+    h_post = 2.0 * jax.nn.sigmoid(p["alpha_post"][0] * post
+                                  + p["b_post"][:, None, None])
+    res = p["alpha_res"][0] * res + p["b_res"][:, :, None, None]
+    return h_post, sinkhorn(jnp.exp(jnp.clip(res, *clamp)), iters, eps,
+                            row_axis=1, col_axis=0)
+
+
+_WRITE_SMALL = ("alpha_post", "alpha_res", "b_post", "b_res")
+
+
+def _write_phi(p):
+    return jnp.concatenate([p["phi_post"], p["phi_res"]], axis=1)
+
+
+def _post_res(zt, n):
+    """The [n + n * n, N, T] rows of the write's product as ``(post [n, N,
+    T], res [n, n, N, T])``: apart from the function autodiff derives, so
+    that the backward rule can hold the two behind a barrier. Without one
+    the compiler carries the reshape down through all of Sinkhorn's
+    rounds and lays every round's divisor out anew for it, forward and
+    transposed (160 standing ops of 5 us a layer on a v5e)."""
+    return zt[:n], zt[n:].reshape((n, n) + zt.shape[1:])
+
+
+@functools.partial(jax.jit, static_argnums=(3, 4, 5))
+def _write_fwd(x, y, p, iters, eps, clamp):
+    """``(X', (maps before their activation, divisor))``: products, sum of
+    squares and ONE pass that reads ``X`` and ``y`` and writes ``X'``."""
+    f32 = jnp.float32
+    n = p["b_post"].shape[0]
+    zt, inv = _stream_products(x, _write_phi(p), eps)
+    h_post, h_res = _write_maps(*_post_res(zt, n), p, iters, eps, clamp)
+    xs, y32 = _stream_slices(x, n), y.astype(f32)
+    mixed = [h_post[i][..., None] * y32
+             + sum(h_res[i, j][..., None] * xj.astype(f32)
+                   for j, xj in enumerate(xs)) for i in range(n)]
+    return jnp.concatenate(mixed, axis=-1).astype(x.dtype), (zt, inv)
+
+
+@functools.partial(jax.jit, static_argnums=(6, 7, 8))
+def _write_bwd(x, y, p, zt, inv, dxo, iters, eps, clamp):
+    """Cotangents of ``(x, y, p)``. (a) over ``dX'``, ``X`` and ``y``:
+    ``dH_res[i, j] = sum_C dX'_i x_j`` and ``dH_post[i] = sum_C dX'_i y``
+    in one pass, ``dy = H_post dX'`` and ``H_res^T dX'`` (rounded to the
+    streams' dtype: a mix over stream slices does not ride in a product's
+    fusion, the compiler would write it out in float32 first) in a
+    second; (b) the small function's ``jax.vjp``; (c) ``phi``'s gradient;
+    (d) one product whose fusion adds ``c x`` and ``H_res^T dX'`` to the
+    maps' term and writes ``dX``."""
+    f32 = jnp.float32
+    n = p["b_post"].shape[0]
+    (h_post, h_res), back = jax.vjp(
+        lambda post, res, small: _write_maps(post, res, small, iters, eps,
+                                             clamp),
+        *jax.lax.optimization_barrier(_post_res(zt, n)),
+        {k: p[k] for k in _WRITE_SMALL})
+    xs, gs, y32 = _stream_slices(x, n), _stream_slices(dxo, n), y.astype(f32)
+    d_post = jnp.stack([jnp.sum(g.astype(f32) * y32, axis=-1) for g in gs])
+    d_res = jnp.stack([jnp.stack(
+        [jnp.sum(g.astype(f32) * xj.astype(f32), axis=-1) for xj in xs])
+        for g in gs])
+    dy = sum(h_post[i][..., None] * g.astype(f32) for i, g in enumerate(gs))
+    mixed = jnp.concatenate(
+        [sum(h_res[i, j][..., None] * g.astype(f32)
+             for i, g in enumerate(gs)) for j in range(n)],
+        axis=-1).astype(x.dtype)
+    d_post, d_res, dsmall = back((d_post, d_res))
+    dzt = jnp.concatenate([d_post, d_res.reshape((n * n,) + d_res.shape[2:])])
+    phi = _write_phi(p).astype(f32)
+    dphi, dz, c = _stream_products_bwd(x, phi, zt, inv, dzt)
+    cols = jnp.moveaxis(dz, 0, -1)                            # [N, T, K]
+    if x.dtype == jnp.bfloat16:
+        d0, d1, d2 = _split3(cols)
+        p0, p1, p2 = _split3(phi)
+        dx = jnp.einsum("ntk,fk->ntf",
+                        jnp.concatenate([d0, d0, d0, d1, d1, d2], axis=-1),
+                        jnp.concatenate([p0, p1, p2, p0, p1, p0], axis=1),
+                        preferred_element_type=f32)
+    else:
+        dx = jnp.einsum("ntk,fk->ntf", cols, phi, precision=_HIGHEST)
+    dx = dx + c[..., None] * x.astype(f32) + mixed.astype(f32)
+    dphi = dphi.astype(p["phi_post"].dtype)
+    return dx.astype(x.dtype), dy.astype(y.dtype), dict(
+        dsmall, phi_post=dphi[:, :n], phi_res=dphi[:, n:])
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4, 5))
+def _hc_write(x, y, p, iters, eps, clamp):
+    return _write_fwd(x, y, p, iters, eps, clamp)[0]
+
+
+def _hc_write_fwd(x, y, p, iters, eps, clamp):
+    out, kept = _write_fwd(x, y, p, iters, eps, clamp)
+    return out, (x, y, p, *kept)
+
+
+def _hc_write_bwd(iters, eps, clamp, res, dxo):
+    return _write_bwd(*res, dxo, iters, eps, clamp)
+
+
+_hc_write.defvjp(_hc_write_fwd, _hc_write_bwd)
 
 
 class _HyperConnection(Layer):
     """What the four hyper-connection layers share: the stream count, a
-    float32 island (streams come and go in the compute dtype, the maps
-    and the mixing are float32), the feature-last layout. Stream ``i`` is
-    the features ``i * C .. (i + 1) * C`` of [N, T, n * C]."""
+    float32 island (streams come and go in the compute dtype and are held
+    in it: no float32 copy of a stream-sized tensor is made or kept; the
+    maps, the mixing and every per-token reduction are float32), the
+    feature-last layout. Stream ``i`` is the features ``i * C .. (i + 1) *
+    C`` of [N, T, n * C]. The read and the write are forward/backward
+    pairs written by hand over the stream-sized tensors (the comment above
+    :func:`_read_maps` says what they keep, what is run again and what
+    autodiff still derives); copying in and summing out are autodiff's."""
 
     input_kind = None
     fp32_params = True
@@ -3037,12 +3284,6 @@ class _HyperConnection(Layer):
         return _steps(it) * 2 * (maps + self.n_streams * max(
             self.nIn or 0, self.nOut or 0))
 
-    def _streams(self, x):
-        """The ``n`` streams of [N, T, n * C], float32 [N, T, C] each."""
-        C = x.shape[-1] // self.n_streams
-        return [x[..., i * C:(i + 1) * C].astype(jnp.float32)
-                for i in range(self.n_streams)]
-
 
 class HyperConnectionIn(_HyperConnection):
     """[N, T, C] -> ``nStreams`` copies side by side, [N, T, n * C]."""
@@ -3056,7 +3297,9 @@ class HyperConnectionIn(_HyperConnection):
     def apply(self, params, state, x, train, key):
         x = _feature_last(self, x)
         with jax.named_scope(_stepprogram.MHC_SCOPE):
-            return jnp.tile(x, (1, 1, self.n_streams)), state
+            # (not ``jnp.tile``: its [N, T, n, C] on the way is laid out
+            # anew on the chip, there and back)
+            return jnp.concatenate([x] * self.n_streams, axis=-1), state
 
 
 class HyperConnectionOut(_HyperConnection):
@@ -3071,14 +3314,23 @@ class HyperConnectionOut(_HyperConnection):
     def apply(self, params, state, x, train, key):
         x = _feature_last(self, x)
         with jax.named_scope(_stepprogram.MHC_SCOPE):
-            return sum(self._streams(x)).astype(x.dtype), state
+            return sum(s.astype(jnp.float32) for s in
+                       _stream_slices(x, self.n_streams)).astype(x.dtype), \
+                state
 
 
 class HyperConnectionRead(_HyperConnection):
     """A sub-block's input under hyper-connections: ``u = H_pre X`` with
     ``H_pre = sigmoid(alpha_pre * (x~ phi_pre) + b_pre)`` [N, T, n], ``x~``
     the RMS norm (no gain) of the flattened streams; one weight a stream
-    and a token."""
+    and a token. A forward and a backward written by hand
+    (:func:`_read_fwd`, :func:`_read_bwd`): kept for the backward are
+    ``X``, the three parameters, ``x~ phi_pre`` [n, N, T] and the norm's
+    divisor [N, T]; a rematerialised stretch runs the forward again (the
+    product, the sum of squares, and ``u`` for the sub-block); autodiff
+    derives the sigmoid's, ``alpha_pre``'s and ``b_pre``'s part on the
+    [n, N, T] maps, the rule everything that touches ``X``, ``u`` and
+    their cotangents."""
 
     def infer_nin(self, it: InputType):
         self.nIn = _sequence_size(it)
@@ -3098,13 +3350,10 @@ class HyperConnectionRead(_HyperConnection):
 
     def apply(self, params, state, x, train, key):
         x = _feature_last(self, x)
+        _MHC_LOWERED.labels("pair").inc()
         with jax.named_scope(_stepprogram.MHC_SCOPE):
-            (z,) = _stream_maps(x, [params["phi_pre"]], self.eps)
-            h_pre = jax.nn.sigmoid(params["alpha_pre"][0] * z
-                                   + params["b_pre"][:, None, None])
-            u = sum(h_pre[i][..., None] * xi
-                    for i, xi in enumerate(self._streams(x)))
-            return u.astype(x.dtype), state
+            return _hc_read(x, params["phi_pre"], params["alpha_pre"],
+                            params["b_pre"], self.eps), state
 
 
 class HyperConnectionWrite(_HyperConnection):
@@ -3114,7 +3363,16 @@ class HyperConnectionWrite(_HyperConnection):
     phi_res) + b_res)))`` [N, T, n, n], the manifold constraint of
     arXiv:2512.24880: a (nearly) doubly stochastic mixing of the streams,
     so that neither a forward signal nor a gradient grows through the
-    depth. ``x~`` is of the streams BEFORE the sub-block, as the read's."""
+    depth. ``x~`` is of the streams BEFORE the sub-block, as the read's.
+    A forward and a backward written by hand (:func:`_write_fwd`,
+    :func:`_write_bwd`): kept for the backward are ``X``, ``y``, the six
+    parameters, ``x~ [phi_post | phi_res]`` [n + n * n, N, T] and the
+    norm's divisor [N, T]; a rematerialised stretch runs only the product
+    and the sum of squares again (``X'`` is dead there) and the backward
+    rule the small function; autodiff derives that function's part
+    (sigmoid, clamp, exp, Sinkhorn's rounds, ``alpha_*``, ``b_*``), the
+    rule everything that touches ``X``, ``y``, ``X'`` and their
+    cotangents."""
 
     n_inputs = 2
 
@@ -3144,37 +3402,22 @@ class HyperConnectionWrite(_HyperConnection):
                 # H_res opens at the identity (nearly: exp(3) to 1)
                 "b_res": 3.0 * jnp.eye(n, dtype=jnp.float32)}, {}
 
-    def _maps(self, params, x):
-        """``(H_post [n, N, T], H_res [n, n, N, T])`` of the streams
-        ``x`` [N, T, n * C]: the maps' numbers lead."""
-        n = self.n_streams
-        post, res = _stream_maps(x, [params["phi_post"], params["phi_res"]],
-                                 self.eps)
-        h_post = 2.0 * jax.nn.sigmoid(params["alpha_post"][0] * post
-                                      + params["b_post"][:, None, None])
-        res = params["alpha_res"][0] * res.reshape((n, n) + res.shape[1:]) \
-            + params["b_res"][:, :, None, None]
-        return h_post, sinkhorn(jnp.exp(jnp.clip(res, *self.clamp)),
-                                self.sinkhorn_iters, self.eps,
-                                row_axis=1, col_axis=0)
-
     def maps(self, params, x):
         """``(H_post [N, T, n], H_res [N, T, n, n])``: a token leads."""
-        h_post, h_res = self._maps(params, x)
+        (zt,) = _stream_maps(x, [_write_phi(params)], self.eps)
+        h_post, h_res = _write_maps(*_post_res(zt, self.n_streams), params,
+                                    self.sinkhorn_iters, self.eps,
+                                    self.clamp)
         return jnp.moveaxis(h_post, 0, -1), \
             jnp.moveaxis(jnp.moveaxis(h_res, 0, -1), 0, -1)
 
     def apply(self, params, state, x, train, key):
         x, y = x
         x = _feature_last(self, x)
+        _MHC_LOWERED.labels("pair").inc()
         with jax.named_scope(_stepprogram.MHC_SCOPE):
-            xs, y32 = self._streams(x), y.astype(jnp.float32)
-            h_post, h_res = self._maps(params, x)
-            mixed = [h_post[i][..., None] * y32
-                     + sum(h_res[i, j][..., None] * xj
-                           for j, xj in enumerate(xs))
-                     for i in range(self.n_streams)]
-            return jnp.concatenate(mixed, axis=-1).astype(x.dtype), state
+            return _hc_write(x, y, dict(params), self.sinkhorn_iters,
+                             self.eps, tuple(self.clamp)), state
 
 
 class LatentAttentionLayer(Layer):

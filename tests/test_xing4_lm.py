@@ -410,10 +410,188 @@ def test_sinkhorns_gradient_through_the_twenty_rounds():
                 (z,), order=1, modes=("rev",), atol=1e-2, rtol=1e-2)
 
 
-def _hc(cls, **kw):
+def _hc(cls, features=4 * 6, **kw):
     layer = cls(nStreams=4, eps=1e-6, weightInit="xavier", **kw)
-    layer.infer_nin(InputType.recurrent(4 * 6, 5))
+    layer.infer_nin(InputType.recurrent(features, 5))
     return layer
+
+
+def _hc_params(read, write):
+    """Parameters away from their opening values, so that every leaf has
+    a gradient of some size."""
+    rp, _ = read.initialize(jax.random.PRNGKey(0))
+    wp, _ = write.initialize(jax.random.PRNGKey(1))
+    rp["b_pre"] = jnp.asarray([0.1, -0.2, 0.3, 0.0])
+    rp["alpha_pre"] = jnp.asarray([0.7])
+    wp["b_res"] = wp["b_res"] + 0.1 * jax.random.normal(
+        jax.random.PRNGKey(2), (4, 4))
+    wp["b_post"] = jnp.asarray([-0.1, 0.2, 0.0, 0.3])
+    wp["alpha_res"], wp["alpha_post"] = jnp.asarray([0.5]), jnp.asarray([0.6])
+    return rp, wp
+
+
+def _plain_read(rp, x):
+    """``u`` in the einsum form, float32."""
+    N, T, F = x.shape
+    h_pre = jax.nn.sigmoid(rp["alpha_pre"][0] * jnp.dot(
+        rms(x, None), rp["phi_pre"], precision=HI) + rp["b_pre"])
+    return jnp.einsum("ntk,ntkc->ntc", h_pre, x.reshape(N, T, 4, F // 4),
+                      precision=HI)
+
+
+def _plain_write(wp, x, y):
+    """``X'`` in the einsum form, float32."""
+    N, T, F = x.shape
+    xt = rms(x, None)
+    h_post = 2 * jax.nn.sigmoid(wp["alpha_post"][0] * jnp.dot(
+        xt, wp["phi_post"], precision=HI) + wp["b_post"])
+    res = wp["alpha_res"][0] * jnp.dot(xt, wp["phi_res"], precision=HI) \
+        .reshape(N, T, 4, 4) + wp["b_res"]
+    h_res = L.sinkhorn(jnp.exp(jnp.clip(res, -30, 30)), 20, 1e-6)
+    out = jnp.einsum("ntij,ntjc->ntic", h_res, x.reshape(N, T, 4, F // 4),
+                     precision=HI) + h_post[..., None] * y[:, :, None, :]
+    return out.reshape(N, T, F)
+
+
+_HC_CASES = {
+    # what runs: (the layers' form, the plain form), both of
+    # (read's parameters, write's parameters, X, y) -> outputs
+    "read": (lambda r, w, rp, wp, x, y: (r.apply(rp, {}, x, True, None)[0],),
+             lambda rp, wp, x, y: (_plain_read(rp, x),)),
+    "write": (lambda r, w, rp, wp, x, y:
+              (w.apply(wp, {}, (x, y), True, None)[0],),
+              lambda rp, wp, x, y: (_plain_write(wp, x, y),)),
+    "read_tanh_write": (
+        lambda r, w, rp, wp, x, y: (w.apply(
+            wp, {}, (x, jnp.tanh(r.apply(rp, {}, x, True, None)[0]) + y),
+            True, None)[0],),
+        lambda rp, wp, x, y: (_plain_write(
+            wp, x, jnp.tanh(_plain_read(rp, x)) + y),)),
+}
+
+
+def _hc_case(what, dtype, T, wrap=lambda f: f):
+    """``(outputs, gradients)`` of the layers' form in ``dtype`` streams
+    and of the plain form in float32 on the same numbers, under one
+    weighted sum of the outputs."""
+    read, write = _hc(L.HyperConnectionRead), _hc(L.HyperConnectionWrite)
+    rp, wp = _hc_params(read, write)
+    ks = jax.random.split(jax.random.PRNGKey(T), 3)
+    # numbers that are exact in bfloat16, for both forms
+    x = jax.random.normal(ks[0], (2, T, 24)).astype(jnp.bfloat16)
+    y = jax.random.normal(ks[1], (2, T, 6)).astype(jnp.bfloat16)
+    mine, plain = _HC_CASES[what]
+    shape = (2, T, 6) if what == "read" else (2, T, 24)
+    weight = jax.random.normal(ks[2], shape).astype(jnp.bfloat16) \
+        .astype(jnp.float32)
+
+    def total(outs):
+        return sum(jnp.sum(weight * o.astype(jnp.float32)) for o in outs)
+    f32 = lambda a: a.astype(jnp.float32)     # noqa: E731
+    got = jax.value_and_grad(
+        lambda rp, wp, x, y: (lambda o: (total(o), o))(
+            wrap(lambda *a: mine(read, write, *a))(rp, wp, x, y)),
+        argnums=(0, 1, 2, 3), has_aux=True)(rp, wp, x.astype(dtype),
+                                            y.astype(dtype))
+    want = jax.value_and_grad(
+        lambda rp, wp, x, y: (lambda o: (total(o), o))(plain(rp, wp, x, y)),
+        argnums=(0, 1, 2, 3), has_aux=True)(rp, wp, f32(x), f32(y))
+    return got, want
+
+
+@pytest.mark.parametrize("T", [5, 128])
+@pytest.mark.parametrize("what", sorted(_HC_CASES))
+@pytest.mark.parametrize("dtype", [jnp.float32, jnp.bfloat16],
+                         ids=["float32", "bfloat16"])
+def test_the_hyper_connection_pairs_against_autodiff_of_the_plain_form(
+        dtype, what, T):
+    """The read and the write are forward/backward pairs written by hand:
+    outputs and the gradients of ``X``, ``y`` and all nine parameter
+    leaves against ``jax.grad`` of the einsum form. Float32 streams to
+    float32's rounding; bfloat16 streams inside bfloat16's rounding of
+    what comes and goes in it (``u``, ``X'``, ``dX``, ``dy``), the maps
+    and every parameter's gradient at float32's precision: where no
+    rounded output lies between the parameters and the loss (the read
+    alone, the write alone) they agree with float32's to 1e-4."""
+    ((_, outs), grads), ((_, outs32), grads32) = _hc_case(what, dtype, T)
+    exact = dtype == jnp.float32
+    step = 1e-5 if exact else 2.0 ** -8        # one rounding of an output
+    for o, o32 in zip(outs, outs32):
+        assert o.dtype == dtype
+        # the chain rounds u on the way to X'
+        np.testing.assert_allclose(o.astype(jnp.float32), o32,
+                                   rtol=(1 if exact else 4) * step,
+                                   atol=(1 if exact else 4) * step)
+    (rp, wp, dx, dy), (rp32, wp32, dx32, dy32) = grads, grads32
+    for g, g32 in ((dx, dx32), (dy, dy32)):
+        assert g.dtype == dtype
+        scale = max(float(jnp.max(jnp.abs(g32))), 1e-30)
+        np.testing.assert_allclose(g.astype(jnp.float32), g32,
+                                   rtol=(1 if exact else 4) * step,
+                                   atol=(1 if exact else 4) * step * scale)
+    used = {"read": [rp], "write": [wp], "read_tanh_write": [rp, wp]}[what]
+    tol = 1e-5 if exact else (1e-4 if what != "read_tanh_write" else 2e-2)
+    n = 0
+    for tree, tree32 in ((rp, rp32), (wp, wp32)):
+        for leaf in tree32:
+            assert tree[leaf].dtype == jnp.float32
+            scale = float(jnp.max(jnp.abs(tree32[leaf])))
+            if any(tree is u for u in used):
+                assert scale > 0, leaf
+                n += 1
+            np.testing.assert_allclose(tree[leaf], tree32[leaf], rtol=tol,
+                                       atol=tol * scale, err_msg=leaf)
+    assert n == {"read": 3, "write": 6, "read_tanh_write": 9}[what]
+
+
+def test_the_pairs_under_a_checkpoint_give_the_same_values():
+    """``rematerializeStack()`` wraps a sub-block in ``jax.checkpoint``:
+    the forward rules run again in the backward pass, and nothing
+    changes."""
+    plain, _ = _hc_case("read_tanh_write", jnp.bfloat16, 5)
+    remat, _ = _hc_case("read_tanh_write", jnp.bfloat16, 5,
+                        wrap=jax.checkpoint)
+    for a, b in zip(jax.tree_util.tree_leaves(plain),
+                    jax.tree_util.tree_leaves(remat)):
+        np.testing.assert_array_equal(a, b)
+
+
+@pytest.mark.parametrize("what", ["read", "write"])
+def test_the_forward_rules_keep_no_float32_stream(what):
+    """What a forward rule hands its backward rule: ``X`` (and ``y``) as
+    they came, the parameters, the maps before their activation [k, N, T]
+    and the norm's divisor [N, T]; on bfloat16 streams no float32 array of
+    a stream's size or more."""
+    N, T, C, n = 2, 16, 64, 4
+    bf = lambda *s: jax.ShapeDtypeStruct(s, jnp.bfloat16)   # noqa: E731
+    layer = _hc(L.HyperConnectionRead if what == "read"
+                else L.HyperConnectionWrite, n * C)
+    params = jax.eval_shape(lambda: layer.initialize(jax.random.PRNGKey(0))[0])
+    if what == "read":
+        out, kept = jax.eval_shape(
+            lambda p, x: L._hc_read_fwd(x, p["phi_pre"], p["alpha_pre"],
+                                        p["b_pre"], 1e-6),
+            params, bf(N, T, n * C))
+        k = n
+    else:
+        out, kept = jax.eval_shape(
+            lambda p, x, y: L._hc_write_fwd(x, y, p, 20, 1e-6, (-30., 30.)),
+            params, bf(N, T, n * C), bf(N, T, C))
+        k = n + n * n
+    assert out.dtype == jnp.bfloat16
+    leaves = jax.tree_util.tree_leaves(kept)
+    own = [tuple(a.shape) for a in jax.tree_util.tree_leaves(params)]
+    for leaf in leaves:
+        assert leaf.dtype == jnp.bfloat16 or leaf.size < N * T * C \
+            or tuple(leaf.shape) in own, leaf
+    small = sorted(tuple(a.shape) for a in leaves
+                   if a.dtype == jnp.float32 and a.shape[-2:] == (N, T))
+    assert small == [(N, T), (k, N, T)]
+    streams = [a.shape for a in leaves if a.dtype == jnp.bfloat16]
+    assert streams == [(N, T, n * C)] + ([(N, T, C)] if what == "write"
+                                         else [])
+    assert len(leaves) == len(jax.tree_util.tree_leaves(params)) \
+        + len(streams) + 2
 
 
 def test_a_sub_block_under_hyper_connections_against_jnp():
@@ -473,6 +651,40 @@ def test_bfloat16_streams_get_their_maps_at_float32s_precision():
     g32 = jax.grad(total)(phis, x.astype(jnp.float32))
     for a, b in zip(g16, g32):
         np.testing.assert_allclose(a, b, rtol=0.02, atol=0.02)
+    # through the layers' own backward rules ``phi``'s gradient is float32's
+    # too: the cotangent goes into the product in three bfloat16 pieces,
+    # where one rounded piece (the transpose autodiff makes of the forward
+    # product, above) is off in the third digit
+    read, write = _hc(L.HyperConnectionRead, 96), _hc(L.HyperConnectionWrite,
+                                                    96)
+    rp, wp = _hc_params(read, write)
+    y = jax.random.normal(jax.random.PRNGKey(5), (2, 16, 24)) \
+        .astype(jnp.bfloat16)
+
+    wu, wx = (jax.random.normal(jax.random.PRNGKey(k), shape)
+              .astype(jnp.bfloat16).astype(jnp.float32)
+              for k, shape in ((6, (2, 16, 24)), (7, (2, 16, 96))))
+
+    def through(x, y):
+        # a weighted sum: the outputs' own rounding to bfloat16 does not
+        # reach the gradients, the products' precision does
+        def loss(rp, wp):
+            u, _ = read.apply(rp, {}, x, True, None)
+            out, _ = write.apply(wp, {}, (x, y), True, None)
+            return jnp.sum(wu * u.astype(jnp.float32)) \
+                + jnp.sum(wx * out.astype(jnp.float32))
+        return jax.grad(loss, argnums=(0, 1))(rp, wp)
+    (r16, w16), (r32, w32) = through(x, y), through(
+        x.astype(jnp.float32), y.astype(jnp.float32))
+    for a, b in ((r16["phi_pre"], r32["phi_pre"]),
+                 (w16["phi_post"], w32["phi_post"]),
+                 (w16["phi_res"], w32["phi_res"])):
+        assert a.dtype == jnp.float32
+        np.testing.assert_allclose(a, b, rtol=1e-4, atol=1e-4 * float(
+            jnp.max(jnp.abs(b))))
+    rough = float(jnp.max(jnp.abs(g16[0] - g32[0]))
+                  / jnp.max(jnp.abs(g32[0])))
+    assert rough > 1e-3
 
 
 def test_streams_copy_in_and_sum_out():
@@ -709,14 +921,29 @@ def test_a_layer_refuses_the_public_layout_and_typos():
 
 
 # ------------------------------------------------------------ the instruments
-def test_the_step_program_carries_the_new_parts_and_the_gauges_read():
+def test_the_step_program_carries_the_new_parts_and_the_gauges_read(
+        monkeypatch):
     net, cfg = tiny_net()
+    texts = []
+    parse = stepprogram.parse
+    monkeypatch.setattr(stepprogram, "parse",
+                        lambda text: texts.append(text) or parse(text))
     profiler.set_profiling_mode("basic")
     try:
         stepprogram.clear()
         lowered = L._MOE_LOWERED.labels("ragged_dot").value
+        pairs_lowered = L._MHC_LOWERED.labels("pair").value
         net.fit(DataSet(*tokens(cfg, 1)[0]))
         assert L._MOE_LOWERED.labels("ragged_dot").value > lowered
+        # once a read or write layer a traced step: one path, one value
+        hyper = [n for n in net.conf.topo if isinstance(
+            n.obj, (L.HyperConnectionRead, L.HyperConnectionWrite))]
+        assert len(hyper) == 2 * 2 * 4      # three layers and the module
+        assert L._MHC_LOWERED.labels("pair").value - pairs_lowered \
+            == len(hyper)
+        assert [k for k in L._MHC_LOWERED.children()] == [("pair",)]
+        assert profiler.get_registry().get("dl4j_mhc_lowered_total") \
+            is L._MHC_LOWERED
         pairs = {k[0]: c.value for k, c in
                  stepping.MOE_HELD_PAIRS.children().items()}
         assert set(pairs) >= {"l1_moe", "l2_moe", "mtp_moe"}
@@ -739,6 +966,33 @@ def test_the_step_program_carries_the_new_parts_and_the_gauges_read():
     assert any(e.layer and "_mtp_" in e.layer for e in entries)
     heads = {e.loop_pass for e in entries if e.part == "head_loss"}
     assert {1, 2} <= heads
+    # the hyper-connections' backward is two rules written by hand, not
+    # the transpose of their forward: every op of them stands in the map
+    # as the hyper-connections', backward, in its read or write layer,
+    # and what a stretch runs again of the forward rules carries the
+    # remat mark beside
+    (by_name,), (text,) = maps.values(), texts
+    seen = {"_read_bwd": 0, "_write_bwd": 0, "remat": 0}
+    for line in text.split("\n"):
+        name, op = stepprogram._INSTRUCTION.match(line), \
+            stepprogram._OP_NAME.search(line)
+        if not name or not op or name.group(1) not in by_name:
+            continue
+        entry, op = by_name[name.group(1)], op.group(1).partition(";")[0]
+        rule = [r for r in ("_read_bwd", "_write_bwd")
+                if f"jit({r})" in op]
+        if rule:
+            # (a fusion may hold the maps' small function once for the
+            # forward run again and for the rule, and then says remat)
+            assert (entry.part, entry.phase) == ("mhc", "backward"), line
+            assert entry.layer and ("_hr" if rule[0] == "_read_bwd"
+                                    else "_hw") in entry.layer, line
+            seen[rule[0]] += 1
+        elif "jit(_read_fwd)" in op and stepprogram.REMAT_MARK in op:
+            assert (entry.part, entry.phase, entry.remat) \
+                == ("mhc", "backward", True), line
+            seen["remat"] += 1
+    assert min(seen.values()) >= len(hyper) // 2, seen
 
 
 def test_marks_of_nested_scopes_take_the_innermost():
