@@ -6,7 +6,8 @@ every device op to the compiled step's own map
 (``profiler.stepprogram.parse``: phase, part, rematerialised) and to the
 instruction's ``op_name`` and HBM bytes as compiled (a fusion parameter
 read only through slices counts its slices). What a ``perf_opt`` on
-``xing4-fit-s4096-b1`` or ``ouro-fit-s4096-b1`` reads before and after a
+``xing4-fit-s4096-b1``, ``ouro-fit-s4096-b1`` or ``lfm2-fit-s8192-b4``
+(``--config lfm2-24b-a2b-l5-bf16 --batch 4``) reads before and after a
 change; the harness's ``mhc_device_ms`` and its like are sums over this
 table (PR 34: the probe reads the ledger's 64.99 as 64.987).
 
@@ -142,6 +143,8 @@ def main(argv=None) -> int:
     ap.add_argument("--steps", type=int, default=4)
     ap.add_argument("--traced", type=int, default=3)
     ap.add_argument("--seed", type=int, default=2147483659)
+    ap.add_argument("--batch", type=int, default=1,
+                    help="sequences a step (lfm2-fit-s8192-b4: 4)")
     args = ap.parse_args(argv)
     root = os.path.abspath(args.root)
     sys.path.insert(0, root)
@@ -169,15 +172,15 @@ def main(argv=None) -> int:
     make_weights = lean.make_weights if lean_driven else plain.make_weights
     weights = jax.block_until_ready(
         make_weights(model.param_spec(cfg), args.seed))
-    build = {"states": lean.make_states(model, cfg, args.seed), "batch": 1} \
-        if lean_driven else {}
+    build = {"states": lean.make_states(model, cfg, args.seed),
+             "batch": args.batch} if lean_driven else {}
     net = base.configure(model.build(cfg, weights, chips=1, **build), cfg)
     del weights
     rng = np.random.default_rng(0)
 
     def batch():
-        rows = rng.integers(0, cfg["vocab_size"], (1, cfg["seq_len"] + 1),
-                            dtype=np.int32)
+        rows = rng.integers(0, cfg["vocab_size"],
+                            (args.batch, cfg["seq_len"] + 1), dtype=np.int32)
         return DataSet(rows[:, :-1].copy(), rows[:, 1:].copy())
 
     def fit(n):

@@ -23,6 +23,7 @@ from deeplearning4j_tpu.nn.layers import (ActivationLayer, BatchNormalization,
                                           ConvolutionLayer, DenseLayer,
                                           DropoutLayer,
                                           EmbeddingSequenceLayer, GatedMLP,
+                                          GatedShortConvLayer,
                                           GlobalPoolingLayer,
                                           HyperConnectionIn,
                                           HyperConnectionOut,
@@ -1132,6 +1133,114 @@ class Xing4(ZooModel):
         return ComputationGraph(g.build())
 
 
+class LFM2(ZooModel):
+    """LFM2-24B-A2B (Liquid AI; defaults: its config.json), a hybrid
+    decoder whose layers differ in kind by a pattern: ``layer_types[l]``
+    says whether layer ``l`` mixes tokens by a gated short convolution
+    (``"conv"``: :class:`GatedShortConvLayer`, kernel ``conv_L_cache``)
+    or by grouped-query attention (``"full_attention"``:
+    ``num_attention_heads`` query heads over ``num_key_value_heads``
+    key/value heads, an RMS norm on every head of q and k, rotary
+    positions); the first ``num_dense_layers`` have a dense SwiGLU MLP,
+    the others sparse experts WITHOUT a shared expert (a sigmoid-scored,
+    bias-selected router over ``num_experts``, ``num_experts_per_tok``
+    selected, gates normalised over the selected). Each sub-block is
+    ``h + F(RMSNorm(h))``; a final norm; the head is the embedding's table
+    (``tiedWith``). ``layers`` lists the published layers held and run
+    (default: all of ``layer_types``): a kept layer keeps its own kind.
+    ``held_experts`` lists the routed experts THIS chip holds (default:
+    all), ``keep_selected`` rows of each expert layer's last selection are
+    kept in its state. The stack is rematerialised a sub-block at a time
+    in a train step (``rematerializeStack``). Trains on ``DataSet(int32
+    tokens [N, T], int32 next tokens [N, T])``.
+
+    The published 40 layers hold 24 B parameters, which no chip trains
+    alone; the cost gates judge the cut the benchmark runs on one chip
+    (``chipbench/configs/lfm2-24b-a2b-l5-bf16``: one dense layer and one
+    whole period of four expert layers, 8 of 64 experts, an eighth of the
+    vocabulary)."""
+
+    PERIOD = ("conv", "conv", "full_attention", "conv")
+    cost_gate_kwargs = {"layers": [0, 2, 3, 4, 5], "num_dense_layers": 1,
+                        "held_experts": list(range(8)), "vocab_size": 8192,
+                        "seq_len": 8192}
+
+    def __init__(self, layer_types=None, layers=None,
+                 num_dense_layers: int = 2, hidden_size: int = 2048,
+                 num_attention_heads: int = 32, num_key_value_heads: int = 8,
+                 head_dim: int = None, conv_L_cache: int = 3,
+                 intermediate_size: int = 11776,
+                 moe_intermediate_size: int = 1536, num_experts: int = 64,
+                 held_experts=None, num_experts_per_tok: int = 4,
+                 routed_scaling_factor: float = 1.0, norm_eps: float = 1e-5,
+                 rope_theta: float = 1e6, vocab_size: int = 65536,
+                 seq_len: int = 8192, keep_selected: int = 0, **kw):
+        self.layer_types = list(layer_types) if layer_types is not None \
+            else list(self.PERIOD) * 10
+        unknown = set(self.layer_types) - {"conv", "full_attention"}
+        if unknown:
+            raise ValueError(f"LFM2: layer_types may say 'conv' or "
+                             f"'full_attention', not {sorted(unknown)}")
+        self.layers = list(range(len(self.layer_types))) if layers is None \
+            else [int(i) for i in layers]
+        self.num_dense_layers = int(num_dense_layers)
+        self.hidden_size = int(hidden_size)
+        self.attention = dict(
+            nHeads=int(num_attention_heads),
+            nKVHeads=int(num_key_value_heads),
+            headSize=int(head_dim or hidden_size // num_attention_heads),
+            ropeTheta=rope_theta, qkNorm=True, qkNormEps=norm_eps)
+        self.conv = dict(kernelSize=int(conv_L_cache))
+        self.intermediate_size = int(intermediate_size)
+        self.experts = dict(
+            nExperts=num_experts, nExpertsPerTok=num_experts_per_tok,
+            nHidden=moe_intermediate_size, heldExperts=held_experts,
+            routedScalingFactor=routed_scaling_factor, nSharedExperts=0,
+            keepSelected=keep_selected)
+        self.norm_eps = norm_eps
+        self.vocab_size, self.seq_len = int(vocab_size), int(seq_len)
+        kw.setdefault("updater", updaters.Adam(3e-4, beta2=0.95))
+        super().__init__(num_classes=vocab_size, **kw)
+
+    def default_input_shape(self):
+        return (self.vocab_size, self.seq_len)
+
+    def conf_builder(self) -> ComputationGraph:
+        vocab, seq_len = self.input_shape
+        g = (NeuralNetConfiguration.Builder()
+             .seed(self.seed).updater(self.updater).weightInit("xavier")
+             .graphBuilder())
+        g.addInputs("tokens")
+        g.setInputTypes(InputType.recurrent(vocab, seq_len))
+        g.rematerializeStack()
+        g.addLayer("embed", EmbeddingSequenceLayer(nOut=self.hidden_size),
+                   "tokens")
+        norm = lambda: RMSNorm(eps=self.norm_eps)   # noqa: E731
+        h = "embed"
+        # the n-th layer held is "l<n>_": leading dense layers count once
+        for n, i in enumerate(self.layers):
+            p = f"l{n}_"
+            conv = self.layer_types[i] == "conv"
+            dense = n < self.num_dense_layers
+            mix, ff = p + ("conv" if conv else "attn"), \
+                p + ("mlp" if dense else "moe")
+            g.addLayer(p + "n1", norm(), h)
+            g.addLayer(mix, GatedShortConvLayer(**self.conv) if conv
+                       else CausalSelfAttentionLayer(**self.attention),
+                       p + "n1")
+            g.addVertex(p + "add1", ElementWiseVertex("Add"), h, mix)
+            g.addLayer(p + "n2", norm(), p + "add1")
+            g.addLayer(ff, GatedMLP(nHidden=self.intermediate_size) if dense
+                       else SparseExpertsLayer(**self.experts), p + "n2")
+            g.addVertex(p + "add2", ElementWiseVertex("Add"), p + "add1", ff)
+            h = p + "add2"
+        g.addLayer("fnorm", norm(), h)
+        g.addLayer("lm", MTPLMOutputLayer(nOut=vocab, tiedWith="embed"),
+                   "fnorm")
+        g.setOutputs("lm")
+        return ComputationGraph(g.build())
+
+
 #: Name -> class registry of every shipped architecture (ref:
 #: ZooModel.select-by-name in the reference's zoo). The analysis CLI's
 #: ``--zoo`` mode lints each of these; ``all_zoo_models()`` instantiates
@@ -1140,7 +1249,7 @@ ZOO_MODELS = {cls.__name__: cls for cls in
               (LeNet, SimpleCNN, AlexNet, VGG16, VGG19, ResNet50,
                Darknet19, SqueezeNet, UNet, Xception, FaceNetNN4Small2,
                TextGenerationLSTM, TinyYOLO, YOLO2, InceptionResNetV1,
-               NASNet, Ouro, Xing4)}
+               NASNet, Ouro, Xing4, LFM2)}
 
 
 def all_zoo_models():

@@ -439,6 +439,13 @@ class ComputationGraphConfiguration:
             n.name: (n.obj.tied_with if n.kind == "layer" and getattr(
                 n.obj, "tied_with", None) in self.node_by_name else n.name)
             for n in self.nodes}
+        for n in self.nodes:
+            if n.kind == "layer" and isinstance(n.obj, L.MTPLMOutputLayer) \
+                    and n.obj.tied_with and self.param_owner[n.name] == n.name:
+                raise ValueError(
+                    f"{n.name}: tiedWith={n.obj.tied_with!r} names no node "
+                    f"of this graph, and a tied head has no W of its own "
+                    f"(nodes: {sorted(self.node_by_name)})")
         self._toposort()
         if self.input_types:
             self._propagate_types()

@@ -2543,16 +2543,41 @@ class CausalSelfAttentionLayer(Layer):
     ``softmax(q k^T / sqrt(headSize) + causal mask) v``, then ``Wo``. The
     attention core (``ops.attention.causal_attention``) has a backward of
     its own that keeps the output and the row log-sum-exp: no [T, T]
-    tensor is kept for it, in a rematerialised stretch or outside one."""
+    tensor is kept for it, in a rematerialised stretch or outside one.
+
+    ``nKVHeads`` < ``nHeads`` is grouped-query attention: ``Wk``, ``Wv``
+    project onto ``nKVHeads`` heads and query head ``h`` reads key/value
+    head ``h // (nHeads / nKVHeads)``; the core takes them as they are.
+    ``qkNorm``: each head of q and of k goes through an RMS norm over its
+    ``headSize`` (gains ``qn``, ``kn`` [headSize], shared by the heads,
+    float32 under a precision policy) before the rotary embedding. The
+    defaults are plain multi-head attention with neither."""
 
     input_kind = "rnn"
+    # a configuration saved before these existed has none of them
+    n_kv_heads = None
+    qk_norm = False
+    qk_norm_eps = 1e-6
 
     def __init__(self, nOut=None, nHeads: int = 1, headSize: int = None,
-                 ropeTheta: float = 10000.0, **kw):
+                 ropeTheta: float = 10000.0, nKVHeads: int = None,
+                 qkNorm: bool = False, qkNormEps: float = 1e-6, **kw):
         super().__init__(nOut=nOut, **kw)
         self.n_heads = int(nHeads)
         self.head_size = headSize
         self.rope_theta = float(ropeTheta)
+        if nKVHeads is not None:
+            self.n_kv_heads = int(nKVHeads)
+            if self.n_kv_heads < 1 or self.n_heads % self.n_kv_heads:
+                raise ValueError(
+                    f"CausalSelfAttentionLayer: nHeads={self.n_heads} query "
+                    f"heads do not divide over nKVHeads={nKVHeads}")
+        if qkNorm:
+            self.qk_norm, self.qk_norm_eps = True, float(qkNormEps)
+
+    @property
+    def fp32_leaves(self):
+        return ("qn", "kn") if self.qk_norm else ()
 
     def infer_nin(self, it: InputType):
         super().infer_nin(it)
@@ -2572,22 +2597,34 @@ class CausalSelfAttentionLayer(Layer):
         if not self.nIn or not self.nOut or not self.head_size:
             return {}
         E = self.n_heads * self.head_size
-        return {"Wq": (self.nIn, E), "Wk": (self.nIn, E),
-                "Wv": (self.nIn, E), "Wo": (E, self.nOut)}
+        Ek = (self.n_kv_heads or self.n_heads) * self.head_size
+        shapes = {"Wq": (self.nIn, E), "Wk": (self.nIn, Ek),
+                  "Wv": (self.nIn, Ek), "Wo": (E, self.nOut)}
+        if self.qk_norm:
+            shapes.update(qn=(self.head_size,), kn=(self.head_size,))
+        return shapes
 
     def initialize(self, key):
         ks = jax.random.split(key, 4)
-        return {name: _initialize(shape, self.weight_init, k)
-                for (name, shape), k in zip(self.param_shapes().items(),
-                                            ks)}, {}
+        shapes = self.param_shapes()
+        out = {name: _initialize(shapes[name], self.weight_init, k)
+               for name, k in zip(("Wq", "Wk", "Wv", "Wo"), ks)}
+        if self.qk_norm:
+            out.update(qn=jnp.ones((self.head_size,), jnp.float32),
+                       kn=jnp.ones((self.head_size,), jnp.float32))
+        return out, {}
 
     def apply(self, params, state, x, train, key):
         x = self._maybe_dropout(_feature_last(self, x), train, key)
         N, T = x.shape[0], x.shape[1]
         H, hs = self.n_heads, self.head_size
+        Hk = self.n_kv_heads or H
         q = (x @ params["Wq"]).reshape(N, T, H, hs)
-        k = (x @ params["Wk"]).reshape(N, T, H, hs)
-        v = (x @ params["Wv"]).reshape(N, T, H, hs)
+        k = (x @ params["Wk"]).reshape(N, T, Hk, hs)
+        v = (x @ params["Wv"]).reshape(N, T, Hk, hs)
+        if self.qk_norm:
+            q = _rms(q, params["qn"], self.qk_norm_eps)
+            k = _rms(k, params["kn"], self.qk_norm_eps)
         q = attention_ops.rotary_embedding(q, self.rope_theta)
         k = attention_ops.rotary_embedding(k, self.rope_theta)
         with jax.named_scope(_stepprogram.ATTN_CORE_SCOPE):
@@ -2642,6 +2679,77 @@ class GatedMLP(Layer):
             return InputType.recurrent(self.nOut,
                                        it.dims.get("timesteps", -1))
         return InputType.feedForward(self.nOut)
+
+
+_SHORTCONV_LOWERED = _prof.get_registry().counter(
+    "dl4j_shortconv_lowered_total",
+    "Traces of nn.layers.GatedShortConvLayer (one a lowering of each call "
+    "site, not one a step) by what runs the causal depthwise convolution",
+    labelnames=("path",))
+
+
+class GatedShortConvLayer(Layer):
+    """A gated short convolution over a feature-last sequence, the token
+    mixer of the LFM2 family's ``conv`` layers: ``[B | G | z] = x Win``
+    (three ``nOut``-wide parts, in that order), ``p = B * z``, a causal
+    depthwise convolution ``c_t = sum_j Wc[j] * p_{t - (K-1) + j}`` over
+    the ``K = kernelSize`` last positions of the SAME sequence (``p`` is
+    nought before its first token; one K-vector a channel), and ``y = (G *
+    c) Wout``. The gates and the convolution run in the stream's dtype,
+    the ``K`` taps summed in float32; the taps are ``K`` shifted
+    multiply-adds that ride in one fusion (no [N, T, K, C] window tensor,
+    no convolution op: at K = 3 a depthwise convolution is three reads of
+    a tensor the gates read anyway). Everything runs under
+    ``dl4j_shortconv``."""
+
+    input_kind = "rnn"
+
+    def __init__(self, nOut=None, kernelSize: int = 3, **kw):
+        super().__init__(nOut=nOut, **kw)
+        self.kernel_size = int(kernelSize)
+        if self.kernel_size < 1:
+            raise ValueError(f"GatedShortConvLayer: kernelSize must be at "
+                             f"least 1, got {kernelSize}")
+
+    def infer_nin(self, it: InputType):
+        super().infer_nin(it)
+        if self.nOut is None:
+            self.nOut = self.nIn
+
+    def mxu_lane_dims(self):
+        return [3 * self.nOut, self.nOut]
+
+    def param_shapes(self):
+        if not self.nIn or not self.nOut:
+            return {}
+        return {"Win": (self.nIn, 3 * self.nOut),
+                "Wc": (self.kernel_size, self.nOut),
+                "Wout": (self.nOut, self.nOut)}
+
+    def initialize(self, key):
+        shapes = self.param_shapes()
+        return {name: _initialize(shapes[name], self.weight_init, k)
+                for name, k in zip(("Win", "Wc", "Wout"),
+                                   jax.random.split(key, 3))}, {}
+
+    def apply(self, params, state, x, train, key):
+        x = self._maybe_dropout(_feature_last(self, x), train, key)
+        T, C, K = x.shape[1], self.nOut, self.kernel_size
+        with jax.named_scope(_stepprogram.SHORTCONV_SCOPE):
+            _SHORTCONV_LOWERED.labels("shifted_taps").inc()
+            bgz = x @ params["Win"]
+            p = bgz[..., :C] * bgz[..., 2 * C:]
+            # p_{t - d} for d = K-1 .. 0: the sequence moved right, noughts
+            # before its first token; a batch's rows never meet
+            late = jnp.pad(p, ((0, 0), (K - 1, 0), (0, 0)))
+            wc = params["Wc"].astype(jnp.float32)
+            c = sum(wc[j] * jax.lax.slice_in_dim(late, j, j + T, axis=1)
+                    .astype(jnp.float32) for j in range(K))
+            y = (bgz[..., C:2 * C] * c.astype(x.dtype)) @ params["Wout"]
+        return y, state
+
+    def output_type(self, it: InputType) -> InputType:
+        return InputType.recurrent(self.nOut, it.dims.get("timesteps", -1))
 
 
 @jax.custom_vjp
@@ -2705,13 +2813,21 @@ def _head_block(rows, n_out):
     return blk if blk < n_out and n_out % blk == 0 else n_out
 
 
-def _block_logits(h, w, labels, v0, blk):
+#: the head's three products by the axis of ``w`` the vocabulary lies on:
+#: 1 for a head [nIn, nOut], 0 for an embedding's table [nOut, nIn] that a
+#: tied head reads as it lies (no transposed copy of it, or of its gradient)
+_CE_PRODUCTS = {1: ("ntd,dv->ntv", "ntd,ntv->dv", "ntv,dv->ntd"),
+                0: ("ntd,vd->ntv", "ntd,ntv->vd", "ntv,vd->ntd")}
+
+
+def _block_logits(h, w, labels, v0, blk, axis=1):
     """``(w block, logits, label mask)`` of the columns ``v0..``: float32
     ``h @ w[:, v0:v0 + blk]`` from operands in ``h``'s dtype, as
     :func:`_logits` gives them (the slice and the cast of the master ``w``
     ride in the product's fusion), and where a row's label is."""
-    wb = jax.lax.dynamic_slice_in_dim(w, v0, blk, axis=1).astype(h.dtype)
-    z = jnp.einsum("ntd,dv->ntv", h, wb, preferred_element_type=jnp.float32)
+    wb = jax.lax.dynamic_slice_in_dim(w, v0, blk, axis=axis).astype(h.dtype)
+    z = jnp.einsum(_CE_PRODUCTS[axis][0], h, wb,
+                   preferred_element_type=jnp.float32)
     return wb, z, (labels - v0)[..., None] == jnp.arange(blk)
 
 
@@ -2723,44 +2839,45 @@ def _block_logits(h, w, labels, v0, blk):
 # (warm ``setup_s`` 37.0 s against the parent's 34.1 with every block
 # traced anew, PR 32). Neither loops on the device: a ``while`` in a step
 # is listed beside its body's ops and a reader of op time counts it twice.
-@functools.partial(jax.jit, static_argnums=4)
-def _ce_fwd_block(h, w, labels, v0, blk):
+@functools.partial(jax.jit, static_argnums=(4, 5))
+def _ce_fwd_block(h, w, labels, v0, blk, axis=1):
     """A block's float32 row log-sum-exp and the label's logit where the
     block holds it (0 elsewhere), [N, T] each."""
-    _, z, hit = _block_logits(h, w, labels, v0, blk)
+    _, z, hit = _block_logits(h, w, labels, v0, blk, axis)
     return (jax.nn.logsumexp(z, axis=-1),
             jnp.sum(jnp.where(hit, z, 0.0), axis=-1))
 
 
-@functools.partial(jax.jit, static_argnums=8)
-def _ce_bwd_block(h, w, labels, lse, g, dh, dw, v0, blk):
+@functools.partial(jax.jit, static_argnums=(8, 9))
+def _ce_bwd_block(h, w, labels, lse, g, dh, dw, v0, blk, axis=1):
     """``dh`` and ``dw`` with a block's share added: the block's logits
     again, ``dz = (exp(z - lse) - onehot) g`` rounded to ``h``'s dtype for
     its two products exactly as :func:`_logits_bwd` rounds it, each
     product's fusion adding into its float32 accumulator (``dw``'s columns
     ``v0..`` in place)."""
-    wb, z, hit = _block_logits(h, w, labels, v0, blk)
+    wb, z, hit = _block_logits(h, w, labels, v0, blk, axis)
     dz = ((jnp.exp(z - lse[..., None]) - hit) * g[..., None]).astype(h.dtype)
-    dwb = jax.lax.dynamic_slice_in_dim(dw, v0, blk, axis=1) + jnp.einsum(
-        "ntd,ntv->dv", h, dz, preferred_element_type=jnp.float32)
-    return (dh + jnp.einsum("ntv,dv->ntd", dz, wb,
+    _, to_w, to_h = _CE_PRODUCTS[axis]
+    dwb = jax.lax.dynamic_slice_in_dim(dw, v0, blk, axis=axis) + jnp.einsum(
+        to_w, h, dz, preferred_element_type=jnp.float32)
+    return (dh + jnp.einsum(to_h, dz, wb,
                             preferred_element_type=jnp.float32),
-            jax.lax.dynamic_update_slice_in_dim(dw, dwb, v0, axis=1))
+            jax.lax.dynamic_update_slice_in_dim(dw, dwb, v0, axis=axis))
 
 
-def _ce_fwd(hs, w, labels, blk):
+def _ce_fwd(hs, w, labels, blk, axis=1):
     """``(ce, lse)`` [P, N, T] float32 of the passes ``hs``, a block and a
     pass after the other; nothing of [T, nOut] leaves a block."""
     parts = []
     for t, h in enumerate(hs):
         with jax.named_scope(_stepprogram.pass_scope(t + 1)):
-            for v0 in range(0, w.shape[1], blk):
+            for v0 in range(0, w.shape[axis], blk):
                 if parts:
                     # the next block starts once the last has finished:
                     # one block's [T, block] tensors are alive at a time
                     h, parts[-1] = attention_ops._then(h, parts[-1])
-                parts.append(_ce_fwd_block(h, w, labels, v0, blk))
-    ces, lses, n = [], [], w.shape[1] // blk
+                parts.append(_ce_fwd_block(h, w, labels, v0, blk, axis))
+    ces, lses, n = [], [], w.shape[axis] // blk
     for t in range(len(hs)):
         with jax.named_scope(_stepprogram.pass_scope(t + 1)):
             mine = parts[t * n:(t + 1) * n]
@@ -2770,7 +2887,7 @@ def _ce_fwd(hs, w, labels, blk):
     return jnp.stack(ces), jnp.stack(lses)
 
 
-def _ce_bwd(hs, w, labels, lses, gs, blk):
+def _ce_bwd(hs, w, labels, lses, gs, blk, axis=1):
     """``(dhs, dw)`` from the cotangents ``gs`` [P, N, T] of the
     cross-entropies: a pass's ``dh`` summed over the blocks in float32 and
     rounded once, ``dw`` summed over blocks and passes in ONE float32
@@ -2780,36 +2897,38 @@ def _ce_bwd(hs, w, labels, lses, gs, blk):
     for t, h in enumerate(hs):
         dh = jnp.zeros(h.shape, jnp.float32)
         with jax.named_scope(_stepprogram.pass_scope(t + 1)):
-            for v0 in range(0, w.shape[1], blk):
+            for v0 in range(0, w.shape[axis], blk):
                 if t or v0:
                     h, (dh, dw) = attention_ops._then(h, (dh, dw))
                 dh, dw = _ce_bwd_block(h, w, labels, lses[t], gs[t], dh, dw,
-                                       v0, blk)
+                                       v0, blk, axis)
         dhs.append(dh.astype(h.dtype))
     return tuple(dhs), dw.astype(w.dtype)
 
 
-@functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
-def _blocked_ce(hs, w, labels, blk):
-    return _ce_fwd(hs, w, labels, blk)[0]
+@functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
+def _blocked_ce(hs, w, labels, blk, axis):
+    return _ce_fwd(hs, w, labels, blk, axis)[0]
 
 
-def _blocked_ce_fwd(hs, w, labels, blk):
-    ce, lse = _ce_fwd(hs, w, labels, blk)
+def _blocked_ce_fwd(hs, w, labels, blk, axis):
+    ce, lse = _ce_fwd(hs, w, labels, blk, axis)
     return ce, (hs, w, labels, lse)
 
 
-def _blocked_ce_bwd(blk, res, g):
-    return _ce_bwd(*res, g, blk) + (None,)
+def _blocked_ce_bwd(blk, axis, res, g):
+    return _ce_bwd(*res, g, blk, axis) + (None,)
 
 
 _blocked_ce.defvjp(_blocked_ce_fwd, _blocked_ce_bwd)
 
 
-def blocked_cross_entropy(hs, w, labels):
+def blocked_cross_entropy(hs, w, labels, table: bool = False):
     """Cross-entropy [P, N, T] float32 of the logits ``h @ w`` of every
     pass ``h`` [N, T, nIn] in the tuple ``hs`` against the INTEGER
-    ``labels`` [N, T], under the master head ``w`` [nIn, nOut]: a forward
+    ``labels`` [N, T], under the master head ``w`` [nIn, nOut] (``table``:
+    ``w`` is an embedding's table [nOut, nIn] and the logits ``h @ w^T``,
+    the products taking it as it lies): a forward
     and a backward written by hand (``jax.custom_vjp``) over vocabulary
     blocks sized by :data:`HEAD_LOGIT_BYTES`, each pass's ops under its
     ``dl4j_ut<t>`` scope; an ``nOut`` that is no multiple of the block
@@ -2819,9 +2938,11 @@ def blocked_cross_entropy(hs, w, labels):
     [T, nOut] tensor outlives a block and ``w``'s gradient is one buffer
     for all passes. Logits, log-sum-exp and loss are float32 from operands
     in ``h``'s dtype; ``w``'s gradient is float32."""
-    blk = _head_block(labels.size, w.shape[1])
-    _HEAD_LOWERED.labels("blocked" if blk < w.shape[1] else "single").inc()
-    return _blocked_ce(tuple(hs), w, labels, blk)
+    axis = 0 if table else 1
+    blk = _head_block(labels.size, w.shape[axis])
+    _HEAD_LOWERED.labels("blocked" if blk < w.shape[axis]
+                         else "single").inc()
+    return _blocked_ce(tuple(hs), w, labels, blk, axis)
 
 
 class LoopedLMOutputLayer(BaseOutputLayer):
@@ -3583,7 +3704,9 @@ class SparseExpertsLayer(Layer):
     gates are ``routedScalingFactor * s_i / sum_selected s_j`` (the sum
     over ALL selected experts, held here or not), and the output is
     ``shared(x) + sum_{i selected and held} g_i E_i(x)``, every expert and
-    the one shared expert a SwiGLU MLP of inner width ``nHidden``.
+    the one shared expert a SwiGLU MLP of inner width ``nHidden``
+    (``nSharedExperts=0``: no shared expert, no ``Sg``/``Su``/``Sd``, the
+    routed sum alone).
     ``heldExperts`` lists the ids held (default: all): the chip's share
     under expert parallelism. What the absent experts would add is left
     out; no code stands in for their exchange.
@@ -3604,13 +3727,19 @@ class SparseExpertsLayer(Layer):
 
     input_kind = None
     fp32_leaves = ("Wr",)
+    n_shared = 1        # (a configuration saved before the key has one)
 
     def __init__(self, nOut=None, nExperts: int = None,
                  nExpertsPerTok: int = 1, nHidden: int = None,
                  heldExperts=None, routedScalingFactor: float = 1.0,
-                 keepSelected: int = 0, **kw):
+                 keepSelected: int = 0, nSharedExperts: int = 1, **kw):
         super().__init__(nOut=nOut, activation="swish", **kw)
         self.keep_selected = int(keepSelected)
+        if nSharedExperts not in (0, 1):
+            raise ValueError(f"SparseExpertsLayer: one shared expert or "
+                             f"none, got nSharedExperts={nSharedExperts}")
+        if not nSharedExperts:
+            self.n_shared = 0
         if not nExperts or not nHidden:
             raise ValueError("SparseExpertsLayer needs nExperts, the "
                              "router's width, and nHidden, an expert's")
@@ -3638,11 +3767,13 @@ class SparseExpertsLayer(Layer):
         if not self.nIn or not self.nOut:
             return {}
         E, F = len(self.held), self.n_hidden
-        return {"Wr": (self.nIn, self.n_experts),
-                "Eg": (E, self.nIn, F), "Eu": (E, self.nIn, F),
-                "Ed": (E, F, self.nOut),
-                "Sg": (self.nIn, F), "Su": (self.nIn, F),
-                "Sd": (F, self.nOut)}
+        shapes = {"Wr": (self.nIn, self.n_experts),
+                  "Eg": (E, self.nIn, F), "Eu": (E, self.nIn, F),
+                  "Ed": (E, F, self.nOut)}
+        if self.n_shared:
+            shapes.update(Sg=(self.nIn, F), Su=(self.nIn, F),
+                          Sd=(F, self.nOut))
+        return shapes
 
     def initialize(self, key):
         out = {}
@@ -3662,14 +3793,14 @@ class SparseExpertsLayer(Layer):
         return out, state
 
     def forward_flops(self, it) -> int:
-        """Router, the shared expert, and the routed products at the load
-        uniform routing gives the experts held: ``nExpertsPerTok * held /
-        nExperts`` experts a token."""
+        """Router, the shared expert where there is one, and the routed
+        products at the load uniform routing gives the experts held:
+        ``nExpertsPerTok * held / nExperts`` experts a token."""
         per_expert = 3 * self.nIn * self.n_hidden
         share = self.top_k * len(self.held) / self.n_experts
         return int(_steps(it) * 2 * (
             self.nIn * self.n_experts
-            + per_expert * (1 + share)))
+            + per_expert * (self.n_shared + share)))
 
     def route(self, x32, wr, select_bias):
         """``(selected ids [M, k], gates [M, k])`` of float32 tokens."""
@@ -3707,7 +3838,7 @@ class SparseExpertsLayer(Layer):
             routed = jnp.sum(jnp.where(held[..., None], ys, 0)
                              * gate[..., None].astype(ys.dtype), axis=1)
         out = routed + (f(xf @ params["Sg"]) * (xf @ params["Su"])) \
-            @ params["Sd"]
+            @ params["Sd"] if self.n_shared else routed
         new_state = {"select_bias": state["select_bias"],
                      "expert_load": jax.lax.stop_gradient(
                          load.astype(jnp.float32))}
@@ -3782,7 +3913,10 @@ class MTPLMOutputLayer(BaseOutputLayer):
     module's states moved ``d`` places right so that every pass meets the
     same labels. Labels are INTEGER ids [N, T]; the state carries each
     head's loss of the last step (``dl4j_lm_loss``). ``apply`` gives the
-    main model's logits. Fed one array it is a plain head."""
+    main model's logits. Fed one array it is a plain head. ``tiedWith`` an
+    embedding it has no ``W`` of its own: the logits are ``h Emb^T``, the
+    embedding's table [nOut, nIn] read as it lies, and the table's
+    gradient is the sum of both uses'."""
 
     input_kind = None
     loss_from_input = True
@@ -3805,18 +3939,20 @@ class MTPLMOutputLayer(BaseOutputLayer):
             * self.n_heads
 
     def param_shapes(self):
-        if not self.nIn or not self.nOut:
+        if not self.nIn or not self.nOut or self.tied_with:
             return {}
         return {"W": (self.nIn, self.nOut)}
 
     def initialize(self, key):
-        return ({"W": _initialize((self.nIn, self.nOut), self.weight_init,
+        return ({} if self.tied_with else
+                {"W": _initialize((self.nIn, self.nOut), self.weight_init,
                                   key)},
                 {"head_loss": jnp.zeros((self.n_heads,), jnp.float32)})
 
     def apply(self, params, state, x, train, key):
         h = x[0] if isinstance(x, tuple) else x
-        return _logits(_feature_last(self, h), params["W"]), state
+        w = params["W"].T if self.tied_with else params["W"]
+        return _logits(_feature_last(self, h), w), state
 
     def loss_from(self, params, x, labels, mask=None):
         """``(loss, state)`` from the heads' inputs."""
@@ -3827,7 +3963,8 @@ class MTPLMOutputLayer(BaseOutputLayer):
         with jax.named_scope(_stepprogram.HEAD_LOSS_SCOPE):
             ce = blocked_cross_entropy(
                 tuple(h if d == 0 else jnp.roll(h, d, axis=1)
-                      for d, h in enumerate(hs)), params["W"], labels)
+                      for d, h in enumerate(hs)), params["W"], labels,
+                table=bool(self.tied_with))
             m = jnp.ones(labels.shape, jnp.float32) if mask is None \
                 else mask.astype(jnp.float32)
             losses = []
@@ -3851,7 +3988,7 @@ class MTPLMOutputLayer(BaseOutputLayer):
 
 #: layers that compute on [N, T, C] (see ``layout_step``)
 SEQUENCE_LAST = (RMSNorm, CausalSelfAttentionLayer, GatedMLP,
-                 LoopedLMOutputLayer, HyperConnectionIn, HyperConnectionOut,
+                 GatedShortConvLayer, LoopedLMOutputLayer, HyperConnectionIn, HyperConnectionOut,
                  HyperConnectionRead, HyperConnectionWrite,
                  LatentAttentionLayer, SparseExpertsLayer, MTPJoinLayer,
                  MTPLMOutputLayer)

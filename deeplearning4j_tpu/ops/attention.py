@@ -233,15 +233,45 @@ _CORE_LOWERED = _prof.get_registry().counter(
     labelnames=("path",))
 
 _QK, _PV, _PTX = "bqhd,bkhd->bhqk", "bhqk,bkhd->bqhd", "bhqk,bqhd->bkhd"
+# the same three with fewer key/value heads than query heads: ``q`` is
+# [B, T, Hk, G, D], the ``G`` query heads that read key/value head ``h``
+# side by side; the products over ``q`` rows sum over the readers too
+_QK_G, _PV_G, _PTX_G = ("bqhgd,bkhd->bhgqk", "bhgqk,bkhd->bqhgd",
+                        "bhgqk,bqhgd->bkhd")
 
 
-def _causal_plan(B, T, H):
+def _fits(B, g, blk, T):
+    return 4 * B * g * blk * T <= CAUSAL_SCORE_BYTES
+
+
+def _causal_plan(B, T, H, Hk=None):
     """``(block, heads a group)`` for a call's shapes: one path, whose
-    block and group counts follow from what it is handed."""
+    block and group counts follow from what it is handed. A group of
+    query heads carries its key/value heads with it, so with ``Hk`` <
+    ``H`` key/value heads a group holds a multiple of ``H / Hk`` heads;
+    where not even the smallest group's scores fit
+    :data:`CAUSAL_SCORE_BYTES` the block is halved (to no fewer than 128
+    rows: with ``H / Hk`` readers a key/value head a product still has
+    512)."""
     blk = CAUSAL_QUERY_BLOCK if T % CAUSAL_QUERY_BLOCK == 0 else T
-    fit = [g for g in range(1, H + 1)
-           if H % g == 0 and 4 * B * g * blk * T <= CAUSAL_SCORE_BYTES]
-    return blk, max(fit, default=1)
+    least = H // (Hk or H)
+    while blk < T and blk > 128 and not _fits(B, least, blk, T):
+        blk //= 2
+    fit = [g for g in range(least, H + 1, least)
+           if H % g == 0 and _fits(B, g, blk, T)]
+    return blk, max(fit, default=least)
+
+
+def _plan_of(q, k):
+    """``(block, heads a group, sequences a group)`` of a call: all the
+    sequences at once, unless not even the smallest head group's scores
+    then fit :data:`CAUSAL_SCORE_BYTES` (rows of a batch never meet in
+    the core); :func:`_causal_plan` for what a group of them is handed."""
+    (B, T, H), Hk = q.shape[:3], k.shape[2]
+    blk = CAUSAL_QUERY_BLOCK if T % CAUSAL_QUERY_BLOCK == 0 else T
+    bg = max((b for b in range(1, B + 1)
+              if B % b == 0 and _fits(b, H // Hk, blk, T)), default=1)
+    return (*_causal_plan(bg, T, H, Hk), bg)
 
 
 def _then(nxt, done):
@@ -261,7 +291,8 @@ def _scores(q, k, first_row, scale=None):
     rides in the product's own fusion over the whole block: cut to the
     diagonal tile it costs a concatenation of the scores (v5e: 11.1 ms
     against 5.8 for the probe above)."""
-    s = jnp.einsum(_QK, q, k, preferred_element_type=jnp.float32) \
+    s = jnp.einsum(_QK if q.ndim == 4 else _QK_G, q, k,
+                   preferred_element_type=jnp.float32) \
         * (q.shape[-1] ** -0.5 if scale is None else scale)
     rows = first_row + jnp.arange(q.shape[1])
     return jnp.where(rows[:, None] >= jnp.arange(k.shape[1])[None, :],
@@ -269,18 +300,46 @@ def _scores(q, k, first_row, scale=None):
 
 
 def _over_head_groups(fn, hg, args, head_axes, out_axes):
-    """``fn`` on groups of ``hg`` heads, one after the other."""
+    """``fn`` on groups of ``hg`` query heads, one after the other; an
+    argument with fewer heads (keys, values and their gradients under
+    grouped key/value heads) is cut into as many groups of its own."""
     H = args[0].shape[2]
     if hg == H:
         return fn(*args)
     parts = []
     for h in range(0, H, hg):
-        xs = [_rows(x, h, h + hg, ax) for x, ax in zip(args, head_axes)]
+        xs = [_rows(x, h * x.shape[ax] // H, (h + hg) * x.shape[ax] // H, ax)
+              for x, ax in zip(args, head_axes)]
         if parts:
             xs, parts[-1] = _then(xs, parts[-1])
         parts.append(fn(*xs))
     return tuple(jnp.concatenate([p[n] for p in parts], ax)
                  for n, ax in enumerate(out_axes))
+
+
+def _over_groups(fn, hg, bg, args, head_axes, out_axes):
+    """:func:`_over_head_groups` on groups of ``bg`` sequences, one after
+    the other (rows of a batch never meet in the core)."""
+    B = args[0].shape[0]
+    if bg == B:
+        return _over_head_groups(fn, hg, args, head_axes, out_axes)
+    parts = []
+    for b in range(0, B, bg):
+        xs = [_rows(x, b, b + bg, 0) for x in args]
+        if parts:
+            xs, parts[-1] = _then(xs, parts[-1])
+        parts.append(_over_head_groups(fn, hg, xs, head_axes, out_axes))
+    return tuple(jnp.concatenate([p[n] for p in parts], 0)
+                 for n in range(len(out_axes)))
+
+
+def _readers(q, k):
+    """``q``-shaped [B, T, H, D] as [B, T, Hk, G, D] where ``k`` has fewer
+    heads (query head ``h`` reads key/value head ``h // G``: a reshape, no
+    copy); as it is where every head has its own."""
+    H, Hk = q.shape[2], k.shape[2]
+    return q if H == Hk else q.reshape(q.shape[:2] + (Hk, H // Hk,
+                                                      q.shape[-1]))
 
 
 # The two functions below are jitted for what a jit shares, not for a
@@ -294,7 +353,11 @@ def _over_head_groups(fn, hg, args, head_axes, out_axes):
 def _fwd_heads(q, k, v, blk, scale=None):
     """``(O, lse)`` of these heads: the output and the float32 row
     log-sum-exp [B, H, T] of the scaled, masked scores, a block of ``blk``
-    query rows after the other; nothing of [T, T] leaves it."""
+    query rows after the other; nothing of [T, T] leaves it. With fewer
+    key/value heads than query heads a product takes the readers of a
+    key/value head together (:func:`_readers`)."""
+    shape, q = q.shape[:3] + v.shape[-1:], _readers(q, k)
+    pv = _PV if q.ndim == 4 else _PV_G
     outs, lses = [], []
     for i in range(0, q.shape[1], blk):
         e = i + blk
@@ -305,11 +368,12 @@ def _fwd_heads(q, k, v, blk, scale=None):
         m = jnp.max(s, axis=-1)
         p = jnp.exp(s - m[..., None])
         l = jnp.sum(p, axis=-1)
-        o = jnp.einsum(_PV, p.astype(v.dtype), _rows(v, 0, e),
+        o = jnp.einsum(pv, p.astype(v.dtype), _rows(v, 0, e),
                        preferred_element_type=jnp.float32)
-        outs.append((o / jnp.swapaxes(l, 1, 2)[..., None]).astype(q.dtype))
+        outs.append((o / jnp.moveaxis(l, -1, 1)[..., None]).astype(q.dtype))
         lses.append(m + jnp.log(l))
-    return jnp.concatenate(outs, 1), jnp.concatenate(lses, -1)
+    return (jnp.concatenate(outs, 1).reshape(shape),
+            jnp.concatenate(lses, -1).reshape(shape[0], shape[2], shape[1]))
 
 
 @functools.partial(jax.jit, static_argnums=(6, 7))
@@ -318,11 +382,17 @@ def _bwd_heads(q, k, v, o, lse, do, blk, scale=None):
     al. 2022, algorithm 4) in plain matmuls: ``p`` again from the scores
     and ``lse``, ``delta = rowsum(dO * O)`` over [T, D], ``ds = p * (dO
     v^T - delta) / sqrt(D)``; bf16 operands, float32 products, ``dk`` and
-    ``dv`` summed in float32 over the query blocks and rounded once."""
+    ``dv`` summed in float32 over the query blocks (and, with fewer
+    key/value heads than query heads, over a key/value head's readers
+    inside the products) and rounded once."""
     softmax_scale, scale = scale, \
         (q.shape[-1] ** -0.5 if scale is None else scale)
-    delta = jnp.swapaxes(jnp.sum(
-        do.astype(jnp.float32) * o.astype(jnp.float32), -1), 1, 2)
+    shape, q, o, do = q.shape, *(_readers(x, k) for x in (q, o, do))
+    qk, pv, ptx = (_QK, _PV, _PTX) if q.ndim == 4 else (_QK_G, _PV_G, _PTX_G)
+    last = q.ndim - 2       # the rows' axis of [B, H, (G,) T] statistics
+    lse = lse.reshape(q.shape[0], *q.shape[2:-1], q.shape[1])
+    delta = jnp.moveaxis(jnp.sum(
+        do.astype(jnp.float32) * o.astype(jnp.float32), -1), 1, -1)
     dk = jnp.zeros(k.shape, jnp.float32)
     dv = jnp.zeros(v.shape, jnp.float32)
     dqs = []
@@ -334,32 +404,33 @@ def _bwd_heads(q, k, v, o, lse, do, blk, scale=None):
             (qi, doi), (dqs[-1], dk, dv) = _then(
                 (qi, doi), (dqs[-1], dk, dv))
         p = jnp.exp(_scores(qi, ki, i, softmax_scale)
-                    - _rows(lse, i, e, 2)[..., None])
-        dvi = jnp.einsum(_PTX, p.astype(v.dtype), doi,
+                    - _rows(lse, i, e, last)[..., None])
+        dvi = jnp.einsum(ptx, p.astype(v.dtype), doi,
                          preferred_element_type=jnp.float32)
-        dp = jnp.einsum(_QK, doi, vi, preferred_element_type=jnp.float32)
-        ds = (p * (dp - _rows(delta, i, e, 2)[..., None]) * scale) \
+        dp = jnp.einsum(qk, doi, vi, preferred_element_type=jnp.float32)
+        ds = (p * (dp - _rows(delta, i, e, last)[..., None]) * scale) \
             .astype(q.dtype)
-        dqs.append(jnp.einsum(_PV, ds, ki, preferred_element_type=jnp.float32)
+        dqs.append(jnp.einsum(pv, ds, ki, preferred_element_type=jnp.float32)
                    .astype(q.dtype))
-        dki = jnp.einsum(_PTX, ds, qi, preferred_element_type=jnp.float32)
+        dki = jnp.einsum(ptx, ds, qi, preferred_element_type=jnp.float32)
         dk = lax.dynamic_update_slice_in_dim(dk, _rows(dk, 0, e) + dki, 0, 1)
         dv = lax.dynamic_update_slice_in_dim(dv, _rows(dv, 0, e) + dvi, 0, 1)
-    return jnp.concatenate(dqs, 1), dk.astype(k.dtype), dv.astype(v.dtype)
+    return (jnp.concatenate(dqs, 1).reshape(shape), dk.astype(k.dtype),
+            dv.astype(v.dtype))
 
 
 def _causal_fwd(q, k, v, scale=None):
     """``(O, lse)`` of every head, a group after the other."""
-    blk, hg = _causal_plan(*q.shape[:3])
-    return _over_head_groups(lambda *xs: _fwd_heads(*xs, blk, scale), hg,
-                             (q, k, v), (2, 2, 2), (2, 1))
+    blk, hg, bg = _plan_of(q, k)
+    return _over_groups(lambda *xs: _fwd_heads(*xs, blk, scale), hg, bg,
+                        (q, k, v), (2, 2, 2), (2, 1))
 
 
 def _causal_bwd(scale, res, do):
     """``(dq, dk, dv)`` from what the forward kept and ``dO``."""
-    blk, hg = _causal_plan(*res[0].shape[:3])
-    return _over_head_groups(lambda *xs: _bwd_heads(*xs, blk, scale), hg,
-                             (*res, do), (2, 2, 2, 2, 1, 2), (2, 2, 2))
+    blk, hg, bg = _plan_of(*res[:2])
+    return _over_groups(lambda *xs: _bwd_heads(*xs, blk, scale), hg, bg,
+                        (*res, do), (2, 2, 2, 2, 1, 2), (2, 2, 2))
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3,))
@@ -376,9 +447,13 @@ _causal_core.defvjp(_causal_core_fwd, _causal_bwd)
 
 
 def causal_attention(q, k, v, scale: float = None):
-    """Causal scaled dot-product attention for a training step, ``q`` and
-    ``k`` [B, T, H, D], ``v`` [B, T, H, Dv] (latent attention's value heads
-    are narrower than its query/key heads; the output has ``v``'s), the
+    """Causal scaled dot-product attention for a training step, ``q``
+    [B, T, H, D], ``k`` [B, T, Hk, D], ``v`` [B, T, Hk, Dv] (latent
+    attention's value heads are narrower than its query/key heads; the
+    output has ``v``'s; with ``Hk`` < ``H``, grouped-query attention, query
+    head ``h`` reads key/value head ``h // (H / Hk)``, no copy of ``k`` or
+    ``v`` a reader is made, and ``dk``, ``dv`` are summed over the readers
+    in float32 inside the backward's products), the
     scores times ``scale`` (``1 / sqrt(D)`` unless handed in: YaRN's
     temperature rides in it): a forward and a backward written by hand (``jax.custom_vjp``)
     in plain XLA matmuls over query blocks of :data:`CAUSAL_QUERY_BLOCK`
@@ -390,7 +465,11 @@ def causal_attention(q, k, v, scale: float = None):
     rematerialised stretch or outside one. Scores, softmax statistics
     and every accumulator are float32; the probabilities are rounded
     once, to ``v``'s dtype, before a product."""
-    blk, _ = _causal_plan(*q.shape[:3])
+    if q.shape[2] % k.shape[2] or v.shape[2] != k.shape[2]:
+        raise ValueError(
+            f"causal_attention: {q.shape[2]} query heads do not divide "
+            f"over {k.shape[2]} key and {v.shape[2]} value heads")
+    blk = _plan_of(q, k)[0]
     _CORE_LOWERED.labels("blocked" if blk < q.shape[1] else "single").inc()
     return _causal_core(q, k, v, None if scale is None else float(scale))
 

@@ -57,9 +57,11 @@ HEAD_LOSS_SCOPE = "dl4j_head_loss"
 MHC_SCOPE = "dl4j_mhc"
 MOE_SCOPE = "dl4j_moe"
 MOE_EXPERTS_SCOPE = "dl4j_moe_experts"
+#: a gated short-convolution mixer: projections, gates and the taps
+SHORTCONV_SCOPE = "dl4j_shortconv"
 PARTS = {ATTN_CORE_SCOPE: "attn_core", HEAD_LOSS_SCOPE: "head_loss",
          MHC_SCOPE: "mhc", MOE_SCOPE: "moe",
-         MOE_EXPERTS_SCOPE: "moe_experts"}
+         MOE_EXPERTS_SCOPE: "moe_experts", SHORTCONV_SCOPE: "shortconv"}
 #: what JAX names the forward ops a ``jax.checkpoint`` runs again in the
 #: backward pass
 REMAT_MARK = "rematted_computation"

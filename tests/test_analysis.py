@@ -503,7 +503,7 @@ class TestCli:
         from deeplearning4j_tpu.analysis.__main__ import main
         assert main(["--zoo"]) == 0
         out = capsys.readouterr().out
-        assert "18 model(s) linted: 18 clean" in out
+        assert "19 model(s) linted: 19 clean" in out
 
     def test_single_model_by_name(self, capsys):
         from deeplearning4j_tpu.analysis.__main__ import main
@@ -751,7 +751,7 @@ class TestDistributionDiagnostics:
         # which TestDistributionAnalysis pins separately
         from deeplearning4j_tpu.models.zoo import all_zoo_models
         for name, net in all_zoo_models():
-            if name == "Xing4":     # pinned alone: the next test
+            if name in ("Xing4", "LFM2"):   # pinned alone: the next tests
                 continue
             report = analyze(net, mesh="data=8", zero=True)
             assert report.ok(warnings_as_errors=True), \
@@ -773,6 +773,26 @@ class TestDistributionDiagnostics:
         assert warned == {"embed", "lm", "mtp_moe"} | {
             f"l{i}_moe" for i in range(2, 40)}
         cut = analyze(Xing4.for_cost_gate().conf_builder(), mesh="data=8",
+                      zero=True)
+        assert cut.ok(warnings_as_errors=True), cut.format()
+
+    def test_lfm2_under_data8_mesh(self):
+        # the published 40 layers hold 24 B parameters, all but 1.6 B in
+        # the 38 expert layers' 64 experts: replicated over a data mesh
+        # they earn the same two codes as the other sparse decoder, and
+        # nothing else (grouped key/value projections, the short
+        # convolutions and the tied head are sized by their own shapes:
+        # a head tied to the embedding has no tensor to all-reduce); the
+        # share one chip holds under expert parallelism is clean
+        from deeplearning4j_tpu.models.zoo import LFM2
+        report = analyze(LFM2().conf_builder(), mesh="data=8", zero=True)
+        assert set(report.codes()) == {"DL4J-E104", "DL4J-W107"}
+        e104, = [d for d in report.diagnostics if d.code == "DL4J-E104"]
+        assert "111.03 GiB" in e104.message
+        warned = {d.location.split("'")[1] for d in report.diagnostics
+                  if d.code == "DL4J-W107"}
+        assert warned == {f"l{i}_moe" for i in range(2, 40)}
+        cut = analyze(LFM2.for_cost_gate().conf_builder(), mesh="data=8",
                       zero=True)
         assert cut.ok(warnings_as_errors=True), cut.format()
 
@@ -840,15 +860,16 @@ class TestCliMesh:
     def test_zoo_clean_under_mesh_flag(self, capsys):
         from deeplearning4j_tpu.analysis.__main__ import main
         # --zero: see test_zoo_clean_under_data8_mesh (W109 otherwise)
-        # 17 clean as ever; the 18th is the 29 B sparse decoder, which no
-        # data mesh trains replicated (test_xing4_under_data8_mesh)
+        # 17 clean as ever; the other two are the 29 B and 24 B sparse
+        # decoders, which no data mesh trains replicated
+        # (test_xing4_under_data8_mesh, test_lfm2_under_data8_mesh)
         assert main(["--zoo", "--mesh", "data=8", "--zero"]) == 1
         out = capsys.readouterr().out
-        assert "18 model(s) linted: 17 clean, 1 with findings (1 error(s)" \
+        assert "19 model(s) linted: 17 clean, 2 with findings (2 error(s)" \
             in out
         assert main(["--zoo", "--mesh", "data=8", "--zero",
                      "--suppress", "E104,W107"]) == 0
-        assert "18 model(s) linted: 18 clean" in capsys.readouterr().out
+        assert "19 model(s) linted: 19 clean" in capsys.readouterr().out
 
     def test_mesh_flag_fails_bad_batch(self, capsys):
         from deeplearning4j_tpu.analysis.__main__ import main
