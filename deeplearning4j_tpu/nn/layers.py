@@ -3657,42 +3657,212 @@ _MOE_LOWERED = _prof.get_registry().counter(
     "over the experts held",
     labelnames=("path",))
 
+#: Rows a pass of the routed path works over, as a multiple of the share
+#: of the ``tokens * nExpertsPerTok`` pairs that uniform routing sends to
+#: the experts held here. 2: the held share is not stationary (only the
+#: held experts' outputs reach the loss, so the router turns toward them:
+#: 12.9% of the pairs at the first step of ``lfm2-fit-s8192-b4``, 19.5%
+#: after 27 at 8 of 64 held, PERF.md section 6) and a selection bias moves
+#: it by the seed (10-16% in ``xing4-fit-s4096-b1``); what a step sends
+#: beyond takes a further pass, so the value costs time, never a token.
+ROUTED_ROWS_OVER_UNIFORM = 2
 
-@jax.custom_vjp
-def _dispatch(x, order, inv, held):
-    """Rows of ``x`` [M, C] laid out a routed (token, expert) pair a row
-    in ``order`` (pair ``j`` is token ``j // k``): [M * k, C]. A gather
-    both ways: the backward brings the pairs' cotangents back in token
-    order (``inv``, the inverse permutation), drops those of pairs no held
-    expert saw (``held`` [M, k]: whatever the grouped product left in such
-    a row is nobody's gradient) and sums a token's ``k``."""
-    return jnp.take(x, order // held.shape[1], axis=0)
-
-
-def _dispatch_fwd(x, order, inv, held):
-    return _dispatch(x, order, inv, held), (inv, held)
+#: a pass's rows are a multiple of this (a float32 tile's sublanes)
+_ROUTED_ROW_TILE = 8
 
 
-def _dispatch_bwd(res, g):
-    inv, held = res
-    back = jnp.take(g, inv, axis=0).reshape(held.shape + g.shape[1:])
-    dx = jnp.sum(jnp.where(held[..., None], back, 0).astype(jnp.float32),
-                 axis=1).astype(g.dtype)
-    return dx, None, None, None
+def _routed_rows(pairs, held, experts):
+    """Rows a pass takes of ``pairs`` = tokens x experts a token, where
+    ``held`` of the router's ``experts`` are here: all of them where that
+    is no more (a layer that holds every expert: one pass, no
+    conditional)."""
+    rows = -(-ROUTED_ROWS_OVER_UNIFORM * pairs * held // experts)
+    rows = -(-rows // _ROUTED_ROW_TILE) * _ROUTED_ROW_TILE
+    return min(pairs, rows)
 
 
-_dispatch.defvjp(_dispatch_fwd, _dispatch_bwd)
+def _routed_passes(held, experts):
+    """The most passes any batch takes (a state is shaped before the
+    batch is known)."""
+    return max(-(-experts // (ROUTED_ROWS_OVER_UNIFORM * held)), 1)
 
 
-@jax.custom_vjp
-def _unpermute(y, order, inv):
-    """``y[inv]``: sorted pair rows back in token order; its backward is
-    the gather ``g[order]``, never a scatter."""
-    return jnp.take(y, inv, axis=0)
+def _pass_index(order, inv, load, lo, rows, k):
+    """What a pass over the sorted pairs ``[lo, lo + rows)`` needs of
+    integers, all of ``rows``, ``tokens`` or ``tokens * k`` int32: the
+    pass's pair ids and which of its rows hold a held pair, the experts'
+    group sizes inside it, the permutation that brings its rows into
+    token order with each row's token there (``tokens`` where the row
+    holds no pair), a token's first row there, and which pairs the pass
+    holds."""
+    pairs, held_pairs = inv.shape[0], jnp.sum(load)
+    at = lo + jnp.arange(rows, dtype=jnp.int32)
+    idx = jax.lax.dynamic_slice_in_dim(order, lo, rows)
+    valid = at < held_pairs
+    ends = jnp.clip(jnp.cumsum(load) - lo, 0, rows)
+    sizes = jnp.diff(ends, prepend=0).astype(jnp.int32)
+    key, perm = jax.lax.sort(
+        (jnp.where(valid, idx, pairs), jnp.arange(rows, dtype=jnp.int32)),
+        num_keys=1, is_stable=True)
+    # a tile of rows that hold no pair past the last: the row of zeros a
+    # token with no pair in the pass takes
+    key = jnp.pad(key, (0, _ROUTED_ROW_TILE), constant_values=pairs)
+    perm = jnp.pad(perm, (0, _ROUTED_ROW_TILE))
+    here = (inv >= lo) & (inv < jnp.minimum(lo + rows, held_pairs))
+    count = jnp.sum(here.reshape(-1, k), axis=1, dtype=jnp.int32)
+    first = jnp.where(count > 0, jnp.cumsum(count) - count, rows)
+    return idx, valid, sizes, perm, key // k, first, here
 
 
-_unpermute.defvjp(lambda y, order, inv: (jnp.take(y, inv, axis=0), order),
-                  lambda order, g: (jnp.take(g, order, axis=0), None, None))
+def _to_tokens(y, w, perm, token, first, k):
+    """``D^T``: rows ``y`` [rows, C] of a pass, each weighted by ``w``
+    [rows] float32 (0 where a row holds no pair), summed back to their
+    tokens [tokens, C] with no scatter: the rows in token order (one
+    gather), a token's at most ``k`` adjacent rows added in float32 by
+    ``k - 1`` shifted masked adds, one row a token (the second gather)."""
+    yt, wt = y.at[perm].get(mode="promise_in_bounds"), w[perm]
+    held = token < first.shape[0]
+    total = jnp.where(held[:, None], yt.astype(jnp.float32) * wt[:, None], 0)
+    for c in range(1, k):
+        same = (jnp.roll(token, -c) == token).at[-c:].set(False) & held
+        total = total + jnp.where(
+            same[:, None], jnp.roll(yt, -c, axis=0).astype(jnp.float32)
+            * jnp.roll(wt, -c)[:, None], 0)
+    return total.astype(y.dtype).at[first].get(mode="promise_in_bounds")
+
+
+@functools.partial(jax.jit, static_argnums=(9, 10))
+def _pass_fwd(x, gate, eg, eu, ed, order, inv, load, lo, rows, f):
+    """One pass of the routed path: what the held experts add to the
+    tokens [tokens, C] from the sorted pairs ``[lo, lo + rows)``, and what
+    its backward keeps: the gathered rows and the two products before the
+    activation, all of ``rows`` rows."""
+    k = gate.shape[1]
+    idx, valid, sizes, perm, token, first, _ = _pass_index(
+        order, inv, load, lo, rows, k)
+    xr = x.at[idx // k].get(mode="promise_in_bounds")          # D
+    with jax.named_scope(_stepprogram.MOE_EXPERTS_SCOPE):
+        a = jax.lax.ragged_dot(xr, eg, sizes)
+        b = jax.lax.ragged_dot(xr, eu, sizes)
+        ys = jax.lax.ragged_dot(f(a) * b, ed, sizes)
+    w = jnp.where(valid, gate.reshape(-1)[idx], 0)
+    return _to_tokens(ys, w, perm, token, first, k), (xr, a, b)
+
+
+@functools.partial(jax.jit, static_argnums=(12, 13))
+def _pass_bwd(gate, eg, eu, ed, order, inv, load, lo, xr, a, b, g, rows, f):
+    """Cotangents ``(dx, dgate, dEg, dEu, dEd)`` of one pass from what
+    :func:`_pass_fwd` kept. ``D`` of the output's cotangent goes into the
+    last product's transposes as it is and the gate is put on the other
+    side of each (``dEd = (gate h)^T g``, ``dh = gate (g Ed^T)``, the
+    gate's own cotangent the row-wise ``h . (g Ed^T)``), so no weighted
+    copy of the cotangent is written and the experts' outputs are not
+    kept; the grouped products' transposes are autodiff's, of each
+    product alone (no forward product runs again); ``D^T`` of the rows'
+    cotangent."""
+    f32, k = jnp.float32, gate.shape[1]
+    idx, valid, sizes, perm, token, first, here = _pass_index(
+        order, inv, load, lo, rows, k)
+    gr = g.at[idx // k].get(mode="promise_in_bounds")          # D
+    w = jnp.where(valid, gate.reshape(-1)[idx], 0)[:, None]
+    with jax.named_scope(_stepprogram.MOE_EXPERTS_SCOPE):
+        h, gated = jax.vjp(lambda a, b: f(a) * b, a, b)
+        dh, ded = jax.vjp(
+            lambda hw, ed: jax.lax.ragged_dot(hw, ed, sizes),
+            (h.astype(f32) * w).astype(h.dtype), ed)[1](gr)
+        dh = dh.astype(f32)
+        dw = jnp.where(valid, jnp.sum(dh * h.astype(f32), axis=-1), 0)
+        dxr, deg, deu = jax.vjp(
+            lambda xr, eg, eu: (jax.lax.ragged_dot(xr, eg, sizes),
+                                jax.lax.ragged_dot(xr, eu, sizes)),
+            xr, eg, eu)[1](gated((dh * w).astype(h.dtype)))
+    dgate = jnp.where(here, dw[jnp.clip(inv - lo, 0, rows - 1)], 0)
+    dx = _to_tokens(dxr, valid.astype(f32), perm, token, first, k)
+    return dx, dgate.reshape(gate.shape), deg, deu, ded
+
+
+def _further(load, rows, passes, acc, one):
+    """``acc`` with what the passes after the first add; ``one(lo)`` is a
+    pass's share, a tree like ``acc``. ONE conditional a call site: where
+    the step's held pairs fit the first pass's rows (the common step) it
+    hands ``acc`` through, else a ``while`` runs the further passes the
+    pairs reach. A pass written out under a conditional of its own for
+    each of the (at most four) passes put three more copies of the pass
+    at every call site: 270 grouped-product kernels in
+    ``xing4-fit-s4096-b1``'s step for the parent's 60, and its warm
+    ``setup_s`` 36.2 -> 40.4 s (my chip run, PR 36); this way there is
+    one. The ``while`` alone, with no conditional around it, kept 0.28
+    GiB more alive at that step's peak (sandbox compile). The barrier
+    keeps what reads the result out of the branches: without it the
+    compiler moves the float32 casts of the experts' gradients into
+    both, and the conditional hands Adam, at the step's end, a second,
+    float32 copy of every expert leaf (1.7 GiB of that step)."""
+    if passes == 1:
+        return acc
+    held = jnp.sum(load)
+
+    def rest(acc):
+        return jax.lax.while_loop(
+            lambda c: c[0] * rows < held,
+            lambda c: (c[0] + 1, jax.tree_util.tree_map(
+                jnp.add, c[1], one(c[0] * rows))),
+            (jnp.int32(1), acc))[1]
+    return jax.lax.optimization_barrier(
+        jax.lax.cond(held > rows, rest, lambda acc: acc, acc))
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(8, 9))
+def _routed(x, gate, eg, eu, ed, order, inv, load, rows, f):
+    """``sum_{pairs held} gate * E(x)`` [tokens, C] of tokens ``x``
+    [tokens, C], their gates [tokens, k] float32 and the selected pairs
+    sorted by held expert (``order``, its inverse ``inv``, the experts'
+    ``load``): the held pairs are the first ``sum(load)`` of ``order``,
+    worked over in passes of ``rows`` rows. The first pass always runs;
+    a further one only where a step's held pairs reach it, so no pair is
+    left out whatever the router does. One forward and one backward rule
+    with the conditional INSIDE them (:func:`_further`): autodiff of
+    ``lax.cond`` would have every branch write zeros for the other
+    branch's residuals, stream-sized ones on the common path for passes
+    that never run. The first pass keeps its rows for the backward; a
+    further pass keeps nothing and its backward runs its forward again,
+    inside the backward rule's own conditional. The further passes are a
+    ``while`` inside the conditional's branch: the map's readers list a
+    ``while`` beside its body (PERF.md section 7 k), so a step that takes
+    a second pass reads twice that pass's time in ``routed_device_ms``;
+    the common step never enters it."""
+    return _routed_fwd(x, gate, eg, eu, ed, order, inv, load, rows, f)[0]
+
+
+# (the two rules are jitted whole as well as the pass: a step's expert
+# layers trace the conditional and the ``while`` once for all their call
+# sites, 0.4 s less tracing and lowering of ``xing4-fit-s4096-b1``'s step,
+# fifteen call sites, than a call site at a time: sandbox, PR 36)
+@functools.partial(jax.jit, static_argnums=(8, 9))
+def _routed_fwd(x, gate, eg, eu, ed, order, inv, load, rows, f):
+    passes = -(-inv.shape[0] // rows)
+    # (the last pass's rows may end past the pairs)
+    order = jnp.pad(order, (0, passes * rows - inv.shape[0]))
+    args = (x, gate, eg, eu, ed, order, inv, load)
+    out, kept = _pass_fwd(*args, jnp.int32(0), rows, f)
+    out = _further(load, rows, passes, out,
+                   lambda lo: _pass_fwd(*args, lo, rows, f)[0])
+    return out, (args, kept)
+
+
+@functools.partial(jax.jit, static_argnums=(0, 1))
+def _routed_bwd(rows, f, res, g):
+    args, kept = res
+    inv, load = args[6:]
+    passes = -(-inv.shape[0] // rows)
+    first = _pass_bwd(*args[1:], jnp.int32(0), *kept, g, rows, f)
+    return _further(
+        load, rows, passes, first,
+        lambda lo: _pass_bwd(*args[1:], lo,
+                             *_pass_fwd(*args, lo, rows, f)[1], g, rows, f)
+    ) + (None, None, None)
+
+
+_routed.defvjp(_routed_fwd, _routed_bwd)
 
 
 class SparseExpertsLayer(Layer):
@@ -3711,14 +3881,25 @@ class SparseExpertsLayer(Layer):
     under expert parallelism. What the absent experts would add is left
     out; no code stands in for their exchange.
 
-    No token is dropped: the selected (token, expert) pairs are sorted by
-    held expert into ``tokens * nExpertsPerTok`` rows (the most any
-    routing can send here) and the held experts' products are grouped
-    matrix products over that one buffer (``jax.lax.ragged_dot``, group
-    sizes the experts' loads), under ``dl4j_moe_experts`` inside
-    ``dl4j_moe``. The state carries the loads of the last step
-    (``expert_load`` [held]) for the gauges ``dl4j_moe_expert_load`` /
-    ``dl4j_moe_held_pairs``, and with ``keepSelected=rows`` the ids it
+    No token is dropped, and only the pairs a held expert takes are
+    moved: the selected (token, expert) pairs are sorted by held expert,
+    so that the held ones are the first ``sum(load)`` of the order, and
+    :func:`_routed` works over them in passes of ``R`` rows, ``R`` =
+    :data:`ROUTED_ROWS_OVER_UNIFORM` (2) times the share of the ``tokens
+    * nExpertsPerTok`` pairs that uniform routing sends to the experts
+    held (:func:`_routed_rows`; all the pairs where every expert is
+    held). A pass gathers its rows by token, runs the held experts'
+    products as grouped matrix products over them
+    (``jax.lax.ragged_dot``, group sizes the experts' loads inside the
+    pass, under ``dl4j_moe_experts`` inside ``dl4j_moe``) and sums the
+    gated rows back to their tokens without a scatter. The first pass
+    always runs; a step whose held pairs exceed ``R`` takes a further
+    pass for each ``R`` more, inside a conditional, so whatever the
+    router does every selected and held pair is computed. The state
+    carries the loads of the last step (``expert_load`` [held]) for the
+    gauges ``dl4j_moe_expert_load`` / ``dl4j_moe_held_pairs``, how many
+    steps took 1, 2, .. passes (``pass_steps``, gauge
+    ``dl4j_moe_pass_steps``), and with ``keepSelected=rows`` the ids it
     selected for the first ``rows`` tokens (``selected`` [rows, k] int32,
     -1 beyond the tokens): which experts a token takes is a discrete
     choice that rounding moves (the k-th and the next score of 64 lie
@@ -3786,7 +3967,10 @@ class SparseExpertsLayer(Layer):
             else:
                 out[name] = _initialize(shape, self.weight_init, sub)
         state = {"select_bias": jnp.zeros((self.n_experts,), jnp.float32),
-                 "expert_load": jnp.zeros((len(self.held),), jnp.float32)}
+                 "expert_load": jnp.zeros((len(self.held),), jnp.float32),
+                 "pass_steps": jnp.zeros(
+                     (_routed_passes(len(self.held), self.n_experts),),
+                     jnp.float32)}
         if self.keep_selected:
             state["selected"] = jnp.full((self.keep_selected, self.top_k),
                                          -1, jnp.int32)
@@ -3816,37 +4000,35 @@ class SparseExpertsLayer(Layer):
         f = act.get(self.activation)
         xf = x.reshape(-1, x.shape[-1])
         M, k, E = xf.shape[0], self.top_k, len(self.held)
+        rows = _routed_rows(M * k, E, self.n_experts)
         with jax.named_scope(_stepprogram.MOE_SCOPE):
             sel, gate = self.route(xf.astype(jnp.float32), params["Wr"],
                                    state["select_bias"])
             # a selected expert's row among the held ones; E = not held
             local = jnp.full((self.n_experts,), E, jnp.int32).at[
                 jnp.asarray(self.held, jnp.int32)].set(
-                    jnp.arange(E, dtype=jnp.int32))[sel]
-            held = local < E
-            order = jnp.argsort(local.reshape(-1), stable=True)
+                    jnp.arange(E, dtype=jnp.int32))[sel].reshape(-1)
+            order = jnp.argsort(local, stable=True)
             inv = jnp.argsort(order)
-            load = jnp.sum(local.reshape(-1, 1) == jnp.arange(E),
+            load = jnp.sum(local[:, None] == jnp.arange(E),
                            axis=0, dtype=jnp.int32)
-            rows = _dispatch(xf, order, inv, held)
-            with jax.named_scope(_stepprogram.MOE_EXPERTS_SCOPE):
-                _MOE_LOWERED.labels("ragged_dot").inc()
-                h = f(jax.lax.ragged_dot(rows, params["Eg"], load)) \
-                    * jax.lax.ragged_dot(rows, params["Eu"], load)
-                ys = jax.lax.ragged_dot(h, params["Ed"], load)
-            ys = _unpermute(ys, order, inv).reshape(M, k, -1)
-            routed = jnp.sum(jnp.where(held[..., None], ys, 0)
-                             * gate[..., None].astype(ys.dtype), axis=1)
+            _MOE_LOWERED.labels("compact").inc()
+            routed = _routed(xf, gate, params["Eg"], params["Eu"],
+                             params["Ed"], order, inv, load, rows, f)
+            ran = jnp.clip(-(-jnp.sum(load) // rows), 1, None)
         out = routed + (f(xf @ params["Sg"]) * (xf @ params["Su"])) \
             @ params["Sd"] if self.n_shared else routed
         new_state = {"select_bias": state["select_bias"],
                      "expert_load": jax.lax.stop_gradient(
-                         load.astype(jnp.float32))}
+                         load.astype(jnp.float32)),
+                     "pass_steps": state["pass_steps"] + (
+                         jnp.arange(state["pass_steps"].shape[0])
+                         == ran - 1)}
         if self.keep_selected:
-            rows = self.keep_selected
+            keep = self.keep_selected
             new_state["selected"] = jnp.pad(
-                sel[:rows].astype(jnp.int32),
-                ((0, max(rows - M, 0)), (0, 0)), constant_values=-1)
+                sel[:keep].astype(jnp.int32),
+                ((0, max(keep - M, 0)), (0, 0)), constant_values=-1)
         return out.reshape(x.shape[:-1] + (self.nOut,)).astype(x.dtype), \
             new_state
 

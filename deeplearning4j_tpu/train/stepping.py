@@ -94,6 +94,12 @@ MOE_EXPERT_LOAD = _prof.get_registry().gauge(
     "Routed pairs at each expert held here (its id among all the "
     "router's) at the last step of the last fit",
     labelnames=("layer", "expert"))
+MOE_PASS_STEPS = _prof.get_registry().gauge(
+    "dl4j_moe_pass_steps",
+    "Steps a sparse-expert layer has run that took this many passes over "
+    "its held pairs (1: they fit the first pass's rows), all the steps "
+    "its state has seen up to the last fit's last",
+    labelnames=("layer", "passes"))
 LM_LOSS = _prof.get_registry().gauge(
     "dl4j_lm_loss",
     "Mean cross-entropy of the main head and of each multi-token-"
@@ -213,21 +219,24 @@ def epoch_span(model):
 def publish_loop_gauges(model) -> None:
     """``dl4j_loop_exit_mass`` / ``dl4j_loop_pass_loss`` from the state the
     last step left in a graph's looped heads, and ``dl4j_moe_held_pairs``
-    / ``dl4j_moe_expert_load`` / ``dl4j_lm_loss`` from its sparse-expert
-    layers' and shared heads'. One device-to-host read, so
-    only while instrumentation is active and only once a ``fit`` call has
-    dispatched its last step: it adds no sync inside the loop."""
+    / ``dl4j_moe_expert_load`` / ``dl4j_moe_pass_steps`` / ``dl4j_lm_loss``
+    from its sparse-expert layers' and shared heads'. One device-to-host
+    read, so only while instrumentation is active and only once a ``fit``
+    call has dispatched its last step: it adds no sync inside the loop."""
     if not _prof.instrumentation_active():
         return
     for name, state in getattr(model, "_states", {}).items():
         if not isinstance(state, dict):
             continue
         if "expert_load" in state:
-            load = jax.device_get(state["expert_load"])
+            load, ran = jax.device_get((state["expert_load"],
+                                        state["pass_steps"]))
             held = model.conf.node_by_name[name].obj.held
             MOE_HELD_PAIRS.labels(name).set(float(load.sum()))
             for e, n in zip(held, load):
                 MOE_EXPERT_LOAD.labels(name, str(e)).set(float(n))
+            for p, n in enumerate(ran):
+                MOE_PASS_STEPS.labels(name, str(p + 1)).set(float(n))
         if "head_loss" in state:
             for d, loss in enumerate(jax.device_get(state["head_loss"])):
                 LM_LOSS.labels("main" if d == 0 else "mtp" if d == 1

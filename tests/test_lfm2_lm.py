@@ -496,6 +496,36 @@ def test_without_a_shared_expert_against_every_expert_on_every_token():
     assert float(jnp.sum(new["expert_load"])) == 2 * 24 * 4
 
 
+@pytest.mark.parametrize("lift", [0.0, 10.0])
+def test_the_routed_sum_alone_with_and_without_a_second_pass(lift):
+    """2 of 16 held: a pass takes 48 of the 192 pairs' rows. With the
+    bias lifted every token takes both held experts, 96 pairs in two
+    passes; the layer without a shared expert and its gradient are the
+    plain form's either way."""
+    layer = _moe([2, 11], nSharedExperts=0)
+    p, st = layer.initialize(jax.random.PRNGKey(5))
+    st["select_bias"] = jnp.zeros(16).at[jnp.asarray([2, 11])].set(lift)
+    x = jax.random.normal(jax.random.PRNGKey(6), (2, 24, 12))
+
+    def mine(p, x):
+        out, new = layer.apply(p, st, x, True, None)
+        return jnp.sum(jnp.square(out)), new
+
+    def plain(p, x):
+        return jnp.sum(jnp.square(_plain_moe(
+            p, st["select_bias"], x.reshape(-1, 12), [2, 11])))
+    (got, new), dgot = jax.value_and_grad(mine, argnums=(0, 1),
+                                          has_aux=True)(p, x)
+    want, dwant = jax.value_and_grad(plain, argnums=(0, 1))(p, x)
+    np.testing.assert_allclose(got, want, rtol=2e-4)
+    for a, b in zip(jax.tree_util.tree_leaves(dgot),
+                    jax.tree_util.tree_leaves(dwant)):
+        np.testing.assert_allclose(a, b, rtol=5e-4, atol=5e-5)
+    pairs = float(jnp.sum(new["expert_load"]))
+    assert pairs == 96 if lift else pairs <= 48
+    assert np.array_equal(new["pass_steps"], np.eye(4)[1 if lift else 0])
+
+
 def test_the_four_shares_add_up_to_the_uncut_layer():
     """The guide's share test: 16 experts in 4 shares of 4, every share
     routing over all 16; the shares' outputs add up to the uncut plain
@@ -759,6 +789,13 @@ def test_the_step_program_carries_the_new_part_and_the_gauges_read():
                  stepping.MOE_HELD_PAIRS.children().items()}
         assert set(pairs) >= set(MODEL.expert_layers_of(cfg))
         assert 0 < pairs["l4_moe"] <= 2 * 32 * 4
+        # 4 of 16 held: two passes at most, and the one step this net has
+        # run stands in one of the two slots of every expert layer
+        for layer in MODEL.expert_layers_of(cfg):
+            ran = [c.value for k, c in
+                   stepping.MOE_PASS_STEPS.children().items()
+                   if k[0] == layer]
+            assert len(ran) == 2 and sorted(ran) == [0.0, 1.0], layer
         maps = stepprogram.maps()
     finally:
         profiler.set_profiling_mode(None)

@@ -10,6 +10,7 @@ import importlib.util
 import json
 import math
 import os
+import re
 
 import jax
 import jax.numpy as jnp
@@ -362,6 +363,119 @@ def test_the_expert_layers_gradient_against_the_plain_form():
     for a, b in zip(jax.tree_util.tree_leaves(got),
                     jax.tree_util.tree_leaves(want)):
         np.testing.assert_allclose(a, b, rtol=5e-4, atol=5e-5)
+
+
+def _quarter_layer():
+    """4 of 32 experts held, 48 tokens x 4: a pass takes 48 of the 192
+    pairs' rows, four passes at most."""
+    layer = _moe([3, 9, 17, 30], n=32)
+    p, st = layer.initialize(jax.random.PRNGKey(4))
+    x = jax.random.normal(jax.random.PRNGKey(5), (2, 24, 12))
+    return layer, p, st, x
+
+
+def _bias_for(layer, p, x, pairs):
+    """A selection bias on the held experts under which exactly ``pairs``
+    of the selected (token, expert) pairs meet one: the count only grows
+    with the bias, a pair at a time."""
+    held = jnp.zeros(layer.n_experts).at[jnp.asarray(layer.held)].set(1.0)
+    lo, hi = -2.0, 2.0
+    for _ in range(60):
+        mid = (lo + hi) / 2
+        sel, _ = layer.route(x.reshape(-1, 12), p["Wr"], mid * held)
+        got = int(jnp.sum(held[sel]))
+        if got == pairs:
+            return mid * held
+        lo, hi = (mid, hi) if got < pairs else (lo, mid)
+    raise AssertionError(f"no bias gives {pairs} held pairs")
+
+
+@pytest.mark.parametrize("pairs", [0, 47, 48, 49, 97, 192])
+def test_the_passes_against_the_plain_form_at_a_planted_held_count(pairs):
+    """None, one short of a pass's rows, just its rows, one more (a
+    second pass for one pair), a third pass's first pair, and every pair
+    of every token: the output, the gradients and the slot of
+    ``pass_steps`` that counts the step."""
+    layer, p, st, x = _quarter_layer()
+    rows = L._routed_rows(192, 4, 32)
+    assert rows == 48 and st["pass_steps"].shape == (4,)
+    st["select_bias"] = _bias_for(layer, p, x, pairs)
+
+    def mine(p, x):
+        out, new = layer.apply(p, st, x, True, jax.random.PRNGKey(0))
+        return jnp.sum(jnp.square(out)), (out, new)
+
+    def plain(p, x):
+        out = _plain_moe(p, st["select_bias"], x.reshape(-1, 12),
+                         layer.held)
+        return jnp.sum(jnp.square(out)), out
+    (_, (got, new)), dgot = jax.value_and_grad(
+        mine, argnums=(0, 1), has_aux=True)(p, x)
+    (_, want), dwant = jax.value_and_grad(
+        plain, argnums=(0, 1), has_aux=True)(p, x)
+    assert float(jnp.sum(new["expert_load"])) == pairs
+    np.testing.assert_allclose(got.reshape(-1, 12), want, rtol=2e-4,
+                               atol=2e-5)
+    for a, b in zip(jax.tree_util.tree_leaves(dgot),
+                    jax.tree_util.tree_leaves(dwant)):
+        np.testing.assert_allclose(a, b, rtol=5e-4, atol=5e-5)
+    slot = max(-(-pairs // rows), 1) - 1
+    assert np.array_equal(new["pass_steps"], np.eye(4)[slot])
+    # a second step adds to the slots, it does not replace them
+    _, again = layer.apply(p, new, x, True, jax.random.PRNGKey(0))
+    assert np.array_equal(again["pass_steps"], 2 * np.eye(4)[slot])
+
+
+def test_a_pass_takes_twice_the_uniform_share_of_the_pairs():
+    assert L.ROUTED_ROWS_OVER_UNIFORM == 2
+    # the two cells: 8 of 64 held, top-4, 32,768 and 4,096 tokens
+    assert L._routed_rows(131072, 8, 64) == 32768
+    assert L._routed_rows(16384, 8, 64) == 4096
+    assert L._routed_passes(8, 64) == 4
+    # rounded up to whole tiles of rows, never beyond the pairs
+    assert L._routed_rows(192, 1, 16) == 24
+    assert L._routed_rows(100, 1, 16) == 16
+    assert L._routed_rows(20, 3, 16) == 8
+    assert L._routed_rows(192, 8, 16) == 192 == L._routed_rows(192, 16, 16)
+    assert L._routed_passes(8, 16) == 1 == L._routed_passes(16, 16)
+    assert L._routed_passes(3, 16) == 3
+    # whatever the batch, no more passes than the state has slots
+    for pairs in (4, 20, 100, 192, 1000):
+        for held, n in ((1, 16), (3, 16), (5, 64)):
+            rows = L._routed_rows(pairs, held, n)
+            assert -(-pairs // rows) <= L._routed_passes(held, n)
+
+
+def _lowered(layer, tokens=64):
+    p, st = layer.initialize(jax.random.PRNGKey(0))
+    x = jnp.zeros((1, tokens, 12))
+
+    def loss(p, x):
+        out, new = layer.apply(p, st, x, True, jax.random.PRNGKey(0))
+        return jnp.sum(out), new
+    return jax.jit(jax.grad(loss, argnums=(0, 1), has_aux=True)).lower(
+        p, x).as_text()
+
+
+def test_a_layer_that_holds_every_expert_lowers_with_no_conditional():
+    text = _lowered(_moe(None))
+    assert "stablehlo.case" not in text and "stablehlo.if" not in text
+    assert "stablehlo.case" in _lowered(_moe([3, 5]))
+
+
+def test_an_eighth_held_lowers_with_no_tensor_of_all_the_pairs_rows():
+    """64 tokens x 4 = 256 pairs, 8 of 64 held: a pass takes 64 rows, and
+    nothing 256 rows long is as wide as the tokens (12) or an expert's
+    inner width (20), forward or backward."""
+    layer = L.SparseExpertsLayer(nExperts=64, nExpertsPerTok=4, nHidden=20,
+                                 heldExperts=list(range(8)),
+                                 weightInit="xavier")
+    layer.infer_nin(InputType.recurrent(12, 64))
+    text = _lowered(layer)
+    assert re.search(r"tensor<64x(12|20)x", text)
+    assert not re.search(r"tensor<256x(12|20)x", text)
+    assert not re.search(r"tensor<64x4x(12|20)x", text)
+    assert re.search(r"tensor<256xi32>", text)      # the pair ids are
 
 
 @pytest.mark.parametrize("rows", [48, 20, 60])
@@ -874,14 +988,25 @@ def test_json_round_trip_keeps_the_new_layers():
     assert again.score(DataSet(x, y)) == net.score(DataSet(x, y))
 
 
-def test_save_and_load_keep_weights_states_and_loss(tmp_path):
+@pytest.mark.parametrize("saved_before", [(), ("pass_steps",)])
+def test_save_and_load_keep_weights_states_and_loss(tmp_path, saved_before):
+    """``saved_before``: state leaves the archive lacks, as one written
+    before the layer had them; they come back as ``init()`` makes them."""
     net, cfg = tiny_net()
     batches = tokens(cfg, 3)
     net.fit(DataSet(*batches[0]))
     path = str(tmp_path / "xing4.zip")
+    states = net._states
+    net._states = {name: {k: v for k, v in state.items()
+                          if k not in saved_before}
+                   for name, state in states.items()}
     net.save(path)
+    net._states = states
     loaded = ComputationGraph.load(path)
     assert loaded.numParams() == net.numParams()
+    # 4 of 16 held: two slots, and the one step stands in one of them
+    assert sorted(loaded._states["l1_moe"]["pass_steps"]) \
+        == ([0.0, 0.0] if saved_before else [0.0, 1.0])
     np.testing.assert_allclose(loaded._states["l1_moe"]["select_bias"],
                                net._states["l1_moe"]["select_bias"])
     net.fit(DataSet(*batches[1]))
@@ -931,10 +1056,11 @@ def test_the_step_program_carries_the_new_parts_and_the_gauges_read(
     profiler.set_profiling_mode("basic")
     try:
         stepprogram.clear()
-        lowered = L._MOE_LOWERED.labels("ragged_dot").value
+        lowered = L._MOE_LOWERED.labels("compact").value
         pairs_lowered = L._MHC_LOWERED.labels("pair").value
         net.fit(DataSet(*tokens(cfg, 1)[0]))
-        assert L._MOE_LOWERED.labels("ragged_dot").value > lowered
+        assert L._MOE_LOWERED.labels("compact").value > lowered
+        assert [k for k in L._MOE_LOWERED.children()] == [("compact",)]
         # once a read or write layer a traced step: one path, one value
         hyper = [n for n in net.conf.topo if isinstance(
             n.obj, (L.HyperConnectionRead, L.HyperConnectionWrite))]
@@ -951,6 +1077,14 @@ def test_the_step_program_carries_the_new_parts_and_the_gauges_read(
                    stepping.MOE_EXPERT_LOAD.children().items()
                    if k[0] == "l1_moe")
         assert load == pairs["l1_moe"] and 0 < load <= 2 * 32 * 4
+        # 4 of 16 held: two passes at most, and the one step this net
+        # has run stands in one of the two slots of every expert layer
+        for layer in ("l1_moe", "l2_moe", "mtp_moe"):
+            ran = {k[1]: c.value for k, c in
+                   stepping.MOE_PASS_STEPS.children().items()
+                   if k[0] == layer}
+            assert set(ran) == {"1", "2"} and sorted(ran.values()) \
+                == [0.0, 1.0], layer
         assert stepping.LM_LOSS.labels("main").value > 0
         assert stepping.LM_LOSS.labels("mtp").value > 0
         maps = stepprogram.maps()
