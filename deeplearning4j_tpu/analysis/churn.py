@@ -81,22 +81,24 @@ class RecompileChurnDetector:
         self._flagged: Set[Tuple[str, int]] = set()
         self._diags: List[Tuple[Optional[int], Diagnostic]] = []
 
-    def record(self, site: str, fingerprint, owner=None) -> Optional[Diagnostic]:
-        """Report one dispatch signature; returns the W201 diagnostic the
-        first time ``site`` (scoped to ``owner``) crosses the threshold."""
+    def record(self, site: str, fingerprint, owner=None) -> bool:
+        """Report one dispatch signature; returns whether it was new to
+        ``site`` (scoped to ``owner``): a first sight, which a jit pays
+        for with a build. The first time a site crosses the threshold its
+        W201 diagnostic is kept for :meth:`diagnostics_for` and warned."""
         key = (site, id(owner) if owner is not None else 0)
         # lock-free fast path for the per-iteration hot loop: a GIL-safe
         # dict/set read suffices once the signature has been seen (the
         # steady-state case — the lock is only taken per NEW signature)
         seen = self._seen.get(key)
         if seen is not None and fingerprint in seen:
-            return None
+            return False
         with self._lock:
             seen = self._seen.get(key)
             if seen is None:
                 seen = self._seen[key] = set()
             if fingerprint in seen:
-                return None
+                return False
             seen.add(fingerprint)
             n = len(seen)
             crossed = n > self.threshold and key not in self._flagged
@@ -104,7 +106,7 @@ class RecompileChurnDetector:
                 self._flagged.add(key)
         self._counter.labels(site=site).inc()
         if not crossed:
-            return None
+            return True
         diag = Diagnostic(
             "DL4J-W201", Severity.WARNING, site,
             f"{n} distinct jit signatures compiled at this site "
@@ -117,7 +119,7 @@ class RecompileChurnDetector:
             self._diags.append((key[1] or None, diag))
         warnings.warn(f"{diag.code} [{site}]: {diag.message}",
                       RuntimeWarning, stacklevel=2)
-        return diag
+        return True
 
     def signature_count(self, site: str, owner=None) -> int:
         key = (site, id(owner) if owner is not None else 0)

@@ -25,18 +25,49 @@ is JAX's own, under ``jit`` and ``lower().compile()`` alike;
 Metrics: ``dl4j_compile_cache_{hits,misses}_total{scope="memory"}`` and
 ``dl4j_compile_seconds{state="cold"}`` (an AOT compile as this process
 saw it: seconds where JAX's persistent cache missed, a fraction of one
-where it hit).
+where it hit). These three and :func:`cache_stats` are the AOT path's
+alone; the main path's builds are the spans below.
+
+**Every build, the main path's too** (:func:`watch_builds`): a program a
+never-warmed ``CachedDispatch``, ``init()`` or any other ``jax.jit``
+builds is traced, lowered and handed to the backend inside one untimed
+call. JAX reports each of the three per program
+(``jax.monitoring``); one listener a process puts what it hears into the
+tracer's ring as it hears it, on the ring's own clock, whatever the
+profiling mode (a build happens once a program, not once a step):
+
+- ``compile:trace`` / ``compile:lower`` / ``compile:backend``, args
+  ``program`` (the function's name, one spelling for all three) and
+  ``cause`` (the ``fit:build`` or ``net:init`` open on this thread, else
+  ``None``); on ``compile:backend`` also ``cache`` (``hit``: JAX's
+  persistent cache answered, the span is its read, deserialise and load;
+  ``miss``: the backend compiled; ``off``: no persistent cache was
+  asked) and ``retrieval_s``. A function jitted inside the step is
+  traced while the step's trace is open, so ``compile:trace`` spans
+  nest: a reader takes the union of their intervals, and which span lies
+  in which follows from ``ts`` and ``dur``;
+- ``fit:build`` (``train.stepping.dispatch``) around the ``step(*args)``
+  of a dispatch during which this thread built a program, and
+  ``net:init`` (:func:`cause_span`) around a network's ``init()``.
+
+The seconds of each stage and the hits and misses are sums over these
+spans; no counter repeats them.
 
 jax-free at module scope; jax loads lazily, on the compile path.
 """
 
 from __future__ import annotations
 
+import re
+import threading
 import time
 import warnings
+from contextlib import contextmanager
 from typing import Optional
 
 from deeplearning4j_tpu.profiler.metrics import get_registry
+from deeplearning4j_tpu.profiler.tracer import get_tracer, now_us
+from deeplearning4j_tpu.utils.environment import jax_compile_cache_status
 
 _REG = get_registry()
 CACHE_HITS = _REG.counter(
@@ -70,7 +101,9 @@ _STATS = {"memory_hits": 0, "memory_misses": 0,
 
 def cache_stats() -> dict:
     """Per-process snapshot: in-process table hit/miss counts and the
-    AOT compiles this process ran, with their seconds."""
+    AOT compiles this process ran, with their seconds. The AOT path's
+    alone: the main path's builds are the ``compile:*`` spans of
+    :func:`watch_builds`."""
     return {
         "memory": {"hits": _STATS["memory_hits"],
                    "misses": _STATS["memory_misses"]},
@@ -125,7 +158,7 @@ class CachedDispatch:
     never pays this — that path IS plain jit.
     """
 
-    __slots__ = ("_jit", "scope", "_compiled", "_warned")
+    __slots__ = ("_jit", "scope", "_compiled", "_warned", "dispatched")
 
     def __init__(self, fn, scope: str, donate_argnums=()):
         import jax
@@ -133,6 +166,9 @@ class CachedDispatch:
         self.scope = scope                  # names the program in warnings
         self._compiled = {}
         self._warned = False
+        # set by train.stepping.dispatch: until then the next dispatch
+        # builds, whatever the churn detector remembers of the signature
+        self.dispatched = False
 
     # ------------------------------------------------------------- call
     def _signature(self, args):
@@ -208,7 +244,176 @@ class CachedDispatch:
 
 def cached_dispatch(fn, scope: str, donate_argnums=()) -> CachedDispatch:
     """The seam the networks' step caches call instead of ``jax.jit``."""
+    watch_builds()
     return CachedDispatch(fn, scope, donate_argnums=donate_argnums)
+
+
+# ------------------------------------------------- builds, wherever made
+NET_INIT = "net:init"
+FIT_BUILD = "fit:build"
+COMPILE_TRACE = "compile:trace"
+COMPILE_LOWER = "compile:lower"
+COMPILE_BACKEND = "compile:backend"
+
+_JAX_EVENTS = "/jax/core/compile/"
+# JAX's event -> span; the last two name the program ``jit(<name>)`` /
+# ``pmap(<name>)`` (``jit_<name>`` in older releases), the first by the
+# function's own name, which is the spelling kept
+_WRAPPED = re.compile(r"(?:jit|pmap)(?:\((.*)\)|_(.*))$")
+_SPANS = {
+    _JAX_EVENTS + "jaxpr_trace_duration": COMPILE_TRACE,
+    _JAX_EVENTS + "jaxpr_to_mlir_module_duration": COMPILE_LOWER,
+    _JAX_EVENTS + "backend_compile_duration": COMPILE_BACKEND,
+}
+_CACHE_EVENTS = "/jax/compilation_cache/"
+_CACHE_HIT = _CACHE_EVENTS + "cache_hits"
+# fires when a program the cache lacked has been compiled and written
+_CACHE_MISS = _CACHE_EVENTS + "cache_misses"
+_CACHE_RETRIEVAL = _CACHE_EVENTS + "cache_retrieval_time_sec"
+
+
+class _ThreadBuilds:
+    """What one thread's builds share between the listener's calls.
+
+    ``cause``: the span open on this thread that the next build belongs
+    to (:class:`BuildCause`). ``heard``: the ``compile:*`` events heard on
+    this thread, which a cause compares before and after. ``cache`` /
+    ``retrieval_s``: what JAX's cache said since the last
+    ``compile:backend``.
+    """
+
+    __slots__ = ("cause", "heard", "cache", "retrieval_s")
+
+    def __init__(self):
+        self.cause = None
+        self.heard = 0
+        self.cache = None
+        self.retrieval_s = None
+
+
+_TLS = threading.local()
+_WATCH_LOCK = threading.Lock()
+_WATCHING = False
+_LISTENER_WARNED = False
+
+
+def thread_builds() -> _ThreadBuilds:
+    st = getattr(_TLS, "builds", None)
+    if st is None:
+        st = _TLS.builds = _ThreadBuilds()
+    return st
+
+
+def watch_builds() -> None:
+    """Register the one duration listener and the one event listener of
+    this process with ``jax.monitoring``; every later call returns at
+    once. Called where programs are made: ``init()`` of both network
+    classes and :func:`cached_dispatch`."""
+    global _WATCHING
+    if _WATCHING:
+        return
+    with _WATCH_LOCK:
+        if _WATCHING:
+            return
+        import jax
+        jax.monitoring.register_event_duration_secs_listener(_on_duration)
+        jax.monitoring.register_event_listener(_on_event)
+        _WATCHING = True
+
+
+def _listener_failed(err: BaseException) -> None:
+    """A listener that raises would fail the dispatch JAX called it
+    from: swallowed, said once."""
+    global _LISTENER_WARNED
+    if not _LISTENER_WARNED:
+        _LISTENER_WARNED = True
+        warnings.warn(
+            f"compile cache: the build listener failed "
+            f"({type(err).__name__}: {err}); compile:* spans may be "
+            "missing from here on", stacklevel=3)
+
+
+def _on_event(event, **_kw):
+    try:
+        if event == _CACHE_HIT:
+            thread_builds().cache = "hit"
+        elif event == _CACHE_MISS:
+            thread_builds().cache = "miss"
+    except Exception as e:
+        _listener_failed(e)
+
+
+def _on_duration(event, duration, **kw):
+    try:
+        _heard(event, duration, kw.get("fun_name"))
+    except Exception as e:
+        _listener_failed(e)
+
+
+def _heard(event, duration, fun_name):
+    """One of JAX's three durations, reported as it ended: a span into
+    the ring that ends now and began ``duration`` earlier. An inner
+    trace ends, and so arrives, before the trace it lies in."""
+    span = _SPANS.get(event)
+    if span is None:
+        if event == _CACHE_RETRIEVAL:
+            thread_builds().retrieval_s = duration
+        return
+    st = thread_builds()
+    st.heard += 1
+    dur_us = duration * 1e6
+    program = str(fun_name)
+    wrapped = _WRAPPED.match(program)
+    if wrapped:
+        program = wrapped.group(1) or wrapped.group(2)
+    args = {"program": program, "cause": st.cause}
+    if span == COMPILE_BACKEND:
+        # a miss too small or too quick to be written fires no event
+        args["cache"] = st.cache or (
+            "miss" if jax_compile_cache_status()[0] else "off")
+        args["retrieval_s"] = st.retrieval_s
+        st.cache = st.retrieval_s = None
+    get_tracer().add_event(span, now_us() - dur_us, dur_us, args)
+
+
+class BuildCause:
+    """``name`` as the ``cause`` of every program this thread builds from
+    here to :meth:`close`, which says whether it built any; :meth:`record`
+    then puts the span between the two into the ring, whatever the
+    profiling mode. Where nothing was built and nothing is recorded this
+    costs two clock reads and one compare."""
+
+    __slots__ = ("name", "_st", "_outer", "_heard", "_t0", "_t1")
+
+    def __init__(self, name: str):
+        self.name = name
+        st = self._st = thread_builds()
+        self._outer, self._heard, st.cause = st.cause, st.heard, name
+        self._t0 = now_us()
+
+    def close(self) -> bool:
+        self._t1 = now_us()
+        self._st.cause = self._outer
+        return self._st.heard != self._heard
+
+    def record(self, args: dict) -> None:
+        get_tracer().add_event(self.name, self._t0, self._t1 - self._t0,
+                               args)
+
+
+@contextmanager
+def cause_span(name: str):
+    """A span that is the ``cause`` of every program this thread builds
+    inside it (``net:init``), recorded whether it built any or not.
+    Yields the dict of its args, for what is known only at its end."""
+    watch_builds()
+    cause = BuildCause(name)
+    args = {}
+    try:
+        yield args
+    finally:
+        cause.close()
+        cause.record(args)
 
 
 # ----------------------------------------------------------------- warmup

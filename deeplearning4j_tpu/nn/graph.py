@@ -670,15 +670,20 @@ class ComputationGraph:
         if strict:
             self.validate().raise_if_errors()
         seed = self.conf.base.seed if seed is None else seed
-        key = jax.random.PRNGKey(seed)
-        self._params, self._states = {}, {}
-        for node in self.conf.topo:
-            if node.kind == "layer":
-                key, sub = jax.random.split(key)
-                p, s = node.obj.initialize(sub)
-                tied = self.conf.param_owner[node.name] != node.name
-                self._params[node.name] = {} if tied else p
-                self._states[node.name] = s
+        # net:init: the cause of the small programs made below
+        with _cc.cause_span(_cc.NET_INIT) as made:
+            key = jax.random.PRNGKey(seed)
+            self._params, self._states = {}, {}
+            for node in self.conf.topo:
+                if node.kind == "layer":
+                    key, sub = jax.random.split(key)
+                    p, s = node.obj.initialize(sub)
+                    tied = self.conf.param_owner[node.name] != node.name
+                    self._params[node.name] = {} if tied else p
+                    self._states[node.name] = s
+            made["parameters"] = self.numParams()
+            made["leaves"] = len(
+                jax.tree_util.tree_leaves(self._params))
         self._opt_state = None
         self._train_step_cache = {}
         self._megastep_cache = {}
@@ -1567,7 +1572,7 @@ class ComputationGraph:
             lmasks = [stage(ds.labels_mask)] if ds.labels_mask is not None else None
         spans.phase(_stepping.FIT_PREPARE)
         # recompile-churn seam (see MultiLayerNetwork._fit_one)
-        _churn.get_churn_detector().record(
+        new_sig = _churn.get_churn_detector().record(
             "ComputationGraph.fit",
             _churn.array_fingerprint(
                 [ins[k] for k in sorted(ins)], labels, lmasks), owner=self)
@@ -1601,8 +1606,8 @@ class ComputationGraph:
         if dyn:     # dynamic loss scale: an extra donated carry
             args.append(self._ensure_scale_state())
         args += [ins, labels, lmasks if lmasks is not None else dummy]
-        spans.note(step, args)
-        out = step(*args)
+        out = _stepping.dispatch(self, step, args, spans,
+                                 "ComputationGraph.fit", new_sig)
         spans.phase(_stepping.FIT_COMMIT)
         with _stepping.dispatch_commit(self, gen) as ok:
             if not ok:      # elastic recovery rolled this step back while
@@ -1655,7 +1660,7 @@ class ComputationGraph:
             lmasks = [stage(mb.labels_mask)] \
                 if mb.labels_mask is not None else None
         spans.phase(_stepping.FIT_PREPARE)
-        _churn.get_churn_detector().record(
+        new_sig = _churn.get_churn_detector().record(
             "ComputationGraph.megastep",
             _churn.array_fingerprint(
                 [ins[k] for k in sorted(ins)], labels, lmasks), owner=self)
@@ -1676,8 +1681,8 @@ class ComputationGraph:
         if dyn:     # dynamic loss scale: an extra scanned carry
             args.append(self._ensure_scale_state())
         args += [ins, labels, lmasks if lmasks is not None else dummy]
-        spans.note(step, args)
-        out = step(*args)
+        out = _stepping.dispatch(self, step, args, spans,
+                                 "ComputationGraph.megastep", new_sig, k)
         spans.phase(_stepping.FIT_COMMIT)
         with _stepping.dispatch_commit(self, gen) as ok:
             if not ok:

@@ -258,13 +258,18 @@ class MultiLayerNetwork:
         if strict:
             self.validate().raise_if_errors()
         seed = self.conf.base.seed if seed is None else seed
-        key = jax.random.PRNGKey(seed)
-        self._params, self._states = [], []
-        for layer in self.layers:
-            key, sub = jax.random.split(key)
-            p, s = layer.initialize(sub)
-            self._params.append(p)
-            self._states.append(s)
+        # net:init: the cause of the small programs made below
+        with _cc.cause_span(_cc.NET_INIT) as made:
+            key = jax.random.PRNGKey(seed)
+            self._params, self._states = [], []
+            for layer in self.layers:
+                key, sub = jax.random.split(key)
+                p, s = layer.initialize(sub)
+                self._params.append(p)
+                self._states.append(s)
+            made["parameters"] = self.numParams()
+            made["leaves"] = len(
+                jax.tree_util.tree_leaves(self._params))
         self._opt_state = None
         self._train_step_cache = {}
         self._megastep_cache = {}
@@ -932,7 +937,7 @@ class MultiLayerNetwork:
         spans.phase(_stepping.FIT_PREPARE)
         # recompile-churn seam: every distinct (shape, dtype) signature
         # here is one XLA compile of the train step
-        _churn.get_churn_detector().record(
+        new_sig = _churn.get_churn_detector().record(
             "MultiLayerNetwork.fit",
             _churn.array_fingerprint(x, y, fmask, lmask), owner=self)
         sig = (fmask is not None, lmask is not None)
@@ -974,8 +979,8 @@ class MultiLayerNetwork:
             args.append(self._ensure_scale_state())
         args += [x, y, fmask if fmask is not None else dummy,
                  lmask if lmask is not None else dummy]
-        spans.note(step, args)
-        out = step(*args)
+        out = _stepping.dispatch(self, step, args, spans,
+                                 "MultiLayerNetwork.fit", new_sig)
         spans.phase(_stepping.FIT_COMMIT)
         with _stepping.dispatch_commit(self, gen) as ok:
             if not ok:      # elastic recovery rolled this step back while
@@ -1022,7 +1027,7 @@ class MultiLayerNetwork:
         fmask = _stepping.stage_batch(self, mb.features_mask, mega=True)
         lmask = _stepping.stage_batch(self, mb.labels_mask, mega=True)
         spans.phase(_stepping.FIT_PREPARE)
-        _churn.get_churn_detector().record(
+        new_sig = _churn.get_churn_detector().record(
             "MultiLayerNetwork.megastep",
             _churn.array_fingerprint(x, y, fmask, lmask), owner=self)
         sig = (fmask is not None, lmask is not None)
@@ -1043,8 +1048,8 @@ class MultiLayerNetwork:
             args.append(self._ensure_scale_state())
         args += [x, y, fmask if fmask is not None else dummy,
                  lmask if lmask is not None else dummy]
-        spans.note(step, args)
-        out = step(*args)
+        out = _stepping.dispatch(self, step, args, spans,
+                                 "MultiLayerNetwork.megastep", new_sig, k)
         spans.phase(_stepping.FIT_COMMIT)
         with _stepping.dispatch_commit(self, gen) as ok:
             if not ok:

@@ -45,8 +45,16 @@ multi-window burn-rate gates, ``dl4j_slo_burn_rate``), and
 :mod:`flightrec` (always-on crash flight recorder dumping debug
 bundles on NonfiniteAttributionError / dispatch timeout / dead peer).
 
-Everything is near-zero-cost when disabled: one module-level flag / enum
-read before any span or sample is allocated.
+Everything per step is near-zero-cost when disabled: one module-level
+flag / enum read before any span or sample is allocated. **"Off" means
+nothing per step**, not nothing at all: what happens once a program
+(``net:init`` around a network's ``init()``, ``fit:build`` around a
+dispatch that built its step, and ``compile:trace`` / ``compile:lower`` /
+``compile:backend`` for every program JAX builds, each naming the span
+that caused it; ``nn.compilecache.watch_builds``) goes into the ring
+whatever the mode: the seconds before the first step run with
+instrumentation off everywhere, and a dispatch that builds nothing pays
+two clock reads and one compare for it.
 """
 
 import gc as _gc

@@ -41,6 +41,7 @@ import numpy as np
 
 from deeplearning4j_tpu import profiler as _prof
 from deeplearning4j_tpu.data.dataset import DataSet, MultiDataSet
+from deeplearning4j_tpu.nn import compilecache as _cc
 from deeplearning4j_tpu.profiler import sanitizer as _sanitizer
 
 # How many update steps the most recent compiled dispatch performed.
@@ -116,6 +117,7 @@ FIT_PREPARE = "fit:prepare"
 FIT_LISTENERS = "fit:listeners"
 FIT_DISPATCH = "fit:dispatch"
 FIT_COMMIT = "fit:commit"
+FIT_BUILD = _cc.FIT_BUILD
 
 
 class _NoSpans:
@@ -188,6 +190,43 @@ class StepSpans:
             args["steps"] = self.steps
             _STEP_SECONDS.observe(seconds)
         _prof.get_tracer().add_event(name, self._t0u, seconds * 1e6, args)
+
+
+def dispatch(model, step, args, spans, site: str, new_signature: bool,
+             steps: int = 1):
+    """``step(*args)``: the compiled dispatch of ``_fit_one`` /
+    ``_fit_mega``, inside ``spans``' ``fit:dispatch``. Where this thread
+    built a program meanwhile (the listener of ``nn.compilecache`` heard
+    one: a first sight of the signature, or a step cache dropped and made
+    again at an old one) a ``fit:build`` span around the call goes into
+    the ring, the ``cause`` of the ``compile:*`` spans inside it, with
+    the churn ``site``, the ``iteration`` that paid, ``steps`` and
+    whether the churn detector called the signature new. That is
+    recorded whatever the profiling mode: a build happens once a
+    program. A dispatch that builds nothing records nothing
+    (``nn.compilecache.BuildCause``). While instrumentation is on a build
+    that can be foreseen (a new signature, a step that never dispatched)
+    is also open as ``dl4j:fit:build`` in a ``jax.profiler`` trace, under
+    its step's ``dl4j:fit:dispatch``; one that cannot (the same step
+    built again) has its ring span alone."""
+    spans.note(step, args)
+    ann = None
+    if spans is not _OFF and (new_signature or not step.dispatched):
+        ann = jax.profiler.TraceAnnotation(
+            "dl4j:" + FIT_BUILD, iteration=model._iteration + 1)
+        ann.__enter__()
+    cause = _cc.BuildCause(FIT_BUILD)
+    try:
+        return step(*args)
+    finally:
+        built = cause.close()
+        step.dispatched = True
+        if ann is not None:
+            ann.__exit__(None, None, None)
+        if built:
+            cause.record({"site": site, "iteration": model._iteration + 1,
+                          "steps": steps, "new_signature": new_signature,
+                          "parent": FIT_DISPATCH})
 
 
 def step_spans(model, steps: int = 1):

@@ -249,13 +249,14 @@ class TestChurnDetector:
             warnings.simplefilter("always")
             results = [det.record("test.site", (("shape", i),))
                        for i in range(5)]
-        assert results[:3] == [None, None, None]
-        assert isinstance(results[3], Diagnostic)       # 4th distinct > 3
-        assert results[3].code == "DL4J-W201"
-        assert results[4] is None                       # flagged once
-        assert any("DL4J-W201" in str(w.message) for w in caught)
-        # repeats are free
-        assert det.record("test.site", (("shape", 0),)) is None
+        assert results == [True] * 5        # every first sight says so
+        flagged = [w for w in caught if "DL4J-W201" in str(w.message)]
+        assert len(flagged) == 1                        # 4th distinct > 3,
+        assert "4 distinct" in str(flagged[0].message)  # flagged once
+        diag, = det.diagnostics_for(None)
+        assert isinstance(diag, Diagnostic) and diag.code == "DL4J-W201"
+        # repeats are free, and say they are repeats
+        assert det.record("test.site", (("shape", 0),)) is False
         assert det.signature_count("test.site") == 5
         child = reg.get("dl4j_recompiles_total").children()[("test.site",)]
         assert child.value == 5

@@ -148,17 +148,29 @@ def test_megastep_dispatch_span_carries_its_steps(instrumented):
 
 
 def test_off_nothing_moves():
+    """OFF means nothing per step. What happens once a program (its
+    build: ``net:init``, ``fit:build``, ``compile:*``) is recorded
+    whatever the mode; a dispatch that builds nothing records nothing."""
     prof.set_profiling_mode(prof.ProfilingMode.OFF)
     try:
         prof.get_tracer().clear()
         stepprogram.clear()
         net = _mln()
         c0 = stepping.TRAIN_H2D_BYTES.value
+        t0 = stepping.TRAIN_TOKENS.value
+        i0 = stepping.TRAIN_ITERATIONS.value
         wait = prof.get_registry().get("dl4j_train_data_wait_seconds")
         w0 = wait.count if wait is not None else 0
         net.fit(_batches(3))
-        assert len(prof.get_tracer()) == 0
+        names = {e["name"] for e in prof.get_tracer().events()}
+        assert {"net:init", "fit:build", "compile:trace", "compile:lower",
+                "compile:backend"} >= names >= {"net:init", "fit:build"}
+        builds = len(prof.get_tracer())
+        net.fit(_batches(3))        # the step is built: nothing is added
+        assert len(prof.get_tracer()) == builds
         assert stepping.TRAIN_H2D_BYTES.value == c0
+        assert stepping.TRAIN_TOKENS.value == t0
+        assert stepping.TRAIN_ITERATIONS.value == i0
         wait = prof.get_registry().get("dl4j_train_data_wait_seconds")
         assert (wait.count if wait is not None else 0) == w0
         assert stepprogram._PENDING == [] and stepprogram._MAPS == {}
