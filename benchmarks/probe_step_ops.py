@@ -193,7 +193,9 @@ def main(argv=None) -> int:
     t0 = time.perf_counter()
     fit(1)
     print(args.tag, "first step s", round(time.perf_counter() - t0, 1),
-          flush=True)
+          "paths lowered", [line for line in profiler.get_registry()
+                            .exposition().split("\n")
+                            if "_lowered_total{" in line], flush=True)
     # the step as compiled, with this tree's scopes in it (what
     # ``stepprogram.flush`` would parse when the mode is left)
     text = stepprogram._compiled_text(*stepprogram._PENDING[0])

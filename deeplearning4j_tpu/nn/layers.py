@@ -2777,13 +2777,13 @@ def _logits_bwd(res, g):
 _logits.defvjp(_logits_fwd, _logits_bwd)
 
 
-#: bytes of the float32 logits [N, T, block] that :func:`blocked_cross_
-#: entropy` has in flight at once: the vocabulary is taken in blocks of the
-#: largest power of two of columns whose logits fit, one block and one pass
-#: after the other, and the compiler then keeps a block's logits,
-#: probabilities and their rounded cotangent on chip instead of in HBM
-#: (the finding of ``ops.attention.CAUSAL_SCORE_BYTES``, on the one tensor
-#: of a language model's step that is larger than any score block).
+#: bytes of the float32 logits of one tile, rows x columns, that
+#: :func:`blocked_cross_entropy` has in flight at once: positions and
+#: vocabulary are taken a tile and a pass after the other, and the compiler
+#: then keeps a tile's logits, probabilities and their rounded cotangent on
+#: chip instead of in HBM (the finding of ``ops.attention.
+#: CAUSAL_SCORE_BYTES``, on the one tensor of a language model's step that
+#: is larger than any score block).
 #: Measured on a v5e, forward + backward of four passes [1, 4096, 2048]
 #: bf16 under a float32 head [2048, 49152], ms a call (PR 32; the
 #: checkpointed autodiff path replaced: 112.9, a pass alone 28.2):
@@ -2795,22 +2795,53 @@ _logits.defvjp(_logits_fwd, _logits_bwd)
 #:
 #: flat, since at every width the 16 products run at 82-97% of the MXU's
 #: peak; 32 MiB makes half as many blocks to compile as 16.
+#: The same, one pass bf16[4, 8192, 2048] under a float32 table
+#: [8192, 2048] (PR 38, ``benchmarks/probe_head_tiles.py``, one chip call;
+#: the products alone take 22.4 ms at the peak):
+#:
+#:     rows x columns a tile   32,768 x 256    8,192 x 1,024   4,096 x 2,048
+#:     bytes a tile                  32 MiB           32 MiB          32 MiB
+#:     ms a call                      46.70            24.36           24.28
+#:     temporaries, MiB               265.9            181.1            93.1
+#:
+#:     rows x columns a tile  2,048 x 4,096    4,096 x 1,024
+#:     bytes a tile                  32 MiB           16 MiB
+#:     ms a call                      24.53            24.09
+#:     temporaries, MiB                21.5             89.4
+#:
+#: all rows in a block (the first, what every shape took before PR 38)
+#: carries a float32 ``dh`` of all rows through HBM once a block; once the
+#: rows go in blocks the shape of a tile no longer matters to a quarter of
+#: a millisecond, and :func:`_head_tile` takes the squarest.
 HEAD_LOGIT_BYTES = 32 << 20
 
 _HEAD_LOWERED = _prof.get_registry().counter(
     "dl4j_head_loss_lowered_total",
     "Traces of nn.layers.blocked_cross_entropy (one a lowering of each "
-    "call site, not one a step) by the path its shapes took: vocabulary "
-    "blocks, or one block because nOut is no multiple of the block",
+    "call site, not one a step) by the path its shapes took: tiled (row "
+    "blocks, each over its vocabulary blocks), blocked (all rows, "
+    "vocabulary blocks), or single (all rows, one block because nOut is "
+    "no multiple of the block)",
     labelnames=("path",))
 
 
-def _head_block(rows, n_out):
-    """Columns a vocabulary block takes for ``rows`` positions: one path,
-    whose block count follows from what it is handed."""
-    fit = max(HEAD_LOGIT_BYTES // (4 * rows), 1)
-    blk = 1 << (fit.bit_length() - 1)
-    return blk if blk < n_out and n_out % blk == 0 else n_out
+def _head_tile(rows, n_out):
+    """``(rows, columns)`` of a tile for ``rows`` positions under ``n_out``
+    outputs: one path, whose tile counts follow from what it is handed.
+    A tile's ``r x c`` float32 logits are the budget, :data:`HEAD_LOGIT_
+    BYTES`. Every column block adds into a row block's float32 ``dh`` and
+    every row block into a column block's float32 ``dw``, so ``dh``'s
+    traffic grows with ``n_out / c``, ``dw``'s with ``rows / r`` and their
+    sum is least near ``r = c``: a tile takes no more rows than the power
+    of two nearest the square's side (4,096 at 32 MiB, whose side is
+    2,896), all of them where there are no more, and the power of two of
+    columns that then fits (an ``n_out`` that is no multiple of it: all
+    columns)."""
+    budget = HEAD_LOGIT_BYTES // 4
+    r = min(rows, 1 << (budget.bit_length() // 2))
+    fit = max(budget // r, 1)
+    c = 1 << (fit.bit_length() - 1)
+    return r, (c if c < n_out and n_out % c == 0 else n_out)
 
 
 #: the head's three products by the axis of ``w`` the vocabulary lies on:
@@ -2865,59 +2896,92 @@ def _ce_bwd_block(h, w, labels, lse, g, dh, dw, v0, blk, axis=1):
             jax.lax.dynamic_update_slice_in_dim(dw, dwb, v0, axis=axis))
 
 
-def _ce_fwd(hs, w, labels, blk, axis=1):
-    """``(ce, lse)`` [P, N, T] float32 of the passes ``hs``, a block and a
-    pass after the other; nothing of [T, nOut] leaves a block."""
-    parts = []
+def _row_block(x, r0, rows):
+    """Rows ``r0..`` of ``x`` [1, all rows, ..] (the last block may be
+    short); ``x`` itself, whatever its leading sizes, where a tile takes
+    all there are."""
+    return x if x.shape[0] * x.shape[1] <= rows \
+        else jax.lax.slice_in_dim(x, r0, min(r0 + rows, x.shape[1]), axis=1)
+
+
+def _whole(blocks):
+    """The row blocks' results end to end."""
+    return blocks[0] if len(blocks) == 1 else jnp.concatenate(blocks, axis=1)
+
+
+def _ce_fwd(hs, w, labels, tile, axis=1):
+    """``(ce, lse)`` [P, N, T] float32 of the passes ``hs``, a tile and a
+    pass after the other; nothing of [rows, nOut] leaves a tile."""
+    (rows, blk), parts = tile, []
     for t, h in enumerate(hs):
         with jax.named_scope(_stepprogram.pass_scope(t + 1)):
-            for v0 in range(0, w.shape[axis], blk):
-                if parts:
-                    # the next block starts once the last has finished:
-                    # one block's [T, block] tensors are alive at a time
-                    h, parts[-1] = attention_ops._then(h, parts[-1])
-                parts.append(_ce_fwd_block(h, w, labels, v0, blk, axis))
-    ces, lses, n = [], [], w.shape[axis] // blk
+            for r0 in range(0, labels.shape[1], rows):
+                yb = _row_block(labels, r0, rows)
+                for v0 in range(0, w.shape[axis], blk):
+                    if parts:
+                        # the next tile starts once the last has finished:
+                        # one tile's [rows, block] tensors are alive at a
+                        # time
+                        h, parts[-1] = attention_ops._then(h, parts[-1])
+                    parts.append(_ce_fwd_block(_row_block(h, r0, rows), w,
+                                               yb, v0, blk, axis))
+    ces, lses = [], []
+    n, a_pass = w.shape[axis] // blk, len(parts) // len(hs)
     for t in range(len(hs)):
         with jax.named_scope(_stepprogram.pass_scope(t + 1)):
-            mine = parts[t * n:(t + 1) * n]
-            lses.append(jax.nn.logsumexp(
-                jnp.stack([lse for lse, _ in mine]), axis=0))
-            ces.append(lses[-1] - sum(picked for _, picked in mine))
+            ce, lse = [], []
+            for i in range(t * a_pass, (t + 1) * a_pass, n):
+                lse.append(jax.nn.logsumexp(
+                    jnp.stack([z for z, _ in parts[i:i + n]]), axis=0))
+                ce.append(lse[-1]
+                          - sum(picked for _, picked in parts[i:i + n]))
+            lses.append(_whole(lse))
+            ces.append(_whole(ce))
     return jnp.stack(ces), jnp.stack(lses)
 
 
-def _ce_bwd(hs, w, labels, lses, gs, blk, axis=1):
+def _ce_bwd(hs, w, labels, lses, gs, tile, axis=1):
     """``(dhs, dw)`` from the cotangents ``gs`` [P, N, T] of the
-    cross-entropies: a pass's ``dh`` summed over the blocks in float32 and
-    rounded once, ``dw`` summed over blocks and passes in ONE float32
-    buffer."""
+    cross-entropies: a row block's ``dh`` summed over its column blocks in
+    float32 and rounded once, ``dw`` summed over tiles and passes in ONE
+    float32 buffer."""
+    rows, blk = tile
     dw = jnp.zeros(w.shape, jnp.float32)
     dhs = []
     for t, h in enumerate(hs):
-        dh = jnp.zeros(h.shape, jnp.float32)
         with jax.named_scope(_stepprogram.pass_scope(t + 1)):
-            for v0 in range(0, w.shape[axis], blk):
-                if t or v0:
-                    h, (dh, dw) = attention_ops._then(h, (dh, dw))
-                dh, dw = _ce_bwd_block(h, w, labels, lses[t], gs[t], dh, dw,
-                                       v0, blk, axis)
-        dhs.append(dh.astype(h.dtype))
+            done = []
+            for r0 in range(0, labels.shape[1], rows):
+                yb = _row_block(labels, r0, rows)
+                dh = jnp.zeros(yb.shape + h.shape[-1:], jnp.float32)
+                for v0 in range(0, w.shape[axis], blk):
+                    if t or r0 or v0:
+                        h, (dh, dw) = attention_ops._then(h, (dh, dw))
+                    # (a pass's ``lses`` and ``gs`` are indexed a tile
+                    # at a time: indexed once a row block, one row block
+                    # no longer lowers to the text tests/test_looped_lm.py
+                    # pins by hash, and two cells' compiled steps with it)
+                    hb, lse, g = (_row_block(a, r0, rows)
+                                  for a in (h, lses[t], gs[t]))
+                    dh, dw = _ce_bwd_block(hb, w, yb, lse, g, dh, dw, v0,
+                                           blk, axis)
+                done.append(dh.astype(h.dtype))
+            dhs.append(_whole(done))
     return tuple(dhs), dw.astype(w.dtype)
 
 
 @functools.partial(jax.custom_vjp, nondiff_argnums=(3, 4))
-def _blocked_ce(hs, w, labels, blk, axis):
-    return _ce_fwd(hs, w, labels, blk, axis)[0]
+def _blocked_ce(hs, w, labels, tile, axis):
+    return _ce_fwd(hs, w, labels, tile, axis)[0]
 
 
-def _blocked_ce_fwd(hs, w, labels, blk, axis):
-    ce, lse = _ce_fwd(hs, w, labels, blk, axis)
+def _blocked_ce_fwd(hs, w, labels, tile, axis):
+    ce, lse = _ce_fwd(hs, w, labels, tile, axis)
     return ce, (hs, w, labels, lse)
 
 
-def _blocked_ce_bwd(blk, axis, res, g):
-    return _ce_bwd(*res, g, blk, axis) + (None,)
+def _blocked_ce_bwd(tile, axis, res, g):
+    return _ce_bwd(*res, g, tile, axis) + (None,)
 
 
 _blocked_ce.defvjp(_blocked_ce_fwd, _blocked_ce_bwd)
@@ -2928,21 +2992,31 @@ def blocked_cross_entropy(hs, w, labels, table: bool = False):
     pass ``h`` [N, T, nIn] in the tuple ``hs`` against the INTEGER
     ``labels`` [N, T], under the master head ``w`` [nIn, nOut] (``table``:
     ``w`` is an embedding's table [nOut, nIn] and the logits ``h @ w^T``,
-    the products taking it as it lies): a forward
-    and a backward written by hand (``jax.custom_vjp``) over vocabulary
-    blocks sized by :data:`HEAD_LOGIT_BYTES`, each pass's ops under its
-    ``dl4j_ut<t>`` scope; an ``nOut`` that is no multiple of the block
-    runs the same pair as one block. What the backward keeps is ``hs``,
+    the products taking it as it lies): a forward and a backward written
+    by hand (``jax.custom_vjp``) over tiles of rows x columns sized by
+    :func:`_head_tile` from ``labels.size`` and ``nOut`` under
+    :data:`HEAD_LOGIT_BYTES`, each pass's ops under its ``dl4j_ut<t>``
+    scope. Up to 4,096 positions a tile takes them all and is a
+    vocabulary block; beyond, the positions of all sequences end to end
+    go in row blocks (the last may be short), each over its vocabulary
+    blocks; an ``nOut`` that is no multiple of the block runs the same
+    pair with all columns in a tile. What the backward keeps is ``hs``,
     ``w``, the labels and the float32 row log-sum-exp [P, N, T], and it
-    runs a block's product again next to the two that consume it, so no
-    [T, nOut] tensor outlives a block and ``w``'s gradient is one buffer
-    for all passes. Logits, log-sum-exp and loss are float32 from operands
-    in ``h``'s dtype; ``w``'s gradient is float32."""
+    runs a tile's product again next to the two that consume it, so no
+    [rows, nOut] tensor outlives a tile; ``h``'s gradient is summed in
+    float32 over the column blocks of ONE row block and rounded once, and
+    ``w``'s gradient is one float32 buffer for all tiles and passes.
+    Logits, log-sum-exp and loss are float32 from operands in ``h``'s
+    dtype; ``w``'s gradient is float32."""
     axis = 0 if table else 1
-    blk = _head_block(labels.size, w.shape[axis])
-    _HEAD_LOWERED.labels("blocked" if blk < w.shape[axis]
-                         else "single").inc()
-    return _blocked_ce(tuple(hs), w, labels, blk, axis)
+    rows, blk = tile = _head_tile(labels.size, w.shape[axis])
+    _HEAD_LOWERED.labels("tiled" if rows < labels.size else "blocked"
+                         if blk < w.shape[axis] else "single").inc()
+    if rows == labels.size:
+        return _blocked_ce(tuple(hs), w, labels, tile, axis)
+    ce = _blocked_ce(tuple(h.reshape(1, -1, h.shape[-1]) for h in hs), w,
+                     labels.reshape(1, -1), tile, axis)
+    return ce.reshape(len(hs), *labels.shape)
 
 
 class LoopedLMOutputLayer(BaseOutputLayer):
@@ -2957,10 +3031,11 @@ class LoopedLMOutputLayer(BaseOutputLayer):
 
     Labels are INTEGER token ids [N, T]. The loss is worked out from the
     layer's input, never from probabilities, by :func:`blocked_cross_
-    entropy`: float32 logits a vocabulary block at a time, of which the
-    backward pass keeps only the row log-sum-exp [P, N, T] beside the
-    hidden states, the head and the labels, and runs a block's product
-    again where it needs it. Its state carries the batch
+    entropy`: float32 logits a tile at a time (all positions x a
+    vocabulary block up to 4,096 positions, row blocks of them beyond),
+    of which the backward pass keeps only the row log-sum-exp [P, N, T]
+    beside the hidden states, the head and the labels, and runs a tile's
+    product again where it needs it. Its state carries the batch
     means of ``p_t`` and ``CE(z_t, y)`` of the last step, for the gauges
     ``dl4j_loop_exit_mass`` / ``dl4j_loop_pass_loss``. ``apply`` (the
     inference forward) gives the last pass's logits. Fed by an ordinary
@@ -4093,7 +4168,14 @@ class MTPLMOutputLayer(BaseOutputLayer):
     (a module's last ``d`` have no label). One head ``W`` for all:
     :func:`blocked_cross_entropy` over the inputs as its passes, a
     module's states moved ``d`` places right so that every pass meets the
-    same labels. Labels are INTEGER ids [N, T]; the state carries each
+    same labels. A block of it is a tile of rows x columns chosen from
+    ``labels.size`` and ``nOut``: one sequence of 4,096 positions is one
+    row block over 2,048-column blocks, four of 8,192 go in eight row
+    blocks of 4,096, each over its column blocks. The backward keeps the
+    heads' inputs, ``W``, the labels and the float32 row log-sum-exp, sums
+    an input's gradient in float32 a row block at a time (rounded once a
+    row block) and ``W``'s in one float32 buffer, and holds no float32
+    value of all rows x nIn. Labels are INTEGER ids [N, T]; the state carries each
     head's loss of the last step (``dl4j_lm_loss``). ``apply`` gives the
     main model's logits. Fed one array it is a plain head. ``tiedWith`` an
     embedding it has no ``W`` of its own: the logits are ``h Emb^T``, the

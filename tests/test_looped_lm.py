@@ -564,8 +564,8 @@ def test_the_blocked_head_against_a_plain_float32_log_softmax(
     block, two passes; the counter says which path, once a traced call."""
     P, N, T, D = 2, 2, 6, 8
     monkeypatch.setattr(L, "HEAD_LOGIT_BYTES", 4 * N * T * block)
-    assert L._head_block(N * T, n_out) == (block if path == "blocked"
-                                           else n_out)
+    assert L._head_tile(N * T, n_out) == (
+        N * T, block if path == "blocked" else n_out)
     rng = np.random.default_rng(n_out + block)
     hs = tuple(jnp.asarray(rng.normal(size=(N, T, D)), dtype)
                for _ in range(P))
@@ -622,6 +622,132 @@ def test_the_blocked_head_passes_check_grads_and_keeps_nothing_of_the_logits():
             (jnp.zeros((1, T, D)),) * P, jnp.zeros((D, V)))[1])
     assert sorted(a.size for a in kept) == [T, P * T, T * D, T * D, D * V]
     assert all(a.size < T * V for a in kept)
+
+
+@pytest.mark.parametrize("passes", [1, 2])
+@pytest.mark.parametrize("dtype", ["float32", "bfloat16"])
+@pytest.mark.parametrize("table", [True, False], ids=["table", "head"])
+@pytest.mark.parametrize("N, T, n_out, tiles", [
+    (2, 24, 24, (3, 3)), (2, 24, 12, (3, 1)), (2, 20, 24, (3, 3))],
+    ids=["rows_x_columns", "rows_x_one_column", "a_short_last_row_block"])
+def test_the_tiled_head_against_a_plain_float32_log_softmax(
+        N, T, n_out, tiles, table, dtype, passes, monkeypatch):
+    """Beyond a tile's rows the positions go in row blocks, each over its
+    vocabulary blocks: ``ce``, every pass's ``dh`` and the summed ``dw``
+    against autodiff of the plain log-softmax, for three row blocks of 16
+    over three column blocks of 8, over one block of 12 columns that is no
+    multiple of 8, and where the last row block holds 8 rows; under a head
+    [nIn, nOut] and an embedding's table [nOut, nIn]; the counter says
+    ``tiled`` once a traced call."""
+    from jax.test_util import check_grads
+    D = 8
+    monkeypatch.setattr(L, "HEAD_LOGIT_BYTES", 4 * 128)
+    rows, block = L._head_tile(N * T, n_out)
+    assert (rows, block) == (16, n_out // tiles[1])
+    assert (-(-N * T // rows), n_out // block) == tiles
+    rng = np.random.default_rng(N * T + n_out)
+    hs = tuple(jnp.asarray(rng.normal(size=(N, T, D)), dtype)
+               for _ in range(passes))
+    w = jnp.asarray(rng.normal(size=(D, n_out)), jnp.float32)
+    y = jnp.asarray(rng.integers(0, n_out, (N, T)), jnp.int32)
+    g = jnp.asarray(rng.normal(size=(passes, N, T)), jnp.float32)
+    before = {p: L._HEAD_LOWERED.labels(p).value
+              for p in ("tiled", "blocked", "single")}
+
+    def tiled(hs, w, y):
+        return L.blocked_cross_entropy(hs, w.T if table else w, y,
+                                       table=table)
+
+    def both(fn, hs, w, y, g):
+        ce, vjp = jax.vjp(lambda hs, w: fn(hs, w, y), hs, w)
+        return (ce,) + vjp(g)
+    fn = jax.jit(lambda *a: both(tiled, *a))
+    ce, dhs, dw = fn(hs, w, y, g)
+    fn(hs, w, y, g)                 # a second call traces nothing
+    assert {p: L._HEAD_LOWERED.labels(p).value - n
+            for p, n in before.items()} \
+        == {"tiled": 1, "blocked": 0, "single": 0}
+    assert ce.dtype == dw.dtype == jnp.float32 \
+        and ce.shape == (passes, N, T)
+    assert all(dh.dtype == h.dtype and dh.shape == h.shape
+               for dh, h in zip(dhs, hs))
+    want_ce, want_dhs, want_dw = jax.jit(
+        lambda *a: both(_plain_cross_entropy, *a))(hs, w, y, g)
+    tol = dict(rtol=1e-5, atol=1e-5) if dtype == "float32" \
+        else dict(rtol=3e-2, atol=6e-2)
+    for got, ref in zip((ce, dw) + dhs, (want_ce, want_dw) + want_dhs):
+        np.testing.assert_allclose(np.asarray(got, np.float32),
+                                   np.asarray(ref, np.float32), **tol)
+    if dtype == "float32":
+        check_grads(lambda hs, w: tiled(hs, w, y), (hs, w), order=1,
+                    modes=("rev",), atol=1e-2, rtol=1e-2, eps=1e-3)
+
+
+def _values(jaxpr):
+    """Every value a jaxpr's equations make, those of the jaxprs they
+    call among them."""
+    for eqn in jaxpr.eqns:
+        yield from (v.aval for v in eqn.outvars)
+        for param in eqn.params.values():
+            inner = getattr(param, "jaxpr", param)
+            if hasattr(inner, "eqns"):
+                yield from _values(inner)
+
+
+def test_a_tile_is_rows_by_columns_and_dh_is_summed_a_row_block_at_a_time():
+    """The rule alone, at the three cells' shapes: 4,096 positions are one
+    row block over 2,048-column blocks (what the two cells at one sequence
+    lowered to before there were row blocks), 4 x 8,192 positions go in
+    tiles of 4,096 x 2,048. At a tiled shape nothing the backward rule
+    makes is a float32 value of rows x nIn elements: ``dh`` is summed a
+    row block at a time and rounded before the blocks are joined."""
+    assert L._head_tile(4096, 49152) == (4096, 2048)
+    assert L._head_tile(4096, 16384) == (4096, 2048)
+    assert L._head_tile(4 * 8192, 8192) == (4096, 2048)
+    assert L._head_tile(3 * 4096 + 8, 8192)[0] == 4096
+    N, T, D, V = 2, 64, 16, 64
+    with pytest.MonkeyPatch.context() as mp:
+        mp.setattr(L, "HEAD_LOGIT_BYTES", 4 * 32 * 16)
+        assert L._head_tile(N * T, V) == (32, 16)
+        lowered = L._HEAD_LOWERED.labels("tiled").value
+        h = jnp.zeros((N, T, D), jnp.bfloat16)
+        _, vjp = jax.vjp(
+            lambda h, w: L.blocked_cross_entropy(
+                (h,), w, jnp.zeros((N, T), jnp.int32), table=True),
+            h, jnp.zeros((V, D)))
+        made = list(_values(jax.make_jaxpr(vjp)(
+            jnp.zeros((1, N, T))).jaxpr))
+    assert L._HEAD_LOWERED.labels("tiled").value == lowered + 1
+    sizes = {(str(a.dtype), a.size) for a in made}
+    assert ("float32", 32 * D) in sizes and ("float32", V * D) in sizes
+    assert ("bfloat16", N * T * D) in sizes
+    assert ("float32", N * T * D) not in sizes
+
+
+#: sha256 of the head's forward + backward lowering at 4,096 positions,
+#: (passes, T, nIn, nOut, table), taken from the tree before there were row
+#: blocks (commit 577310b) under this installation's jax: with all rows in
+#: one block the rule is that program, which is why ``_ce_bwd`` indexes a
+#: pass's ``lses`` and ``gs`` a tile at a time
+_PARENT_TEXT = {(2, 4096, 64, 8192, False): "60b4d8adb1715df9",
+                (1, 4096, 64, 4096, True): "286aa5e4e75285fd"}
+
+
+@pytest.mark.parametrize("shape", sorted(_PARENT_TEXT))
+def test_one_row_block_lowers_to_the_text_before_there_were_row_blocks(shape):
+    import hashlib
+    P, T, D, V, table = shape
+    assert L._head_tile(T, V) == (T, 2048)
+
+    def f(hs, w, y):
+        return jax.value_and_grad(lambda hs, w: jnp.sum(
+            L.blocked_cross_entropy(hs, w, y, table=table)), (0, 1))(hs, w)
+    text = jax.jit(f).lower(
+        (jax.ShapeDtypeStruct((1, T, D), jnp.bfloat16),) * P,
+        jax.ShapeDtypeStruct((V, D) if table else (D, V), jnp.float32),
+        jax.ShapeDtypeStruct((1, T), jnp.int32)).as_text()
+    assert hashlib.sha256(text.encode()).hexdigest()[:16] \
+        == _PARENT_TEXT[shape]
 
 
 def test_a_sequence_layer_refuses_the_public_layout():
@@ -739,7 +865,7 @@ def test_the_map_marks_the_heads_backward_rule(monkeypatch):
     left for a checkpoint to run again), while the stack's stretches still
     are; and the counter reads ``blocked`` once, for the one call site."""
     monkeypatch.setattr(L, "HEAD_LOGIT_BYTES", 4 * 2 * 32 * 128)
-    assert L._head_block(2 * 32, 512) == 128
+    assert L._head_tile(2 * 32, 512) == (2 * 32, 128)
     texts = []
     parse = stepprogram.parse
     monkeypatch.setattr(stepprogram, "parse",
