@@ -1593,6 +1593,70 @@ def compute_dtype_of(conf_dtype) -> Optional[Any]:
     return None
 
 
+#: a float32 matrix leaf of at least this many elements whose products run
+#: over more than :data:`UPDATE_APART_ROWS` rows of activations hands its
+#: gradient to the updater apart from the product that makes it
+#: (:func:`_cast_apart`). Fused, the compiler puts Adam's update of the
+#: leaf into the weight gradient's product as a multi-output epilogue that
+#: reads and writes p, m and v; at 32,768 rows those float32 tiles shrink
+#: the product's output windows (1x64x3 against 1x256x4 apart for the
+#: dense MLP's [2048, 11776]) and the rows are streamed more often.
+#: Measured on a v5e, ms a call, fused / apart, forward + both gradients
+#: + Adam (``benchmarks/probe_update_apart.py``):
+#:
+#:     rows a step         4,096        8,192       16,384       32,768
+#:     x @ [2048, 512]   .35/.35      .35/.34      .45/.51      .80/.80
+#:     x @ [2048, 2048]  .51/.52      .86/.88     1.61/1.66    3.56/3.08
+#:     x @ [2048, 6144] 1.28/1.62    2.39/2.84    4.78/4.88    9.93/9.15
+#:     x @ [2048,11776] 2.27/3.21    4.82/5.21    8.86/9.41   19.99/18.08
+#:     GatedMLP, 11776 11.10/11.83  20.21/20.46  40.24/40.10  87.59/80.07
+#:
+#: fused is right up to 16,384 rows and wrong at 32,768, where a
+#: [2048, 512] leaf is a wash.
+UPDATE_APART_ELEMENTS = 2048 * 2048
+UPDATE_APART_ROWS = 16384
+
+_UPDATE_APART_LOWERED = _prof.get_registry().counter(
+    "dl4j_update_apart_lowered_total",
+    "Float32 leaves cast to the compute dtype (one a leaf a lowering of "
+    "each call site, not one a step) by where their gradient meets the "
+    "update: apart (the gradient leaves its product's fusion in the "
+    "compute dtype, the update is a fusion of its own) or fused",
+    labelnames=("path",))
+
+
+def update_apart(shape, rows) -> bool:
+    """Whether a leaf of ``shape`` whose products run over ``rows`` rows of
+    activations hands its gradient over apart (:data:`UPDATE_APART_ROWS`):
+    a matrix (a convolution kernel is 4-D, an expert stack 3-D) of at least
+    :data:`UPDATE_APART_ELEMENTS` elements."""
+    return len(shape) == 2 and math.prod(shape) >= UPDATE_APART_ELEMENTS \
+        and rows > UPDATE_APART_ROWS
+
+
+@functools.partial(jax.custom_vjp, nondiff_argnums=(1,))
+def _cast_apart(w, dtype):
+    """``w.astype(dtype)`` whose gradient crosses an optimization barrier
+    in ``dtype`` before it is widened to ``w``'s float32: the product that
+    makes it ends there, and the update cannot ride in its fusion. The
+    gradient is the product's result in ``dtype``, as the program states
+    it in both forms; fused, a TPU compiler may hand the update the
+    product's float32 accumulator instead (excess precision), so there the
+    two forms can differ by ``dtype``'s rounding of the gradient."""
+    return w.astype(dtype)
+
+
+def _cast_apart_fwd(w, dtype):
+    return w.astype(dtype), None
+
+
+def _cast_apart_bwd(dtype, _, g):
+    return (jax.lax.optimization_barrier(g).astype(jnp.float32),)
+
+
+_cast_apart.defvjp(_cast_apart_fwd, _cast_apart_bwd)
+
+
 def policy_cast(layer, params, x, compute_dt):
     """Cast (params, input) for one layer under the dtype policy.
 
@@ -1633,8 +1697,16 @@ def policy_cast(layer, params, x, compute_dt):
         # a layer's ``fp32_leaves`` stay masters (a router, a norm's gain
         # inside an attention layer): the layer casts where it uses them
         keep = getattr(layer, "fp32_leaves", ())
-        cast = lambda a: a.astype(compute_dt) \
-            if getattr(a, "dtype", None) == jnp.float32 else a  # noqa: E731
+        rows = x.size // x.shape[-1] \
+            if jnp.issubdtype(x.dtype, jnp.floating) and x.ndim > 1 else 0
+
+        def cast(a):
+            if getattr(a, "dtype", None) != jnp.float32:
+                return a
+            apart = update_apart(a.shape, rows)
+            _UPDATE_APART_LOWERED.labels("apart" if apart else "fused").inc()
+            return _cast_apart(a, compute_dt) if apart \
+                else a.astype(compute_dt)
         params = jax.tree_util.tree_map(cast, params) if not keep else {
             k: v if k in keep else jax.tree_util.tree_map(cast, v)
             for k, v in params.items()}
