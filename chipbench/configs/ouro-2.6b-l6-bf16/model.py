@@ -73,6 +73,12 @@ def layer_applications(cfg) -> int:
     return cfg["num_layers"] * cfg["total_ut_steps"]
 
 
+#: the names every language model's file gives the attention core's count
+#: (``attn_roofline``): its FLOPs an application, its applications a sequence
+core_flops = attention_flops
+attention_applications = layer_applications
+
+
 def flops_per_sample(cfg) -> float:
     """Forward FLOPs of one sequence: every layer application's seven
     matrix products and causal attention core, and a head after every

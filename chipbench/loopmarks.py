@@ -3,10 +3,11 @@
 The step-program map of a tree that has ``LoopVertex``
 (``deeplearning4j_tpu.profiler.stepprogram``) gives every instruction,
 after phase, layer, kernel and ``mixed``, the pass of the loop it runs in
-(``loop_pass``, from 1), the part it belongs to (``attn_core``: scores,
-softmax and weighted sum; ``head_loss``: a pass's head, gate and the
-loss) and whether it is forward work a rematerialised stretch runs again
-in the backward pass (``remat``). The readers of ``loop_stack_device_ms``,
+(``loop_pass``, from 1; ``None`` outside a loop, as in every op of the
+sparse and hybrid decoders), the part it belongs to (``attn_core``:
+scores, softmax and weighted sum; ``head_loss``: a head and its loss) and
+whether it is forward work a rematerialised stretch runs again in the
+backward pass (``remat``). The readers of ``loop_stack_device_ms``,
 ``head_loss_device_ms``, ``remat_device_ms``, ``attn_device_ms`` and
 ``attn_roofline`` sum device-op time by those marks. A map without them
 (a program from before the loop construct) gives ``None``: no reading,

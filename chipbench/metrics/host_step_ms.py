@@ -2,10 +2,11 @@
 steps: from its ``fit:pull`` span's start to its ``fit:listeners(done)``
 span's end (the program's tracer ring).
 
-The traced steps are the 16 right after the profile's sync, where nothing
-holds the host back: this is the host's own cost of an iteration. Over the
-whole window the runtime's queue paces the host to the device's step (the
-wait sits in ``fit:prepare``), which ``step_device_ms`` already says."""
+The traced steps (16 in the CNN cells, 8 in the language models) are
+the ones right after the profile's sync, where nothing holds the host
+back: this is the host's own cost of an iteration. Over the whole window
+the runtime's queue paces the host to the device's step (the wait sits in
+``fit:prepare``), which ``step_device_ms`` already says."""
 
 from chipbench import programspans as ps
 

@@ -5,8 +5,9 @@ need at the MXU's peak and the time their bytes need at HBM's
 pairs that met a held expert at the last step, the gauge
 ``dl4j_moe_held_pairs``), three times that a step (forward, input
 gradient, weight gradient), over the device time a step of the ops under
-``dl4j_moe_experts``. At a few hundred tokens an expert the bytes bound
-it: the experts' weights are read whatever the load. The same work
+``dl4j_moe_experts``. At a few hundred tokens an expert (the sparse
+decoder's 256) the bytes bound it: the experts' weights are read whatever
+the load; at the hybrid decoder's 2,048 the FLOPs do. The same work
 whatever implements the products; what a rematerialised stretch runs
 again is not required work."""
 

@@ -161,22 +161,6 @@ def unsure_share(red, maps):
     return 100.0 * unsure / total if total > 0 else None
 
 
-def kernel_ops(red, maps):
-    """The device ops the map names as one of the program's own Pallas
-    kernels (``dl4j_*``): ``[(seconds, HBM bytes as compiled)]``; empty
-    where the step program holds none, ``None`` without a map."""
-    smap = step_map(red, maps)
-    if smap is None:
-        return None
-    out = []
-    for s, e, name, _cls, nbytes in red.busiest().ops:
-        entry = smap.get(head(name))
-        if entry is not None and entry[2] \
-                and str(entry[2]).startswith("dl4j_"):
-            out.append((e - s, nbytes))
-    return out
-
-
 # ------------------------------------------------ spans of traced steps
 def traced_iterations(spans, traced):
     """The iterations of the traced stretch, in order, each as ``{span

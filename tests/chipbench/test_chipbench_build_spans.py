@@ -166,7 +166,22 @@ def test_manifest_lists_the_seven_for_the_six_cells():
 
 
 # ------------------------------------------- the tiny harness, end to end
-def test_tiny_traced_run_reports_all_seven(tmp_path, monkeypatch, capsys):
+@pytest.fixture
+def no_jax_cache():
+    """No persistent compilation cache for one test, whatever an earlier
+    test of this process placed: jax decides at a compile whether it uses
+    one and keeps that decision, so it is reset on both sides."""
+    from jax.experimental.compilation_cache import compilation_cache as cc
+    was = jax.config.jax_enable_compilation_cache
+    jax.config.update("jax_enable_compilation_cache", False)
+    cc.reset_cache()
+    yield
+    jax.config.update("jax_enable_compilation_cache", was)
+    cc.reset_cache()
+
+
+def test_tiny_traced_run_reports_all_seven(tmp_path, monkeypatch, capsys,
+                                           no_jax_cache):
     """A ``--trace 1`` run of the tiny Tiny YOLO cell on the CPU: set-up
     builds the net's step with the profiling mode off, and the seven
     readers read it out of the live program's ring."""

@@ -282,8 +282,8 @@ def read(name, ctx):
 def test_the_readers_on_a_hand_made_trace():
     red, maps = made_up()
     model = types.SimpleNamespace(
-        attention_flops=lambda cfg: 197e12 * 1e-3,  # 1 ms at the peak
-        layer_applications=lambda cfg: 2)
+        core_flops=lambda cfg: 197e12 * 1e-3,       # 1 ms at the peak
+        attention_applications=lambda cfg: 2)
     ctx = ctx_of(red, maps, model)
     assert read("loop_stack_device_ms", ctx) == pytest.approx(56.0)
     assert read("head_loss_device_ms", ctx) == pytest.approx(21.0)
@@ -317,18 +317,23 @@ def test_tokens_a_second_reads_the_programs_counter(monkeypatch):
     monkeypatch.setattr(ps, "counter_total",
                         lambda name: {"dl4j_train_tokens_total": 81920.0}
                         .get(name))
-    assert read("tok_per_s_per_chip", ctx) == pytest.approx(8192.0)
+    # 4,096 ids a step over the window's 20 steps; 2 traced steps in the
+    # 182 ms from the first step program's start to the last one's end
+    assert read("tok_per_s_per_chip", ctx) == pytest.approx(
+        81920 / 20 * 2 / 0.182)
     monkeypatch.setattr(ps, "counter_total", lambda name: None)
     assert read("tok_per_s_per_chip", ctx) is None
 
 
 def test_manifest_lists_the_new_metrics_for_the_new_cell_alone():
+    """Found by name, wherever later PRs append theirs: the cell is one of
+    the cells each lists."""
     data = Manifest().data["per_layer"]
-    assert [m["name"] for m in data][-len(NEW):] == NEW
+    by_name = {m["name"]: m for m in data}
     layers = {"tok_per_s_per_chip": "fit driver and input",
               "attn_device_ms": "kernels", "attn_roofline": "kernels"}
-    for m in data[-len(NEW):]:
-        assert m["workloads"] == [CELL]
+    for m in (by_name[name] for name in NEW):
+        assert CELL in m["workloads"]
         assert m["moves"] == "img_per_s_per_chip"
         assert m["layer"] == layers.get(m["name"], "step program")
         assert m["source"] == ("program_counter"
@@ -340,7 +345,7 @@ def test_manifest_lists_the_new_metrics_for_the_new_cell_alone():
                           "nonconv_roofline", "device_idle_share",
                           "data_wait_share", "compiles_in_window"}
     got = {m["name"] for m in Manifest().metrics_for(CELL, "per_layer")}
-    assert got == everywhere | set(NEW)
+    assert got >= everywhere | set(NEW)
 
 
 def test_traced_run_reports_the_new_per_layer_metrics(manifest, monkeypatch):

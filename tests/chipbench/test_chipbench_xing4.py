@@ -29,7 +29,7 @@ CONFIG = "xing4.0-29b-a4b-l5-bf16"
 TRAFFIC = "fit-tokens-lean-s4096-b1"
 URL = "https://huggingface.co/XingChen-AGI/Xing4.0-29B-A4B/blob/main/" \
     "config.json"
-NEW = ["mla_core_device_ms", "mla_core_roofline", "moe_device_ms",
+NEW = ["attn_device_ms", "attn_roofline", "moe_device_ms",
        "moe_expert_roofline", "mhc_device_ms", "mhc_roofline",
        "mtp_device_ms", "moe_held_pair_share", "moe_load_max_over_mean"]
 TINY = dict(num_layers=2, hidden_size=32, num_attention_heads=4,
@@ -553,9 +553,9 @@ def test_the_readers_on_a_hand_made_trace(monkeypatch):
         expert_product_bytes=lambda cfg, n: 819e9 * (
             0.3e-3 if n == 60 else 0.5e-3))
     ctx = ctx_of(red, maps, model)
-    assert read("mla_core_device_ms", ctx) == pytest.approx(24.0)
+    assert read("attn_device_ms", ctx) == pytest.approx(24.0)
     # 3 x 1 ms x 2 applications required, over 24 ms
-    assert read("mla_core_roofline", ctx) == pytest.approx(100 * 6 / 24)
+    assert read("attn_roofline", ctx) == pytest.approx(100 * 6 / 24)
     assert read("mhc_device_ms", ctx) == pytest.approx(12.0)
     # 4 x 0.5 ms x 2 sub-blocks required, over 12 ms
     assert read("mhc_roofline", ctx) == pytest.approx(100 * 4 / 12)
@@ -589,9 +589,9 @@ def test_a_program_without_the_marks_gives_no_reading(monkeypatch):
         entry[1] = entry[1].replace("_mtp_", "_x_")
     ctx = ctx_of(red, maps, model)
     for name in ("moe_device_ms", "mhc_device_ms", "mtp_device_ms",
-                 "mhc_roofline", "moe_expert_roofline", "mla_core_roofline"):
+                 "mhc_roofline", "moe_expert_roofline", "attn_roofline"):
         assert read(name, ctx) is None, name
-    assert read("mla_core_device_ms", ctx) == pytest.approx(24.0)
+    assert read("attn_device_ms", ctx) == pytest.approx(24.0)
     untraced = ctx_of(red, maps, model)
     untraced.result["traced"] = None
     monkeypatch.setattr(xm, "gauge", GAUGES.get)
@@ -607,12 +607,14 @@ def test_the_gauges_are_read_from_the_programs_registry():
 
 
 def test_manifest_lists_the_new_metrics_last_for_the_new_cell_alone():
+    """Found by name, wherever later PRs append theirs: the cell is one of
+    the cells each lists."""
     data = Manifest().data["per_layer"]
-    assert [m["name"] for m in data][-len(NEW):] == NEW
-    layers = {"mla_core_device_ms": "kernels", "mla_core_roofline": "kernels",
+    by_name = {m["name"]: m for m in data}
+    layers = {"attn_device_ms": "kernels", "attn_roofline": "kernels",
               "moe_expert_roofline": "kernels", "mhc_roofline": "kernels"}
-    for m in data[-len(NEW):]:
-        assert m["workloads"] == [CELL]
+    for m in (by_name[name] for name in NEW):
+        assert CELL in m["workloads"]
         assert m["moves"] == "img_per_s_per_chip"
         assert m["layer"] == layers.get(m["name"], "step program")
         assert m["source"] == ("program_counter"
@@ -623,24 +625,32 @@ def test_manifest_lists_the_new_metrics_last_for_the_new_cell_alone():
             ("_roofline", "_share")) else m["unit"] in ("ms", "x")
     everywhere = {m["name"] for m in data if "workloads" not in m}
     got = {m["name"] for m in Manifest().metrics_for(CELL, "per_layer")}
-    assert got == everywhere | set(NEW)
-    assert len(Manifest().data["workloads"]) == 5
+    assert got >= everywhere | set(NEW)
     assert sum(w["chips"] == 4 for w in Manifest().data["workloads"]) == 1
 
 
 def test_traced_run_reports_the_new_per_layer_metrics(manifest, monkeypatch):
     """A ``--trace 1`` run of the tiny cell on the CPU, with the hand-made
     trace handed to the reduction in place of the CPU's own (which has no
-    device plane) and its map to the join: the gauges are the live
-    program's, the marks are the hand-made ones."""
+    device plane) and its map to the join: the gauges, counters and
+    fit-loop spans are the live program's, the marks are the hand-made
+    ones. Every per-layer metric the cell lists reads a number."""
     red, maps = made_up()
     monkeypatch.setattr(trace, "reduce_xspace",
                         lambda path, step_module=None: red)
-    monkeypatch.setattr(ps, "from_program", lambda: ([], maps))
+    live = ps.from_program
+    monkeypatch.setattr(ps, "from_program", lambda: (live()[0], maps))
     line = runmod.run_cell(manifest, tiny.run_args(CELL, seed=11, trace=1,
                                                    seconds=0.5),
                            jax.devices()[:1], tiny.v5e_peak())
     got = line["metrics"]
+    listed = {m["name"] for m in manifest.metrics_for(CELL, "per_layer")}
+    # the hand-made trace holds no op of the `other` class and fewer
+    # product events a step than the configuration has matmuls, so the two
+    # class rooflines read nothing by their own rules here;
+    # test_chipbench_arith.py reads them on traces made for them
+    unread = {"conv_roofline", "nonconv_roofline"}
+    assert listed - unread <= set(got), sorted(listed - unread - set(got))
     assert set(NEW) <= set(got), sorted(set(NEW) - set(got))
     assert got["mhc_device_ms"]["value"] == pytest.approx(12.0)
     # 4 of 16 experts held, top-4: a quarter of the pairs, more or less
