@@ -3804,6 +3804,13 @@ _MOE_LOWERED = _prof.get_registry().counter(
     "over the experts held",
     labelnames=("path",))
 
+_MOE_ROWS_LOWERED = _prof.get_registry().counter(
+    "dl4j_moe_rows_lowered_total",
+    "Traces of nn.layers.SparseExpertsLayer's routed path (one a lowering "
+    "of each call site) by what moves its rows: the DMA row kernels of "
+    "ops.row_kernels (kernel) or the compiler's gathers (xla)",
+    labelnames=("path",))
+
 #: Rows a pass of the routed path works over, as a multiple of the share
 #: of the ``tokens * nExpertsPerTok`` pairs that uniform routing sends to
 #: the experts held here. 2: the held share is not stationary (only the
@@ -3840,8 +3847,8 @@ def _pass_index(order, inv, load, lo, rows, k):
     pass's pair ids and which of its rows hold a held pair, the experts'
     group sizes inside it, the permutation that brings its rows into
     token order with each row's token there (``tokens`` where the row
-    holds no pair), a token's first row there, and which pairs the pass
-    holds."""
+    holds no pair), a token's first row there and how many it has, and
+    which pairs the pass holds."""
     pairs, held_pairs = inv.shape[0], jnp.sum(load)
     at = lo + jnp.arange(rows, dtype=jnp.int32)
     idx = jax.lax.dynamic_slice_in_dim(order, lo, rows)
@@ -3858,7 +3865,7 @@ def _pass_index(order, inv, load, lo, rows, k):
     here = (inv >= lo) & (inv < jnp.minimum(lo + rows, held_pairs))
     count = jnp.sum(here.reshape(-1, k), axis=1, dtype=jnp.int32)
     first = jnp.where(count > 0, jnp.cumsum(count) - count, rows)
-    return idx, valid, sizes, perm, key // k, first, here
+    return idx, valid, sizes, perm, key // k, first, count, here
 
 
 def _to_tokens(y, w, perm, token, first, k):
@@ -3878,6 +3885,37 @@ def _to_tokens(y, w, perm, token, first, k):
     return total.astype(y.dtype).at[first].get(mode="promise_in_bounds")
 
 
+def _rows_by_kernel(width, rows, tokens, dtype):
+    """Whether the routed path's ``D`` and ``D^T`` run as the DMA row
+    kernels of :mod:`ops.row_kernels`: on a TPU, where they take the
+    shape and a pass's rows are enough to pay for them. Elsewhere the
+    compiler's gathers, as :func:`_dispatch` and :func:`_to_tokens` write
+    them. (The module is imported where it is used: Pallas takes seconds
+    to import, and no other path needs it.)"""
+    if jax.default_backend() != "tpu":
+        return False
+    from deeplearning4j_tpu.ops import row_kernels
+    return row_kernels.supported(width, rows, tokens,
+                                 jnp.dtype(dtype).itemsize)
+
+
+def _dispatch(x, at):
+    """``D``: the rows ``x[at]``."""
+    if _rows_by_kernel(x.shape[1], at.shape[0], x.shape[0], x.dtype):
+        from deeplearning4j_tpu.ops import row_kernels
+        return row_kernels.gather_rows(x, at)
+    return x.at[at].get(mode="promise_in_bounds")
+
+
+def _combine(y, w, perm, token, first, count, k):
+    """``D^T`` of a pass's rows, as :func:`_to_tokens` has it."""
+    if _rows_by_kernel(y.shape[1], y.shape[0], first.shape[0], y.dtype):
+        from deeplearning4j_tpu.ops import row_kernels
+        return row_kernels.combine_rows(y, w, perm, token, first, count,
+                                        k=k)
+    return _to_tokens(y, w, perm, token, first, k)
+
+
 @functools.partial(jax.jit, static_argnums=(9, 10))
 def _pass_fwd(x, gate, eg, eu, ed, order, inv, load, lo, rows, f):
     """One pass of the routed path: what the held experts add to the
@@ -3885,15 +3923,15 @@ def _pass_fwd(x, gate, eg, eu, ed, order, inv, load, lo, rows, f):
     its backward keeps: the gathered rows and the two products before the
     activation, all of ``rows`` rows."""
     k = gate.shape[1]
-    idx, valid, sizes, perm, token, first, _ = _pass_index(
+    idx, valid, sizes, perm, token, first, count, _ = _pass_index(
         order, inv, load, lo, rows, k)
-    xr = x.at[idx // k].get(mode="promise_in_bounds")          # D
+    xr = _dispatch(x, idx // k)
     with jax.named_scope(_stepprogram.MOE_EXPERTS_SCOPE):
         a = jax.lax.ragged_dot(xr, eg, sizes)
         b = jax.lax.ragged_dot(xr, eu, sizes)
         ys = jax.lax.ragged_dot(f(a) * b, ed, sizes)
     w = jnp.where(valid, gate.reshape(-1)[idx], 0)
-    return _to_tokens(ys, w, perm, token, first, k), (xr, a, b)
+    return _combine(ys, w, perm, token, first, count, k), (xr, a, b)
 
 
 @functools.partial(jax.jit, static_argnums=(12, 13))
@@ -3908,9 +3946,9 @@ def _pass_bwd(gate, eg, eu, ed, order, inv, load, lo, xr, a, b, g, rows, f):
     product alone (no forward product runs again); ``D^T`` of the rows'
     cotangent."""
     f32, k = jnp.float32, gate.shape[1]
-    idx, valid, sizes, perm, token, first, here = _pass_index(
+    idx, valid, sizes, perm, token, first, count, here = _pass_index(
         order, inv, load, lo, rows, k)
-    gr = g.at[idx // k].get(mode="promise_in_bounds")          # D
+    gr = _dispatch(g, idx // k)
     w = jnp.where(valid, gate.reshape(-1)[idx], 0)[:, None]
     with jax.named_scope(_stepprogram.MOE_EXPERTS_SCOPE):
         h, gated = jax.vjp(lambda a, b: f(a) * b, a, b)
@@ -3924,7 +3962,7 @@ def _pass_bwd(gate, eg, eu, ed, order, inv, load, lo, xr, a, b, g, rows, f):
                                 jax.lax.ragged_dot(xr, eu, sizes)),
             xr, eg, eu)[1](gated((dh * w).astype(h.dtype)))
     dgate = jnp.where(here, dw[jnp.clip(inv - lo, 0, rows - 1)], 0)
-    dx = _to_tokens(dxr, valid.astype(f32), perm, token, first, k)
+    dx = _combine(dxr, valid.astype(f32), perm, token, first, count, k)
     return dx, dgate.reshape(gate.shape), deg, deu, ded
 
 
@@ -3975,7 +4013,7 @@ def _routed(x, gate, eg, eu, ed, order, inv, load, rows, f):
     inside the backward rule's own conditional. The further passes are a
     ``while`` inside the conditional's branch: the map's readers list a
     ``while`` beside its body (PERF.md section 7 k), so a step that takes
-    a second pass reads twice that pass's time in ``routed_device_ms``;
+    a second pass reads twice that pass's time in ``moe_device_ms``;
     the common step never enters it."""
     return _routed_fwd(x, gate, eg, eu, ed, order, inv, load, rows, f)[0]
 
@@ -4039,7 +4077,9 @@ class SparseExpertsLayer(Layer):
     products as grouped matrix products over them
     (``jax.lax.ragged_dot``, group sizes the experts' loads inside the
     pass, under ``dl4j_moe_experts`` inside ``dl4j_moe``) and sums the
-    gated rows back to their tokens without a scatter. The first pass
+    gated rows back to their tokens without a scatter; on a TPU the
+    gather and the sum are DMA row kernels (:mod:`ops.row_kernels`,
+    counter ``dl4j_moe_rows_lowered_total{path}``). The first pass
     always runs; a step whose held pairs exceed ``R`` takes a further
     pass for each ``R`` more, inside a conditional, so whatever the
     router does every selected and held pair is computed. The state
@@ -4160,6 +4200,9 @@ class SparseExpertsLayer(Layer):
             load = jnp.sum(local[:, None] == jnp.arange(E),
                            axis=0, dtype=jnp.int32)
             _MOE_LOWERED.labels("compact").inc()
+            _MOE_ROWS_LOWERED.labels(
+                "kernel" if _rows_by_kernel(xf.shape[1], rows, M, xf.dtype)
+                else "xla").inc()
             routed = _routed(xf, gate, params["Eg"], params["Eu"],
                              params["Ed"], order, inv, load, rows, f)
             ran = jnp.clip(-(-jnp.sum(load) // rows), 1, None)
